@@ -99,6 +99,18 @@ def run_train(
             instances.update(replace(inst, status="COMPLETED", end_time=_now()))
             logger.info("training finished: instance %s (%d bytes of models)",
                         instance_id, len(blob))
+        # placement evidence from the process that owns the devices: the
+        # trained tables are still resident here (device-gather models)
+        from incubator_predictionio_tpu.utils.tracing import (
+            device_memory_report,
+        )
+
+        for row in device_memory_report():
+            if row["bytes_in_use"] is not None:  # CPU has no allocator stats
+                logger.info(
+                    "device memory: %s bytes_in_use=%s peak=%s limit=%s",
+                    row["device"], row["bytes_in_use"],
+                    row["peak_bytes_in_use"], row["bytes_limit"])
         return instance_id
     except Exception:
         if primary:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import datetime as _dt
 import fcntl
+import logging
 import os
 import threading
 import uuid
@@ -40,6 +41,8 @@ from incubator_predictionio_tpu.native import (
     scan as native_scan,
 )
 from incubator_predictionio_tpu.native import format as fmt
+
+logger = logging.getLogger(__name__)
 
 
 class ReadOnlyLogError(StorageError):
@@ -575,6 +578,11 @@ class EventLogEvents(EventStore):
                 missing_value, dedup, n_shards=n_shards,
                 shard_index=shard_index,
             )
+        # host side, so either reader is correct — but the operator (and
+        # chip_smoke.py) should see which one served a multi-million-row read
+        logger.info("assemble_triples: app %s read by the %s", app_id,
+                    "Python mirror (native scan unavailable)"
+                    if result is None else "native C++ scan")
         if result is None:
             return super().assemble_triples(
                 app_id, channel_id, start_time, until_time, entity_type,

@@ -32,7 +32,11 @@ from typing import Optional, Sequence
 
 from incubator_predictionio_tpu.distributed import dist_metrics
 from incubator_predictionio_tpu.distributed.meshdir import MeshDirectory
-from incubator_predictionio_tpu.parallel.launcher import CLI_MODULE, free_port
+from incubator_predictionio_tpu.parallel.launcher import (
+    CLI_MODULE,
+    free_port,
+    refuse_local_tpu_fanout,
+)
 from incubator_predictionio_tpu.resilience.clock import Clock, SYSTEM_CLOCK
 
 logger = logging.getLogger(__name__)
@@ -88,6 +92,7 @@ class Supervisor:
     ):
         if num_processes < 1:
             raise ValueError("num_processes must be >= 1")
+        refuse_local_tpu_fanout(num_processes, cpu_devices_per_process, env)
         self.cli_args = list(cli_args)
         self.num_processes = num_processes
         self.meshdir = MeshDirectory(state_dir)

@@ -33,9 +33,10 @@ leave on in production:
   ``device_memory_report``'s point read, sampled at exposition time and
   from the sampler thread, on ``pio_device_bytes_peak``.
 
-Everything here degrades to near-zero cost when idle: no jax import is
-ever triggered (``"jax" in sys.modules`` guards), the sampler is off by
-default, and phase timers are plain arithmetic.
+Everything here degrades to near-zero cost when idle: no jax import and no
+backend creation is ever triggered (device reads happen only in a process
+that already holds a backend), the sampler is off by default, and phase
+timers are plain arithmetic.
 """
 
 from __future__ import annotations
@@ -232,24 +233,43 @@ def reset_phases() -> None:
 _peak_cache: list = []  # [float | None] once detected
 
 
+def _backend_live() -> bool:
+    """Whether THIS process already created a JAX backend. A TPU chip belongs
+    to one process, and processes that merely import jax-using modules (the
+    stream updater, the jobs worker) must not have a ``/metrics`` scrape be
+    what first claims it — so device reads key on this, never on "jax was
+    imported"."""
+    mesh = sys.modules.get("incubator_predictionio_tpu.parallel.mesh")
+    return mesh is not None and mesh.backend_initialized()
+
+
+def peak_flops_for(platform: str, device_kind: str) -> Optional[float]:
+    """Peak bf16 FLOPs/s from :data:`TPU_PEAK_FLOPS`, or ``None`` for a
+    device the table does not know — there is no default chip (an MFU
+    against a guessed peak is a made-up number)."""
+    if platform != "tpu":
+        return None
+    kind = device_kind.lower()
+    return next((f for key, f in TPU_PEAK_FLOPS if key in kind), None)
+
+
 def detected_peak_flops() -> Optional[float]:
-    """Peak bf16 FLOPs/s of local device 0, from :data:`TPU_PEAK_FLOPS`.
-    ``None`` off-TPU (a CPU 'MFU' would be a lie) and when jax was never
-    imported. Cached after first successful read."""
+    """Peak bf16 FLOPs/s of local device 0. ``None`` off-TPU (a CPU 'MFU'
+    would be a lie), on a TPU kind the table does not list (logged once),
+    and while this process holds no backend. Cached after the first read."""
     if _peak_cache:
         return _peak_cache[0]
-    if "jax" not in sys.modules:
+    if not _backend_live():
         return None
-    try:
-        import jax
+    import jax
 
-        d = jax.local_devices()[0]
-    except Exception:  # noqa: BLE001 - device probe must never raise here
-        return None
-    peak: Optional[float] = None
-    if d.platform == "tpu":
-        kind = getattr(d, "device_kind", "").lower()
-        peak = next((f for key, f in TPU_PEAK_FLOPS if key in kind), 197e12)
+    d = jax.local_devices()[0]
+    peak = peak_flops_for(d.platform, d.device_kind)
+    if peak is None and d.platform == "tpu":
+        logger.warning(
+            "no peak FLOP/s known for device kind %r: pio_training_mfu "
+            "stays unset (add it to obs/profile.TPU_PEAK_FLOPS)",
+            d.device_kind)
     _peak_cache.append(peak)
     return peak
 
@@ -273,9 +293,10 @@ def record_training_step(flops: float, seconds: float,
 
 def update_device_watermark() -> None:
     """Fold each local device's current/peak bytes-in-use into the
-    ``pio_device_bytes_peak`` watermark gauges. Never imports jax itself;
-    never raises (runs as a collector and inside the sampler thread)."""
-    if "jax" not in sys.modules:
+    ``pio_device_bytes_peak`` watermark gauges. Never imports jax and never
+    creates a backend itself; never raises (runs as a collector and inside
+    the sampler thread)."""
+    if not _backend_live():
         return
     try:
         from incubator_predictionio_tpu.utils.tracing import (

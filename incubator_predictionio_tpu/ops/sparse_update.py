@@ -135,11 +135,14 @@ def _adam_rows_jit():
         return _ROWS_JIT
     import jax
 
+    from incubator_predictionio_tpu.parallel.mesh import kernel_backend
+
     def step(rows, m, v, g, bc1, bc2, *, lr, b1, b2, eps, interpret):
-        on_tpu = jax.devices()[0].platform == "tpu"
-        if on_tpu or interpret:
+        backend = kernel_backend()
+        if backend or interpret:
             return _pallas_adam_rows(
-                rows, m, v, g, bc1, bc2, lr, b1, b2, eps, interpret)
+                rows, m, v, g, bc1, bc2, lr, b1, b2, eps,
+                interpret or backend == "interpret")
         import jax.numpy as jnp
 
         m = b1 * m + (1.0 - b1) * g
@@ -195,15 +198,18 @@ def _fused_fn():
         return _FUSED_JIT
     import jax
 
+    from incubator_predictionio_tpu.parallel.mesh import kernel_backend
+
     def fused(table, m_tab, v_tab, idx, g, bc1, bc2,
               *, lr, b1, b2, eps, interpret):
         rows = table[idx]
         m = m_tab[idx]
         v = v_tab[idx]
-        on_tpu = jax.devices()[0].platform == "tpu"
-        if on_tpu or interpret:
+        backend = kernel_backend()
+        if backend or interpret:
             rows, m, v = _pallas_adam_rows(
-                rows, m, v, g, bc1, bc2, lr, b1, b2, eps, interpret)
+                rows, m, v, g, bc1, bc2, lr, b1, b2, eps,
+                interpret or backend == "interpret")
         else:
             import jax.numpy as jnp
 

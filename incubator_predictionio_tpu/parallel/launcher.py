@@ -4,8 +4,9 @@ The reference scales out by forking ``spark-submit`` with a serialized env
 (tools/Runner.scala:185-335); here scale-out is N identical processes running
 the SAME CLI verb under ``jax.distributed``, with XLA collectives over
 ICI/DCN doing what Spark's shuffle/RPC did. This module is the process
-spawner for the single-host/multi-process form (and the integration-test
-stand-in for a pod, using CPU devices + gloo); on a real multi-host pod the
+spawner for the single-host/multi-process form — the integration-test
+stand-in for a pod, using CPU devices + gloo (on a TPU host one process
+drives all local chips through the mesh instead); on a real multi-host pod the
 operator runs one ``pio-tpu <verb> --distributed`` per host and
 ``jax.distributed.initialize`` auto-detects the topology, so no launcher
 process is needed at all.
@@ -30,6 +31,8 @@ import sys
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from incubator_predictionio_tpu.parallel.mesh import local_tpu_chips
+
 CLI_MODULE = "incubator_predictionio_tpu.tools.cli"
 
 
@@ -37,6 +40,36 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+def refuse_local_tpu_fanout(
+    num_processes: int,
+    cpu_devices_per_process: Optional[int],
+    env: Optional[dict[str, str]] = None,
+) -> None:
+    """Raise when N local processes would contend for this host's chips.
+
+    A TPU chip belongs to one process at a time and the children get no
+    per-process chip assignment, so each would try to claim every local
+    chip: all but one die on the libtpu lock and the survivor waits for
+    peers that never join. On a TPU host ONE process drives all local chips
+    through the mesh; N local processes are the CPU rehearsal topology."""
+    if num_processes <= 1 or cpu_devices_per_process:
+        return
+    if {**os.environ, **(env or {})}.get(
+            "JAX_PLATFORMS", "").startswith("cpu"):
+        return
+    chips = local_tpu_chips()
+    if chips:
+        raise RuntimeError(
+            f"refusing to start {num_processes} local processes on a TPU "
+            f"host ({len(chips)} chip(s): {', '.join(chips)}): a chip "
+            "belongs to one process at a time and every child would try to "
+            "claim all of them. Drive the local chips from ONE process "
+            "through the mesh (pio-tpu train --mesh-axes "
+            "'{\"data\": 2, \"model\": 2}'), or pass "
+            "--cpu-devices-per-process for a CPU rehearsal; a multi-host "
+            "pod runs one `pio-tpu <verb> --distributed` per host.")
 
 
 @dataclass
@@ -62,8 +95,9 @@ def launch_local(
     """Run ``pio-tpu <cli_args>`` as ``num_processes`` coordinated processes.
 
     ``cpu_devices_per_process`` forces a CPU mesh with that many virtual
-    devices per process (the no-hardware test topology); leave it ``None`` on
-    real accelerators, where each process claims its locally attached chips.
+    devices per process (the no-hardware test topology). Without it, on a
+    host with TPU chips, more than one process is refused
+    (:func:`refuse_local_tpu_fanout`).
     Processes run concurrently and are all waited on; output is captured
     per process. ``command`` replaces the default ``python -m <cli>`` argv
     entirely (same coordination env) — used by harness dry runs that execute
@@ -74,6 +108,7 @@ def launch_local(
 
     if num_processes < 1:
         raise ValueError("num_processes must be >= 1")
+    refuse_local_tpu_fanout(num_processes, cpu_devices_per_process, env)
     port = coordinator_port or free_port()
     procs: list[subprocess.Popen] = []
     # capture into temp files, not pipes: a child blocked on a full 64KB

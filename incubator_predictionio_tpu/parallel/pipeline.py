@@ -27,11 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from incubator_predictionio_tpu.parallel.ring import (
-    _SHARD_MAP_KW,
-    _mark_varying,
-    _shard_map,
-)
+from incubator_predictionio_tpu.parallel.ring import _mark_varying
 
 
 def stack_layers(layers: list[dict]) -> dict:
@@ -73,13 +69,12 @@ def pipeline_forward(stacked_layers, h0, apply_layer, mesh,
         return h
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         # stacked layers split over the pipe axis; microbatch rows keep
         # their data sharding (dim 1 after the [m, mb, ...] reshape)
         in_specs=(P(axis), P(None, data_axis)),
         out_specs=P(None, data_axis),
-        **_SHARD_MAP_KW,
     )
     def run(layers_sharded, h0_rep):
         stage = jax.lax.axis_index(axis)
@@ -100,8 +95,8 @@ def pipeline_forward(stacked_layers, h0, apply_layer, mesh,
             return handoff, collected
 
         # the carry becomes device-varying after the first ppermute; mark
-        # the zeros init varying over the pipe axis up front (jax 0.9 vma
-        # typing — same helper as parallel/ring.py, identity on older jax)
+        # the zeros init varying over the pipe axis up front (same helper
+        # as parallel/ring.py)
         init = _mark_varying(jnp.zeros_like(h0_rep[0]), (axis,))
         _, collected = jax.lax.scan(step, init, jnp.arange(m + s - 1))
         # step t >= s-1 emits microbatch t-(s-1) from the last stage;

@@ -27,23 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax ≥ 0.6 top-level export; experimental path before that
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW: dict = {}
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # the old rep-checker mis-types ppermute-carrying scan grads (jax#15175
-    # lineage); its own error message prescribes check_rep=False
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def _axis_size(axis_name):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # pragma: no cover - older jax
-
-
 def _chunk_attend(q, k, v, mask, m, l, o):
     """One online-softmax update with an extra additive mask.
 
@@ -71,14 +54,9 @@ def _chunk_attend(q, k, v, mask, m, l, o):
 
 
 def _mark_varying(x, axes):
-    """Mark ``x`` device-varying over manual ``axes`` — pcast on jax ≥ 0.9,
-    pvary before it (pinned here so an upgrade can't silently break the ring;
-    tests assert the suite is deprecation-warning-free)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)  # pragma: no cover - older jax
-    return x  # pre-varying-type jax: scan carries need no marking
+    """Mark ``x`` device-varying over manual ``axes`` (scan carries that a
+    ppermute makes varying must start out typed that way)."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def ring_attention(q, k, v, axis_name: str, pvary_axes=None):
@@ -89,7 +67,7 @@ def ring_attention(q, k, v, axis_name: str, pvary_axes=None):
     ``pvary_axes``: all manual axes in scope (defaults to just ``axis_name``);
     fresh accumulators must be marked varying over every one of them.
     """
-    s_size = _axis_size(axis_name)
+    s_size = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, lc, h, d = q.shape
     neg = jnp.float32(-jnp.inf)
@@ -108,8 +86,7 @@ def ring_attention(q, k, v, axis_name: str, pvary_axes=None):
         return (kc, vc, m, l, o), None
 
     # fresh accumulators must be marked varying over the manual axes, or scan
-    # rejects the carry (unvarying input vs varying output); pcast is the
-    # current API (pvary deprecated in jax 0.9)
+    # rejects the carry (unvarying input vs varying output)
     axes = tuple(pvary_axes) if pvary_axes is not None else (axis_name,)
     _vary = functools.partial(_mark_varying, axes=axes)
     m0 = _vary(jnp.full((b, h, lc), neg))
@@ -128,13 +105,12 @@ def ring_attention_sharded(q, k, v, mesh, data_axis: str = "data",
     """shard_map wrapper: q/k/v [B, L, H, D] with B sharded over ``data_axis``
     and L over ``seq_axis``."""
     spec = P(data_axis, seq_axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis,
                           pvary_axes=mesh.axis_names),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_SHARD_MAP_KW,
     )
     return fn(q, k, v)
 
@@ -162,8 +138,12 @@ def causal_attention(q, k, v):
     reference — tile-aligned blocking needs room to pay off, and the
     reference doubles as the kernel's correctness oracle in tests.
     Layout: [B, L, H, DH] in and out (the kernel wants [B, H, L, DH])."""
+    from incubator_predictionio_tpu.parallel.mesh import kernel_backend
+
     l = q.shape[1]
-    is_tpu = jax.devices()[0].platform == "tpu"
+    # the stock flash kernel has no interpreter switch, so only a real TPU
+    # takes the kernels here
+    is_tpu = kernel_backend() == "mosaic"
     if is_tpu:
         from incubator_predictionio_tpu.ops.attention import (
             causal_mha_small_head,

@@ -55,7 +55,7 @@ _DEADLINE_EXPIRED = REGISTRY.counter(
 class TransientError(StorageError):
     """A failure worth retrying (connection reset, timeout, 5xx): transports
     wrap their raw socket/HTTP errors in this so the policy engine never has
-    to know each library's exception taxonomy.
+    to know each library's exception hierarchy.
 
     ``no_retry = True`` on a subclass marks a condition that is transient
     *cluster-wise* but can never improve by retrying THIS endpoint (an

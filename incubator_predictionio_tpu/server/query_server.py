@@ -1117,8 +1117,13 @@ class QueryServer:
         if "text/html" in request.headers.get("Accept", ""):
             return web.Response(
                 text=self._status_html(), content_type="text/html")
+        devices = self.ctx.mesh.devices.flat
         return web.json_response({
             "status": "alive",
+            # the devices THIS process holds, as JAX reports them
+            "platform": devices[0].platform,
+            "deviceKind": devices[0].device_kind,
+            "deviceCount": self.ctx.n_devices,
             "engineInstance": {
                 "id": inst.id,
                 "engineId": inst.engine_id,

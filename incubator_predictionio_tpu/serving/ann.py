@@ -412,21 +412,23 @@ class IVFIndex:
         (ops/retrieval.py ``score_centroids_quantized``); the fp32
         mean-member-bias column is added after the rescale."""
         if q_quant is not None:
-            import sys
-
             from incubator_predictionio_tpu.ops.retrieval import (
                 int8_matmul_exact,
             )
+            from incubator_predictionio_tpu.parallel.mesh import (
+                kernel_backend,
+            )
 
             q_q, q_scales = q_quant
-            if "jax" in sys.modules and \
-                    sys.modules["jax"].default_backend() == "tpu":
+            backend = kernel_backend()
+            if backend:
                 # the Pallas int8 coarse kernel (ops/retrieval.py). Same
                 # int8×int8→int32 + one-rescale contract as the host twin
                 # below — the accumulation is exact integers either way;
                 # only the final rescale may FMA-contract (≤1 ulp), so
                 # probe sets agree except exact near-ties at the boundary
-                coarse = self._probe_tpu(q_q, q_scales)
+                coarse = self._probe_tpu(
+                    q_q, q_scales, interpret=backend == "interpret")
             else:
                 cent_q, cent_scales = self._coarse_quant()
                 coarse = (int8_matmul_exact(q_q, cent_q)
@@ -439,7 +441,8 @@ class IVFIndex:
             return np.tile(np.arange(self.n_partitions), (len(q), 1))
         return np.argpartition(-coarse, nprobe - 1, axis=1)[:, :nprobe]
 
-    def _probe_tpu(self, q_q: np.ndarray, q_scales: np.ndarray) -> np.ndarray:
+    def _probe_tpu(self, q_q: np.ndarray, q_scales: np.ndarray,
+                   interpret: bool = False) -> np.ndarray:
         """Coarse scores through the Pallas int8 kernel on a resident
         device copy of the quantized centroid table. The batch pads to a
         power-of-two bucket (≥ 8) so the query mix shares a handful of
@@ -456,10 +459,12 @@ class IVFIndex:
 
         dev = self._cent_device
         if dev is None:
+            # _coarse_quant takes the (non-reentrant) lock itself: resolve
+            # it BEFORE entering the locked section below
+            cent_q, cent_scales = self._coarse_quant()
             with self._rehydrate_lock:
                 dev = self._cent_device
                 if dev is None:
-                    cent_q, cent_scales = self._coarse_quant()
                     cq, cs, cb = pad_centroids(
                         cent_q, cent_scales,
                         np.asarray(self.centroids[:, -1], np.float32))
@@ -475,7 +480,8 @@ class IVFIndex:
         with jitstats.dispatch_timer(
                 ("ivf_coarse_int8", bp, int(cq.shape[0]))):
             out = jax.device_get(score_centroids_quantized(
-                jnp.asarray(qq), jnp.asarray(qs), cq, cs, cb))
+                jnp.asarray(qq), jnp.asarray(qs), cq, cs, cb,
+                interpret=interpret))
         return np.asarray(out)[:b, : self.n_partitions]
 
     def candidate_ids(self, qrow: np.ndarray, nprobe: int) -> np.ndarray:

@@ -347,11 +347,6 @@ def _sharded_exact_fn(mesh, num: int, kl: int, with_rmask: bool):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover - newer jax moved it
-        from jax import shard_map
-
     def per_shard(uq, ubq, mean, it, ib, m, rm):
         s = jax.lax.axis_index(SHARD_AXIS)
         # EXACTLY the single-host _topk_scores expression (same op order,
@@ -384,8 +379,8 @@ def _sharded_exact_fn(mesh, num: int, kl: int, with_rmask: bool):
         def body(uq, ubq, mean, it, ib, m):
             return per_shard(uq, ubq, mean, it, ib, m, None)
 
-    smapped = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=(P(), P()), check_rep=False)
+    smapped = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=(P(), P()), check_vma=False)
 
     def fn(uidx, ue_bf, ub, mean, item_t, bias, mask, rmask=None):
         # device gather of the query rows from the row-sharded user table

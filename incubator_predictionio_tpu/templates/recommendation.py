@@ -458,10 +458,9 @@ class RecModel(PersistentModel):
         # on TPU the catalog is int8-quantized and scored by the fused Pallas
         # retrieval kernel — the deployed server runs the fast path, not just
         # the synthetic bench (round-2 weak #5)
-        import jax
+        from incubator_predictionio_tpu.parallel.mesh import kernel_backend
 
-        self.mf.prepare_for_serving(
-            quantize=jax.devices()[0].platform == "tpu")
+        self.mf.prepare_for_serving(quantize=kernel_backend() is not None)
         return self
 
     # -- streaming deltas (docs/streaming.md) -----------------------------

@@ -649,13 +649,10 @@ def _resolve_channel(args, storage: Storage) -> Optional[int]:
 
 def cmd_status(args, storage: Storage) -> int:
     """(commands/Management.scala:99-181 + Storage.verifyAllDataObjects)"""
-    import jax
-
-    from incubator_predictionio_tpu.parallel.mesh import honor_platform_env
+    from incubator_predictionio_tpu.parallel.mesh import claim_devices
 
     _out(f"incubator_predictionio_tpu {piotpu.__version__}")
-    honor_platform_env()
-    devices = jax.devices()
+    devices = claim_devices()
     _out(f"Devices: {len(devices)} × {devices[0].platform}"
          f" ({devices[0].device_kind})")
     from incubator_predictionio_tpu.utils.tracing import device_memory_report
@@ -3802,13 +3799,17 @@ def cmd_launch(args, storage: Storage) -> int:
         return 2
     if "--distributed" not in verb_args:
         verb_args.append("--distributed")
-    result = launch_local(
-        verb_args,
-        num_processes=args.num_processes,
-        coordinator_port=args.coordinator_port,
-        cpu_devices_per_process=args.cpu_devices_per_process,
-        timeout=args.timeout,
-    )
+    try:
+        result = launch_local(
+            verb_args,
+            num_processes=args.num_processes,
+            coordinator_port=args.coordinator_port,
+            cpu_devices_per_process=args.cpu_devices_per_process,
+            timeout=args.timeout,
+        )
+    except RuntimeError as e:  # N local processes on a TPU host: refused
+        _err(f"launch: {e}")
+        return 2
     if result.timed_out:
         _out(f"launch: timed out after {args.timeout}s; job killed "
              "(per-process logs below show which peer wedged)")
