@@ -1,0 +1,12 @@
+"""Device, serving cells: share of the device's idle time in the traced part
+of the window that no program span (``pio.*`` on the profiler's timeline)
+covers, in %: an event loop with no request is idle under no span. Prints the
+idle seconds per span."""
+
+from benchmarks import program_spans
+
+
+def read(ev: dict):
+    if ev.get("kind") == "train":
+        return None
+    return program_spans.unspanned_pct(ev)

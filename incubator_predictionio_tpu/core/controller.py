@@ -39,6 +39,7 @@ from incubator_predictionio_tpu.core.base import (
     TD,
     doer,
 )
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.parallel.mesh import MeshContext
 from incubator_predictionio_tpu.utils.params import EmptyParams, Params, params_from_json
 
@@ -316,14 +317,15 @@ class Engine(BaseEngine[TD, EI, Q, P, A]):
         params: WorkflowParams = WorkflowParams(),
     ) -> list[Any]:
         data_source, preparator, algorithms, _ = self._instantiate(engine_params)
-        td = data_source.read_training(ctx)
-        _sanity_check(td, "training data", params)
-        if params.stop_after_read:
-            raise StopAfterReadInterruption()
-        pd = preparator.prepare(ctx, td)
-        _sanity_check(pd, "prepared data", params)
-        if params.stop_after_prepare:
-            raise StopAfterPrepareInterruption()
+        with span("train.verb.read"):  # DataSource + Preparator
+            td = data_source.read_training(ctx)
+            _sanity_check(td, "training data", params)
+            if params.stop_after_read:
+                raise StopAfterReadInterruption()
+            pd = preparator.prepare(ctx, td)
+            _sanity_check(pd, "prepared data", params)
+            if params.stop_after_prepare:
+                raise StopAfterPrepareInterruption()
         models = []
         for i, algo in enumerate(algorithms):
             logger.info("training algorithm %d/%d: %s", i + 1, len(algorithms),

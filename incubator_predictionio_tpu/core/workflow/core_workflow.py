@@ -26,6 +26,8 @@ from incubator_predictionio_tpu.data.storage.base import (
     Model,
 )
 from incubator_predictionio_tpu.data.storage.registry import Storage, get_storage
+from incubator_predictionio_tpu.obs.profile import device_memory_report
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.parallel.mesh import MeshContext
 from incubator_predictionio_tpu.utils.serialization import serialize_model
 
@@ -73,6 +75,16 @@ def run_train(
     them), but only process 0 touches storage — the single-Spark-driver role
     (``MeshContext.is_primary``); secondaries return a placeholder id."""
     storage = storage or get_storage()
+    # one trace per verb: train.verb is the root of everything the verb does
+    # (train.verb.read|bimaps|index|persist|commit, train.fit.*,
+    # train.persist.orbax — docs/observability.md "Profiling")
+    with span("train.verb") as root:
+        return _run_train(engine, engine_params, engine_instance, params,
+                          storage, ctx, root)
+
+
+def _run_train(engine, engine_params, engine_instance, params, storage, ctx,
+               root) -> str:
     instances = storage.get_meta_data_engine_instances()
     ctx = ctx or MeshContext.from_conf(engine_instance.mesh_conf or None)
     primary = ctx.is_primary
@@ -82,6 +94,7 @@ def run_train(
             instances.update(engine_instance)
     else:
         instance_id = engine_instance.id or "<secondary>"
+    root.set_attr("instance", instance_id)
     try:
         with ctx.activate():
             models = engine.train(ctx, engine_params, params)
@@ -89,22 +102,22 @@ def run_train(
             # but persistence — and its save side effects, e.g.
             # PersistentModel files keyed by instance id — is primary-only
             if primary:
-                persisted = engine.models_for_persistence(
-                    ctx, models, instance_id, engine_params
-                )
+                with span("train.verb.persist"):
+                    persisted = engine.models_for_persistence(
+                        ctx, models, instance_id, engine_params
+                    )
         if primary:
-            blob = serialize_model(persisted)
-            storage.get_model_data_models().insert(Model(instance_id, blob))
-            inst = instances.get(instance_id)
-            instances.update(replace(inst, status="COMPLETED", end_time=_now()))
+            with span("train.verb.commit"):
+                blob = serialize_model(persisted)
+                storage.get_model_data_models().insert(
+                    Model(instance_id, blob))
+                inst = instances.get(instance_id)
+                instances.update(
+                    replace(inst, status="COMPLETED", end_time=_now()))
             logger.info("training finished: instance %s (%d bytes of models)",
                         instance_id, len(blob))
         # placement evidence from the process that owns the devices: the
         # trained tables are still resident here (device-gather models)
-        from incubator_predictionio_tpu.utils.tracing import (
-            device_memory_report,
-        )
-
         for row in device_memory_report():
             if row["bytes_in_use"] is not None:  # CPU has no allocator stats
                 logger.info(

@@ -368,6 +368,7 @@ class RouterServer:
         t0 = self._clock.monotonic()
         try:
             with trace.span("forward", service="fleet_router",
+                            thread_scoped=False,
                             replica=replica.url) as fsp:
                 headers = dict(headers)
                 trace.inject(headers)

@@ -71,7 +71,9 @@ def telemetry_middleware(service: str):
         status = 500
         http_exc = False
         with trace.trace_scope(parent):
+            # crosses the handler's awaits: never a profiler annotation
             with trace.span(f"{request.method} {route}", service=service,
+                            thread_scoped=False,
                             method=request.method, route=route) as sp:
                 try:
                     resp = await handler(request)

@@ -1,6 +1,6 @@
-"""Always-on, low-overhead profiler: phase timers, a sampling wall-stack
-profiler, MFU, and device-memory watermarks (docs/observability.md
-"Profiling").
+"""Always-on, low-overhead profiler: the per-phase aggregate every span
+feeds, a sampling wall-stack profiler, MFU, and device-memory watermarks
+(docs/observability.md "Profiling").
 
 `/metrics` says *how much* and *how slow*; nothing in the repo said *where
 the time goes*. ALX (arxiv 2112.02194) attributes TPU matrix-factorization
@@ -8,19 +8,20 @@ step time to per-phase buckets (gather/compute/collective) to find its
 wins — this module makes that attribution continuous and cheap enough to
 leave on in production:
 
-- **Phase timers.** ``step_scope(scope)`` times an enclosing unit of work
-  (one ``fit``, one micro-batch dispatch, one per-shard search) and
-  ``phase_scope(scope, phase)`` attributes slices of it to named buckets
-  (``"gather"``/``"compute"``/``"collective"``/``"h2d"``/…). Both take an
-  injectable :class:`~incubator_predictionio_tpu.resilience.clock.Clock`
-  so the timer *logic* is testable on
-  :class:`~incubator_predictionio_tpu.resilience.clock.FakeClock`; callers
-  drop a :func:`fence` (``jax.block_until_ready``) at phase edges so async
-  device work is billed to the phase that launched it, not whichever phase
-  happens to block next. The conservation contract (tested): the sum of a
-  scope's phase buckets stays within ~10% of the enclosing wall time.
-  Cost per phase edge: one ``clock.monotonic()`` pair, two counter incs,
-  and one small dict update under a short lock.
+- **Phase aggregate.** The program opens spans through ONE primitive,
+  :func:`incubator_predictionio_tpu.obs.trace.span`. On exit a span named
+  ``<scope>.<phase>`` (``train.fit.compute``, ``serve.batch.dispatch``)
+  adds its duration to that scope and phase here (:func:`record_span`
+  keeps a pending sum per name, folded in when the aggregate is read):
+  ``pio_profile_phase_seconds_total`` / ``pio_profile_phases_total``,
+  ``GET /profile.json``, ``pio-tpu profile``. A span whose own name is a
+  scope that already holds phases (``train.verb`` over
+  ``train.verb.read`` …) also books the scope's enclosing wall time, so
+  the unattributed remainder shows. Callers drop a :func:`fence`
+  (``jax.block_until_ready``) at a span's edge so async device work is
+  billed to the span that launched it, not whichever blocks next.
+  :func:`record_phases` folds durations a caller measured itself
+  (``shard.search``, ``stream.fold``) into the same aggregate.
 - **Wall-stack sampler.** A daemon thread samples every Python thread's
   stack at ``PIO_PROFILE_HZ`` (default 0 = off; a few Hz is the intended
   always-on rate) and aggregates self-symbolized collapsed stacks — the
@@ -30,13 +31,16 @@ leave on in production:
   flops model bench.py uses, folded into a live ``pio_training_mfu``
   gauge so sustained efficiency is observable outside bench runs.
 - **Device-memory watermark**: the high-water mark of
-  ``device_memory_report``'s point read, sampled at exposition time and
-  from the sampler thread, on ``pio_device_bytes_peak``.
+  :func:`device_memory_report`'s point read, sampled at exposition time
+  and from the sampler thread, on ``pio_device_bytes_peak``.
+- :func:`profile_trace` captures an XLA/TPU profiler trace of a block
+  (``pio-tpu train --profile-dir DIR``); every thread-scoped span opened
+  inside it lies on that timeline as ``pio.<name>``.
 
 Everything here degrades to near-zero cost when idle: no jax import and no
 backend creation is ever triggered (device reads happen only in a process
-that already holds a backend), the sampler is off by default, and phase
-timers are plain arithmetic.
+that already holds a backend), the sampler is off by default, and the
+aggregate is plain arithmetic.
 """
 
 from __future__ import annotations
@@ -44,12 +48,12 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
+import re
 import sys
 import threading
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Iterator, Optional
 
 from incubator_predictionio_tpu.obs.metrics import REGISTRY
-from incubator_predictionio_tpu.resilience.clock import Clock, SYSTEM_CLOCK
 
 logger = logging.getLogger(__name__)
 
@@ -125,65 +129,81 @@ def _scope_entry(scope: str) -> dict[str, Any]:
     return entry
 
 
-@contextlib.contextmanager
-def step_scope(scope: str, clock: Clock = SYSTEM_CLOCK) -> Iterator[None]:
-    """Time one enclosing unit of work (a fit, a dispatch, a fold) under
-    ``scope``. Phases recorded inside via :func:`phase_scope` with the same
-    scope name must sum to ~this wall time (the conservation contract)."""
-    t0 = clock.monotonic()
-    try:
-        yield
-    finally:
-        dt = max(0.0, clock.monotonic() - t0)
-        with _AGG_LOCK:
-            entry = _scope_entry(scope)
-            entry["wall_seconds"] += dt
-            entry["count"] += 1
-        SCOPE_SECONDS.labels(scope=scope).inc(dt)
-        SCOPES_TOTAL.labels(scope=scope).inc()
+def _add_phase(bucket: dict[str, Any], phase: str, seconds: float,
+               count: int = 1) -> None:
+    ph = bucket.get(phase)
+    if ph is None:
+        ph = bucket[phase] = {"seconds": 0.0, "count": 0}
+    ph["seconds"] += seconds
+    ph["count"] += count
 
 
-@contextlib.contextmanager
-def phase_scope(scope: str, phase: str,
-                clock: Clock = SYSTEM_CLOCK) -> Iterator[None]:
-    """Attribute the enclosed block's wall time to ``phase`` within
-    ``scope``. Put a :func:`fence` on the phase's outputs before leaving
-    the block so launched-but-unfinished device work bills here."""
-    t0 = clock.monotonic()
-    try:
-        yield
-    finally:
-        dt = max(0.0, clock.monotonic() - t0)
-        with _AGG_LOCK:
-            phases = _scope_entry(scope)["phases"]
-            ph = phases.get(phase)
-            if ph is None:
-                ph = phases[phase] = {"seconds": 0.0, "count": 0}
-            ph["seconds"] += dt
-            ph["count"] += 1
-        PHASE_SECONDS.labels(scope=scope, phase=phase).inc(dt)
-        PHASES_TOTAL.labels(scope=scope, phase=phase).inc()
+#: a span feeds the aggregate when its name is dotted lower-case identifiers
+#: (``train.fit.compute``); route spans (``POST /queries.json``) and bare
+#: names (``forward``) are spans like any other and no phase of anything
+_PHASE_NAME = re.compile(r"[a-z0-9_]+(\.[a-z0-9_]+)+\Z")
+
+#: span name -> [seconds, count] since the last fold: all a finished span
+#: costs here is one short lock and two additions; :func:`_fold_spans` moves
+#: the sums into the aggregate and the counter families when either is read
+_PENDING_LOCK = threading.Lock()
+_PENDING: dict[str, list] = {}
+
+
+def record_span(name: str, seconds: float) -> None:
+    """One finished span (called by ``obs/trace`` at every span's exit)."""
+    with _PENDING_LOCK:
+        acc = _PENDING.get(name)
+        if acc is None:
+            acc = _PENDING[name] = [0.0, 0]
+        acc[0] += seconds
+        acc[1] += 1
+
+
+def _fold_spans() -> None:
+    """Move what the spans accumulated into the aggregate: ``<scope>.<phase>``
+    adds to that scope's phase bucket; a name that IS a scope with phases
+    (``train.verb`` over ``train.verb.read`` …) also books the scope's
+    enclosing wall. Runs when the aggregate is read: ``phase_snapshot`` and,
+    as a registry collector, every ``/metrics`` exposition."""
+    global _PENDING
+    with _PENDING_LOCK:
+        pending, _PENDING = _PENDING, {}
+    named = {n: acc for n, acc in pending.items() if _PHASE_NAME.match(n)}
+    with _AGG_LOCK:
+        for name, (seconds, count) in named.items():
+            scope, _, phase = name.rpartition(".")
+            _add_phase(_scope_entry(scope)["phases"], phase,
+                       max(0.0, seconds), count)
+        walls = {n: acc for n, acc in named.items() if n in _AGG}
+        for name, (seconds, count) in walls.items():
+            _AGG[name]["wall_seconds"] += max(0.0, seconds)
+            _AGG[name]["count"] += count
+    for name, (seconds, count) in named.items():
+        scope, _, phase = name.rpartition(".")
+        PHASE_SECONDS.labels(scope=scope, phase=phase).inc(max(0.0, seconds))
+        PHASES_TOTAL.labels(scope=scope, phase=phase).inc(count)
+    for name, (seconds, count) in walls.items():
+        SCOPE_SECONDS.labels(scope=name).inc(max(0.0, seconds))
+        SCOPES_TOTAL.labels(scope=name).inc(count)
+
+
+REGISTRY.add_collector("profile_spans", _fold_spans)
 
 
 def record_phases(scope: str, phases: dict[str, float],
                   wall_seconds: Optional[float] = None) -> None:
-    """Fold externally measured phase durations into the same aggregates
-    :func:`phase_scope` feeds — for linear pipelines that already keep
-    precise per-phase timers (``TwoTowerMF.fit``'s ``model.timings``),
-    where re-wrapping every block would duplicate the clock reads.
+    """Fold phase durations the caller measured itself into the aggregate
+    the spans feed — for pipelines that keep their own per-phase timers
+    (``shard.search``'s per-shard threads, ``stream.fold``).
     ``wall_seconds`` defaults to the phase sum (a fully attributed step)."""
     wall = sum(phases.values()) if wall_seconds is None else wall_seconds
     with _AGG_LOCK:
         entry = _scope_entry(scope)
         entry["wall_seconds"] += max(0.0, wall)
         entry["count"] += 1
-        bucket = entry["phases"]
         for phase, dt in phases.items():
-            ph = bucket.get(phase)
-            if ph is None:
-                ph = bucket[phase] = {"seconds": 0.0, "count": 0}
-            ph["seconds"] += max(0.0, dt)
-            ph["count"] += 1
+            _add_phase(entry["phases"], phase, max(0.0, dt))
     SCOPE_SECONDS.labels(scope=scope).inc(max(0.0, wall))
     SCOPES_TOTAL.labels(scope=scope).inc()
     for phase, dt in phases.items():
@@ -206,12 +226,15 @@ def fence(*values: Any) -> None:
 
 
 def phase_snapshot() -> dict[str, dict[str, Any]]:
-    """Deep copy of the per-scope phase aggregates (``/profile.json``,
-    conservation tests)."""
+    """Deep copy of the per-scope phase aggregates (``/profile.json``).
+    A scope no enclosing span or :func:`record_phases` call has timed
+    (``count`` 0) reports the sum of its phases as its wall."""
+    _fold_spans()
     with _AGG_LOCK:
         return {
             scope: {
-                "wall_seconds": e["wall_seconds"],
+                "wall_seconds": e["wall_seconds"] if e["count"] else sum(
+                    ph["seconds"] for ph in e["phases"].values()),
                 "count": e["count"],
                 "phases": {p: dict(ph) for p, ph in e["phases"].items()},
             }
@@ -222,6 +245,9 @@ def phase_snapshot() -> dict[str, dict[str, Any]]:
 def reset_phases() -> None:
     """Test hook: drop the in-process aggregates (registry families are
     reset separately via ``REGISTRY.reset()``)."""
+    global _PENDING
+    with _PENDING_LOCK:
+        _PENDING = {}
     with _AGG_LOCK:
         _AGG.clear()
 
@@ -291,6 +317,43 @@ def record_training_step(flops: float, seconds: float,
     return mfu
 
 
+def device_memory_report() -> list[dict[str, Any]]:
+    """One row per local device: platform + allocator stats when available
+    (``pio-tpu status``; platforms without allocator stats — CPU — report
+    ``None`` values)."""
+    import jax
+
+    rows: list[dict[str, Any]] = []
+    for d in jax.local_devices():
+        try:
+            stats = d.memory_stats() or {}
+        except Exception:  # noqa: BLE001 — CPU/older backends have no stats
+            stats = {}
+        rows.append({
+            "device": str(d),
+            "platform": d.platform,
+            "bytes_in_use": stats.get("bytes_in_use"),
+            "bytes_limit": stats.get("bytes_limit"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        })
+    return rows
+
+
+@contextlib.contextmanager
+def profile_trace(log_dir: str) -> Iterator[None]:
+    """Capture a jax.profiler trace of the enclosed block into ``log_dir``
+    (TensorBoard 'profile' plugin layout): device timelines, HLO cost
+    breakdowns, and every thread-scoped ``obs/trace.span`` opened inside the
+    block as a ``pio.<name>`` host event."""
+    import jax
+
+    jax.profiler.start_trace(log_dir)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
 def update_device_watermark() -> None:
     """Fold each local device's current/peak bytes-in-use into the
     ``pio_device_bytes_peak`` watermark gauges. Never imports jax and never
@@ -299,10 +362,6 @@ def update_device_watermark() -> None:
     if not _backend_live():
         return
     try:
-        from incubator_predictionio_tpu.utils.tracing import (
-            device_memory_report,
-        )
-
         for row in device_memory_report():
             seen = row.get("peak_bytes_in_use")
             if seen is None:
@@ -490,10 +549,10 @@ def profile_payload() -> dict[str, Any]:
 
 __all__ = [
     "ENV_HZ", "ENV_TOPN", "TPU_PEAK_FLOPS", "StackSampler",
-    "step_scope", "phase_scope", "record_phases", "fence",
+    "record_span", "record_phases", "fence",
     "phase_snapshot", "reset_phases",
     "record_training_step", "detected_peak_flops",
-    "update_device_watermark",
+    "device_memory_report", "profile_trace", "update_device_watermark",
     "configure_profiler_from_env", "active_sampler", "close_profiler",
     "profile_payload",
 ]

@@ -24,18 +24,26 @@ head-based keep/drop decision when it roots a trace, and the decision rides
 the ``X-PIO-Trace`` header as a ``:s=0|1`` suffix so every downstream hop
 agrees. Tail-based keep rules (error spans, slow spans) are applied by the
 export hook regardless of the head decision (docs/observability.md).
+
+:func:`span` is the ONE way the program opens a span. Besides the ring and
+the exporter, every finished span feeds the per-phase aggregate in
+:mod:`.profile` (a span named ``<scope>.<phase>`` is a row of
+``pio_profile_phase_seconds_total``) and, when jax is loaded and the body
+stays on one thread, lies on the jax profiler's timeline as
+``pio.<name>`` — the same clock as the device's ``XLA Ops`` line.
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import random
+import sys
 import threading
 import time
-import uuid
 from collections import deque
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, Optional
+
+from incubator_predictionio_tpu.obs import profile as _profile
 
 #: Propagation header: ``<trace_id>:<span_id>[:s=0|1]`` (ids are 16 hex
 #: chars; the optional third field is the head sampling decision — peers
@@ -56,30 +64,94 @@ class SpanContext:
 
 
 class Span:
-    """One timed operation. Mutable while open (attrs, status); recorded
-    into the buffer exactly once, at exit."""
+    """One timed operation, and the context manager that times it:
+    ``with span(name, **attrs) as sp`` opens it as a child of the current
+    context (or the root of a fresh trace), makes it current for the block —
+    while open it is the ambient identity of its children, with the three
+    fields of a :class:`SpanContext` — and records it on exit: into the
+    ring, into the aggregate (``<scope>.<phase>`` →
+    ``pio_profile_phase_seconds_total``) and to the exporter. Mutable while
+    open (attrs, status). An escaping exception marks
+    ``status="error:<Type>"`` and re-raises.
+
+    ``thread_scoped`` (default): the body runs on one thread with no
+    ``await`` inside, so the span also lies on the jax profiler's timeline
+    as ``pio.<name>``, beside the device's operations, whenever a profiler
+    session is running. Pass ``False`` for a span that crosses an ``await``
+    — the profiler's annotations are per thread, and interleaved coroutines
+    would nest wrongly.
+
+    ``start`` (a ``time.perf_counter()`` reading): the interval began
+    before the block could be entered — on another task, across an
+    ``await`` — so the recorded span starts there; the annotation on the
+    timeline still covers the block alone."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "service",
                  "start_unix", "duration", "status", "attrs", "sampled",
-                 "_t0")
+                 "_t0", "_buffer", "_thread_scoped", "_token", "_ann")
 
-    def __init__(self, trace_id: str, span_id: str, parent_id: Optional[str],
-                 name: str, service: Optional[str], attrs: dict[str, Any],
-                 sampled: bool = True):
-        self.trace_id = trace_id
-        self.span_id = span_id
-        self.parent_id = parent_id
+    def __init__(self, name: str, service: Optional[str] = None,
+                 buffer: Optional["TraceBuffer"] = None,
+                 thread_scoped: bool = True, start: Optional[float] = None,
+                 **attrs: Any):
         self.name = name
         self.service = service
-        self.start_unix = time.time()
+        self.attrs = attrs
         self.duration = 0.0
         self.status = "ok"
-        self.attrs = attrs
-        self.sampled = sampled
-        self._t0 = time.perf_counter()
+        self._buffer = buffer
+        self._thread_scoped = thread_scoped
+        self._t0 = start
+        self._ann = None
+
+    def _begin(self, parent: Optional[Any]) -> None:
+        """Identity and clocks, under ``parent`` or as a fresh trace's root
+        (which mints the trace's head sampling decision)."""
+        if parent is not None:
+            self.trace_id = parent.trace_id
+            self.parent_id = parent.span_id
+            self.sampled = parent.sampled
+        else:
+            self.trace_id = _new_id()
+            self.parent_id = None
+            self.sampled = _mint_sampled()
+        self.span_id = _new_id()
+        now = time.perf_counter()
+        if self._t0 is None:
+            self._t0 = now
+        self.start_unix = time.time() - (now - self._t0)
+
+    def __enter__(self) -> "Span":
+        self._begin(_CURRENT.get())
+        self._token = _CURRENT.set(self)
+        if self._thread_scoped:
+            cls = _TRACE_ANNOTATION or _trace_annotation()
+            if cls is not None and cls.is_enabled():
+                self._ann = cls("pio." + self.name)
+                self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.duration = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        # the body may have already classified the outcome (the telemetry
+        # middleware downgrades raised 4xx HTTPExceptions to a non-error
+        # terminal status before they propagate) — respect it
+        if exc_type is not None and self.status == "ok":
+            self.status = f"error:{exc_type.__name__}"
+        _CURRENT.reset(self._token)
+        self._token = None
+        _finish(self)
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
+
+    @property
+    def context(self) -> "Span":
+        """This span as the ambient identity of its children."""
+        return self
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -96,12 +168,22 @@ class Span:
         }
 
 
-_CURRENT: contextvars.ContextVar[Optional[SpanContext]] = \
+#: ``obs/trace.span`` is the ONE way the program opens a span
+span = Span
+
+
+#: the ambient identity: a :class:`SpanContext` adopted from a header, or
+#: the open :class:`Span` itself (same three fields)
+_CURRENT: contextvars.ContextVar[Optional[Any]] = \
     contextvars.ContextVar("pio_trace_context", default=None)
 
 
+_ID_RNG = random.Random()  # seeded from os.urandom, per process
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    # 64 random bits fill the header's 16 hex chars at 0.3 µs an id
+    return "%016x" % _ID_RNG.getrandbits(64)
 
 
 # -- sampling + export configuration ----------------------------------------
@@ -199,6 +281,9 @@ class TraceBuffer:
         """Recent traces, newest first: one entry per trace id with its span
         tree flattened (spans in start order).
 
+        Every span carries ``"selfSec"`` (:func:`self_seconds`): where a
+        request's time went is the spans with the largest self time.
+
         Each entry carries ``"complete"``: the root span is present AND no
         span's ``parentId`` dangles. A trace whose older spans were evicted
         by the ring looks exactly like a short trace otherwise — the flag is
@@ -221,12 +306,16 @@ class TraceBuffer:
             has_root = any(s.parent_id is None for s in spans)
             dangling = any(s.parent_id is not None and s.parent_id not in ids
                            for s in spans)
+            dicts = [s.to_dict() for s in spans]
+            own = self_seconds(dicts)
+            for d in dicts:
+                d["selfSec"] = own[d["spanId"]]
             out.append({
                 "traceId": tid,
                 "spanCount": len(spans),
                 "durationSec": max((s.duration for s in spans), default=0.0),
                 "complete": has_root and not dangling,
-                "spans": [s.to_dict() for s in spans],
+                "spans": dicts,
             })
         return out
 
@@ -239,50 +328,91 @@ class TraceBuffer:
 TRACES = TraceBuffer()
 
 
-@contextlib.contextmanager
-def span(name: str, service: Optional[str] = None,
-         buffer: Optional[TraceBuffer] = None, **attrs: Any) -> Iterator[Span]:
-    """Open a span as a child of the current context (or the root of a fresh
-    trace), make it current for the block, and record it on exit. An escaping
-    exception marks ``status="error:<Type>"`` and re-raises."""
-    parent = _CURRENT.get()
-    trace_id = parent.trace_id if parent is not None else _new_id()
-    parent_id = parent.span_id if parent is not None else None
-    sampled = parent.sampled if parent is not None else _mint_sampled()
-    sp = Span(trace_id, _new_id(), parent_id, name, service, attrs,
-              sampled=sampled)
-    token = _CURRENT.set(SpanContext(trace_id, sp.span_id, sampled))
-    try:
-        yield sp
-    except BaseException as e:
-        # the body may have already classified the outcome (the telemetry
-        # middleware downgrades raised 4xx HTTPExceptions to a non-error
-        # terminal status before they propagate) — respect it
-        if sp.status == "ok":
-            sp.status = f"error:{type(e).__name__}"
-        raise
-    finally:
-        sp.duration = time.perf_counter() - sp._t0
-        _CURRENT.reset(token)
-        (buffer or TRACES).add(sp)
-        exporter = _EXPORTER
-        if exporter is not None:
-            exporter(sp)
+def self_seconds(spans: list[dict]) -> dict[str, float]:
+    """``{spanId: seconds}`` of each span's own time: its duration minus
+    what its direct children cover of it (the union of their intervals, so
+    children that overlap — coalesced requests, concurrent attempts — are
+    not subtracted twice). Takes ``Span.to_dict()`` rows of one trace."""
+    kids: dict[str, list[tuple[float, float]]] = {}
+    for s in spans:
+        if s["parentId"] is not None:
+            kids.setdefault(s["parentId"], []).append(
+                (s["startUnix"], s["startUnix"] + s["durationSec"]))
+    out = {}
+    for s in spans:
+        lo, hi = s["startUnix"], s["startUnix"] + s["durationSec"]
+        covered, cur = 0.0, lo
+        for a, b in sorted(kids.get(s["spanId"], ())):
+            a, b = max(a, cur), min(b, hi)
+            if b > a:
+                covered += b - a
+                cur = b
+        out[s["spanId"]] = max(0.0, s["durationSec"] - covered)
+    return out
 
 
-@contextlib.contextmanager
-def trace_scope(ctx: Optional[SpanContext]) -> Iterator[None]:
+def context_of(ctx: contextvars.Context) -> Optional[SpanContext]:
+    """The span identity a captured ``contextvars.Context`` carries (the
+    micro-batcher keeps each request's context beside its queue entry)."""
+    return ctx.get(_CURRENT)
+
+
+_TRACE_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation once jax is up
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None while jax is not imported
+    (this module never imports it)."""
+    global _TRACE_ANNOTATION
+    jax = sys.modules.get("jax")
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is not None:
+        _TRACE_ANNOTATION = cls
+    return cls
+
+
+def _finish(sp: Span) -> None:
+    """Every finished span, however it was timed: ring, aggregate, export."""
+    (sp._buffer or TRACES).add(sp)
+    _profile.record_span(sp.name, sp.duration)
+    exporter = _EXPORTER
+    if exporter is not None:
+        exporter(sp)
+
+
+def record_span(name: str, start: float, duration: float,
+                context: Optional[Any] = None,
+                service: Optional[str] = None,
+                buffer: Optional[TraceBuffer] = None, **attrs: Any) -> Span:
+    """Record a span whose interval was timed by the caller: ``start`` is a
+    ``time.perf_counter()`` reading, ``duration`` seconds. For intervals no
+    ``with`` block can hold — they cross an ``await`` or begin on another
+    task (a request's wait in the batcher's queue). A child of ``context``
+    (default: the current one); lands in the ring, the aggregate and the
+    exporter, never on the profiler's timeline."""
+    sp = Span(name, service, buffer, False, start, **attrs)
+    sp._begin(context if context is not None else _CURRENT.get())
+    sp.duration = max(0.0, duration)
+    _finish(sp)
+    return sp
+
+
+class trace_scope:
     """Force the ambient context for a block — how a server middleware adopts
     a remote parent parsed from ``X-PIO-Trace`` (``ctx=None`` is a no-op, not
     a reset: spans below still start a fresh trace naturally)."""
-    if ctx is None:
-        yield
-        return
-    token = _CURRENT.set(ctx)
-    try:
-        yield
-    finally:
-        _CURRENT.reset(token)
+
+    __slots__ = ("_ctx", "_token")
+
+    def __init__(self, ctx: Optional[Any]):
+        self._ctx = ctx
+
+    def __enter__(self) -> None:
+        self._token = None if self._ctx is None else _CURRENT.set(self._ctx)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._token is not None:
+            _CURRENT.reset(self._token)
 
 
 # -- header propagation -----------------------------------------------------

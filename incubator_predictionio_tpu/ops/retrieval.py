@@ -200,23 +200,27 @@ def score_centroids_quantized(q_q, q_scales, cent_q, cent_scales, cent_bias,
             f"centroid rows ({c}) must be padded to {ITEM_BLOCK}")
     grid = (c // ITEM_BLOCK,)
     col = lambda j: (0, j)
-    return pl.pallas_call(
-        _coarse_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, d), lambda j: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((ITEM_BLOCK, d), lambda j: (j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, 1), lambda j: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ITEM_BLOCK), col, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ITEM_BLOCK), col, memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((b, ITEM_BLOCK), col,
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
-        interpret=interpret,
-    )(q_q, cent_q, q_scales.reshape(b, 1), cent_scales.reshape(1, c),
-      cent_bias.reshape(1, c))
+    # scope and kernel name are what a device trace shows (the custom call
+    # is named by the innermost of them: keep the executable's own)
+    with jax.named_scope("score"):
+        return pl.pallas_call(
+            _coarse_kernel,
+            name="score_centroids_quantized",
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((b, d), lambda j: (0, 0), memory_space=pltpu.VMEM),
+                pl.BlockSpec((ITEM_BLOCK, d), lambda j: (j, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((b, 1), lambda j: (0, 0), memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, ITEM_BLOCK), col, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, ITEM_BLOCK), col, memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((b, ITEM_BLOCK), col,
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+            interpret=interpret,
+        )(q_q, cent_q, q_scales.reshape(b, 1), cent_scales.reshape(1, c),
+          cent_bias.reshape(1, c))
 
 
 def score_centroids_reference(q_q, q_scales, cent_q, cent_scales, cent_bias):

@@ -45,6 +45,7 @@ from incubator_predictionio_tpu.obs.http import (
     telemetry_middleware,
 )
 from incubator_predictionio_tpu.obs import profile as _profile
+from incubator_predictionio_tpu.obs import trace as _trace
 from incubator_predictionio_tpu.obs import slo as _slo
 from incubator_predictionio_tpu.obs.metrics import (
     REGISTRY,
@@ -119,13 +120,22 @@ _ROLLBACKS = REGISTRY.counter(
     "pio_deploy_rollbacks_total",
     "Reloads rejected by the smoke-query gate or auto-rolled back during "
     "the post-swap probation window (docs/resilience.md)")
+#: the serve bucket ladder: the batch-size histogram's edges and the
+#: ``bucket`` attribute of a batch's spans
+_BATCH_EDGES = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 _H_TEMPLATE_BATCH = REGISTRY.histogram(
     "pio_serving_template_batch_size",
     "Live queries per coalesced batch_predict dispatch, per algorithm class "
     "— proves the micro-batcher's coalescing reaches the vectorized "
     "template paths (docs/serving.md)",
-    labels=("template",),
-    buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0))
+    labels=("template",), buckets=tuple(map(float, _BATCH_EDGES)))
+
+
+def _batch_attrs(n: int) -> dict:
+    """A batch's span attributes: its live size and the ladder edge it
+    falls under."""
+    return {"batch": n,
+            "bucket": next((e for e in _BATCH_EDGES if n <= e), n)}
 
 #: per-algorithm wall times of the current dispatch, set by ``predict_batch``
 #: and read back from the SAME Context object after ``Context.run`` returns
@@ -371,6 +381,12 @@ class DeployedEngine:
         queries) leave the breaker alone.
         Queries are served from whichever algorithms survived; a query no
         algorithm could answer carries its first error."""
+        # the batch's whole life in the worker thread: serve.batch.dispatch
+        # minus this is the thread hand-over and the loop's wake-up
+        with _trace.span("serve.batch.predict", **_batch_attrs(len(payloads))):
+            return self._predict_batch(payloads)
+
+    def _predict_batch(self, payloads: list[dict]) -> list[Any]:
         out: list[Any] = [None] * len(payloads)
         bound: list[Any] = [None] * len(payloads)
         for i, p in enumerate(payloads):
@@ -452,17 +468,27 @@ class DeployedEngine:
         return out
 
 
+def _run_under(parent: "_trace.SpanContext", fn, *args):
+    """``fn(*args)`` with ``parent`` as the ambient span (worker-thread side
+    of a span that crossed the executor hop)."""
+    with _trace.trace_scope(parent):
+        return fn(*args)
+
+
 class _Delivered:
     """Marker wrapper the dispatcher resolves futures with: the payload's
     result plus the batch's per-algorithm timings. A distinct type (not a
     tuple) so a prediction that happens to BE a tuple can never be mistaken
     for the envelope; error paths deliver bare exceptions."""
 
-    __slots__ = ("result", "algo_times")
+    __slots__ = ("result", "algo_times", "resolved_at")
 
-    def __init__(self, result: Any, algo_times: list):
+    def __init__(self, result: Any, algo_times: list, resolved_at: float):
         self.result = result
         self.algo_times = algo_times
+        #: ``perf_counter`` when the batch's futures were resolved: where
+        #: each request's ``serve.request.respond`` starts
+        self.resolved_at = resolved_at
 
 
 class MicroBatcher:
@@ -544,10 +570,11 @@ class MicroBatcher:
     async def submit(self, payload: dict) -> Any:
         return (await self.submit_timed(payload))[0]
 
-    async def submit_timed(self, payload: dict) -> tuple[Any, list]:
+    async def submit_timed(self, payload: dict) -> tuple[Any, list, float]:
         """Submit and also return the dispatch's per-algorithm wall times
-        (the X-PIO-Server-Timing source) — per-call data, never read off
-        shared state, so overlapping dispatches can't swap timings."""
+        (the X-PIO-Server-Timing source) and the instant the batch resolved
+        this request's future — per-call data, never read off shared state,
+        so overlapping dispatches can't swap timings."""
         self.start()
         fut = asyncio.get_running_loop().create_future()
         # deadline tagged at enqueue (docs/resilience.md shedding order):
@@ -569,12 +596,13 @@ class MicroBatcher:
             fut.cancel()
             raise
         if isinstance(got, _Delivered):
-            result, algo_times = got.result, got.algo_times
+            result, algo_times, resolved_at = (
+                got.result, got.algo_times, got.resolved_at)
         else:  # error paths deliver bare exceptions
-            result, algo_times = got, []
+            result, algo_times, resolved_at = got, [], time.perf_counter()
         if isinstance(result, Exception):
             raise result
-        return result, algo_times
+        return result, algo_times, resolved_at
 
     async def resize(self, n: int) -> None:
         """Resize the dispatch-slot semaphore live (reload can swap in an
@@ -612,18 +640,27 @@ class MicroBatcher:
                 except asyncio.CancelledError:
                     sem.release()
                     raise
-                t_phase = time.perf_counter()
-                while len(batch) < self.max_batch:
-                    try:
-                        batch.append(self.queue.get_nowait())
-                    except asyncio.QueueEmpty:
-                        break
-                now = time.perf_counter()
-                for entry in batch:
-                    self.queue_delay.record(now - entry[2])
-                t_assemble, t_phase = now - t_phase, now
-                batch = self._evict_expired(batch)
-                t_mask = time.perf_counter() - t_phase
+                # the batch's spans hang under the first request's trace
+                # (coalesced followers share them), each request's queue
+                # wait under its own — docs/observability.md "Profiling"
+                lead = _trace.context_of(batch[0][3])
+                with _trace.trace_scope(lead):
+                    with _trace.span("serve.batch.assemble") as sp:
+                        while len(batch) < self.max_batch:
+                            try:
+                                batch.append(self.queue.get_nowait())
+                            except asyncio.QueueEmpty:
+                                break
+                        attrs = _batch_attrs(len(batch))
+                        sp.attrs.update(attrs)
+                    now = time.perf_counter()
+                    for entry in batch:
+                        self.queue_delay.record(now - entry[2])
+                        _trace.record_span(
+                            "serve.request.queue", entry[2], now - entry[2],
+                            context=_trace.context_of(entry[3]))
+                    with _trace.span("serve.batch.mask", **attrs):
+                        batch = self._evict_expired(batch)
                 if not batch:
                     # the whole assembly was dead on arrival: no dispatch,
                     # hand the slot back and keep draining
@@ -631,8 +668,7 @@ class MicroBatcher:
                     continue
                 self.batches_served += 1
                 self.max_batch_seen = max(self.max_batch_seen, len(batch))
-                task = loop.create_task(
-                    self._dispatch(loop, batch, t_assemble, t_mask))
+                task = loop.create_task(self._dispatch(loop, batch))
                 self._inflight.add(task)
                 task.add_done_callback(self._inflight.discard)
                 task.add_done_callback(lambda _t: sem.release())
@@ -679,9 +715,7 @@ class MicroBatcher:
                 self._admission.on_shed_expired(shed)
         return live
 
-    async def _dispatch(self, loop, batch,
-                        t_assemble: float = 0.0, t_mask: float = 0.0) -> None:
-        t0 = time.perf_counter()
+    async def _dispatch(self, loop, batch) -> None:
         payloads = [entry[0] for entry in batch]
         # run_in_executor does not copy contextvars — run_with_deadline
         # re-establishes the deadline scope inside the worker thread, and
@@ -689,11 +723,19 @@ class MicroBatcher:
         # identity across the thread hop (each request's context is captured
         # once at submit, so it is never entered twice)
         ctx = batch[0][3]
+        lead = _trace.context_of(ctx)
+        # crosses the await: ring and aggregate only. The worker re-enters
+        # the span's identity, so what predict_batch opens hangs under it
+        attrs = _batch_attrs(len(batch))
+        dispatch = _trace.span("serve.batch.dispatch", thread_scoped=False,
+                               **attrs)
         try:
-            results = await loop.run_in_executor(
-                None, ctx.run, run_with_deadline, self.deadline_sec,
-                self.deployed.predict_batch, payloads
-            )
+            with _trace.trace_scope(lead), dispatch as sp:
+                results = await loop.run_in_executor(
+                    None, ctx.run, _run_under, sp.context,
+                    run_with_deadline, self.deadline_sec,
+                    self.deployed.predict_batch, payloads
+                )
         except asyncio.CancelledError:
             # cancelled mid-dispatch: these futures are already dequeued, so
             # the queue-drain in stop() can't see them — fail them here or
@@ -704,22 +746,17 @@ class MicroBatcher:
             raise
         except Exception as e:  # noqa: BLE001 - keep serving
             results = [e] * len(batch)
-        t_dispatch = time.perf_counter() - t0
-        self.dispatch_sec.record(t_dispatch)
+        self.dispatch_sec.record(sp.duration)
         # predict_batch published its per-algorithm times inside ctx; writes
         # made under Context.run persist in the Context object
         algo_times = ctx.get(_DISPATCH_ALGO_TIMES, [])
-        t_merge = time.perf_counter()
-        for entry, r in zip(batch, results):
-            if not entry[1].done():
-                entry[1].set_result(_Delivered(r, algo_times))
-        # perf-plane phases for this batch's full life: coalesce (assemble),
-        # deadline eviction (mask), device round-trip (dispatch), future
-        # resolution (merge) — docs/observability.md "Profiling"
-        _profile.record_phases("serve.batch", {
-            "assemble": t_assemble, "mask": t_mask, "dispatch": t_dispatch,
-            "merge": time.perf_counter() - t_merge,
-        })
+        with _trace.trace_scope(lead), \
+                _trace.span("serve.batch.merge", **attrs):
+            resolved_at = time.perf_counter()
+            for entry, r in zip(batch, results):
+                if not entry[1].done():
+                    entry[1].set_result(
+                        _Delivered(r, algo_times, resolved_at))
 
 
 # LatencyReservoir moved to obs/metrics.py (it is a general primitive the
@@ -743,20 +780,24 @@ def load_deployed_engine(
     engine_params = engine.engine_params_from_variant(variant)
     import os
 
-    instances = storage.get_meta_data_engine_instances()
-    instance = instances.get_latest_completed(
-        variant.get("id", "default"), variant.get("version", "1"),
-        os.path.abspath(config.engine_variant),
-    )
-    if instance is None:
-        raise RuntimeError(
-            f"No COMPLETED engine instance for variant {config.engine_variant}; "
-            "run train first (reference: CreateServer.scala:199 'Invalid engine instance')"
+    # deploy.* spans (docs/observability.md "Profiling"): load here and at
+    # the model's sidecar, then restore | quantize | ensure_host | index |
+    # warmup inside the model's own load / prepare_for_serving / warmup
+    with _trace.span("deploy.load", part="instance"):
+        instances = storage.get_meta_data_engine_instances()
+        instance = instances.get_latest_completed(
+            variant.get("id", "default"), variant.get("version", "1"),
+            os.path.abspath(config.engine_variant),
         )
-    blob = storage.get_model_data_models().get(instance.id)
-    if blob is None:
-        raise RuntimeError(f"model blob missing for instance {instance.id}")
-    persisted = deserialize_model(blob.models)
+        if instance is None:
+            raise RuntimeError(
+                f"No COMPLETED engine instance for variant {config.engine_variant}; "
+                "run train first (reference: CreateServer.scala:199 'Invalid engine instance')"
+            )
+        blob = storage.get_model_data_models().get(instance.id)
+        if blob is None:
+            raise RuntimeError(f"model blob missing for instance {instance.id}")
+        persisted = deserialize_model(blob.models)
     models = engine.prepare_deploy(ctx, engine_params, persisted, instance.id)
     logger.info("deployed engine instance %s (trained %s)", instance.id,
                 instance.start_time)
@@ -925,11 +966,7 @@ class QueryServer:
 
         if "jax" in sys.modules:  # never the import that drags jax in
             try:
-                from incubator_predictionio_tpu.utils.tracing import (
-                    device_memory_report,
-                )
-
-                for row in device_memory_report():
+                for row in _profile.device_memory_report():
                     if row["bytes_in_use"] is not None:
                         _G_DEV_MEM.labels(device=row["device"]).set(
                             row["bytes_in_use"])
@@ -1234,9 +1271,11 @@ class QueryServer:
 </html>"""
 
     async def handle_query(self, request: web.Request) -> web.Response:
+        t_entry = time.perf_counter()
         if self._drain_state.draining:
             return self._drain_state.reject_response()
-        status, result, headers = await self._serve_payload(await request.read())
+        status, result, headers = await self._serve_payload(
+            await request.read(), t_entry)
         return web.json_response(result, status=status, headers=headers)
 
     @staticmethod
@@ -1272,12 +1311,17 @@ class QueryServer:
             task.add_done_callback(self._resize_tasks.discard)
 
     async def _serve_payload(
-            self, body: bytes) -> tuple[int, Any, Optional[dict]]:
+            self, body: bytes, t_entry: Optional[float] = None,
+    ) -> tuple[int, Any, Optional[dict]]:
         """The whole query lifecycle from raw body bytes — ONE code path
         shared by the aiohttp route and the native front, so their behavior
         cannot drift. Returns (status, jsonable body, response headers or
         None) — headers carry X-PIO-Server-Timing on predictions and
-        Retry-After on overload rejections."""
+        Retry-After on overload rejections. ``t_entry``: the
+        ``perf_counter`` reading at the handler's entry, when the body was
+        still to be read (``serve.request.parse`` starts there)."""
+        if t_entry is None:
+            t_entry = time.perf_counter()
         t0 = self._clock.monotonic()
         try:
             payload = json.loads(body)
@@ -1311,6 +1355,10 @@ class QueryServer:
             return 200, await loop.run_in_executor(
                 None, self._degraded_result, payload,
                 "serving breaker open"), None
+        # handler entry → enqueue: body, JSON, admission, breaker (timed by
+        # hand: the body read is an await)
+        _trace.record_span("serve.request.parse", t_entry,
+                           time.perf_counter() - t_entry)
         try:
             submitted = self.batcher.submit_timed(payload)
             if self.config.query_timeout_sec is not None:
@@ -1323,10 +1371,10 @@ class QueryServer:
                 # shed path unreachable and charge the serving breaker
                 # (and probation rollback) for pure overload
                 budget = self.config.query_timeout_sec
-                prediction, algo_times = await asyncio.wait_for(
+                prediction, algo_times, resolved_at = await asyncio.wait_for(
                     submitted, budget + max(0.05, 0.1 * budget))
             else:
-                prediction, algo_times = await submitted
+                prediction, algo_times, resolved_at = await submitted
         except asyncio.CancelledError:
             # client disconnected mid-await (aiohttp cancels the handler):
             # no verdict on the engine's health — hand back the admitted
@@ -1375,6 +1423,17 @@ class QueryServer:
             self._feed_admission(self._clock.monotonic() - t0,
                                  observe_latency=False)
             raise
+        # future resolved → answer built: the wait for the loop to reach
+        # this request behind its batch-mates' answers, then the block (the
+        # response object's own JSON encoding, tens of µs, is left to the
+        # route span)
+        with _trace.span("serve.request.respond", start=resolved_at):
+            return self._respond(payload, prediction, algo_times, t0)
+
+    def _respond(self, payload: dict, prediction: Any, algo_times: list,
+                 t0: float) -> tuple[int, Any, Optional[dict]]:
+        """A clean prediction's way out: bookkeeping, ``to_jsonable``,
+        output plugins, the last-good cache, the timing header."""
         self._serving_breaker.record_success()
         dt = self._clock.monotonic() - t0
         self.request_count += 1

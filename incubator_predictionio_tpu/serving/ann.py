@@ -40,6 +40,7 @@ bitwise parity). See docs/serving.md ("Two-stage retrieval").
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -49,6 +50,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from incubator_predictionio_tpu.obs.metrics import REGISTRY
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.serving.topk import topk_row
 
 #: Rows per chunk for the full-catalog assignment pass at build time — keeps
@@ -583,19 +585,26 @@ class IVFIndex:
             return (np.zeros((0, num), np.int64), np.zeros((0, num), np.float32))
         nprobe = resolved_nprobe(self.n_partitions) if nprobe is None \
             else min(max(1, nprobe), self.n_partitions)
-        t0 = time.perf_counter()
-        q_quant = None
-        if self.quantized:
-            from incubator_predictionio_tpu.ops.retrieval import quantize_rows
+        # the two stages are spans (retrieval.batch.coarse|rerank); the
+        # histograms read the same clock. Per-shard searches (observe=False)
+        # are accounted once, by their caller
+        def stage(name):
+            return span(name, batch=b, nprobe=nprobe) if observe \
+                else contextlib.nullcontext()
 
-            # one per-row query quantization serves BOTH stages (the int8
-            # coarse probe and the int8 rerank share q_q/q_scales)
-            q_quant = quantize_rows(np.asarray(q, np.float32))
-        int8_coarse = q_quant is not None and quant_coarse_enabled(True)
-        probe = self.probe(q, nprobe, q_quant=q_quant if int8_coarse else None)
-        counts = np.diff(self.offsets)[probe].sum(axis=1)
+        with stage("retrieval.batch.coarse") as sp:
+            q_quant = None
+            if self.quantized:
+                from incubator_predictionio_tpu.ops.retrieval import quantize_rows
+
+                # one per-row query quantization serves BOTH stages (the int8
+                # coarse probe and the int8 rerank share q_q/q_scales)
+                q_quant = quantize_rows(np.asarray(q, np.float32))
+            int8_coarse = q_quant is not None and quant_coarse_enabled(True)
+            probe = self.probe(q, nprobe, q_quant=q_quant if int8_coarse else None)
+            counts = np.diff(self.offsets)[probe].sum(axis=1)
         if observe:
-            COARSE_SEC.observe(time.perf_counter() - t0)
+            COARSE_SEC.observe(sp.duration)
             if int8_coarse:
                 INT8_COARSE.inc()
         if int(counts.min()) < num:
@@ -608,60 +617,60 @@ class IVFIndex:
         excl_sorted = None
         if exclude is not None and len(exclude):
             excl_sorted = np.sort(np.asarray(exclude, np.int64))
-        t0 = time.perf_counter()
-        part_scores = None
-        if q_quant is not None:
-            part_scores = self._int8_partition_scores(probe, q_quant)
-            if observe:
-                INT8_RERANK.inc()
-        out_idx = np.empty((b, num), np.int64)
-        out_scores = np.empty((b, num), np.float32)
-        for r in range(b):
-            parts = np.sort(probe[r])  # ordered slices walk memory forward
-            cnt = int(counts[r])
-            ids = np.empty(cnt, np.int32)
-            scores = np.empty(cnt, np.float32)
-            qrow = q[r]
-            pos = 0
-            bnds = self.offsets[parts].tolist()
-            ubnds = self.offsets[parts + 1].tolist()
-            for p, lo, hi in zip(parts.tolist(), bnds, ubnds):
-                m = hi - lo
-                if not m:
-                    continue
-                ids[pos:pos + m] = self.member_ids[lo:hi]
-                if part_scores is not None:
-                    # rows come off each partition's iterator in ascending
-                    # query order — exactly this loop's visit order
-                    scores[pos:pos + m] = next(part_scores[p])
-                else:
-                    scores[pos:pos + m] = \
-                        self.emb_m[lo:hi] @ qrow + self.bias_m[lo:hi]
-                pos += m
-            if self.stale_ids is not None and len(self.stale_ids):
-                ids, scores = self._apply_stale_overlay(ids, scores, qrow)
-            scores += user_bias[r] + mean
-            if excl_sorted is not None:
-                pos = np.minimum(np.searchsorted(excl_sorted, ids),
-                                 len(excl_sorted) - 1)
-                scores[excl_sorted[pos] == ids] = -np.inf
-            if row_mask is not None:
-                scores += row_mask[r, ids]
-            top = topk_row(scores, num)
-            if not np.isfinite(scores[top[-1]]):
-                # fewer than num candidates survived the rule filters in
-                # THIS probe set — a masked (-inf) item would fill the
-                # trailing slots where the exact path, seeing the whole
-                # catalog, still has unmasked items to place. Fall back.
+        with stage("retrieval.batch.rerank") as sp:
+            part_scores = None
+            if q_quant is not None:
+                part_scores = self._int8_partition_scores(probe, q_quant)
                 if observe:
-                    FALLBACKS.inc()
-                return None
-            out_idx[r] = ids[top]
-            out_scores[r] = scores[top]
-            if observe:
-                CANDIDATES.observe(cnt)
+                    INT8_RERANK.inc()
+            out_idx = np.empty((b, num), np.int64)
+            out_scores = np.empty((b, num), np.float32)
+            for r in range(b):
+                parts = np.sort(probe[r])  # ordered slices walk memory forward
+                cnt = int(counts[r])
+                ids = np.empty(cnt, np.int32)
+                scores = np.empty(cnt, np.float32)
+                qrow = q[r]
+                pos = 0
+                bnds = self.offsets[parts].tolist()
+                ubnds = self.offsets[parts + 1].tolist()
+                for p, lo, hi in zip(parts.tolist(), bnds, ubnds):
+                    m = hi - lo
+                    if not m:
+                        continue
+                    ids[pos:pos + m] = self.member_ids[lo:hi]
+                    if part_scores is not None:
+                        # rows come off each partition's iterator in ascending
+                        # query order — exactly this loop's visit order
+                        scores[pos:pos + m] = next(part_scores[p])
+                    else:
+                        scores[pos:pos + m] = \
+                            self.emb_m[lo:hi] @ qrow + self.bias_m[lo:hi]
+                    pos += m
+                if self.stale_ids is not None and len(self.stale_ids):
+                    ids, scores = self._apply_stale_overlay(ids, scores, qrow)
+                scores += user_bias[r] + mean
+                if excl_sorted is not None:
+                    pos = np.minimum(np.searchsorted(excl_sorted, ids),
+                                     len(excl_sorted) - 1)
+                    scores[excl_sorted[pos] == ids] = -np.inf
+                if row_mask is not None:
+                    scores += row_mask[r, ids]
+                top = topk_row(scores, num)
+                if not np.isfinite(scores[top[-1]]):
+                    # fewer than num candidates survived the rule filters in
+                    # THIS probe set — a masked (-inf) item would fill the
+                    # trailing slots where the exact path, seeing the whole
+                    # catalog, still has unmasked items to place. Fall back.
+                    if observe:
+                        FALLBACKS.inc()
+                    return None
+                out_idx[r] = ids[top]
+                out_scores[r] = scores[top]
+                if observe:
+                    CANDIDATES.observe(cnt)
         if observe:
-            RERANK_SEC.observe(time.perf_counter() - t0)
+            RERANK_SEC.observe(sp.duration)
             TWO_STAGE_BATCHES.inc()
         return out_idx, out_scores
 

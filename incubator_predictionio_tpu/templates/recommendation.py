@@ -48,6 +48,7 @@ from incubator_predictionio_tpu.models.two_tower import (
     TwoTowerMF,
     TwoTowerModel,
 )
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.parallel.mesh import MeshContext
 
 logger = logging.getLogger(__name__)
@@ -380,7 +381,8 @@ class RecModel(PersistentModel):
         # step 0 under a fresh sidecar would serve old embeddings with new
         # id maps; drop any prior state first
         ckpt.delete_all()
-        ckpt.save(0, self.mf._tables)
+        with span("train.persist.orbax"):
+            ckpt.save(0, self.mf._tables)
         meta = {
             "config": self.mf.config,
             "mean": self.mf.mean,
@@ -411,6 +413,7 @@ class RecModel(PersistentModel):
         import os
         import pickle
 
+        import jax
         import jax.numpy as jnp
 
         from incubator_predictionio_tpu.utils.checkpoint import (
@@ -418,8 +421,9 @@ class RecModel(PersistentModel):
         )
 
         d = cls._device_dir(model_id)
-        with open(os.path.join(d, "sidecar.pkl"), "rb") as f:
-            meta = pickle.load(f)
+        with span("deploy.load", part="sidecar"):
+            with open(os.path.join(d, "sidecar.pkl"), "rb") as f:
+                meta = pickle.load(f)
         cfg = meta["config"]
         # like-template fixes the restored leaves' placement: "model"-axis
         # row sharding when the deploy mesh has one (and the padded rows
@@ -437,12 +441,14 @@ class RecModel(PersistentModel):
             meta["n_items"], cfg.rank,
             trained.n_shards if trained is not None else 1)
 
-        like = {
-            k: jnp.zeros((rows, cfg.rank + 1), jnp.float32,
-                         device=row_sharding_for(ctx, rows, serve_shards))
-            for k, rows in meta["table_rows"].items()
-        }
-        tables = TrainCheckpointer(d, max_to_keep=1).restore(like=like)
+        with span("deploy.restore"):
+            like = {
+                k: jnp.zeros((rows, cfg.rank + 1), jnp.float32,
+                             device=row_sharding_for(ctx, rows, serve_shards))
+                for k, rows in meta["table_rows"].items()
+            }
+            tables = TrainCheckpointer(d, max_to_keep=1).restore(like=like)
+            jax.block_until_ready(tables)  # bill the restore here
         mf = TwoTowerModel(mean=meta["mean"], config=cfg)
         mf._tables = tables
         mf._n_users = meta["n_users"]
@@ -571,8 +577,9 @@ class ALSAlgorithm(PAlgorithm):
                 "ALSAlgorithmParams.num_iterations = %d > 30: long schedules "
                 "rarely help MF; consider lowering", p.num_iterations,
             )
-        user_map = BiMap({u: i for i, u in enumerate(pd.user_vocab)})
-        item_map = BiMap({t: i for i, t in enumerate(pd.item_vocab)})
+        with span("train.verb.bimaps"):
+            user_map = BiMap({u: i for i, u in enumerate(pd.user_vocab)})
+            item_map = BiMap({t: i for i, t in enumerate(pd.item_vocab)})
         cfg = TwoTowerConfig(
             rank=p.rank,
             learning_rate=p.learning_rate,
@@ -598,7 +605,8 @@ class ALSAlgorithm(PAlgorithm):
         # device-model sidecar or default model pickling), so the index ships
         # with the model and redeploys skip the re-cluster. No-op below the
         # auto threshold; prepare_for_serving still (re)builds on env drift.
-        mf._prepare_index()
+        with span("train.verb.index"):
+            mf._prepare_index()
         return RecModel(mf, user_map, item_map)
 
     @staticmethod
@@ -747,43 +755,47 @@ class ALSAlgorithm(PAlgorithm):
                 serve_bucket,
             )
 
-            banned = [self._banned(model, q) for _, q in known]
-            uidx = np.asarray([model.user_map[q.user] for _, q in known], np.int32)
-            inv = model.item_map.inverse()
-            n_items = model.mf.n_items
-            # gate on the BUCKET the dispatch will pad to — the same
-            # criterion warmup uses — so a row-mask dispatch always lands on
-            # a pre-compiled executable (never an XLA compile on a live path)
-            if any(banned) and serve_bucket(len(known)) * n_items <= ROW_MASK_MAX_ELEMENTS:
-                # per-query blacklists ride as a [B, n] row mask INTO the
-                # single scoring dispatch (ops/retrieval.py carries it
-                # through the Pallas kernel on the quantized path) — no
-                # over-fetch + host re-filter
-                num = max(q.num for _, q in known)
-                row_mask = np.zeros((len(known), n_items), np.float32)
-                for r, b in enumerate(banned):
-                    if b:
-                        row_mask[r, np.fromiter(b, np.int64)] = -np.inf
-                idx, scores = TwoTowerMF.recommend_batch(
-                    model.mf, uidx, num, row_mask=row_mask)
-                for (qi, q), row_idx, row_scores in zip(known, idx, scores):
-                    out.append((qi, PredictedResult(tuple(
-                        ItemScore(inv[int(i)], float(s))
-                        for i, s in zip(row_idx, row_scores) if np.isfinite(s)
-                    )[: q.num])))
-            else:
-                # huge catalogs (or no blacklists at all): a dense
-                # batch×catalog mask would cost more to build and ship than
-                # the scoring it filters — over-fetch a few extra columns
-                # and drop banned rows host-side instead
-                num = max(q.num + len(b) for (_, q), b in zip(known, banned))
-                idx, scores = TwoTowerMF.recommend_batch(model.mf, uidx, num)
+            # host work before the scorer: BiMap look-ups, banned sets, uidx
+            with span("retrieval.batch.lookup", batch=len(known)):
+                banned = [self._banned(model, q) for _, q in known]
+                uidx = np.asarray(
+                    [model.user_map[q.user] for _, q in known], np.int32)
+                inv = model.item_map.inverse()
+                n_items = model.mf.n_items
+                # gate on the BUCKET the dispatch will pad to — the same
+                # criterion warmup uses — so a row-mask dispatch always lands
+                # on a pre-compiled executable (never an XLA compile on a
+                # live path)
+                masked = any(banned) and (
+                    serve_bucket(len(known)) * n_items <= ROW_MASK_MAX_ELEMENTS)
+                if masked:
+                    # per-query blacklists ride as a [B, n] row mask INTO the
+                    # single scoring dispatch (ops/retrieval.py carries it
+                    # through the Pallas kernel on the quantized path) — no
+                    # over-fetch + host re-filter
+                    num = max(q.num for _, q in known)
+                    row_mask = np.zeros((len(known), n_items), np.float32)
+                    for r, b in enumerate(banned):
+                        if b:
+                            row_mask[r, np.fromiter(b, np.int64)] = -np.inf
+                else:
+                    # huge catalogs (or no blacklists at all): a dense
+                    # batch×catalog mask would cost more to build and ship
+                    # than the scoring it filters — over-fetch a few extra
+                    # columns and drop banned rows host-side instead
+                    num = max(q.num + len(b)
+                              for (_, q), b in zip(known, banned))
+                    row_mask = None
+            idx, scores = TwoTowerMF.recommend_batch(
+                model.mf, uidx, num, row_mask=row_mask)
+            # host work after it: the PredictedResult rows
+            with span("retrieval.batch.rows", batch=len(known)):
                 for (qi, q), b, row_idx, row_scores in zip(
                         known, banned, idx, scores):
                     out.append((qi, PredictedResult(tuple(
                         ItemScore(inv[int(i)], float(s))
                         for i, s in zip(row_idx, row_scores)
-                        if int(i) not in b and np.isfinite(s)
+                        if (masked or int(i) not in b) and np.isfinite(s)
                     )[: q.num])))
         return out
 

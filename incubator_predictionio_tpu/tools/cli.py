@@ -246,7 +246,7 @@ def cmd_train(args, storage: Storage) -> int:
         distributed=getattr(args, "distributed", False),
     )
     if getattr(args, "profile_dir", None):
-        from incubator_predictionio_tpu.utils.tracing import profile_trace
+        from incubator_predictionio_tpu.obs.profile import profile_trace
 
         trace = profile_trace(args.profile_dir)
     else:
@@ -655,7 +655,7 @@ def cmd_status(args, storage: Storage) -> int:
     devices = claim_devices()
     _out(f"Devices: {len(devices)} × {devices[0].platform}"
          f" ({devices[0].device_kind})")
-    from incubator_predictionio_tpu.utils.tracing import device_memory_report
+    from incubator_predictionio_tpu.obs.profile import device_memory_report
 
     for row in device_memory_report():
         if row["bytes_in_use"] is not None:

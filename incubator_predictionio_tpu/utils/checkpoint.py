@@ -191,7 +191,7 @@ def checkpointed_epochs(
     subgroup-collective rendezvous requires). Returns
     ``(params, opt_state, loss)``; ``loss`` is ``None`` when no epoch ran.
     """
-    from incubator_predictionio_tpu.utils.tracing import step_annotation
+    from incubator_predictionio_tpu.obs.trace import span
 
     ckpt, params, opt_state, start_epoch = maybe_resume(
         directory, every, keep, params, opt_state, epochs, mesh,
@@ -208,9 +208,11 @@ def checkpointed_epochs(
                 # next cross-process collective
                 on_chunk(e)
             chunk = min(every, epochs - e) if ckpt is not None else epochs - e
-            with step_annotation("train_epochs", e):
+            # one dispatch of the jitted schedule, fenced: the chunk's whole
+            # device time lies under this span on the profiler's timeline
+            with span("train.epochs.chunk", epoch=e, epochs=chunk):
                 params, opt_state, loss = train_epochs(params, opt_state, chunk)
-            loss.block_until_ready()
+                loss.block_until_ready()
             e += chunk
             if ckpt is not None:
                 ckpt.save(e, {"params": params, "opt": opt_state,

@@ -86,8 +86,12 @@ def adam_tree_init(params, moments_dtype: str = "float32"):
 
 
 def adam_apply(params, grads, state, lr: float, b1: float = 0.9,
-               b2: float = 0.999, eps: float = 1e-8):
+               b2: float = 0.999, eps: float = 1e-8, scopes=None):
     """One adam step; returns (new_params, new_state).
+
+    ``scopes`` (``{top-level key of params: name}``) puts each such leaf's
+    update under a ``jax.named_scope`` — metadata only, so a device trace
+    says which table an update belongs to.
 
     Bit-matches ``optax.adam`` update math in fp32-moments mode (same
     moment EMAs, bias correction by ``1-beta**t``, eps outside the sqrt) —
@@ -108,10 +112,22 @@ def adam_apply(params, grads, state, lr: float, b1: float = 0.9,
     def v32(g, v_):
         return b2 * v_.astype(jnp.float32) + (1.0 - b2) * (g * g)
 
-    new_p = jax.tree.map(
+    def tree_map(fn, *trees):
+        if not scopes:
+            return jax.tree.map(fn, *trees)
+
+        def scoped(path, *leaves):
+            name = scopes.get(getattr(path[0], "key", None)) if path else None
+            if name is None:
+                return fn(*leaves)
+            with jax.named_scope(name):
+                return fn(*leaves)
+        return jax.tree_util.tree_map_with_path(scoped, *trees)
+
+    new_p = tree_map(
         lambda p, g, m_, v_: p - lr * (m32(g, m_) / bc1)
         / (jnp.sqrt(v32(g, v_) / bc2) + eps),
         params, grads, m, v)
-    new_m = jax.tree.map(lambda g, m_: m32(g, m_).astype(m_.dtype), grads, m)
-    new_v = jax.tree.map(lambda g, v_: v32(g, v_).astype(v_.dtype), grads, v)
+    new_m = tree_map(lambda g, m_: m32(g, m_).astype(m_.dtype), grads, m)
+    new_v = tree_map(lambda g, v_: v32(g, v_).astype(v_.dtype), grads, v)
     return new_p, (count, new_m, new_v)

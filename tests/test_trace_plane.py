@@ -37,8 +37,9 @@ def _clean_trace_state(monkeypatch):
 
 def _span(trace_id, span_id, parent_id=None, name="op", service="svc",
           start=0.0, duration=0.001, status="ok", sampled=True) -> trace.Span:
-    sp = trace.Span(trace_id, span_id, parent_id, name, service, {},
-                    sampled=sampled)
+    sp = trace.Span(name, service)
+    sp.trace_id, sp.span_id, sp.parent_id = trace_id, span_id, parent_id
+    sp.sampled = sampled
     sp.start_unix = start
     sp.duration = duration
     sp.status = status

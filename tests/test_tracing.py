@@ -1,4 +1,5 @@
-"""Profiling hooks (utils/tracing.py): trace capture + memory report."""
+"""Profiling hooks (obs/profile.py): trace capture + memory report, with
+spans from the one primitive (obs/trace.span) inside the capture."""
 
 import os
 
@@ -6,21 +7,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from incubator_predictionio_tpu.utils.tracing import (
-    annotate,
+from incubator_predictionio_tpu.obs.profile import (
     device_memory_report,
     profile_trace,
-    step_annotation,
 )
+from incubator_predictionio_tpu.obs.trace import span
 
 
 def test_profile_trace_writes_tensorboard_profile(tmp_path):
     log_dir = str(tmp_path / "trace")
     with profile_trace(log_dir):
-        with annotate("matmul_block"):
+        with span("test.matmul.block"):
             x = jnp.ones((64, 64))
             for step in range(2):
-                with step_annotation("step", step):
+                with span("test.matmul.step", step=step):
                     (x @ x).block_until_ready()
     # standard layout: <log_dir>/plugins/profile/<run>/<files>
     profile_root = os.path.join(log_dir, "plugins", "profile")
@@ -37,7 +37,8 @@ def test_device_memory_report_shape():
 
 
 def test_two_tower_trains_under_trace(tmp_path):
-    """The epoch-loop step annotations must not break training."""
+    """The fit's spans (train.fit.*, train.epochs.chunk) must not break
+    training under a live profiler session."""
     from incubator_predictionio_tpu.models.two_tower import TwoTowerConfig, TwoTowerMF
     from incubator_predictionio_tpu.parallel.mesh import MeshContext
 
