@@ -729,13 +729,16 @@ class TwoTowerMF:
 
         # the fit's phases are spans (obs/trace.py): train.fit.order|h2d|
         # init|compute|gather land in /metrics, /profile.json, the trace
-        # ring and the profiler's timeline; model.timings reads them back
+        # ring and the profiler's timeline; model.timings reads them back.
+        # A fit orders twice: the host fixes each batch's composition (the
+        # first train.fit.order), the device sorts within the batches once
+        # they are staged (the second, which carries n_batches and batch)
         if rows_are_local and ctx.process_count > 1:
-            sp_order = None
             with span("train.fit.h2d") as sp_h2d:
                 ub, ib, rb, wb, mean = self._stage_local(
                     ctx, users, items, ratings)
                 jax.block_until_ready((ub, ib, rb, wb))
+            t_stage = sp_h2d.duration
         else:
             with span("train.fit.order") as sp_order:
                 mean = float(ratings.mean()) if n else 0.0
@@ -743,30 +746,29 @@ class TwoTowerMF:
                     min(cfg.batch_size, max(n, 1)))
                 n_batches = max(1, (n + global_batch - 1) // global_batch)
                 n_pad = n_batches * global_batch
+                # the rng's two draws, in this order, fix which triples
+                # share a batch: rng.permutation(n), which IS arange +
+                # shuffle, here in place in the one buffer (a 160 MB result
+                # copied into a second one costs more than the shuffle),
+                # then the padding, the tail of the last batch
                 rng = np.random.default_rng(cfg.seed)
-                perm = rng.permutation(n)
-                pad_idx = rng.integers(0, max(n, 1), n_pad - n)
-                order = np.concatenate([perm, pad_idx])
-                w = np.concatenate(
-                    [np.ones(n, np.float32), np.zeros(n_pad - n, np.float32)])
-                order, w = _sort_batches_by_entity(
-                    order, w, np.asarray(users, np.int32),
-                    n_batches, global_batch)
-
-            def stage(a, dtype):
-                a = np.asarray(a, dtype)[order] if len(a) == n else np.asarray(a, dtype)
-                a = a.reshape(n_batches, global_batch)
-                return ctx.put(a, None, ctx.data_axis)
+                order = np.arange(n_pad)
+                rng.shuffle(order[:n])
+                order[n:] = rng.integers(0, max(n, 1), n_pad - n)
 
             with span("train.fit.h2d") as sp_h2d:
-                ub = stage(users, np.int32)
-                ib = stage(items, np.int32)
-                rb = stage(ratings.astype(np.float32) - mean, np.float32)
-                wb = ctx.put(w.reshape(n_batches, global_batch), None, ctx.data_axis)
+                blocks = _stage_blocks(
+                    ctx, order, global_batch, np.asarray(users, np.int32),
+                    np.asarray(items, np.int32),
+                    ratings.astype(np.float32) - mean)
                 # phase fence: staging transfers (h2d) must bill to this
                 # span, not to whichever later one first blocks on the batches
+                jax.block_until_ready(blocks)
+            with span("train.fit.order", n_batches=n_batches,
+                      batch=global_batch) as sp_sort:
+                ub, ib, rb, wb = _order_blocks(ctx, blocks, n, global_batch)
                 jax.block_until_ready((ub, ib, rb, wb))
-        t_stage = sp_h2d.duration + (sp_order.duration if sp_order else 0.0)
+            t_stage = sp_order.duration + sp_h2d.duration + sp_sort.duration
         with span("train.fit.init") as sp_init:
             key = jax.random.key(cfg.seed)
             ku, ki = jax.random.split(key)
@@ -860,8 +862,8 @@ class TwoTowerMF:
                     config=cfg,
                 )
         model.final_loss = float(loss)
-        # exactly these four keys (the benchmark sums them): stage = order
-        # + h2d; full precision lives in the spans
+        # exactly these four keys (the benchmark sums them): stage = every
+        # order + h2d span of the fit; full precision lives in the spans
         model.timings = {
             "stage_sec": round(t_stage, 4),
             "init_sec": round(sp_init.duration, 4),
@@ -1164,13 +1166,18 @@ def _sort_batches_by_entity(
     order: np.ndarray, w: np.ndarray, entities: np.ndarray,
     n_batches: int, batch: int,
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Sort each batch's rows by entity (user) index, host-side at staging.
+    """Sort each batch's rows by entity (user) index, on the host.
 
     Batch composition — and therefore the math — is unchanged (the loss sums
     over the batch); only the within-batch ORDER changes, which lets the
     device gather/scatter walk the big user table quasi-sequentially
     (measured ~15% off the step time at 1M users). Returns the re-ordered
-    (order, w) pair; ``w`` rides along so padding rows keep zero weight."""
+    (order, w) pair; ``w`` rides along so padding rows keep zero weight.
+
+    Used by :meth:`TwoTowerMF._stage_local`, where each process sorts its
+    own ``b_local`` slice BEFORE a global array exists (a separate need, not
+    a second copy of :func:`_order_batches`, which every other fit takes),
+    and by the tests as the device sort's oracle (tests/test_batch_order.py)."""
     o2 = order.reshape(n_batches, batch)
     keys = entities[o2] if len(entities) else o2
     srt = np.argsort(keys, axis=1, kind="stable")
@@ -1178,6 +1185,100 @@ def _sort_batches_by_entity(
         np.take_along_axis(o2, srt, 1).reshape(-1),
         np.take_along_axis(w.reshape(n_batches, batch), srt, 1).reshape(-1),
     )
+
+
+# rows of one _order_batches dispatch. Its compile is dear at a wide batch
+# (14.5 s on a v5e at 65536 columns, whatever the rows) and its run cheap
+# (30 ms for 306 rows), so its shape is fixed, [_ORDER_ROWS, the batch width
+# padded to its power of two], and a fit loops over such blocks: the sort
+# compiles once for a batch size and not again as the event table grows.
+# Few, tall blocks keep the compile of _join_batches, which follows their
+# number, well under a second (PERF.md section 6, PR 25)
+_ORDER_ROWS = 64
+
+
+def _stage_blocks(ctx: MeshContext, order: np.ndarray, batch: int,
+                  users: np.ndarray, items: np.ndarray, ratings: np.ndarray):
+    """``users[order]``, ``items[order]``, ``ratings[order]`` as batches of
+    ``batch``, on the mesh unsorted, in the blocks :func:`_order_batches`
+    takes: a list of ``(ub, ib, rb)``, each ``[_ORDER_ROWS, width]``. The
+    random gathers stay on the host: a scalar gather is what the chip is
+    worst at (see train.fit.init). What pads a block to its shape holds
+    the largest int32 as its user, so it sorts behind every real column."""
+    per = _ORDER_ROWS * batch
+    width = ctx.pad_to_batch_multiple(1 << (batch - 1).bit_length())
+
+    def stage(a, fill=0):
+        for lo in range(0, len(order), per):
+            blk = a[order[lo:lo + per]].reshape(-1, batch)
+            if blk.shape != (_ORDER_ROWS, width):
+                blk = np.pad(blk, ((0, _ORDER_ROWS - len(blk)),
+                                   (0, width - batch)), constant_values=fill)
+            yield ctx.put(blk, None, ctx.data_axis)
+
+    return list(zip(stage(users, np.iinfo(np.int32).max), stage(items),
+                    stage(ratings)))
+
+
+def _order_blocks(ctx: MeshContext, blocks, n: int, batch: int):
+    """The staged blocks of :func:`_stage_blocks`, each sorted on the device
+    and joined: ``(ub, ib, rb, wb)`` as ``_train_epochs`` takes them, the
+    first ``n`` staged triples real. The blocks are donated."""
+    out = ctx.sharding(None, ctx.data_axis)
+    per = _ORDER_ROWS * batch
+    return _join_batches(
+        [_order_batches(*blk, min(n - k * per, per), batch, out)
+         for k, blk in enumerate(blocks)],
+        max(1, -(-n // batch)), batch, out)
+
+
+@partial(jax.jit, static_argnames=("out",), donate_argnums=(0, 1, 2))
+def _order_batches(ub, ib, rb, n_real, batch, out):
+    """The within-batch stable sort by user index, on the device: what
+    :func:`_sort_batches_by_entity` does on the host, over one block of
+    batches already staged unsorted ``[_ORDER_ROWS, width]``. Returns
+    ``(ub, ib, rb, wb)`` with every row of ``ub`` non-decreasing
+    (``_train_epochs`` gathers with ``indices_are_sorted=True``: a wrong sort
+    there is undefined behaviour, not an error) and ``wb`` the 0/1 weights
+    made here: of the block's triples, ``batch`` a row, the first ``n_real``
+    as staged are real, the rest the padding of the last batch.
+
+    ``batch`` and ``n_real`` are traced, so the executable depends on the
+    block's shape alone: a row's columns from ``batch`` on, and the rows
+    past the last batch, are there to make that shape. The user key of such
+    a column is the largest int32, so a row's first ``batch`` columns come
+    out as the batch; :func:`_join_batches` drops the rest.
+
+    The order is the stable one spelled as its definition: by (user, staged
+    column), a key that is unique in a row, so there is one answer wherever
+    the sort runs and the batches are the host sort's bit for bit. XLA's
+    ``is_stable`` does the same with a column operand of its own; sorting
+    ours lets ``wb`` be read off the sorted columns instead of riding as a
+    fifth operand (on a v5e at 306 x 65536: 14.5 s to compile and 30 ms to
+    run, against 20.0 s and 37 ms; PERF.md section 6, PR 25).
+
+    ``out`` (static) is the sharding ``_train_epochs`` takes the batches
+    in, ``(None, data_axis)``: a mesh with ``data`` > 1 gets the globally
+    sorted batch split as the host staging split it. The executable's name
+    ``jit__order_batches`` is pinned (tests/test_program_spans.py)."""
+    col = jax.lax.broadcasted_iota(jnp.int32, ub.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, ub.shape, 0)
+    ub, col, ib, rb = jax.lax.sort((ub, col, ib, rb), dimension=1,
+                                   is_stable=False, num_keys=2)
+    wb = ((col < batch) & (row * batch + col < n_real)).astype(jnp.float32)
+    return tuple(jax.lax.with_sharding_constraint(b, out)
+                 for b in (ub, ib, rb, wb))
+
+
+@partial(jax.jit, static_argnames=("n_batches", "batch", "out"))
+def _join_batches(blocks, n_batches, batch, out):
+    """The sorted blocks of :func:`_order_batches`, ``(ub, ib, rb, wb)``
+    each, as the four ``[n_batches, batch]`` arrays ``_train_epochs`` scans.
+    This is the executable that follows the event count: copies alone."""
+    return tuple(
+        jax.lax.with_sharding_constraint(
+            jnp.concatenate(bs)[:n_batches, :batch], out)
+        for bs in zip(*blocks))
 
 
 @partial(jax.jit, static_argnames=("lr", "reg", "n_epochs"), donate_argnums=(0, 1))
