@@ -1,0 +1,433 @@
+"""The latent-attention / routed-expert block of the sequence transformer
+(``TransformerConfig(attention_kind="mla")``; equations:
+models/reference/mla_moe.py, which this module is held to).
+
+- RMSNorm, low-rank query and key/value projections with a decoupled rotary
+  part: what a token leaves behind for later queries is its **latent**
+  ``[kv_lora_rank + qk_rope_head_dim]`` row (normalised ``ckv`` ‖ rotated
+  ``kr``), 320 values where full keys and values would be 8192;
+- two forms of the same attention over a context of latent rows: the
+  **up-projected** one rebuilds keys and values (long blocks), the
+  **absorbed** one carries the query into the latent space and the weighted
+  sum back out of it (short blocks against a long context);
+- sigmoid-scored top-k routing over ``n_routed_experts`` with a
+  selection-only bias and weights normalised over all k picks; gated-SiLU
+  experts as one grouped matmul (``jax.lax.ragged_dot``) over the
+  ``experts_held`` experts this chip holds, token-pick pairs sorted by
+  expert, no capacity and no dropped token; picks that fall on an expert
+  held elsewhere add nothing here. One shared expert beside them.
+
+Weights live in ``weight_dtype`` (bfloat16 when served, float32 when ``fit``
+trains a small instance); products accumulate in float32; norms, router and
+softmax are float32.
+
+Named scopes inside every executable, for the device trace: ``mla_proj``,
+``mla_attn``, ``moe_router``, ``moe_experts``, ``moe_shared``, ``head_topk``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from incubator_predictionio_tpu.models.reference.mla_moe import (
+    rope_amplitude,
+    softmax_scale,
+    yarn_inv_freq,
+)
+
+F32 = jnp.float32
+NEG = -1e30          # finite: a row with no visible key stays finite
+Q_CHUNK = 512        # queries a chunk in the up-projected form
+SCOPES = ("mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared",
+          "head_topk")
+#: per-layer device counters: held experts' routed picks, then picks that
+#: fell on absent experts, then held experts that got at least one pick
+#: (summed over dispatches)
+N_EXTRA_COUNTERS = 2
+
+
+def published(cfg) -> dict:
+    """``TransformerConfig`` → the reference's dict, under the published
+    config's key names."""
+    return {
+        "hidden_size": cfg.d_model, "num_attention_heads": cfg.n_heads,
+        "q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "rms_norm_eps": cfg.rms_norm_eps,
+        "n_routed_experts": cfg.n_routed_experts,
+        "num_experts_per_tok": cfg.experts_per_token,
+        "n_shared_experts": cfg.n_shared_experts,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "experts_held": cfg.experts_held or cfg.n_routed_experts,
+        "expert_offset": cfg.expert_offset,
+        "rope_parameters": dict(cfg.rope_parameters),
+    }
+
+
+def cache_width(cfg) -> int:
+    """Values a token's row takes in the serving cache: the latent row
+    padded to whole 128-lane tiles. (A row of 320 makes the TPU compiler lay
+    the cache out column-major, and every layer call then copies all of it
+    to scatter into it and copies it back: measured, PERF.md PR 26.)"""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def experts_held(cfg) -> int:
+    return cfg.experts_held or cfg.n_routed_experts
+
+
+def layer_shapes(cfg) -> dict:
+    """One layer's arrays: ``{name: (shape, float32-always?)}``."""
+    d, h = cfg.d_model, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    f, e = cfg.moe_intermediate_size, experts_held(cfg)
+    fs = f * cfg.n_shared_experts
+    return {
+        "norm1": ((d,), True), "norm2": ((d,), True),
+        "norm_q": ((cfg.q_lora_rank,), True),
+        "norm_kv": ((cfg.kv_lora_rank,), True),
+        "w_dq": ((d, cfg.q_lora_rank), False),
+        "w_uq": ((cfg.q_lora_rank, h * (dn + dr)), False),
+        "w_dkv": ((d, cfg.kv_lora_rank + dr), False),
+        "w_ukv": ((cfg.kv_lora_rank, h * (dn + dv)), False),
+        "w_o": ((h * dv, d), False),
+        "w_r": ((d, cfg.n_routed_experts), True),
+        "b_r": ((cfg.n_routed_experts,), True),
+        "we1": ((e, d, f), False), "we3": ((e, d, f), False),
+        "we2": ((e, f, d), False),
+        "ws1": ((d, fs), False), "ws3": ((d, fs), False),
+        "ws2": ((fs, d), False),
+    }
+
+
+def init_params(key, cfg) -> dict:
+    """Trainable initial parameters (``fit``): norms one, router bias zero,
+    matrices normal with fan-in scaling, embeddings and head at 0.02."""
+    wdt = jnp.dtype(cfg.weight_dtype)
+    keys = iter(jax.random.split(key, 2 + 16 * cfg.n_layers))
+
+    def normal(shape, scale, dtype=wdt):
+        return (jax.random.normal(next(keys), shape, F32) * scale).astype(dtype)
+
+    layers = []
+    for _ in range(cfg.n_layers):
+        lw = {}
+        for name, (shape, f32) in layer_shapes(cfg).items():
+            if name.startswith("norm"):
+                lw[name] = jnp.ones(shape, F32)
+            elif name == "b_r":
+                lw[name] = jnp.zeros(shape, F32)
+            else:
+                lw[name] = normal(shape, shape[-2] ** -0.5,
+                                  F32 if f32 else wdt)
+        layers.append(lw)
+    params = {"item_emb": normal((cfg.vocab_size, cfg.d_model), 0.02),
+              "norm_f": jnp.ones((cfg.d_model,), F32), "layers": layers}
+    if not cfg.tie_head:
+        params["head"] = normal((cfg.vocab_size, cfg.d_model), 0.02)
+    return params
+
+
+def head_matrix(params: dict):
+    return params.get("head", params["item_emb"])
+
+
+# -- pieces ------------------------------------------------------------------------
+
+def _precision(dtype):
+    # float32 weights are the small trained / tested instance: exact products
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _mm(x, w):
+    return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=F32,
+                      precision=_precision(w.dtype))
+
+
+def _einsum(spec, a, b, dtype):
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=F32, precision=_precision(dtype))
+
+
+def rms_norm(x, g, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
+
+
+def _rotate(x, pos, cfg):
+    """RoPE on the last axis of ``x`` ``[B, T, ..., dr]`` at ``pos``
+    ``[B, T]``: pairs ``(2i, 2i+1)``, yarn frequencies."""
+    rope = dict(cfg.rope_parameters)
+    inv_freq = jnp.asarray(yarn_inv_freq(rope, cfg.qk_rope_head_dim))
+    amp = rope_amplitude(rope)
+    ang = pos.astype(F32)[..., None] * inv_freq
+    shape = pos.shape + (1,) * (x.ndim - 3) + (inv_freq.shape[0],)
+    cos, sin = (jnp.cos(ang) * amp).reshape(shape), \
+        (jnp.sin(ang) * amp).reshape(shape)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                     -1).reshape(x.shape)
+
+
+def project(x, lw, cfg, pos):
+    """``x [B, T, d]`` → ``(q_nope [B, T, H, dn], q_rope [B, T, H, dr],
+    latent [B, T, kvr + dr])``: queries carry the softmax scale and the
+    long-context query factor; the latent row is what the cache keeps."""
+    b, t, _ = x.shape
+    h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    kvr, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
+    rope = dict(cfg.rope_parameters)
+    cq = rms_norm(_mm(x, lw["w_dq"]), lw["norm_q"], eps)
+    q = _mm(cq, lw["w_uq"]).reshape(b, t, h, dn + dr)
+    beta = float(rope.get("llama_4_scaling_beta", 0.0))
+    orig = float(rope["original_max_position_embeddings"])
+    factor = softmax_scale(published(cfg)) * (
+        1.0 + beta * jnp.log1p(jnp.floor(pos.astype(F32) / orig)))
+    q = q * factor[..., None, None]
+    kv = _mm(x, lw["w_dkv"])
+    ckv = rms_norm(kv[..., :kvr], lw["norm_kv"], eps)
+    kr = _rotate(kv[..., kvr:], pos, cfg)
+    wdt = lw["w_dkv"].dtype
+    latent = jnp.concatenate([ckv, kr], -1).astype(wdt)
+    return q[..., :dn], _rotate(q[..., dn:], pos, cfg), latent
+
+
+def _visible(q_index, key_valid, tc):
+    """``[B, 1, T, Tc]``: key j is seen by the query at absolute index i when
+    ``j <= i`` and the key is a real token of the session."""
+    causal = jnp.arange(tc)[None, None, :] <= q_index[:, :, None]
+    return (causal & key_valid[:, None, :])[:, None]
+
+
+def attend_up(q_nope, q_rope, ctx, q_index, key_valid, lw, cfg):
+    """Up-projected form: keys and values of the whole context are rebuilt
+    from its latent rows; queries go in chunks of ``Q_CHUNK`` so the score
+    matrix of a long block is never whole."""
+    b, t, h, dn = q_nope.shape
+    tc, kvr, dv = ctx.shape[1], cfg.kv_lora_rank, cfg.v_head_dim
+    wdt = lw["w_ukv"].dtype
+    kvu = _mm(ctx[..., :kvr], lw["w_ukv"]).reshape(b, tc, h, dn + dv)
+    k_nope, v = kvu[..., :dn], kvu[..., dn:]
+    k_rope = ctx[..., kvr:kvr + cfg.qk_rope_head_dim]
+
+    def chunk(args):
+        qn, qr, qi = args
+        s = (_einsum("bthd,bshd->bhts", qn, k_nope, wdt)
+             + _einsum("bthr,bsr->bhts", qr, k_rope, wdt))
+        p = jax.nn.softmax(
+            jnp.where(_visible(qi, key_valid, tc), s, NEG), axis=-1)
+        return _einsum("bhts,bshd->bthd", p, v, wdt)
+
+    if t <= Q_CHUNK:
+        out = chunk((q_nope, q_rope, q_index))
+    else:
+        n = t // Q_CHUNK
+
+        def split(a):
+            return jnp.moveaxis(
+                a.reshape((b, n, Q_CHUNK) + a.shape[2:]), 1, 0)
+
+        out = jax.lax.map(
+            chunk, (split(q_nope), split(q_rope), split(q_index)))
+        out = jnp.moveaxis(out, 0, 1).reshape(b, t, h, dv)
+    return out.reshape(b, t, h * dv)
+
+
+def attend_absorbed(q_nope, q_rope, ctx, q_index, key_valid, lw, cfg):
+    """Absorbed form: ``q_nope W_uk`` meets the latent rows directly and the
+    values are up-projected after the weighted sum; no key or value of the
+    context is ever rebuilt."""
+    b, t, h, dn = q_nope.shape
+    tc, kvr, dv = ctx.shape[1], cfg.kv_lora_rank, cfg.v_head_dim
+    wdt = lw["w_ukv"].dtype
+    w = lw["w_ukv"].reshape(kvr, h, dn + dv)
+    q_lat = _einsum("bthd,khd->bthk", q_nope, w[..., :dn], wdt)
+    q_all = jnp.concatenate([q_lat, q_rope], -1)
+    # (a served context's rows are padded to whole tiles: zeros meet zeros)
+    q_all = jnp.pad(q_all, [(0, 0)] * 3 + [(0, ctx.shape[-1] - q_all.shape[-1])])
+    s = _einsum("bthk,bsk->bhts", q_all, ctx, wdt)
+    p = jax.nn.softmax(
+        jnp.where(_visible(q_index, key_valid, tc), s, NEG), axis=-1)
+    o_lat = _einsum("bhts,bsk->bthk", p, ctx[..., :kvr], wdt)
+    return _einsum("bthk,khd->bthd", o_lat, w[..., dn:], wdt).reshape(
+        b, t, h * dv)
+
+
+ATTEND = {"up": attend_up, "absorbed": attend_absorbed}
+
+
+def moe_router(x, lw, cfg):
+    """``x [N, d]`` → ``(idx [N, k], w [N, k])``. Sigmoid scoring with a
+    selection-only bias; a softmax router would change the one line that
+    makes ``g``."""
+    g = jax.nn.sigmoid(jnp.matmul(
+        x.astype(F32), lw["w_r"], precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(g + lw["b_r"], cfg.experts_per_token)
+    gi = jnp.take_along_axis(g, idx, -1)
+    return idx, gi / gi.sum(-1, keepdims=True) * cfg.routed_scaling_factor
+
+
+def _gated(x, w1, w3, w2, dot):
+    a = jax.nn.silu(dot(x, w1)) * dot(x, w3)
+    return dot(a.astype(w2.dtype), w2)
+
+
+def moe_experts(x, idx, w, token_valid, lw, cfg):
+    """The routed experts held here: token-pick pairs sorted by expert, one
+    grouped matmul per expert matrix, unsorted, weighted, summed per token.
+    Pairs of padding tokens or of experts held elsewhere sort behind the
+    last group, where the grouped matmul does no work. Returns ``(y [N, d],
+    counters [held + 2])``."""
+    n, k = idx.shape
+    held = experts_held(cfg)
+    wdt = lw["we1"].dtype
+    local = idx - cfg.expert_offset
+    here = (local >= 0) & (local < held) & token_valid[:, None]
+    key = jnp.where(here, local, held).reshape(n * k)
+    order = jnp.argsort(key)                      # stable
+    group_sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
+    xs = x.astype(wdt)[order // k]
+
+    def dot(a, m):
+        return jax.lax.ragged_dot(a, m, group_sizes,
+                                  preferred_element_type=F32,
+                                  precision=_precision(wdt))
+
+    out = _gated(xs, lw["we1"], lw["we3"], lw["we2"], dot)
+    weight = jnp.where(here, w, 0.0).reshape(n * k)[order]
+    out = jnp.where((key[order] < held)[:, None], out, 0.0) * weight[:, None]
+    y = jnp.zeros((n * k, x.shape[-1]), F32).at[order].set(
+        out, unique_indices=True)
+    picks = token_valid.sum() * k
+    counters = jnp.concatenate([
+        group_sizes,
+        jnp.stack([picks - group_sizes.sum(),
+                   (group_sizes > 0).sum()]).astype(jnp.int32)])
+    return y.reshape(n, k, -1).sum(1), counters
+
+
+def moe_shared(x, lw):
+    return _gated(x.astype(lw["ws1"].dtype), lw["ws1"], lw["ws3"], lw["ws2"],
+                  _mm)
+
+
+def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form="up"):
+    """One block on ``h [B, T, d]`` (float32). ``context(latent)`` takes the
+    block's new latent rows and returns ``(ctx [B, Tc, kvr + dr], key_valid
+    [B, Tc], state)``: the block itself while training, the session cache
+    after the write while serving. Returns ``(h, counters, state)``."""
+    b, t, d = h.shape
+    eps = cfg.rms_norm_eps
+    x = rms_norm(h, lw["norm1"], eps)
+    with jax.named_scope("mla_proj"):
+        q_nope, q_rope, latent = project(x, lw, cfg, pos)
+    with jax.named_scope("mla_attn"):
+        ctx, key_valid, state = context(latent)  # cache write and gather
+        a = ATTEND[form](q_nope, q_rope, ctx, q_index, key_valid, lw, cfg)
+    with jax.named_scope("mla_proj"):
+        h = h + _mm(a, lw["w_o"])
+    x = rms_norm(h, lw["norm2"], eps).reshape(b * t, d)
+    with jax.named_scope("moe_router"):
+        idx, w = moe_router(x, lw, cfg)
+    with jax.named_scope("moe_experts"):
+        y, counters = moe_experts(x, idx, w, token_valid.reshape(b * t), lw,
+                                  cfg)
+    with jax.named_scope("moe_shared"):
+        y = y + moe_shared(x, lw)
+    return h + y.reshape(b, t, d), counters, state
+
+
+def forward(params, tokens, positions, cfg):
+    """Training forward over left-padded rows ``tokens [B, L]`` (0 =
+    padding; ``positions`` count a row's real tokens from 0) → final-normed
+    hidden ``[B, L, d]``. Padding is no key and is routed nowhere."""
+    wdt = params["item_emb"].dtype
+    h = params["item_emb"][tokens].astype(F32)
+    valid = tokens != 0
+    q_index = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    for lw in params["layers"]:
+        h, _, _ = layer_apply(
+            lw, h, cfg, positions, q_index, valid,
+            lambda latent: (latent.astype(wdt), valid, None))
+    return rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
+
+
+def xent_sum(h, head, targets, weights):
+    """``sum_t weights[t] * xent(h[t] head^T, targets[t])`` with float32
+    logits."""
+    lp = jax.nn.log_softmax(_mm(h, head.T), -1)
+    return -(weights * jnp.take_along_axis(lp, targets[:, None], -1)[:, 0]
+             ).sum()
+
+
+def real_positions(tokens: np.ndarray) -> np.ndarray:
+    """Positions of left-padded rows: the first real token is position 0."""
+    n_pad = (tokens == 0).sum(1, keepdims=True)
+    return np.maximum(np.arange(tokens.shape[1])[None, :] - n_pad, 0).astype(
+        np.int32)
+
+
+# -- serving steps: one executable a (batch, block) bucket, called a layer at a
+# time, so a bucket compiles one layer whatever the depth -------------------------
+
+def _block_geometry(pages, offsets, counts, t, page):
+    """From a dispatch's page table: the absolute index of every block
+    token, which of them are real, where each is written and the flat rows
+    of the whole context. Padding tokens write to page 0, which no session
+    owns."""
+    b, pc = pages.shape
+    step = jnp.arange(t)[None, :]
+    q_index = offsets[:, None] + step
+    token_valid = step < counts[:, None]
+    slot = jnp.take_along_axis(
+        pages, jnp.clip(q_index // page, 0, pc - 1), 1) * page \
+        + q_index % page
+    write = jnp.where(token_valid, slot, step % page)
+    read = (pages[:, :, None] * page + jnp.arange(page)).reshape(b, pc * page)
+    key_valid = jnp.arange(pc * page)[None, :] < (offsets + counts)[:, None]
+    return q_index, token_valid, write, read, key_valid
+
+
+def embed_step(item_emb, tok_cache, tokens, pages, offsets, counts, *, page):
+    """Embeds a block and notes its tokens beside the latent cache (the
+    history mask of ``head_step`` reads them back)."""
+    _, _, write, _, _ = _block_geometry(
+        pages, offsets, counts, tokens.shape[1], page)
+    return item_emb[tokens].astype(F32), tok_cache.at[write].set(tokens)
+
+
+def layer_step(lw, cache, counters, h, pages, offsets, counts, *, cfg, form):
+    """One layer of "extend a batch of sessions by a block each": the
+    block's latent rows are written to the sessions' pages, then every query
+    attends over its session's whole cached context."""
+    page = cfg.cache_page
+    q_index, token_valid, write, read, key_valid = _block_geometry(
+        pages, offsets, counts, h.shape[1], page)
+
+    def context(latent):
+        pad = cache.shape[-1] - latent.shape[-1]
+        new = cache.at[write].set(jnp.pad(latent, [(0, 0), (0, 0), (0, pad)]))
+        return new[read], key_valid, new
+
+    h, layer_counters, cache = layer_apply(
+        lw, h, cfg, q_index, q_index, token_valid, context, form)
+    return h, cache, counters + layer_counters
+
+
+def head_step(norm_f, head, tok_cache, h, pages, offsets, counts, *, cfg, k):
+    """Logits of each session's last real position over this chip's slice
+    of the vocabulary, padding and the session's own items masked, top-k."""
+    with jax.named_scope("head_topk"):
+        b, t, _ = h.shape
+        _, _, _, read, key_valid = _block_geometry(
+            pages, offsets, counts, t, cfg.cache_page)
+        last = jnp.clip(counts - 1, 0, t - 1)
+        x = rms_norm(h[jnp.arange(b), last], norm_f, cfg.rms_norm_eps)
+        logits = _mm(x, head.T)
+        seen = jnp.where(key_valid, tok_cache[read], 0)
+        logits = logits.at[jnp.arange(b)[:, None], seen].set(-jnp.inf)
+        return jax.lax.top_k(logits, k)

@@ -31,6 +31,9 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from incubator_predictionio_tpu.core.controller import PersistentModel
+from incubator_predictionio_tpu.models import latent_moe
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.parallel.mesh import MeshContext
 from incubator_predictionio_tpu.parallel.ring import (
     causal_attention,
@@ -79,9 +82,66 @@ class TransformerConfig:
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 0     # epochs between checkpoints
     checkpoint_keep: int = 3
+    # -- the block is chosen by the config -------------------------------
+    # "mha": the block above (LayerNorm, learned positions, as many KV heads
+    # as query heads, 4*d GELU FFN or the capacity-and-drop top-1 experts).
+    # "mla": models/latent_moe.py: RMSNorm, low-rank query / key-value
+    # projections with a decoupled rotary part (latent attention), routed
+    # gated-SiLU experts (sigmoid-scored, no dropped token) plus shared
+    # experts. The norm kind and the router's scoring follow from the block
+    attention_kind: str = "mha"
+    rms_norm_eps: float = 1e-6
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the published ``rope_parameters`` (yarn) as sorted (key, value) pairs
+    rope_parameters: tuple = ()
+    n_routed_experts: int = 0
+    experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    # one chip's share of an expert-parallel deployment: experts
+    # [expert_offset, expert_offset + experts_held) live here (0 = all); the
+    # router still scores every expert and normalises over all its picks
+    experts_held: int = 0
+    expert_offset: int = 0
+    tie_head: bool = True         # False: an output matrix of its own
+    weight_dtype: str = "float32"  # "bfloat16" when served at published widths
+    # serving: the latent cache's page (tokens) and size in tokens (live
+    # sessions x the length they may reach; 0 = 16 sessions of max_len)
+    cache_page: int = 128
+    cache_tokens: int = 0
+
+    def __post_init__(self):
+        if self.attention_kind == "mla":
+            if (not self.n_routed_experts or not self.rope_parameters
+                    or self.n_experts):
+                raise ValueError(
+                    "attention_kind='mla' is the latent-attention block: "
+                    "n_routed_experts > 0, rope_parameters set, n_experts "
+                    "(the old top-1 layer) 0")
+            if self.max_len % self.cache_page:
+                raise ValueError(
+                    f"max_len={self.max_len} must be a multiple of "
+                    f"cache_page={self.cache_page}")
+        elif self.attention_kind != "mha":
+            raise ValueError(f"unknown attention_kind {self.attention_kind!r}")
+        elif self.n_routed_experts or not self.tie_head:
+            raise ValueError(
+                "routed experts and an untied head belong to "
+                "attention_kind='mla'")
+
+    @property
+    def latent(self) -> bool:
+        return self.attention_kind == "mla"
 
 
 def _init_params(key, cfg: TransformerConfig):
+    if cfg.latent:
+        return latent_moe.init_params(key, cfg)
     k = iter(jax.random.split(key, 4 + 8 * cfg.n_layers))
     d, dh = cfg.d_model, cfg.d_model * 4
     init = lambda kk, shape, scale: jax.random.normal(kk, shape, jnp.float32) * scale
@@ -218,6 +278,9 @@ def _apply_layer(layer, h, cfg: TransformerConfig, mesh=None, use_ring=False,
 def _forward(params, tokens, positions, cfg: TransformerConfig,
              mesh=None, use_ring=False):
     """tokens, positions: [B, L] int32 → (hidden [B, L, D] fp32, aux loss)."""
+    if cfg.latent:
+        return (latent_moe.forward(params, tokens, positions, cfg),
+                jnp.float32(0.0))
     h = params["item_emb"][tokens] + params["pos_emb"][positions]
     aux_total = jnp.float32(0.0)
     token_mask = (tokens != 0) if cfg.n_experts else None
@@ -291,8 +354,11 @@ def _train_epochs_fn(cfg: TransformerConfig, mesh, use_ring: bool,
         # fused CE: fp32 [B, L, V] logits never materialize; beyond the
         # long-context threshold the logits matrix doesn't materialize in
         # ANY dtype (ops/xent.py — VERDICT r3 weak #4)
-        loss_sum = weighted_xent_sum(
-            h.reshape(-1, h.shape[-1]), p["item_emb"],
+        # (the latent block is trained small and held to a float32
+        # reference: its logits stay float32)
+        xent = latent_moe.xent_sum if cfg.latent else weighted_xent_sum
+        loss_sum = xent(
+            h.reshape(-1, h.shape[-1]), latent_moe.head_matrix(p),
             by.reshape(-1), bw.reshape(-1))
         task = loss_sum / jnp.maximum(jnp.sum(bw), 1.0)
         return task + cfg.router_aux_weight * aux
@@ -392,20 +458,110 @@ def _place_params_expert_sharded(ctx: MeshContext, host_params):
 
 
 @dataclasses.dataclass
-class TransformerModel:
+class TransformerModel(PersistentModel):
+    """Parameters + the item ↔ token map. The ``mha`` block's model is host
+    numpy and is pickled into MODELDATA (``save`` returns False); the latent
+    block's (``config.latent``) stays on the device and persists through the
+    PersistentModel SPI: an orbax checkpoint written from and restored to
+    device arrays, plus a small pickled sidecar (config, item map)."""
+
     params: dict
     item_map: object  # BiMap item id ↔ token (token 0 = padding)
     config: TransformerConfig
+    serving: object = None  # serving/latent_cache.LatentServing once deployed
+
+    @staticmethod
+    def _device_dir(model_id: str) -> str:
+        import os
+
+        from incubator_predictionio_tpu.utils.fs import subdir
+
+        return os.path.join(subdir("device_models"), model_id)
+
+    def save(self, model_id: str, params, ctx: MeshContext) -> bool:
+        if not self.config.latent:
+            return False
+        import os
+        import pickle
+
+        from incubator_predictionio_tpu.utils.checkpoint import (
+            TrainCheckpointer,
+        )
+
+        d = self._device_dir(model_id)
+        ckpt = TrainCheckpointer(d, max_to_keep=1)
+        ckpt.delete_all()  # a retrain in place must not keep the old step
+        with span("train.persist.orbax"):
+            ckpt.save(0, self.params)
+        shapes = jax.tree.map(lambda a: (a.shape, str(a.dtype)), self.params)
+        with open(os.path.join(d, "sidecar.pkl"), "wb") as f:
+            pickle.dump({"config": self.config, "item_map": self.item_map,
+                         "shapes": shapes}, f)
+        return True
+
+    @classmethod
+    def load(cls, model_id: str, params, ctx: MeshContext) -> "TransformerModel":
+        import os
+        import pickle
+
+        from incubator_predictionio_tpu.utils.checkpoint import (
+            TrainCheckpointer,
+        )
+
+        d = cls._device_dir(model_id)
+        with span("deploy.load", part="sidecar"):
+            with open(os.path.join(d, "sidecar.pkl"), "rb") as f:
+                meta = pickle.load(f)
+        with span("deploy.restore"):
+            # shapes only: a template of zeros would hold the model twice
+            where = jax.sharding.SingleDeviceSharding(
+                ctx.mesh.devices.flat[0])
+            like = jax.tree.map(
+                lambda sd: jax.ShapeDtypeStruct(
+                    sd[0], jnp.dtype(sd[1]), sharding=where),
+                meta["shapes"], is_leaf=lambda x: isinstance(x, tuple))
+            restored = TrainCheckpointer(d, max_to_keep=1).restore(like=like)
+            jax.block_until_ready(restored)  # bill the restore here
+        return cls(restored, meta["item_map"], meta["config"])
 
     def prepare_for_serving(self) -> "TransformerModel":
         self.params = jax.device_put(self.params)
+        if self.config.latent and self.serving is None:
+            from incubator_predictionio_tpu.serving.latent_cache import (
+                LatentServing,
+            )
+
+            with span("deploy.cache", layers=self.config.n_layers):
+                self.serving = LatentServing(self.params, self.config)
         return self
+
+    def warmup(self, max_batch: int = 64) -> int:
+        """Compile every dispatch shape of the latent block's ladder."""
+        if self.serving is None:
+            return 0
+        return self.serving.warmup(max_batch)
 
     def serving_info(self) -> dict:
         """Status-page observability (see TwoTowerModel.serving_info)."""
+        if self.serving is not None:
+            return self.serving.info()
         return {"path": "device-params",
                 "vocab": self.config.vocab_size,
                 "max_len": self.config.max_len}
+
+    def release(self) -> None:
+        """A retired deployment gives the device back: cache, executables
+        and weights (a stopped server object can outlive its use, and with
+        it this model)."""
+        if self.serving is not None:
+            self.serving.close()
+            self.serving = None
+        self.params = None
+
+    def __getstate__(self):
+        state = dict(self.__dict__)
+        state["serving"] = None  # device state is rebuilt at deploy
+        return state
 
 
 class TransformerRecommender:
@@ -455,13 +611,22 @@ class TransformerRecommender:
                 raise ValueError(
                     "pipeline parallelism composes with dp (and local "
                     "attention), not with ring attention or MoE")
+        if cfg.latent and (use_ring or cfg.pipeline_stages
+                           or cfg.tensor_parallel):
+            raise ValueError(
+                "the latent-attention block trains data-parallel only: no "
+                "ring attention, pipeline or tensor parallelism")
         tokens = sequences[:, :-1]
         targets = sequences[:, 1:]
         weights = (targets != 0).astype(np.float32) * (tokens != 0).astype(np.float32)
         n, l = tokens.shape
         if l != cfg.max_len:
             raise ValueError(f"sequences must be max_len+1 = {cfg.max_len + 1} wide")
-        positions = np.broadcast_to(np.arange(l, dtype=np.int32), (n, l))
+        if cfg.latent:
+            # rotary positions count a session's real tokens, as serving does
+            positions = latent_moe.real_positions(tokens)
+        else:
+            positions = np.broadcast_to(np.arange(l, dtype=np.int32), (n, l))
 
         if rows_are_local and ctx.process_count > 1:
             if use_ring:
@@ -596,7 +761,12 @@ class TransformerRecommender:
         final_loss = float(loss) if loss is not None else float("nan")
         t_train = _time.perf_counter() - t_train  # float(loss) blocked above
         t_gather = _time.perf_counter()
-        host_trained = ctx.host_gather(params)
+        if cfg.latent:
+            # device arrays in, device arrays out: the model persists through
+            # orbax (TransformerModel.save) and serves from where it lies
+            host_trained = jax.device_put(params, ctx.mesh.devices.flat[0])
+        else:
+            host_trained = ctx.host_gather(params)
         if use_pipeline:
             host_trained = _unstack_layers(host_trained, cfg.n_layers)
         model = TransformerModel(host_trained, item_map, cfg)

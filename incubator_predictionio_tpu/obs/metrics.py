@@ -437,9 +437,13 @@ class MetricsRegistry:
         with self._lock:
             self._collectors[key] = fn
 
-    def remove_collector(self, key: str) -> None:
+    def remove_collector(self, key: str,
+                         fn: Optional[Callable[[], None]] = None) -> None:
+        """Drop a collector; with ``fn``, only while it is still the one
+        registered under ``key`` (a successor may have replaced it)."""
         with self._lock:
-            self._collectors.pop(key, None)
+            if fn is None or self._collectors.get(key) == fn:
+                self._collectors.pop(key, None)
 
     # -- exposition -------------------------------------------------------
     def expose(self, exemplars: bool = False) -> str:

@@ -2060,6 +2060,9 @@ class QueryServer:
         if lag is not None:
             lag.cancel()
         await self.batcher.stop()
+        # the registry must not keep a stopped server (and through it the
+        # deployed models' device arrays) alive
+        REGISTRY.remove_collector(self.name, self._collect_metrics)
         # lifecycle flush for the trace spool: the drain's last spans (the
         # 503s it answered, the final dispatches) must reach disk before
         # the process exits

@@ -1,0 +1,425 @@
+"""Serving state of the latent-attention block (models/latent_moe.py): a
+device-resident, paged cache of per-token latent rows, the table of sessions
+that own its pages, and the one entry point that extends a batch of sessions
+by a block of new tokens each and returns each one's top-k next items.
+
+- The cache is ``n_layers`` arrays ``[pages * page, kv_lora_rank +
+  qk_rope_head_dim padded to whole 128-lane tiles]`` in the weights' dtype
+  plus one int32 array of the tokens themselves (the history mask reads it).
+  Page 0 belongs to nobody: padding writes land there.
+- A session is keyed by the query's ``user``. The table keeps the tokens it
+  has cached; an incoming list reuses the longest prefix that equals them,
+  token for token, and computes the rest (at least the last token, whose
+  hidden state the answer needs). Least-recently-used sessions are evicted
+  when pages run out. The answer never depends on the table: hit, partial
+  hit, miss and eviction compute the same function of the incoming list.
+- Block lengths come from a short ladder of buckets. The shortest bucket
+  batches sessions and uses the absorbed attention form; longer blocks go
+  one session a dispatch in the up-projected form, over a context no longer
+  than the block when the whole session fits it (a cold session does). The
+  choice of form is by block length alone. Each bucket is three executables (embed, one
+  layer, head + top-k), compiled once by ``warmup`` and called a layer at a
+  time, so a bucket compiles one layer whatever the depth.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+import re
+import threading
+import weakref
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from incubator_predictionio_tpu.models import latent_moe
+from incubator_predictionio_tpu.obs.metrics import REGISTRY
+from incubator_predictionio_tpu.obs.trace import span
+
+_TOKENS_COMPUTED = REGISTRY.counter(
+    "pio_seq_tokens_computed_total",
+    "Session tokens run through the block by the sequence template")
+_TOKENS_REUSED = REGISTRY.counter(
+    "pio_seq_tokens_reused_total",
+    "Session tokens answered from the latent cache (prefix already held)")
+_EVICTIONS = REGISTRY.counter(
+    "pio_seq_cache_evictions_total",
+    "Sessions evicted from the latent cache (least recently used first)")
+_CACHE_TOKENS = REGISTRY.gauge(
+    "pio_seq_cache_tokens", "Latent cache tokens by state (used, capacity)",
+    ("state",))
+_DISPATCHES = REGISTRY.counter(
+    "pio_seq_dispatches_total",
+    "Extend dispatches by (batch x block) bucket", ("bucket",))
+_EXPERT_TOKENS = REGISTRY.counter(
+    "pio_moe_expert_tokens_total",
+    "Token-picks routed to each expert held on this chip", ("layer", "expert"))
+_UNHELD = REGISTRY.counter(
+    "pio_moe_tokens_unheld_total",
+    "Token-picks that fell on experts held on other chips", ("layer",))
+_TOUCHED = REGISTRY.counter(
+    "pio_moe_experts_touched_total",
+    "Held experts that received at least one pick, summed over dispatches",
+    ("layer",))
+
+TOP_K = 16                       # the head's k the ladder is warmed for
+BLOCK_LADDER = (16, 128, 512, 1024, 1536, 2048, 3072)
+BATCH_LADDER = (4, 16, 64)
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', re.M)
+
+
+@dataclasses.dataclass
+class _Session:
+    tokens: np.ndarray            # what the cache holds for it, in order
+    pages: list
+
+
+@dataclasses.dataclass
+class _Block:
+    """One request as matched: compute ``tokens[offset:]`` at ``offset``."""
+    row: int
+    tokens: np.ndarray
+    offset: int
+    pages: list
+
+
+def _bucket(ladder: Sequence[int], n: int) -> int:
+    return next(b for b in ladder if b >= n)
+
+
+class LatentServing:
+    def __init__(self, params: dict, cfg):
+        self.cfg, self.params = cfg, params
+        self.page = cfg.cache_page
+        self.blocks = tuple(
+            b for b in BLOCK_LADDER if b < cfg.max_len) + (cfg.max_len,)
+        self.batches = BATCH_LADDER
+        self.device = next(iter(params["item_emb"].devices()))
+        width = latent_moe.cache_width(cfg)
+        wdt = params["item_emb"].dtype
+        self.bytes_per_token = cfg.n_layers * width * wdt.itemsize + 4
+        # ``cache_tokens`` is the operator's: live sessions x the length they
+        # may reach (default: 16 sessions of ``max_len``); never less than
+        # two whole sessions. Page 0 belongs to nobody.
+        tokens = cfg.cache_tokens or 16 * cfg.max_len
+        n_pages = max(tokens, 2 * cfg.max_len) // self.page + 1
+        rows = n_pages * self.page
+        with jax.default_device(self.device):
+            self.cache = [jnp.zeros((rows, width), wdt)
+                          for _ in range(cfg.n_layers)]
+            self.tok_cache = jnp.zeros((rows,), jnp.int32)
+            self.counters = [
+                jnp.zeros((latent_moe.experts_held(cfg)
+                           + latent_moe.N_EXTRA_COUNTERS,), jnp.int32)
+                for _ in range(cfg.n_layers)]
+        self.capacity_tokens = (n_pages - 1) * self.page
+        self._free = list(range(n_pages - 1, 0, -1))
+        self._sessions: "collections.OrderedDict[str, _Session]" = \
+            collections.OrderedDict()
+        self._exe: dict = {}
+        self._lock = threading.Lock()
+        self._published = np.zeros(
+            (cfg.n_layers, self.counters[0].shape[0]), np.int64)
+        _CACHE_TOKENS.labels(state="capacity").set(self.capacity_tokens)
+        # weakly: the registry must not keep a retired deployment's cache
+        # and weights on the device
+        me, key = weakref.ref(self), f"latent_serving:{id(self)}"
+        REGISTRY.add_collector(key, lambda: me() and me()._collect())
+        self._finalizer = weakref.finalize(
+            self, REGISTRY.remove_collector, key)
+
+    # -- executables --------------------------------------------------------------
+    def form(self, block: int) -> str:
+        return "absorbed" if block == self.blocks[0] else "up"
+
+    def ladder(self) -> list:
+        """Every (batch, block, context) bucket a dispatch can take. Short
+        blocks attend over a whole-length context; a long block whose
+        session fits the block itself (a cold session does) attends over
+        just that, else over the whole length."""
+        full = self.cfg.max_len
+        out = [(b, self.blocks[0], full) for b in self.batches]
+        for t in self.blocks[1:]:
+            out += [(1, t, t)] + ([(1, t, full)] if t < full else [])
+        return out
+
+    @staticmethod
+    def label(batch: int, block: int, ctx: int) -> str:
+        return f"{batch}x{block}@{ctx}"
+
+    def _compile(self, batch: int, block: int, ctx: int) -> dict:
+        cfg, page = self.cfg, self.page
+        tag = f"b{batch}_t{block}_c{ctx}"
+
+        def named(fn, name):
+            fn.__name__ = fn.__qualname__ = f"seq_{name}_{tag}"
+            return fn
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        small = (spec((batch, ctx // page), jnp.int32),
+                 spec((batch,), jnp.int32), spec((batch,), jnp.int32))
+        h = spec((batch, block, cfg.d_model), jnp.float32)
+        form = self.form(block)
+        # (the CPU backend cannot reuse a donated buffer and says so)
+        keep = self.device.platform == "cpu"
+        with jax.default_device(self.device):
+            embed = jax.jit(named(
+                lambda emb, toks, tokens, pages, offsets, counts:
+                latent_moe.embed_step(emb, toks, tokens, pages, offsets,
+                                      counts, page=page), "embed"),
+                donate_argnums=() if keep else (1,)).lower(
+                self.params["item_emb"], self.tok_cache,
+                spec((batch, block), jnp.int32), *small).compile()
+            layer = jax.jit(named(
+                lambda lw, cache, counters, h, pages, offsets, counts:
+                latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
+                                      counts, cfg=cfg, form=form), "layer"),
+                donate_argnums=() if keep else (1, 2, 3)).lower(
+                self.params["layers"][0], self.cache[0], self.counters[0], h,
+                *small).compile()
+        return {"embed": embed, "layer": layer,
+                "head": {TOP_K: self._compile_head(batch, block, ctx, TOP_K)}}
+
+    def _compile_head(self, batch: int, block: int, ctx: int, k: int):
+        cfg = self.cfg
+
+        def head(norm_f, head, toks, h, pages, offsets, counts):
+            return latent_moe.head_step(norm_f, head, toks, h, pages, offsets,
+                                        counts, cfg=cfg, k=k)
+
+        head.__name__ = head.__qualname__ = \
+            f"seq_head_b{batch}_t{block}_c{ctx}"
+        int32 = jnp.int32
+        with jax.default_device(self.device):
+            return jax.jit(head).lower(
+                self.params["norm_f"], latent_moe.head_matrix(self.params),
+                self.tok_cache,
+                jax.ShapeDtypeStruct((batch, block, cfg.d_model), jnp.float32),
+                jax.ShapeDtypeStruct((batch, ctx // self.page), int32),
+                jax.ShapeDtypeStruct((batch,), int32),
+                jax.ShapeDtypeStruct((batch,), int32)).compile()
+
+    def warmup(self, max_batch: int = 64) -> int:
+        """Compiles every bucket of the ladder and runs it once on page 0
+        (one ``deploy.warmup.bucket`` span each). Batches of short blocks go
+        up to ``max_batch``."""
+        self.batches = tuple(
+            b for b in BATCH_LADDER if b < max_batch) + (max_batch,)
+        with span("deploy.warmup", buckets=len(self.ladder())):
+            for bucket in self.ladder():
+                with span("deploy.warmup.bucket", bucket=self.label(*bucket),
+                          path=f"latent-{self.form(bucket[1])}"):
+                    self._exe[bucket] = self._compile(*bucket)
+                    empty = _Block(0, np.zeros(0, np.int32), 0, [])
+                    self._dispatch([empty], *bucket, count=False)
+        return len(self._exe)
+
+    def device_scopes(self) -> dict:
+        """``{executable name: {HLO instruction: named scope}}`` from the
+        compiled programs' own metadata: a device trace names operations by
+        instruction, and this is what tells ``moe_experts`` from
+        ``mla_attn`` inside one executable."""
+        out = {}
+        for (batch, block, ctx), exes in self._exe.items():
+            for kind in ("layer", "head"):
+                found = {}
+                exe = exes[kind][TOP_K] if kind == "head" else exes[kind]
+                for name, op in _INSTRUCTION.findall(exe.as_text()):
+                    parts = [p for p in op.split("/")
+                             if p in latent_moe.SCOPES]
+                    if parts:
+                        found[name] = parts[-1]
+                    elif op.startswith("ragged-dot"):
+                        # the TPU compiler's grouped-matmul kernel comes
+                        # back without the scope it was traced under; the
+                        # routed experts are the only grouped matmul here
+                        found[name] = "moe_experts"
+                out[f"jit_seq_{kind}_b{batch}_t{block}_c{ctx}"] = found
+        return out
+
+    # -- the session table ---------------------------------------------------------
+    def _take_pages(self, n: int, busy: set) -> list:
+        while len(self._free) < n:
+            victim = next((k for k in self._sessions if k not in busy), None)
+            if victim is None:
+                raise RuntimeError(
+                    "the latent cache is too small for this batch "
+                    f"({self.capacity_tokens} tokens)")
+            self._free.extend(self._sessions.pop(victim).pages)
+            _EVICTIONS.inc()
+        return [self._free.pop() for _ in range(n)]
+
+    def _match(self, row: int, key: Optional[str], tokens: np.ndarray,
+               busy: set, release: list) -> _Block:
+        sess = self._sessions.pop(key, None) if key is not None else None
+        if sess is None:
+            sess, reuse = _Session(tokens[:0], []), 0
+        else:
+            n = min(len(sess.tokens), len(tokens))
+            differ = np.flatnonzero(sess.tokens[:n] != tokens[:n])
+            reuse = int(differ[0]) if len(differ) else n
+        reuse = min(reuse, len(tokens) - 1)
+        need = -(-len(tokens) // self.page) - len(sess.pages)
+        if need > 0:
+            sess.pages = sess.pages + self._take_pages(need, busy)
+        elif need < 0:
+            release.extend(sess.pages[need:])
+            sess.pages = sess.pages[:need]
+        sess.tokens = tokens
+        if key is not None:
+            self._sessions[key] = sess        # most recently used
+        else:
+            release.extend(sess.pages)        # nobody can come back to it
+        _TOKENS_REUSED.inc(reuse)
+        _TOKENS_COMPUTED.inc(len(tokens) - reuse)
+        return _Block(row, tokens, reuse, list(sess.pages))
+
+    # -- the entry point -------------------------------------------------------------
+    def extend(self, requests: Sequence[tuple], encode=None,
+               num: int = TOP_K) -> tuple:
+        """``[(key or None, session)]`` → ``(scores [R, k], tokens [R, k])``
+        of each session's last position, best first, padding and the
+        session's own tokens masked; ``k >= num``. ``encode`` turns a session
+        as given into its int32 tokens (default: it is them already). A
+        session with no token gets a row of ``-inf``."""
+        k = TOP_K if num <= TOP_K else min(
+            1 << (num - 1).bit_length(), self.cfg.vocab_size)
+        with self._lock:
+            release: list = []
+            with span("seq.batch.match", sessions=len(requests)) as sp:
+                busy = {key for key, _ in requests if key is not None}
+                blocks = []
+                for row, (key, session) in enumerate(requests):
+                    tokens = np.asarray(
+                        encode(session) if encode else session, np.int32)
+                    if len(tokens):
+                        blocks.append(
+                            self._match(row, key, tokens, busy, release))
+                hits = sum(b.offset > 0 for b in blocks)
+                sp.set_attr("hits", hits)
+                sp.set_attr("misses", len(blocks) - hits)
+                sp.set_attr("reused", sum(b.offset for b in blocks))
+            scores = np.full((len(requests), k), -np.inf, np.float32)
+            items = np.zeros((len(requests), k), np.int32)
+            for group, bucket in self._plan(
+                    [requests[b.row][0] for b in blocks], blocks):
+                vals, idx = self._dispatch(group, *bucket, k)
+                rows = [b.row for b in group]
+                scores[rows], items[rows] = vals[:len(rows)], idx[:len(rows)]
+            self._free.extend(release)
+            used = sum(len(s.pages) for s in self._sessions.values())
+            _CACHE_TOKENS.labels(state="used").set(used * self.page)
+        return scores, items
+
+    def _plan(self, keys, blocks):
+        """Dispatches in order. Two requests of one session never share a
+        dispatch (they would write the same pages): the later one waits for
+        the next round."""
+        rounds, depth = [], {}
+        for key, blk in zip(keys, blocks):
+            r = depth.get(key, 0) if key is not None else 0
+            if key is not None:
+                depth[key] = r + 1
+            while len(rounds) <= r:
+                rounds.append([])
+            rounds[r].append(blk)
+        short, full = self.blocks[0], self.cfg.max_len
+        for members in rounds:
+            quick = [b for b in members if len(b.tokens) - b.offset <= short]
+            for i in range(0, len(quick), self.batches[-1]):
+                group = quick[i:i + self.batches[-1]]
+                yield group, (_bucket(self.batches, len(group)), short, full)
+            for b in members:
+                n = len(b.tokens) - b.offset
+                if n > short:
+                    block = _bucket(self.blocks, n)
+                    yield [b], (1, block,
+                                block if len(b.tokens) <= block else full)
+
+    def _head(self, batch: int, block: int, ctx: int, k: int):
+        """The head + top-k executable of a bucket at ``k``; the ladder is
+        warmed at ``TOP_K``, a larger ``num`` compiles its own when first
+        asked for."""
+        exe = self._exe[batch, block, ctx]
+        if k not in exe["head"]:
+            exe["head"][k] = self._compile_head(batch, block, ctx, k)
+        return exe["head"][k]
+
+    def _dispatch(self, group: list, batch: int, block: int, ctx: int,
+                  k: int = TOP_K, count: bool = True) -> tuple:
+        exe = self._exe[batch, block, ctx]
+        form = self.form(block)
+        n_new = sum(len(b.tokens) - b.offset for b in group)
+        with span("seq.batch.extend", bucket=self.label(batch, block, ctx),
+                  tokens=n_new, form=form):
+            tokens = np.zeros((batch, block), np.int32)
+            pages = np.zeros((batch, ctx // self.page), np.int32)
+            offsets = np.zeros((batch,), np.int32)
+            counts = np.zeros((batch,), np.int32)
+            for i, b in enumerate(group):
+                new = b.tokens[b.offset:]
+                tokens[i, :len(new)] = new
+                pages[i, :len(b.pages)] = b.pages
+                offsets[i], counts[i] = b.offset, len(new)
+            small = (pages, offsets, counts)
+            h, self.tok_cache = exe["embed"](
+                self.params["item_emb"], self.tok_cache, tokens, *small)
+            for i, lw in enumerate(self.params["layers"]):
+                h, self.cache[i], self.counters[i] = exe["layer"](
+                    lw, self.cache[i], self.counters[i], h, *small)
+            out = self._head(batch, block, ctx, k)(
+                self.params["norm_f"], latent_moe.head_matrix(self.params),
+                self.tok_cache, h, *small)
+            vals, idx = jax.device_get(out)
+        if count:
+            _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
+        return vals, idx
+
+    # -- what the status page and /metrics show ----------------------------------------
+    def _collect(self) -> None:
+        """The device's per-layer expert counters, read when ``/metrics`` is
+        read: the counters families advance by what was added since."""
+        with self._lock:
+            now = np.stack(jax.device_get(self.counters)).astype(np.int64)
+        delta, self._published = now - self._published, now
+        held = latent_moe.experts_held(self.cfg)
+        for layer, row in enumerate(delta):
+            for j in np.flatnonzero(row[:held]):
+                _EXPERT_TOKENS.labels(
+                    layer=str(layer),
+                    expert=str(self.cfg.expert_offset + j)).inc(int(row[j]))
+            _UNHELD.labels(layer=str(layer)).inc(int(row[held]))
+            _TOUCHED.labels(layer=str(layer)).inc(int(row[held + 1]))
+
+    def info(self) -> dict:
+        cfg = self.cfg
+        return {
+            "path": "device-latent-cache",
+            "cache_capacity_tokens": self.capacity_tokens,
+            "cache_page": self.page,
+            "cache_bytes_per_token": self.bytes_per_token,
+            "sessions": len(self._sessions),
+            "buckets": [f"{self.label(*b)}:{self.form(b[1])}"
+                        for b in self.ladder()],
+            "short_block": self.blocks[0],
+            "experts_held": latent_moe.experts_held(cfg),
+            "expert_offset": cfg.expert_offset,
+            "n_routed_experts": cfg.n_routed_experts,
+            "vocab": cfg.vocab_size, "max_len": cfg.max_len,
+            "weight_dtype": cfg.weight_dtype,
+        }
+
+    def close(self) -> None:
+        """Gives the device back: the cache, the counters, the executables
+        and this object's hold on the weights."""
+        self._finalizer()
+        with self._lock:
+            self.cache = self.counters = self.tok_cache = self.params = None
+            self._exe.clear()
+            self._sessions.clear()

@@ -11,6 +11,16 @@ Query: ``{"recent_items": [...], "num": N}`` scores the next item after an
 explicit session, or ``{"user": U, "num": N}`` reads the user's recent
 view/buy events live from the event store (LEventStore, like the ecommerce
 template's serving-time reads).
+
+With the latent-attention block (``attentionKind: "mla"``) the deployed
+model keeps a device-resident latent cache per session
+(serving/latent_cache.py). ``recent_items`` is still the whole session as the
+application knows it; ``user``, when given WITH it, is the **cache key**: the
+server reuses the longest prefix of the incoming list that equals what it has
+cached under that key, token for token, and computes only the rest. The
+answer never depends on the cache (a hit, a partial hit, a miss and an
+evicted session all give the same scores); a query without ``user``, or with
+``user`` alone, is computed whole.
 """
 
 from __future__ import annotations
@@ -42,6 +52,7 @@ from incubator_predictionio_tpu.models.transformer import (
     TransformerModel,
     TransformerRecommender,
 )
+from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.parallel.mesh import MeshContext
 
 logger = logging.getLogger(__name__)
@@ -250,6 +261,28 @@ class TransformerAlgorithmParams(Params):
     recent_events: tuple[str, ...] = ("view", "buy")
     checkpoint_dir: Optional[str] = None   # mid-training resume (utils/checkpoint.py)
     checkpoint_every: int = 0
+    # the block (models/transformer.py TransformerConfig): "mha" or "mla",
+    # the latent-attention / routed-expert block, whose sizes follow under
+    # the published config's names (d_model / n_heads / n_layers above)
+    attention_kind: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rms_norm_eps: float = 1e-6
+    rope_parameters: Optional[dict] = None
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0         # this chip's share (0 = all), from expert_offset
+    expert_offset: int = 0
+    tie_head: bool = True
+    weight_dtype: str = "float32"
+    cache_page: int = 128         # latent cache: tokens a page, and its size
+    cache_tokens: int = 0         # in tokens (0 = 16 sessions of max_len)
 
 
 class TransformerAlgorithm(PAlgorithm):
@@ -261,10 +294,27 @@ class TransformerAlgorithm(PAlgorithm):
         super().__init__(params)
         self._levents = LEventStore()
 
-    def train(self, ctx: MeshContext, pd: TrainingData) -> TransformerModel:
+    def model_config(self, vocab_size: int) -> TransformerConfig:
         p = self.params
-        cfg = TransformerConfig(
-            vocab_size=len(pd.item_map) + 1,
+        latent = {}
+        if p.attention_kind == "mla":
+            latent = dict(
+                rms_norm_eps=p.rms_norm_eps,
+                q_lora_rank=p.q_lora_rank, kv_lora_rank=p.kv_lora_rank,
+                qk_nope_head_dim=p.qk_nope_head_dim,
+                qk_rope_head_dim=p.qk_rope_head_dim, v_head_dim=p.v_head_dim,
+                rope_parameters=tuple(sorted(
+                    (p.rope_parameters or {}).items())),
+                n_routed_experts=p.n_routed_experts,
+                experts_per_token=p.num_experts_per_tok,
+                moe_intermediate_size=p.moe_intermediate_size,
+                n_shared_experts=p.n_shared_experts,
+                routed_scaling_factor=p.routed_scaling_factor,
+                experts_held=p.experts_held, expert_offset=p.expert_offset,
+                tie_head=p.tie_head, weight_dtype=p.weight_dtype,
+                cache_page=p.cache_page, cache_tokens=p.cache_tokens)
+        return TransformerConfig(
+            vocab_size=vocab_size,
             max_len=p.max_len,
             d_model=p.d_model,
             n_heads=p.n_heads,
@@ -281,7 +331,11 @@ class TransformerAlgorithm(PAlgorithm):
             tensor_parallel=p.tensor_parallel,
             checkpoint_dir=p.checkpoint_dir,
             checkpoint_every=p.checkpoint_every,
+            attention_kind=p.attention_kind, **latent,
         )
+
+    def train(self, ctx: MeshContext, pd: TrainingData) -> TransformerModel:
+        cfg = self.model_config(len(pd.item_map) + 1)
         return TransformerRecommender(cfg).fit(
             ctx, pd.sequences, pd.item_map,
             rows_are_local=pd.rows_are_local)
@@ -310,6 +364,8 @@ class TransformerAlgorithm(PAlgorithm):
     ) -> list[tuple[int, PredictedResult]]:
         if not queries:
             return []
+        if model.config.latent:
+            return self._batch_predict_latent(model, queries)
         histories = [self._history(q, model) for _, q in queries]
         rows = np.stack([
             encode_session(h, model.item_map, model.config.max_len)
@@ -335,6 +391,36 @@ class TransformerAlgorithm(PAlgorithm):
                 ItemScore(inv[int(t)], float(s[t]))
                 for t in top if np.isfinite(s[t])
             ))))
+        return out
+
+
+    def _batch_predict_latent(self, model, queries):
+        """The latent block's path: sessions are matched against the device
+        cache, extended by what is new and ranked on the device; only the
+        top rows come back."""
+        if model.serving is None:   # eval / batchpredict outside a deploy
+            model.prepare_for_serving().warmup()
+        item_map, width = model.item_map, model.config.max_len
+
+        def encode(items):
+            tokens = [item_map[i] for i in items if i in item_map]
+            return np.asarray(tokens[-width:], np.int32)
+
+        requests = [
+            # ``user`` keys the cache only beside the session it names
+            (q.user if q.recent_items is not None else None,
+             self._history(q, model)) for _, q in queries]
+        scores, tokens = model.serving.extend(
+            requests, encode, max(q.num for _, q in queries))
+        with span("seq.batch.rows", rows=len(queries)):
+            inv = item_map.inverse()
+            out = []
+            for (qi, q), s, t in zip(queries, scores, tokens):
+                keep = np.isfinite(s[:max(q.num, 0)])
+                out.append((qi, PredictedResult(tuple(
+                    ItemScore(inv[int(tok)], float(sc))
+                    for tok, sc in zip(t[:len(keep)][keep],
+                                       s[:len(keep)][keep])))))
         return out
 
 
