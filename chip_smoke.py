@@ -81,7 +81,8 @@ N_SERIAL, N_BURST, NUM = 32, 64, 10
 #: server counters that say which path answered (docs/observability.md)
 COUNTERS = ("pio_retrieval_two_stage_total", "pio_retrieval_fallback_total",
             "pio_retrieval_int8_coarse_total",
-            "pio_retrieval_int8_rerank_total", "pio_shard_batches_total",
+            "pio_retrieval_int8_rerank_total",
+            "pio_retrieval_device_rerank_total", "pio_shard_batches_total",
             "pio_shard_fallback_total", "pio_shard_full_gather_total")
 MIN_RECALL = 0.9
 

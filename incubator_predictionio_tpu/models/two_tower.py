@@ -215,8 +215,17 @@ class TwoTowerModel:
             _profile.fence(self._device_users, self._device_items,
                            self._device_items_q)
         if build_index:
+            from incubator_predictionio_tpu.serving import ann
+
             with span("deploy.index"):
                 self._prepare_index()
+                if (self._ivf is not None and self._device_users is not None
+                        and ann.two_stage_enabled(self.n_items)
+                        and kernel_backend()):
+                    # towers and kernels are on a device: the index's int8
+                    # tables join them, and two-stage retrieval runs as one
+                    # device leg (serving/ann.IVFIndex.search_device)
+                    self._ivf.prepare_device()
         return self
 
     def _prepare_index(self) -> None:
@@ -401,9 +410,10 @@ class TwoTowerModel:
             self._sharded is not None and any(self._sharded.ivf or ()))
         two_stage = has_ivf and ann.two_stage_enabled(self.n_items)
         if two_stage and self._ivf is not None:
-            # the two-stage path reads the towers on the host: pull them
-            # under their own span (deploy.ensure_host), not inside the
-            # first warm-up dispatch
+            # the two-stage host routine reads the towers on the host: pull
+            # them under their own span (deploy.ensure_host), not inside
+            # the first warm-up dispatch or a live batch that finds the
+            # device leg gone (an overlay, a flipped knob)
             self.ensure_host()
         with span("deploy.warmup", max_batch=max_batch):
             return self._warmup_buckets(max_batch, two_stage)
@@ -426,15 +436,19 @@ class TwoTowerModel:
                         for i in self._sharded.ivf or ()))
             if quantized and kernel_backend():
                 # the int8 coarse kernel pads queries to power-of-two
-                # buckets (serving/ann._probe_tpu): compile each bucket's
-                # `ivf_coarse_int8` executable now so no live batch shape
-                # pays it (jitstats names them; the batch-1 prime above
-                # already built the ≤8 bucket)
+                # buckets (serving/ann.coarse_bucket), and the device leg's
+                # other two executables follow it: compile each bucket's
+                # now so no live batch shape pays it (jitstats names them;
+                # the batch-1 prime above already built the ≤8 bucket)
+                from incubator_predictionio_tpu.serving.ann import (
+                    coarse_bucket,
+                )
+
                 seen = {8}
                 for b in SERVE_BUCKETS:
                     if b > max(1, max_batch):
                         break
-                    bp = 1 << max(3, (b - 1).bit_length())
+                    bp = coarse_bucket(b)
                     if bp in seen:
                         continue
                     seen.add(bp)
@@ -1122,15 +1136,36 @@ def _recommend_batch_two_stage(
     the exact math, and ``exclude``/``row_mask`` land on the rerank scores
     in candidate-index space after the gather. Returns None when the probe
     can't cover ``num`` candidates — the caller's exact path answers."""
-    if not model._ivf.hydrated:
+    from incubator_predictionio_tpu.serving import ann
+
+    ivf = model._ivf
+    filtered = row_mask is not None or (
+        exclude is not None and len(exclude) > 0)
+    backend = kernel_backend() if (
+        not filtered and ivf.device_ready
+        and model._device_users is not None
+        and ann.quant_coarse_enabled(True)) else None
+    if backend:
+        # which routine runs follows what is resident and what the batch
+        # carries, not a setting. The queries are the bfloat16 rows the
+        # exact path scores with: the fused float32 [U, D+1] tower lies
+        # column-major on the device, and a row gather from it copies all
+        # of it. A rule-filtered batch stays with the host routine below:
+        # search_device answers it alike, but a dense catalog-length mask
+        # a batch costs more to make and send (10-15 MB at a bucket of 8,
+        # 17 ms on the v5e host) than the host rerank it would save
+        return ivf.search_device(
+            user_idx, model._device_users, model.mean, num,
+            k=model._serve_k, interpret=backend == "interpret")
+    if not ivf.hydrated:
         # persisted slim and this model never ran _prepare_index (e.g. a
         # build_index=False prepare): rebuild the rerank tables lazily
-        model._ivf.rehydrate(*model._host_item_table())
+        ivf.rehydrate(*model._host_item_table())
     model.ensure_host()  # no-op unless the towers are device-resident
     uidx = np.asarray(user_idx, np.int64)
     q = np.asarray(model.user_emb, np.float32)[uidx]
     ub = np.asarray(model.user_bias, np.float32)[uidx]
-    return model._ivf.search(
+    return ivf.search(
         q, ub, model.mean, num, exclude=exclude, row_mask=row_mask)
 
 

@@ -44,6 +44,15 @@ def quantize_rows(items: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return q, scale[:, 0]
 
 
+def _quantize_rows_traced(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """:func:`quantize_rows` inside a jitted program: the same symmetric
+    per-row contract, ``(int8 rows, f32 scales [rows])``."""
+    amax = jnp.abs(rows).max(axis=1, keepdims=True)
+    scale = (amax / 127.0 + 1e-12).astype(jnp.float32)
+    q = jnp.clip(jnp.round(rows / scale), -127, 127).astype(jnp.int8)
+    return q, scale[:, 0]
+
+
 @jax.jit
 def quantize_catalog_device(
     item_emb: jax.Array, item_bias: jax.Array
@@ -53,13 +62,11 @@ def quantize_catalog_device(
     the catalog through host numpy. Returns ``(items_q, scales, bias, mask)``
     padded to the :data:`ITEM_BLOCK` multiple (padding masked with -inf)."""
     n, _ = item_emb.shape
-    amax = jnp.abs(item_emb).max(axis=1, keepdims=True)
-    scale = (amax / 127.0 + 1e-12).astype(jnp.float32)
-    q = jnp.clip(jnp.round(item_emb / scale), -127, 127).astype(jnp.int8)
+    q, scale = _quantize_rows_traced(item_emb)
     pad = (-n) % ITEM_BLOCK
     return (
         jnp.pad(q, ((0, pad), (0, 0))),
-        jnp.pad(scale[:, 0], (0, pad)),
+        jnp.pad(scale, (0, pad)),
         jnp.pad(item_bias.astype(jnp.float32), (0, pad)),
         jnp.pad(jnp.zeros(n, jnp.float32), (0, pad),
                 constant_values=-jnp.inf),
@@ -248,6 +255,124 @@ def pad_centroids(cent_q: np.ndarray, cent_scales: np.ndarray,
         np.concatenate([cent_scales, np.zeros(pad, np.float32)]),
         np.concatenate([cent_bias, np.full(pad, -np.inf, np.float32)]),
     )
+
+
+# -- two-stage retrieval as one device leg -----------------------------------
+#
+# serving/ann.IVFIndex.search_device: the queries are gathered and quantized
+# on the device (quantize_user_rows), the coarse kernel above scores them
+# against the centroids, and two_stage_rerank does everything the host
+# routine (IVFIndex.search) does after it — the three run back to back with
+# their intermediates left on the device and ONE device_get at the end. The
+# coarse kernel stays its own executable: a device trace finds it by name.
+
+
+@jax.jit
+def quantize_user_rows(uidx, ue_tab, ub_tab):
+    """Rows ``uidx`` of the resident user tower as int8 queries:
+    ``(q_q [B, D] int8, q_scales [B] f32, user_bias [B] f32)`` — the
+    :func:`quantize_rows` contract, so both stages share one quantization
+    as they do on the host."""
+    with jax.named_scope("gather"):
+        rows = ue_tab[uidx].astype(jnp.float32)
+        ubias = ub_tab[uidx].astype(jnp.float32)
+    with jax.named_scope("quantize"):
+        q_q, q_scales = _quantize_rows_traced(rows)
+    return q_q, q_scales, ubias
+
+
+def _rerank_kernel(probe_ref, sizes_ref, q_ref, qs_ref, ub_ref, emb_ref,
+                   scale_ref, bias_ref, mask_ref, out_ref):
+    """One probed partition of one query: its ``[L, D]`` int8 member block
+    (picked by the prefetched ``probe``) against the query's int8 row, into
+    the query's row of the probe slot's ``[B, L]`` output block."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    size = sizes_ref[probe_ref[i, j]]
+    acc = jax.lax.dot_general(
+        q_ref[:], emb_ref[:], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )[0:1, :]                                            # [1, L] int32 MXU
+    scores = (acc.astype(jnp.float32) * (qs_ref[:] * scale_ref[:])
+              + bias_ref[:])
+    scores = scores + ub_ref[:] + mask_ref[:]
+    member = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    out_ref[pl.ds(i, 1), :] = jnp.where(member < size, scores, -jnp.inf)
+
+
+#: Sublanes the rerank kernel's query block is repeated to (an int8 MXU
+#: operand of one row is no tile; the coarse kernel's least bucket is 8 too)
+_QUERY_ROWS = 8
+
+
+@functools.partial(jax.jit, static_argnames=("nprobe", "k", "interpret"))
+def two_stage_rerank(coarse, q_q, q_scales, ubias, mean, sizes, emb_p,
+                     scales_p, bias_p, ids_p, mask_p, row_mask=None, *,
+                     nprobe, k, interpret=False):
+    """Second stage of two-stage retrieval over a partition-padded member
+    table: ``coarse [B, C]`` f32 centroid scores → top-``nprobe`` partitions
+    → their members' int8×int8→int32 scores with one f32 rescale → masks →
+    top-``k``. Returns ``(ids [B, k] int32, scores [B, k] f32, counts [B]
+    int32)``; ``counts`` is the candidates the probe gathered per row.
+
+    The member tables hold partition ``p`` in rows ``[p, :sizes[p]]`` of a
+    fixed length ``L`` (≥ the largest partition): ``emb_p [P, L, D]`` int8;
+    ``scales_p``, ``bias_p``, ``mask_p`` f32 and ``ids_p`` int32
+    ``[P, 1, L]``. The kernel's grid is (probe slot, query): each step has
+    its partition's block brought in by the pipeline (block index = the
+    probed partition id, prefetched to SMEM), so a probed partition is one
+    contiguous read and nothing is gathered row by row; rows past a
+    partition's length come out -inf. ``mask_p`` is the additive 0/-inf
+    ``exclude`` mask in member order, ``row_mask [B, n_items]`` the
+    per-query one in catalog order, gathered at the candidates' ids.
+    Arithmetic and mask order are ``IVFIndex._int8_partition_scores`` /
+    ``IVFIndex.search``'s."""
+    b, d = q_q.shape
+    length = emb_p.shape[1]
+    with jax.named_scope("probe_select"):
+        _, probe = jax.lax.top_k(coarse, nprobe)             # [B, nprobe]
+        # centroid padding (-inf bias) is never probed while nprobe ≤ P
+        probe = jnp.minimum(probe, emb_p.shape[0] - 1).astype(jnp.int32)
+        counts = sizes[probe].sum(axis=1)
+    part = lambda j, i, probe, sizes: (probe[i, j], 0, 0)
+    query = lambda j, i, probe, sizes: (i, 0, 0)
+    aux = pl.BlockSpec((None, 1, length), part, memory_space=pltpu.VMEM)
+    one = pl.BlockSpec((None, 1, 1), query, memory_space=pltpu.VMEM)
+    with jax.named_scope("rerank"):
+        scores = pl.pallas_call(
+            _rerank_kernel,
+            name="rerank_members_quantized",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(nprobe, b),
+                in_specs=[
+                    pl.BlockSpec((None, _QUERY_ROWS, d), query,
+                                 memory_space=pltpu.VMEM),
+                    one, one,
+                    pl.BlockSpec((None, length, d), part,
+                                 memory_space=pltpu.VMEM),
+                    aux, aux, aux,
+                ],
+                # a slot's block stays put while the queries fill its rows
+                out_specs=pl.BlockSpec(
+                    (b, length), lambda j, i, probe, sizes: (0, j),
+                    memory_space=pltpu.VMEM),
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, nprobe * length),
+                                           jnp.float32),
+            interpret=interpret,
+        )(probe, sizes,
+          jnp.broadcast_to(q_q[:, None, :], (b, _QUERY_ROWS, d)),
+          q_scales.reshape(b, 1, 1), (ubias + mean).reshape(b, 1, 1),
+          emb_p, scales_p, bias_p, mask_p)
+        if row_mask is not None:
+            with jax.named_scope("gather"):
+                ids = ids_p[probe].reshape(b, nprobe * length)
+            scores = scores + jnp.take_along_axis(row_mask, ids, axis=1)
+    with jax.named_scope("topk"):
+        top_scores, top = jax.lax.top_k(scores, k)
+        part_of = jnp.take_along_axis(probe, top // length, axis=1)
+        top_ids = ids_p[part_of, 0, top % length]
+    return top_ids, top_scores, counts
 
 
 def pad_catalog(items_q: np.ndarray, *vectors: np.ndarray,

@@ -84,6 +84,19 @@ INT8_RERANK = REGISTRY.counter(
     "Batches whose candidate rerank scored int8×int8→int32 over the "
     "quantized member slices (one fp32 rescale per candidate; the fp32 "
     "dequantize-first path is retired)")
+DEVICE_RERANK = REGISTRY.counter(
+    "pio_retrieval_device_rerank_total",
+    "Two-stage batches answered by the device leg (probe selection, member "
+    "gather, int8 rerank and top-k on the chip, one device_get); its share "
+    "of pio_retrieval_two_stage_total is how often the leg is engaged")
+
+#: The device leg holds every partition as one block of a fixed length: a
+#: power of two that holds this many times the MEAN partition, and the
+#: largest one if that is longer still (k-means leaves the largest at 3-5
+#: times the mean). The length is a shape of the leg's executables: taken
+#: from the catalog's size it stays put from one retrain to the next, and a
+#: deploy finds them compiled; taken from the largest partition it would not.
+DEVICE_BLOCK_SKEW = 4
 
 
 # -- env knobs ---------------------------------------------------------------
@@ -152,6 +165,22 @@ def quant_coarse_enabled(index_quantized: bool) -> bool:
     if not index_quantized:
         return False
     return val != "0"
+
+
+def _device_free_bytes() -> Optional[int]:
+    """Free memory of the default device, where the backend reports it."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def coarse_bucket(b: int) -> int:
+    """Rows the int8 coarse kernel (and the device leg behind it) pads a
+    batch of ``b`` queries to: a power of two, at least 8."""
+    return 1 << max(3, (b - 1).bit_length())
 
 
 def build_key(n_items: int) -> dict:
@@ -234,12 +263,14 @@ class IVFIndex:
         self._rehydrate_lock = threading.Lock()
         self._cent_quant = None
         self._cent_device = None
+        self._member_device = None
+        self._mean_device = None
 
     def __getstate__(self):
         state = dict(self.__dict__)
-        state.pop("_rehydrate_lock", None)
-        state.pop("_cent_quant", None)
-        state.pop("_cent_device", None)
+        for k in ("_rehydrate_lock", "_cent_quant", "_cent_device",
+                  "_member_device", "_mean_device"):
+            state.pop(k, None)
         for k in ("emb_m", "emb_q", "scales_m", "bias_m"):
             state[k] = None
         return state
@@ -249,6 +280,8 @@ class IVFIndex:
         self._rehydrate_lock = threading.Lock()
         self._cent_quant = None
         self._cent_device = None
+        self._member_device = None
+        self._mean_device = None
 
     def _coarse_quant(self) -> tuple[np.ndarray, np.ndarray]:
         """Lazy ``(cent_q [C, D] int8, cent_scales [C] f32)`` — the
@@ -443,24 +476,16 @@ class IVFIndex:
             return np.tile(np.arange(self.n_partitions), (len(q), 1))
         return np.argpartition(-coarse, nprobe - 1, axis=1)[:, :nprobe]
 
-    def _probe_tpu(self, q_q: np.ndarray, q_scales: np.ndarray,
-                   interpret: bool = False) -> np.ndarray:
-        """Coarse scores through the Pallas int8 kernel on a resident
-        device copy of the quantized centroid table. The batch pads to a
-        power-of-two bucket (≥ 8) so the query mix shares a handful of
-        executables; centroid padding carries -inf bias and can never win
-        a probe slot."""
-        import jax
-        import jax.numpy as jnp
-
-        from incubator_predictionio_tpu.ops.retrieval import (
-            pad_centroids,
-            score_centroids_quantized,
-        )
-        from incubator_predictionio_tpu.utils import jitstats
-
+    def _centroid_device(self) -> tuple:
+        """Resident device copy of the quantized centroid table, padded to
+        the coarse kernel's block (``(cent_q, cent_scales, cent_bias)``;
+        padding carries -inf bias and can never win a probe slot)."""
         dev = self._cent_device
         if dev is None:
+            import jax
+
+            from incubator_predictionio_tpu.ops.retrieval import pad_centroids
+
             # _coarse_quant takes the (non-reentrant) lock itself: resolve
             # it BEFORE entering the locked section below
             cent_q, cent_scales = self._coarse_quant()
@@ -472,9 +497,24 @@ class IVFIndex:
                         np.asarray(self.centroids[:, -1], np.float32))
                     dev = self._cent_device = tuple(
                         jax.device_put(v) for v in (cq, cs, cb))
-        cq, cs, cb = dev
+        return dev
+
+    def _probe_tpu(self, q_q: np.ndarray, q_scales: np.ndarray,
+                   interpret: bool = False) -> np.ndarray:
+        """Coarse scores through the Pallas int8 kernel on the resident
+        centroid table. The batch pads to :func:`coarse_bucket` so the
+        query mix shares a handful of executables."""
+        import jax
+        import jax.numpy as jnp
+
+        from incubator_predictionio_tpu.ops.retrieval import (
+            score_centroids_quantized,
+        )
+        from incubator_predictionio_tpu.utils import jitstats
+
+        cq, cs, cb = self._centroid_device()
         b = q_q.shape[0]
-        bp = 1 << max(3, (b - 1).bit_length())
+        bp = coarse_bucket(b)
         qq = np.zeros((bp, q_q.shape[1]), np.int8)
         qq[:b] = q_q
         qs = np.zeros(bp, np.float32)
@@ -552,6 +592,175 @@ class IVFIndex:
             out[p] = iter(acc)
         return out
 
+    # -- the device leg ---------------------------------------------------
+    #
+    # search() below is the semantic reference and the only routine on a
+    # host without a chip. Where the queries' tower and this index's int8
+    # tables are resident on a device, search_device() runs the same two
+    # stages there without coming back to the host in between.
+
+    def prepare_device(self) -> bool:
+        """Put the int8 member tables on the device beside the centroids
+        (deploy time), one fixed-length block a partition so that a probed
+        partition is one block read: ``emb_q`` as ``[P, L, D]`` int8 and
+        ``scales_m``, ``bias_m``, ``member_ids`` and a zero ``exclude`` mask
+        as ``[P, 1, L]``, ``L`` a power of two set by the catalog's size
+        (:data:`DEVICE_BLOCK_SKEW`) — ``P × L × (D + 16)`` bytes. Returns
+        whether the device leg can serve this index: quantized, hydrated,
+        no stale overlay (its rows are float32 and live on the host), and
+        a layout that fits in half of what the device has free (one giant
+        partition pads every other to its length)."""
+        if not (self.quantized and self.hydrated) or self.stale_count:
+            return False
+        if self._member_device is not None:
+            return True
+        import jax
+
+        self._centroid_device()
+        with self._rehydrate_lock:
+            if self._member_device is None:
+                n, c = self.n_items, self.n_partitions
+                sizes = np.diff(self.offsets).astype(np.int32)
+                longest = max(int(sizes.max()),
+                              -(-DEVICE_BLOCK_SKEW * n // c), 128)
+                length = 1 << (longest - 1).bit_length()
+                free = _device_free_bytes()
+                need = c * length * (self.emb_q.shape[1] + 16)
+                if free is not None and need > free // 2:
+                    return False
+                # member row -> its slot in the [P, L] layout
+                part = np.repeat(np.arange(c), sizes)
+                slot = part * length + (np.arange(n) - self.offsets[part])
+
+                def blocks(a, shape):
+                    out = np.zeros((c * length,) + a.shape[1:], a.dtype)
+                    out[slot] = a
+                    return out.reshape(shape + a.shape[1:])
+
+                position = np.empty(n, np.int64)  # catalog id -> slot
+                position[self.member_ids] = slot
+                tables = jax.block_until_ready(tuple(
+                    jax.device_put(v) for v in (
+                        sizes, blocks(self.emb_q, (c, length)),
+                        blocks(self.scales_m, (c, 1, length)),
+                        blocks(self.bias_m, (c, 1, length)),
+                        blocks(self.member_ids.astype(np.int32),
+                               (c, 1, length)),
+                        np.zeros((c, 1, length), np.float32))))
+                self._member_device = (position, tables)
+        return True
+
+    @property
+    def device_ready(self) -> bool:
+        """Whether :meth:`search_device` can answer: :meth:`prepare_device`
+        made the tables resident and no overlay has been laid since."""
+        return self._member_device is not None and not self.stale_count
+
+    def search_device(
+        self,
+        user_idx: np.ndarray,        # [B] rows of the resident user tower
+        user_tables: tuple,          # device (user_emb [U, D], user_bias [U])
+        mean: float,
+        num: int,
+        k: Optional[int] = None,
+        nprobe: Optional[int] = None,
+        exclude: Optional[np.ndarray] = None,
+        row_mask: Optional[np.ndarray] = None,
+        interpret: bool = False,
+    ) -> Optional[tuple[np.ndarray, np.ndarray]]:
+        """:meth:`search` as one device leg (ops/retrieval.py): the host
+        pads ``user_idx`` to :func:`coarse_bucket`, three executables run
+        back to back on the device — gather + quantize the user rows, the
+        int8 coarse kernel, probe selection + member gather + int8 rerank +
+        masks + top-k — and ONE ``device_get`` brings ids, scores and
+        candidate counts back. Same probe rule, rerank arithmetic, mask
+        order and ``None`` (→ the caller's exact path) as :meth:`search`.
+
+        ``k`` ≥ ``num`` is the static top-k the executable is compiled for
+        (a deployment's ``serve_k``, so that ``num`` never recompiles)."""
+        import jax
+        import jax.numpy as jnp
+
+        from incubator_predictionio_tpu.ops.retrieval import (
+            quantize_user_rows,
+            score_centroids_quantized,
+            two_stage_rerank,
+        )
+        from incubator_predictionio_tpu.utils import jitstats
+
+        b = len(user_idx)
+        if num <= 0:
+            return (np.zeros((b, 0), np.int64), np.zeros((b, 0), np.float32))
+        if b == 0:
+            return (np.zeros((0, num), np.int64), np.zeros((0, num), np.float32))
+        nprobe = resolved_nprobe(self.n_partitions) if nprobe is None \
+            else min(max(1, nprobe), self.n_partitions)
+        position, tables = self._member_device
+        length = int(tables[1].shape[1])
+        # a scalar costs a host-to-device transfer of its own on every
+        # launch it is passed to (0.3-0.5 ms on the v5e host): keep it there
+        held = self._mean_device
+        if held is None or held[0] != mean:
+            held = self._mean_device = (mean, jax.device_put(np.float32(mean)))
+        k = min(max(k or num, num), nprobe * length)
+        if num > k:
+            # more than the probe can hold at all: search()'s counts < num
+            FALLBACKS.inc()
+            return None
+        bucket = coarse_bucket(b)
+        with span("retrieval.batch.coarse", batch=b, nprobe=nprobe,
+                  where="device") as sp:
+            uidx = np.zeros(bucket, np.int32)
+            uidx[:b] = np.asarray(user_idx, np.int32)
+            cq, cs, cb = self._centroid_device()
+            with jitstats.dispatch_timer(
+                    ("ivf_quantize_users", bucket,
+                     tuple(user_tables[0].shape), str(user_tables[0].dtype))):
+                q_q, q_scales, ubias = quantize_user_rows(uidx, *user_tables)
+            with jitstats.dispatch_timer(
+                    ("ivf_coarse_int8", bucket, int(cq.shape[0]))):
+                coarse = score_centroids_quantized(
+                    q_q, q_scales, cq, cs, cb, interpret=interpret)
+        COARSE_SEC.observe(sp.duration)
+        INT8_COARSE.inc()
+        with span("retrieval.batch.rerank", batch=b, nprobe=nprobe,
+                  where="device") as sp:
+            mask_m = tables[-1]
+            if exclude is not None and len(exclude):
+                # search() drops ids outside the catalog without a word
+                ex = np.asarray(exclude, np.int64)
+                ex = ex[(ex >= 0) & (ex < self.n_items)]
+                m = np.zeros(mask_m.shape, np.float32)
+                m.reshape(-1)[position[ex]] = -np.inf
+                mask_m = jnp.asarray(m)
+            rmask = None
+            if row_mask is not None:
+                rm = row_mask
+                if b < bucket:
+                    rm = np.zeros((bucket, self.n_items), np.float32)
+                    rm[:b] = row_mask
+                rmask = jnp.asarray(rm, jnp.float32)
+            INT8_RERANK.inc()
+            with jitstats.dispatch_timer(
+                    ("ivf_rerank_int8", bucket, k, nprobe,
+                     tuple(tables[1].shape), rmask is not None)):
+                ids, scores, counts = jax.device_get(two_stage_rerank(
+                    coarse, q_q, q_scales, ubias, held[1],
+                    *tables[:-1], mask_m, rmask,
+                    nprobe=nprobe, k=k, interpret=interpret))
+            if (int(counts[:b].min()) < num
+                    or not np.isfinite(scores[:b, num - 1]).all()):
+                # too few candidates, or too few that survive the rule
+                # filters: the exact path sees the whole catalog
+                FALLBACKS.inc()
+                return None
+        RERANK_SEC.observe(sp.duration)
+        for cnt in counts[:b].tolist():
+            CANDIDATES.observe(cnt)
+        TWO_STAGE_BATCHES.inc()
+        DEVICE_RERANK.inc()
+        return ids[:b, :num].astype(np.int64), scores[:b, :num]
+
     def search(
         self,
         q: np.ndarray,               # [B, D] f32 user vectors
@@ -589,8 +798,8 @@ class IVFIndex:
         # histograms read the same clock. Per-shard searches (observe=False)
         # are accounted once, by their caller
         def stage(name):
-            return span(name, batch=b, nprobe=nprobe) if observe \
-                else contextlib.nullcontext()
+            return span(name, batch=b, nprobe=nprobe, where="host") \
+                if observe else contextlib.nullcontext()
 
         with stage("retrieval.batch.coarse") as sp:
             q_quant = None

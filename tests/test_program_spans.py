@@ -591,6 +591,28 @@ def _centroids_lowered():
         interpret=True)
 
 
+def _quantize_users_lowered():
+    from incubator_predictionio_tpu.ops import retrieval
+
+    return retrieval.quantize_user_rows.lower(
+        jnp.zeros(8, jnp.int32), jnp.zeros((64, 128), jnp.bfloat16),
+        jnp.zeros(64))
+
+
+def _rerank_lowered():
+    """The device leg's second stage, in its rule-filtered form (the plain
+    one lacks only the ``gather`` of candidate ids for the row mask)."""
+    from incubator_predictionio_tpu.ops import retrieval
+
+    blocks = jnp.zeros((6, 1, 256))
+    return retrieval.two_stage_rerank.lower(
+        jnp.zeros((8, 512)), jnp.zeros((8, 128), jnp.int8), jnp.zeros(8),
+        jnp.zeros(8), jnp.float32(3.5), jnp.zeros(6, jnp.int32),
+        jnp.zeros((6, 256, 128), jnp.int8), blocks, blocks,
+        blocks.astype(jnp.int32), blocks, jnp.zeros((8, 1000)),
+        nprobe=3, k=16, interpret=True)
+
+
 @pytest.mark.parametrize("lower, module, scopes", [
     (_train_lowered, "jit__train_epochs",
      ("gather", "loss_grad", "scatter", "adam_user", "adam_item")),
@@ -598,8 +620,12 @@ def _centroids_lowered():
     (_centroids_lowered, "jit_score_centroids_quantized", ("score",)),
     (_init_lowered, "jit_init", ()),
     (_order_lowered, "jit__order_batches", ()),
+    (_quantize_users_lowered, "jit_quantize_user_rows",
+     ("gather", "quantize")),
+    (_rerank_lowered, "jit_two_stage_rerank",
+     ("probe_select", "rerank", "gather", "topk")),
 ], ids=["train_epochs", "topk_quantized", "score_centroids", "init",
-        "order_batches"])
+        "order_batches", "quantize_user_rows", "two_stage_rerank"])
 def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
     """``benchmarks/layer_metrics/*_roofline.py`` find these executables by
     name in a device trace's ``XLA Modules`` line: a rename has to fail

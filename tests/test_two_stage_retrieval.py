@@ -671,3 +671,239 @@ def test_int8_is_the_serving_default(two_stage_env, monkeypatch):
     assert model._ivf.stats()["bytes_saved"] > 0
     monkeypatch.setenv("PIO_RETRIEVAL_QUANTIZE", "0")
     assert not ann.quantize_enabled()
+
+
+# -- the device leg (ISSUE 27) ----------------------------------------------
+#
+# IVFIndex.search is the semantic reference; IVFIndex.search_device runs the
+# same two stages as three executables with one device_get. Here the kernels
+# run under the Pallas interpreter on the CPU backend.
+
+def _uneven_index(seed=5, n_items=3000, rank=16, partitions=24):
+    """A seeded quantized index with uneven partitions, one of them empty
+    (a twin of a live centroid that owns no members, so it IS probed)."""
+    model = _clustered_model(seed=seed, n_users=96, n_items=n_items,
+                             rank=rank)
+    key = dict(ann.build_key(n_items), n_partitions=partitions,
+               quantize=True)
+    ivf = ann.build_ivf(model.item_emb, model.item_bias, key=key)
+    at = int(np.argmax(np.diff(ivf.offsets))) + 1
+    ivf = ann.IVFIndex(
+        centroids=np.insert(ivf.centroids, at, ivf.centroids[at - 1], axis=0),
+        member_ids=ivf.member_ids,
+        offsets=np.insert(ivf.offsets, at, ivf.offsets[at]),
+        bias_m=ivf.bias_m, key=ivf.key, emb_q=ivf.emb_q,
+        scales_m=ivf.scales_m)
+    sizes = np.diff(ivf.offsets)
+    assert sizes[at] == 0 and sizes.max() > 2 * sizes[sizes > 0].min()
+    assert ivf.prepare_device()
+    return model, ivf
+
+
+@pytest.fixture(scope="module")
+def uneven():
+    import jax.numpy as jnp
+
+    model, ivf = _uneven_index()
+    tables = (jnp.asarray(model.user_emb), jnp.asarray(model.user_bias))
+    return model, ivf, tables
+
+
+def _mask_forms(model, ivf, users, nprobe, num):
+    """exclude / row_mask that hit what the plain search returns, so a
+    mask that is not applied shows as a wrong answer."""
+    q = model.user_emb[users]
+    plain = ivf.search(q, model.user_bias[users], model.mean, num,
+                       nprobe=nprobe, observe=False)
+    exclude = np.unique(plain[0][:, :2])
+    row_mask = np.zeros((len(users), model.n_items), np.float32)
+    row_mask[np.arange(len(users))[:, None], plain[0][:, 2:5]] = -np.inf
+    return {"exclude": (exclude, None), "row_mask": (None, row_mask),
+            "both": (exclude, row_mask)}
+
+
+_DEVICE_CASES = [
+    (f"b{b}-{form}", b, form, 6, 10)
+    for b in (1, 3, 8, 9, 33)
+    for form in ("plain", "exclude", "row_mask", "both")
+] + [
+    # nprobe ≥ partitions: every partition probed, the empty one too
+    ("nprobe_all", 5, "both", 99, 10),
+    # one probe holds fewer than num candidates
+    ("probe_short", 4, "plain", 1, 0),
+    # the probe holds enough, the rule filters leave fewer than num
+    ("survivors_short", 4, "whitelist", 6, 10),
+]
+
+
+@pytest.mark.parametrize(
+    "b, form, nprobe, num", [c[1:] for c in _DEVICE_CASES],
+    ids=[c[0] for c in _DEVICE_CASES])
+def test_device_leg_matches_host_search(uneven, b, form, nprobe, num):
+    model, ivf, tables = uneven
+    users = (np.arange(b, dtype=np.int32) * 7 + 3) % model.n_users
+    q, ub = model.user_emb[users], model.user_bias[users]
+    if not num:  # probe_short: more than the largest partition holds
+        num = int(np.diff(ivf.offsets).max()) + 1
+    if form == "whitelist":
+        inside = ivf.candidate_ids(q[1], nprobe)
+        white = np.full((b, model.n_items), -np.inf, np.float32)
+        white[:, inside[:num]] = 0.0
+        white[1, inside[num - 1]] = -np.inf  # row 1 keeps num - 1
+        exclude, row_mask = None, white
+    elif form == "plain":
+        exclude, row_mask = None, None
+    else:
+        exclude, row_mask = _mask_forms(model, ivf, users, nprobe, num)[form]
+    fallbacks = ann.FALLBACKS._default().value
+    engaged = ann.DEVICE_RERANK._default().value
+    host = ivf.search(q, ub, model.mean, num, nprobe=nprobe,
+                      exclude=exclude, row_mask=row_mask, observe=False)
+    got = ivf.search_device(users, tables, model.mean, num, k=16,
+                            nprobe=nprobe, exclude=exclude,
+                            row_mask=row_mask, interpret=True)
+    if form == "whitelist" or nprobe == 1:
+        assert host is None and got is None
+        assert ann.FALLBACKS._default().value == fallbacks + 1
+        assert ann.DEVICE_RERANK._default().value == engaged
+        return
+    assert ann.FALLBACKS._default().value == fallbacks
+    assert ann.DEVICE_RERANK._default().value == engaged + 1
+    assert got[0].shape == host[0].shape == (b, num)
+    assert got[0].dtype == np.int64 and got[1].dtype == np.float32
+    np.testing.assert_allclose(got[1], host[1], rtol=0, atol=1e-5)
+    # ids: equal wherever the host's own order is decided by the scores
+    # (its top num + 1 all distinct: no tie inside, none at the boundary)
+    wider = ivf.search(q, ub, model.mean, num + 1, nprobe=nprobe,
+                       exclude=exclude, row_mask=row_mask, observe=False)
+    decided = (np.diff(wider[1], axis=1) != 0).all(axis=1) \
+        if wider is not None else np.ones(b, bool)
+    assert decided.sum() >= b - 1
+    np.testing.assert_array_equal(got[0][decided], host[0][decided])
+    if exclude is not None:
+        assert not np.isin(got[0], exclude).any()
+    if row_mask is not None:
+        assert (np.take_along_axis(row_mask, got[0], axis=1) == 0).all()
+
+
+def _device_model(monkeypatch, quantize_index="1"):
+    """A model whose towers and kernels are 'on a device': the kernels under
+    the Pallas interpreter, the int8 catalog + bf16 users resident."""
+    monkeypatch.setenv("PIO_RETRIEVAL_MODE", "two_stage")
+    monkeypatch.setenv("PIO_RETRIEVAL_NPROBE", "16")
+    monkeypatch.setenv("PIO_RETRIEVAL_QUANTIZE", quantize_index)
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "1")
+    model = _clustered_model(n_items=2048)
+    model.prepare_for_serving(quantize=True, serve_k=16, host_max_elements=0)
+    return model
+
+
+@pytest.mark.parametrize(
+    "case", ["resident", "stale_overlay", "float32_index",
+             "no_kernel_backend", "coarse_knob_off", "no_room",
+             "exclude", "row_mask"])
+def test_device_leg_runs_iff_resident(monkeypatch, case):
+    """Which routine answers follows what is resident and what the batch
+    carries (no setting): the device leg iff kernels, towers and a
+    quantized index without an overlay are on a device and the batch has no
+    rule filter (a dense mask a batch costs more to send than the host
+    rerank it would save); IVFIndex.search otherwise."""
+    if case == "no_room":  # the padded layout wants over half of what is free
+        monkeypatch.setattr(ann, "_device_free_bytes", lambda: 1 << 16)
+    model = _device_model(
+        monkeypatch, quantize_index="0" if case == "float32_index" else "1")
+    assert model._ivf.device_ready == (
+        case not in ("float32_index", "no_room"))
+    if case == "stale_overlay":
+        rows = {7: np.ones(model.config.rank + 1, np.float32)}
+        moved = model.with_row_updates(item_rows=rows)
+        moved.prepare_for_serving(quantize=True, serve_k=16,
+                                  host_max_elements=0)
+        assert moved._ivf.stale_count == 1 and not moved._ivf.device_ready
+        assert model._ivf.device_ready  # the live model's view is its own
+        model = moved
+    elif case == "no_kernel_backend":
+        monkeypatch.delenv("PIO_PALLAS_INTERPRET")
+    elif case == "coarse_knob_off":
+        monkeypatch.setenv("PIO_RETRIEVAL_QUANT_COARSE", "0")
+    engaged = ann.DEVICE_RERANK._default().value
+    batches = ann.TWO_STAGE_BATCHES._default().value
+    users = np.arange(5, dtype=np.int32)
+    filters = {"exclude": dict(exclude=np.arange(3)),
+               "row_mask": dict(row_mask=np.zeros((5, model.n_items),
+                                                  np.float32))}
+    idx, scores = TwoTowerMF.recommend_batch(
+        model, users, 10, **filters.get(case, {}))
+    assert idx.shape == (5, 10) and np.isfinite(scores).all()
+    assert ann.TWO_STAGE_BATCHES._default().value == batches + 1
+    assert ann.DEVICE_RERANK._default().value == engaged + (case == "resident")
+
+
+def test_device_leg_leaves_a_restored_tower_on_the_device(monkeypatch):
+    """A restored deployment keeps its towers on the device: the leg reads
+    the bfloat16 serving copy there and pulls nothing to the host; against
+    the host routine over the float32 rows the answers differ by the
+    bfloat16 rounding of the queries alone."""
+    import jax.numpy as jnp
+
+    host = _device_model(monkeypatch)
+    fused = TwoTowerModel(mean=host.mean, config=host.config)
+    fused._tables = {
+        "ue": jnp.asarray(np.c_[host.user_emb, host.user_bias]),
+        "ie": jnp.asarray(np.c_[host.item_emb, host.item_bias])}
+    fused._n_users, fused._n_items = host.n_users, host.n_items
+    fused.prepare_for_serving(quantize=True, serve_k=16, host_max_elements=0)
+    assert fused._ivf.device_ready and fused.user_emb is None
+    users = np.arange(12, dtype=np.int32)
+    engaged = ann.DEVICE_RERANK._default().value
+    idx, scores = TwoTowerMF.recommend_batch(fused, users, 10)
+    assert ann.DEVICE_RERANK._default().value == engaged + 1
+    assert fused.user_emb is None  # nothing was pulled to the host
+    want = fused._ivf.search(host.user_emb[users], host.user_bias[users],
+                             host.mean, 10, observe=False)
+    np.testing.assert_allclose(scores, want[1], rtol=5e-3, atol=0)
+    assert (idx == want[0]).mean() > 0.8
+
+
+def test_device_leg_spans_and_fallback_to_exact(monkeypatch):
+    """The stage spans say where they ran; a probe too narrow for num is
+    counted and answered by the exact path, as on the host."""
+    from incubator_predictionio_tpu.obs import trace
+
+    model = _device_model(monkeypatch)
+    trace.TRACES.clear()
+    users = np.arange(3, dtype=np.int32)
+    TwoTowerMF.recommend_batch(model, users, 10)
+    stages = {s["name"]: s["attrs"] for s in trace.TRACES.spans()
+              if s["name"].startswith("retrieval.batch.")}
+    assert stages["retrieval.batch.coarse"]["where"] == "device"
+    assert stages["retrieval.batch.rerank"]["where"] == "device"
+    monkeypatch.setenv("PIO_RETRIEVAL_NPROBE", "1")
+    num = int(np.diff(model._ivf.offsets).max()) + 1
+    fallbacks = ann.FALLBACKS._default().value
+    idx, _ = TwoTowerMF.recommend_batch(model, users, num)
+    assert ann.FALLBACKS._default().value == fallbacks + 1
+    monkeypatch.setenv("PIO_RETRIEVAL_MODE", "exact")
+    exact, _ = TwoTowerMF.recommend_batch(model, users, num)
+    np.testing.assert_array_equal(idx, exact)
+
+
+def test_device_leg_warmup_leaves_nothing_to_compile(monkeypatch):
+    """After the deploy's warm-up a dispatch at each coarse bucket builds no
+    executable: jitstats' keys and the jit caches themselves stay flat."""
+    from incubator_predictionio_tpu.ops import retrieval
+    from incubator_predictionio_tpu.utils import jitstats
+
+    model = _device_model(monkeypatch)
+    model._warmup_buckets(max_batch=64, two_stage=True)
+    fns = (retrieval.quantize_user_rows, retrieval.score_centroids_quantized,
+           retrieval.two_stage_rerank)
+    keys, sizes = jitstats.count(), [f._cache_size() for f in fns]
+    engaged = ann.DEVICE_RERANK._default().value
+    for b in (8, 16, 32, 64):
+        idx, _ = TwoTowerMF.recommend_batch(
+            model, np.arange(b, dtype=np.int32), 10)
+        assert idx.shape == (b, 10)
+    assert ann.DEVICE_RERANK._default().value == engaged + 4
+    assert jitstats.count() == keys
+    assert [f._cache_size() for f in fns] == sizes
