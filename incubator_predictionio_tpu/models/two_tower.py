@@ -42,6 +42,11 @@ from incubator_predictionio_tpu.parallel.mesh import (
     MeshContext,
     kernel_backend,
 )
+from incubator_predictionio_tpu.serving import plan as serve_plan
+from incubator_predictionio_tpu.serving.plan import (
+    HOST_SERVE_MAX_ELEMENTS,
+    serve_bucket,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -73,37 +78,6 @@ class TwoTowerConfig:
     gather: str = "auto"
 
 
-#: Micro-batch bucket ladder for serving: every request batch is padded up to
-#: the next bucket so the jitted scorers see a handful of static shapes
-#: instead of one per batch size (the round-2 compile-churn bug). Beyond the
-#: largest bucket, batches round up to a multiple of it.
-SERVE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
-
-#: Catalogs with ≤ this many table elements (rows × columns) serve from HOST
-#: numpy instead of the device: scoring a 3.7k-item catalog is ~100 µs of
-#: numpy, while EVERY device call pays a dispatch/result round trip. Big
-#: catalogs amortize the round trip over real MXU work and stay on device.
-HOST_SERVE_MAX_ELEMENTS = 2_000_000
-
-#: Per-row rule masks are DENSE [batch, n_items] f32 — the host build +
-#: device transfer scales with batch × catalog, so the row-mask path (and
-#: its deploy-time warmup) is limited to batches where that mask stays
-#: modest (≤ this many elements, 32 MB f32). Above it, callers fall back to
-#: shared-exclude / over-fetch semantics and warmup skips the row-mask
-#: executables (which are then never dispatched — the compile-count gauge
-#: stays flat either way).
-ROW_MASK_MAX_ELEMENTS = 8_000_000
-
-
-def serve_bucket(b: int) -> int:
-    """Smallest bucket ≥ ``b`` (multiples of the top bucket past the ladder)."""
-    for s in SERVE_BUCKETS:
-        if b <= s:
-            return s
-    top = SERVE_BUCKETS[-1]
-    return ((b + top - 1) // top) * top
-
-
 @dataclasses.dataclass
 class TwoTowerModel:
     """user/item factor tables + biases + global mean.
@@ -130,11 +104,15 @@ class TwoTowerModel:
     _tables = None  # device-resident fused tables (device mode)
     _n_users = 0  # real (unpadded) row counts in device mode
     _n_items = 0
-    _device_items = None  # (item_embᵀ bf16, item_bias, zero mask) for serving
-    _device_items_q = None  # int8-quantized catalog (pallas retrieval kernel)
+    # the plan's device scorer's catalog: (item_embᵀ bf16, item_bias, zero
+    # mask), or int8-quantized (items, scales, bias, mask; pallas kernel)
+    _device_items = None
     _device_users = None  # (user_emb bf16, user_bias) — gathered inside jit
     _host_items = None  # small-catalog host fast path (item_embᵀ, item_bias)
-    _serve_k = 0  # static top-k the serving executables are compiled for
+    # the serve plan (serving/plan.py): which scorer and which pruned routine
+    # answer and the static top-k the executables are compiled for, fixed by
+    # prepare_for_serving; None until then. Derived state
+    _plan: Optional[serve_plan.ServePlan] = None
     # two-stage retrieval index (serving/ann.py). Unlike the device handles
     # it IS host numpy and rides default pickling, so a persisted model
     # redeploys without re-clustering the catalog
@@ -179,8 +157,8 @@ class TwoTowerModel:
         # inputs — _shard_ivf, _shard_spec — do persist)
         self.ensure_host()
         return {k: v for k, v in self.__dict__.items()
-                if k not in ("_tables", "_device_items", "_device_items_q",
-                             "_device_users", "_host_items", "_sharded")}
+                if k not in ("_tables", "_device_items", "_device_users",
+                             "_host_items", "_sharded", "_plan")}
 
     def prepare_for_serving(
         self, quantize: bool = False, serve_k: int = 128,
@@ -188,45 +166,63 @@ class TwoTowerModel:
     ) -> "TwoTowerModel":
         """Make serving state resident for the query hot path.
 
-        Catalogs up to :data:`HOST_SERVE_MAX_ELEMENTS` serve from host numpy
-        — scoring a few-thousand-item catalog is microseconds of numpy and
-        paying a device round trip per query only adds latency. Bigger
-        catalogs go device-resident; ``quantize=True`` additionally stores
-        the catalog int8 row-quantized and scores through the fused Pallas
-        retrieval kernel (ops/retrieval.py) — 4× less HBM for the item table
-        and a faster score pass on TPU.
+        Catalogs up to :data:`HOST_SERVE_MAX_ELEMENTS` serve from host numpy;
+        bigger ones go device-resident, and ``quantize=True`` additionally
+        stores the catalog int8 row-quantized and scores through the fused
+        Pallas retrieval kernel (ops/retrieval.py) — 4× less HBM for the item
+        table and a faster score pass on TPU.
 
         ``serve_k`` fixes the static top-k the device executables compute:
         queries asking ``num ≤ serve_k`` share ONE executable per batch bucket
         (results sliced host-side), so per-query ``num`` never recompiles.
 
-        When two-stage retrieval is enabled for this catalog
-        (``PIO_RETRIEVAL_MODE``, serving/ann.py) this also builds — or
-        reuses, when a persisted index's build key still matches — the IVF
-        partition the coarse stage probes; the exact buffers above stay
-        resident as the fallback and recall oracle. ``build_index=False``
-        opts out — for callers (the ecommerce/similarity templates) whose
-        serving path never goes through :meth:`TwoTowerMF.recommend_batch`
-        and would pay the clustering for nothing."""
+        When the plan prunes this catalog (``PIO_RETRIEVAL_MODE``,
+        serving/plan.py) this also builds — or reuses, when a persisted
+        index's build key still matches — the IVF partition the coarse
+        stage probes; the exact buffers above stay resident as the fallback
+        and recall oracle. ``build_index=False`` opts out — for callers
+        (the ecommerce/similarity templates) whose serving path never goes
+        through :meth:`TwoTowerMF.recommend_batch` and would pay the
+        clustering for nothing.
+
+        ``self._plan`` is final when this returns: warm-up, ``serving_info``
+        and every dispatch read it (docs/serving.md "How the serve path is
+        chosen")."""
+        plan = self._resolve_plan(quantize, serve_k, host_max_elements)
         with span("deploy.quantize", quantize=quantize):
-            self._prepare_scoring(quantize, serve_k, host_max_elements)
+            self._prepare_scoring(plan)
             # the device-to-device slice / cast / quantize is dispatched
             # asynchronously: bill it here, not to the first warm-up bucket
-            _profile.fence(self._device_users, self._device_items,
-                           self._device_items_q)
+            _profile.fence(self._device_users, self._device_items)
+        on_device = False
         if build_index:
-            from incubator_predictionio_tpu.serving import ann
-
             with span("deploy.index"):
                 self._prepare_index()
-                if (self._ivf is not None and self._device_users is not None
-                        and ann.two_stage_enabled(self.n_items)
-                        and kernel_backend()):
+                if self._ivf is not None and plan.wants_device_leg:
                     # towers and kernels are on a device: the index's int8
                     # tables join them, and two-stage retrieval runs as one
                     # device leg (serving/ann.IVFIndex.search_device)
-                    self._ivf.prepare_device()
+                    on_device = self._ivf.prepare_device()
+        live = [i for i in ([self._ivf] if self._sharded is None
+                            else self._sharded.ivf or ()) if i is not None]
+        index = None if not live else (
+            "int8" if any(i.quantized for i in live) else "fp32")
+        self._plan = plan.settle(index, on_device)
         return self
+
+    def _resolve_plan(
+        self, quantize: bool = False, serve_k: int = 128,
+        host_max_elements: Optional[int] = None,
+    ) -> serve_plan.ServePlan:
+        """:func:`serving.plan.resolve` over this model's facts."""
+        from incubator_predictionio_tpu.sharding import serve as shard_serve
+
+        return serve_plan.resolve(
+            n_items=self.n_items, rank=self.config.rank,
+            tables_on_device=self.device_resident and self.user_emb is None,
+            layout_shards=shard_serve.layout_shards_of(self),
+            backend=kernel_backend(), quantize=quantize, serve_k=serve_k,
+            host_max_elements=host_max_elements)
 
     def _prepare_index(self) -> None:
         """Build/reuse the two-stage IVF partition (serving/ann.py)."""
@@ -282,43 +278,45 @@ class TwoTowerModel:
                 np.ascontiguousarray(host_ie[: self._n_items, k],
                                      dtype=np.float32))
 
-    def _prepare_scoring(
-        self, quantize: bool = False, serve_k: int = 128,
-        host_max_elements: Optional[int] = None,
-    ) -> "TwoTowerModel":
-        self._serve_k = min(serve_k, self.n_items)
+    def _prepare_scoring(self, plan: serve_plan.ServePlan) -> None:
+        """Make the plan's full-catalog scorer resident."""
         # re-preparation switches paths cleanly: clear every serving buffer
         # first (a stale _host_items would shadow a requested device path)
+        self._plan = None
         self._host_items = None
         self._device_items = None
-        self._device_items_q = None
         self._device_users = None
         self._sharded = None
-        host_max = (HOST_SERVE_MAX_ELEMENTS if host_max_elements is None
-                    else host_max_elements)
-        # sharded serving (sharding/serve.py): per-shard top-k + cross-shard
-        # merge straight from the model-axis layout. auto engages when the
-        # tables restored sharded (or the simulated HBM budget says one chip
-        # can't hold the catalog) AND the catalog is device-scale;
-        # PIO_SHARD_SERVE=1 forces it (host models get virtual shards).
-        # serving_shards_for is the ONE engage decision (train-time IVF
-        # build and restore layout use it too)
-        from incubator_predictionio_tpu.sharding import serve as shard_serve
+        quantize = plan.scorer == serve_plan.DEVICE_INT8
+        if plan.scorer == serve_plan.SHARDED:
+            # per-shard top-k + cross-shard merge straight from the
+            # model-axis layout (sharding/serve.py): device-resident models
+            # derive the state device-to-device from the sharded tables
+            # (the item table never visits the host); host models split
+            # into virtual shard blocks (the CPU-parity twin)
+            from incubator_predictionio_tpu.sharding.serve import (
+                ShardedServing,
+            )
 
-        n_shards = shard_serve.serving_shards_for(
-            self, host_max_elements=host_max)
-        if n_shards > 1:
-            self._build_sharded(n_shards)
-            return self
-        # host check first: ``quantize`` applies to device-resident catalogs;
-        # a catalog small enough for the host path never benefits from it
-        if self.n_items * (self.config.rank + 1) <= host_max:
+            serve_k = plan.serve_k or min(128, self.n_items)
+            if plan.tables_on_device:
+                self._sharded = ShardedServing.build_device(
+                    self._tables, self._n_users, self._n_items,
+                    self.config.rank, self.mean, serve_k,
+                    min(plan.n_shards, len(jax.devices())))
+            else:
+                self._sharded = ShardedServing.build_host(
+                    np.asarray(self.item_emb, np.float32),
+                    np.asarray(self.item_bias, np.float32),
+                    self.n_users, self.mean, serve_k, plan.n_shards)
+            return
+        if plan.scorer == serve_plan.HOST_NUMPY:
             self.ensure_host()  # no-op unless forced device mode on tiny tables
             self._host_items = (
                 np.ascontiguousarray(np.asarray(self.item_emb, np.float32).T),
                 np.asarray(self.item_bias, np.float32),
             )
-            return self
+            return
         if self.device_resident and self.user_emb is None:
             # device→device: slice/cast the resident fused tables — serving
             # state is derived without a single host round trip (the whole
@@ -336,7 +334,7 @@ class TwoTowerModel:
                     quantize_catalog_device,
                 )
 
-                self._device_items_q = tuple(
+                self._device_items = tuple(
                     quantize_catalog_device(item_emb, item_bias))
             else:
                 self._device_items = (
@@ -344,7 +342,7 @@ class TwoTowerModel:
                     item_bias.astype(jnp.float32),
                     jnp.zeros(self._n_items, jnp.float32),
                 )
-            return self
+            return
         self._device_users = (
             jax.device_put(np.asarray(self.user_emb, np.float32).astype(jnp.bfloat16)),
             jax.device_put(np.asarray(self.user_bias, np.float32)),
@@ -360,7 +358,7 @@ class TwoTowerModel:
             items_q, scales, bias, mask = pad_catalog(
                 items_q, scales, np.asarray(self.item_bias, np.float32), base_mask
             )
-            self._device_items_q = tuple(
+            self._device_items = tuple(
                 jax.device_put(v) for v in (items_q, scales, bias, mask)
             )
         else:
@@ -373,120 +371,41 @@ class TwoTowerModel:
                 jax.device_put(np.asarray(self.item_bias, np.float32)),
                 jax.device_put(np.zeros(self.n_items, np.float32)),
             )
-        return self
-
-    def _build_sharded(self, n_shards: int) -> None:
-        """Materialize the per-shard serving state (sharding/serve.py):
-        device-resident models derive it device-to-device from the sharded
-        tables (the item table never visits the host); host models split
-        into virtual shard blocks (the CPU-parity twin)."""
-        import jax
-
-        from incubator_predictionio_tpu.sharding.serve import ShardedServing
-
-        serve_k = self._serve_k or min(128, self.n_items)
-        if self.device_resident and self.user_emb is None:
-            n_shards = min(n_shards, len(jax.devices()))
-            self._sharded = ShardedServing.build_device(
-                self._tables, self._n_users, self._n_items,
-                self.config.rank, self.mean, serve_k, n_shards)
-        else:
-            self._sharded = ShardedServing.build_host(
-                np.asarray(self.item_emb, np.float32),
-                np.asarray(self.item_bias, np.float32),
-                self.n_users, self.mean, serve_k, n_shards)
 
     def warmup(self, max_batch: int = 64) -> int:
         """Pre-compile the serving executable for every batch bucket up to
-        ``max_batch`` (deploy-time cost, so no live query ever waits on XLA).
-        Returns the number of buckets warmed (0 on the host fast path —
-        nothing compiles there)."""
-        if (self._device_users is None and self._host_items is None
-                and self._sharded is None):
-            self.prepare_for_serving()
-        from incubator_predictionio_tpu.serving import ann
-
-        has_ivf = self._ivf is not None or (
-            self._sharded is not None and any(self._sharded.ivf or ()))
-        two_stage = has_ivf and ann.two_stage_enabled(self.n_items)
-        if two_stage and self._ivf is not None:
+        ``max_batch`` (deploy-time cost, so no live query ever waits on XLA):
+        one ``deploy.warmup.bucket`` span per dispatch shape of the plan's
+        warm list (serving/plan.ServePlan.warm_shapes). Returns the number
+        of buckets warmed, the pruned path's prime not counted (0 on the
+        host fast path — nothing compiles there)."""
+        plan = self._plan or self.prepare_for_serving()._plan
+        if plan.pruned is not None and self._ivf is not None:
             # the two-stage host routine reads the towers on the host: pull
             # them under their own span (deploy.ensure_host), not inside
-            # the first warm-up dispatch or a live batch that finds the
-            # device leg gone (an overlay, a flipped knob)
+            # the first warm-up dispatch or the first filtered live batch
             self.ensure_host()
+        shapes = plan.warm_shapes(max_batch)
+        k = max(plan.serve_k, 1)
         with span("deploy.warmup", max_batch=max_batch):
-            return self._warmup_buckets(max_batch, two_stage)
-
-    def _warmup_buckets(self, max_batch: int, two_stage: bool) -> int:
-        """One ``deploy.warmup.bucket`` span per dispatch shape warmed."""
-        n = 0
-        if two_stage:
-            # prime the two-stage path too: on host no XLA is involved (the
-            # coarse + rerank stages are numpy), but the first dispatch
-            # faults the member-order tables into memory and spins up the
-            # BLAS thread pool — deploy-time cost, not the first live
-            # query's
-            k = min(max(self._serve_k, 1), self.n_items)
-            with span("deploy.warmup.bucket", bucket=1, path="two_stage"):
-                TwoTowerMF.recommend_batch(self, np.zeros(1, np.int32), k)
-            quantized = (self._ivf is not None and self._ivf.quantized) or (
-                self._sharded is not None
-                and any(i is not None and i.quantized
-                        for i in self._sharded.ivf or ()))
-            if quantized and kernel_backend():
-                # the int8 coarse kernel pads queries to power-of-two
-                # buckets (serving/ann.coarse_bucket), and the device leg's
-                # other two executables follow it: compile each bucket's
-                # now so no live batch shape pays it (jitstats names them;
-                # the batch-1 prime above already built the ≤8 bucket)
-                from incubator_predictionio_tpu.serving.ann import (
-                    coarse_bucket,
-                )
-
-                seen = {8}
-                for b in SERVE_BUCKETS:
-                    if b > max(1, max_batch):
-                        break
-                    bp = coarse_bucket(b)
-                    if bp in seen:
+            for shape in shapes:
+                users = np.zeros(shape.bucket, np.int32)
+                with span("deploy.warmup.bucket", bucket=shape.bucket,
+                          path=shape.path):
+                    if shape.path == "two_stage":
+                        TwoTowerMF.recommend_batch(self, users, k)
                         continue
-                    seen.add(bp)
-                    with span("deploy.warmup.bucket", bucket=b,
-                              path="two_stage"):
+                    # exact=True: under a pruning plan the full-catalog
+                    # executables, its fallback, would else compile on the
+                    # first live query that needs them
+                    TwoTowerMF.recommend_batch(self, users, k, exact=True)
+                    if shape.row_mask:
+                        # the rule-filtered variant ([b, n] row mask) is a
+                        # distinct executable
                         TwoTowerMF.recommend_batch(
-                            self, np.zeros(b, np.int32), k)
-                    n += 1
-        if self._host_items is not None or (
-                self._sharded is not None and self._sharded.device is None):
-            # pure-numpy serving paths: nothing compiles
-            return 0
-        for b in SERVE_BUCKETS:
-            if b > max(1, max_batch):
-                break
-            with span("deploy.warmup.bucket", bucket=b, path="exact"):
-                # _force_exact: with two-stage retrieval active these warmup
-                # dispatches would route to the (host-side) pruned path and the
-                # exact executables — the two-stage FALLBACK — would compile on
-                # the first live query that needs them
-                TwoTowerMF.recommend_batch(
-                    self, np.zeros(b, np.int32), self._serve_k or 1,
-                    _force_exact=True,
-                )
-                # the rule-filtered variant ([b, n] row mask) is a distinct
-                # executable — warm it too so the first filtered live batch
-                # doesn't pay an XLA compile. Only under ROW_MASK_MAX_ELEMENTS:
-                # beyond it serving never dispatches the row-mask form (callers
-                # fall back to shared-exclude/over-fetch), and warming it would
-                # cost a batch×catalog host allocation + transfer per bucket
-                if b * self.n_items <= ROW_MASK_MAX_ELEMENTS:
-                    TwoTowerMF.recommend_batch(
-                        self, np.zeros(b, np.int32), self._serve_k or 1,
-                        row_mask=np.zeros((b, self.n_items), np.float32),
-                        _force_exact=True,
-                    )
-            n += 1
-        return n
+                            self, users, k, exact=True, row_mask=np.zeros(
+                                (shape.bucket, self.n_items), np.float32))
+        return len(shapes) - (plan.pruned is not None)
 
     @property
     def n_items(self) -> int:
@@ -566,7 +485,7 @@ class TwoTowerModel:
                               if new._sharded.ivf is not None
                               else self._shard_ivf)
             new._shard_spec = self._shard_spec
-            new._serve_k = self._serve_k
+            new._plan = self._plan
         return new
 
     def _with_row_updates_sharded(
@@ -585,7 +504,7 @@ class TwoTowerModel:
 
         new = TwoTowerModel(mean=self.mean, config=self.config)
         new._n_users, new._n_items = self._n_users, self._n_items
-        new._serve_k = self._serve_k
+        new._plan = self._plan
         new._shard_spec = self._shard_spec
         new._sharded = self._sharded.with_row_updates(
             user_rows or {}, item_rows or {})
@@ -599,9 +518,7 @@ class TwoTowerModel:
             for name, rows_dict in (("ue", user_rows), ("ie", item_rows)):
                 if not rows_dict:
                     continue
-                ids = np.asarray(sorted(int(i) for i in rows_dict), np.int64)
-                rows = np.stack([np.asarray(rows_dict[int(i)], np.float32)
-                                 for i in ids])
+                ids, rows = _stacked_rows(rows_dict)
                 tables[name] = _set_rows_fn()(
                     tables[name], jnp.asarray(ids, jnp.int32),
                     jnp.asarray(rows))
@@ -619,9 +536,7 @@ class TwoTowerModel:
             # (the host path's _updated_index semantics, minus its
             # rebuild-past-threshold branch, which needs host towers)
             if item_rows:
-                ids = np.asarray(sorted(int(i) for i in item_rows), np.int64)
-                rows = np.stack([np.asarray(item_rows[int(i)], np.float32)
-                                 for i in ids])
+                ids, rows = _stacked_rows(item_rows)
                 k = self.config.rank
                 new._ivf = self._ivf.with_updated_rows(
                     ids, rows[:, :k], rows[:, k])
@@ -636,14 +551,12 @@ class TwoTowerModel:
 
         from incubator_predictionio_tpu.serving import ann
 
-        ids = np.asarray(sorted(int(i) for i in item_rows), np.int64)
-        rows = np.stack([np.asarray(item_rows[int(i)], np.float32)
-                         for i in ids])
+        ids, rows = _stacked_rows(item_rows)
         k = self.config.rank
         overlaid = self._ivf.with_updated_rows(ids, rows[:, :k], rows[:, k])
         frac = float(_os.environ.get("PIO_STREAM_STALE_REBUILD_FRAC", "0.25"))
-        if overlaid.stale_fraction > frac and ann.two_stage_enabled(
-                new.n_items):
+        if overlaid.stale_fraction > frac and (
+                self._plan or self._resolve_plan()).two_stage:
             return ann.build_ivf(
                 np.asarray(new.item_emb, np.float32),
                 np.asarray(new.item_bias, np.float32),
@@ -651,26 +564,9 @@ class TwoTowerModel:
         return overlaid
 
     def serving_info(self) -> dict:
-        """Which serving path this model runs (status-page observability)."""
-        if self._sharded is not None:
-            path = ("sharded-device-bf16" if self._sharded.device is not None
-                    else "sharded-host-numpy")
-        elif self._device_items_q is not None:
-            # name the scorer _topk_quantized actually dispatches
-            path = {"mosaic": "device-int8-pallas",
-                    "interpret": "device-int8-pallas-interpret",
-                    None: "device-int8-jnp"}[kernel_backend()]
-        elif self._device_items is not None:
-            path = "device-bf16"
-        elif self._host_items is not None:
-            path = "host-numpy"
-        else:
-            path = "unprepared"
-        from incubator_predictionio_tpu.serving import ann
-
-        has_index = self._ivf is not None or (
-            self._sharded is not None and any(self._sharded.ivf or ()))
-        two_stage = has_index and ann.two_stage_enabled(self.n_items)
+        """Which serving path this model runs (status-page observability):
+        the serve plan, as prepare fixed it."""
+        plan = self._plan
         if self._ivf is not None:
             index = self._ivf.stats()
         elif self._sharded is not None and self._sharded.ivf:
@@ -678,9 +574,12 @@ class TwoTowerModel:
                      for i in self._sharded.ivf]
         else:
             index = None
-        return {"path": path, "serve_k": self._serve_k,
+        pruned = plan.pruned if plan is not None else None
+        return {"path": plan.path if plan is not None else "unprepared",
+                "serve_k": plan.serve_k if plan is not None else 0,
                 "catalog_rows": self.n_items,
-                "retrieval_mode": "two_stage" if two_stage else "exact",
+                "retrieval_mode": "two_stage" if pruned else "exact",
+                "pruned": pruned,
                 "sharding": (self._sharded.info()
                              if self._sharded is not None else None),
                 "index": index}
@@ -714,6 +613,12 @@ class TwoTowerModel:
             "requires_sharding": requires_sharding(
                 self.n_items, k + 1, self.config.adam_moments_dtype),
         }
+
+
+def _stacked_rows(rows: dict) -> tuple[np.ndarray, np.ndarray]:
+    """A delta's ``{row index: fused row}`` as ``(ids ascending, [n, k+1])``."""
+    ids = np.asarray(sorted(int(i) for i in rows), np.int64)
+    return ids, np.stack([np.asarray(rows[int(i)], np.float32) for i in ids])
 
 
 class TwoTowerMF:
@@ -977,9 +882,14 @@ class TwoTowerMF:
         num: int,
         exclude: Optional[np.ndarray] = None,
         row_mask: Optional[np.ndarray] = None,
-        _force_exact: bool = False,
+        exact: bool = False,
     ) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized top-k over the full catalog for a batch of users.
+
+        Which routine answers is the model's serve plan (serving/plan.py)
+        and the one thing only the batch knows: whether it carries a rule
+        filter. ``exact=True`` skips a plan's pruned stage: the full-catalog
+        scorer answers (warm-up, the recall oracle of streaming/guard.py).
 
         Shape discipline (the serving hot path): the user batch is padded to
         a :data:`SERVE_BUCKETS` bucket and the top-k size is the model's
@@ -1001,35 +911,36 @@ class TwoTowerMF:
             # answers empty; never hand a non-positive k to top-k
             return (np.zeros((len(user_idx), 0), np.int64),
                     np.zeros((len(user_idx), 0), np.float32))
-        if (model._device_items is None and model._device_items_q is None
-                and model._host_items is None and model._sharded is None):
-            model.prepare_for_serving()
+        plan = model._plan or model.prepare_for_serving()._plan
         if row_mask is not None and row_mask.shape != (len(user_idx), model.n_items):
             raise ValueError(
                 f"row_mask shape {row_mask.shape} != "
                 f"(batch, n_items) {(len(user_idx), model.n_items)}")
-        if model._sharded is not None:
-            # sharded layout: per-shard top-k + cross-shard merge
-            # (sharding/serve.py); _force_exact skips only the pruned
-            # (per-shard IVF) stage — exact answers stay sharded
-            return _recommend_batch_sharded(
-                model, user_idx, num, exclude, row_mask, _force_exact)
-        if model._ivf is not None and not _force_exact:
-            from incubator_predictionio_tpu.serving import ann
-
-            if ann.two_stage_enabled(model.n_items):
-                res = _recommend_batch_two_stage(
-                    model, user_idx, num, exclude, row_mask)
-                if res is not None:
-                    return res
-                # fewer candidates than num survived the probe — the exact
-                # path below answers (pio_retrieval_fallback_total counts it)
-        if model._host_items is not None:
+        pruned = plan.pruned is not None and not exact
+        if plan.scorer == serve_plan.SHARDED:
+            # sharded layout (sharding/serve.py): the per-shard IVF prune +
+            # merge-rerank when pruned (any shard under-covering sends the
+            # batch on), else per-shard exact top-k + cross-shard merge
+            sh = model._sharded
+            res = sh.search_ivf(
+                *sh.user_rows(model, user_idx), num, exclude=exclude,
+                row_mask=row_mask, nprobe=plan.nprobe,
+                backend=plan.backend) if pruned else None
+            return res if res is not None else sh.search_exact(
+                model, user_idx, num, exclude=exclude, row_mask=row_mask)
+        if pruned:
+            res = _recommend_batch_two_stage(
+                model, plan, user_idx, num, exclude, row_mask)
+            if res is not None:
+                return res
+            # fewer candidates than num survived the probe — the exact
+            # path below answers (pio_retrieval_fallback_total counts it)
+        if plan.scorer == serve_plan.HOST_NUMPY:
             return _recommend_batch_host(model, user_idx, num, exclude, row_mask)
         b = len(user_idx)
         bucket = serve_bucket(max(b, 1))
-        k = model._serve_k if 0 < num <= model._serve_k else num
-        quantized = model._device_items_q is not None
+        k = plan.serve_k if 0 < num <= plan.serve_k else num
+        quantized = plan.scorer == serve_plan.DEVICE_INT8
         # the int8 executable gets its own jitstats name so `pio-tpu status`
         # top-compiles attributes quantized-kernel compiles distinctly from
         # the bf16 exact scorer (utils/jitstats.executable_name)
@@ -1040,7 +951,7 @@ class TwoTowerMF:
             uidx[:b] = np.asarray(user_idx, np.int32)
             ue_tab, ub_tab = model._device_users
             if quantized:
-                items_q, scales, bias, base_mask = model._device_items_q
+                items_q, scales, bias, base_mask = model._device_items
             else:
                 item_t, item_b, base_mask = model._device_items
             mask = base_mask
@@ -1099,64 +1010,34 @@ def _row_mask_pad_buffer(bucket: int, n_cols: int) -> np.ndarray:
     return buf
 
 
-def _recommend_batch_sharded(
-    model: TwoTowerModel,
-    user_idx: np.ndarray,
-    num: int,
-    exclude: Optional[np.ndarray] = None,
-    row_mask: Optional[np.ndarray] = None,
-    force_exact: bool = False,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Sharded retrieval (sharding/serve.py): the per-shard IVF prune +
-    merge-rerank when two-stage is enabled (falling back to sharded-exact
-    when any shard under-covers), else per-shard exact top-k + merge."""
-    sh = model._sharded
-    if (sh.ivf is not None and any(sh.ivf) and not force_exact):
-        from incubator_predictionio_tpu.serving import ann
-
-        if ann.two_stage_enabled(model.n_items):
-            q, ub = sh.user_rows(model, user_idx)
-            res = sh.search_ivf(q, ub, num, exclude=exclude,
-                                row_mask=row_mask)
-            if res is not None:
-                return res
-    return sh.search_exact(model, user_idx, num, exclude=exclude,
-                           row_mask=row_mask)
-
-
 def _recommend_batch_two_stage(
     model: TwoTowerModel,
+    plan: serve_plan.ServePlan,
     user_idx: np.ndarray,
     num: int,
-    exclude: Optional[np.ndarray] = None,
-    row_mask: Optional[np.ndarray] = None,
+    exclude: Optional[np.ndarray],
+    row_mask: Optional[np.ndarray],
 ) -> Optional[tuple[np.ndarray, np.ndarray]]:
     """Coarse IVF pruning + exact rerank (serving/ann.py): centroid scores
     pick top-nprobe partitions per user, only their members are scored with
     the exact math, and ``exclude``/``row_mask`` land on the rerank scores
     in candidate-index space after the gather. Returns None when the probe
     can't cover ``num`` candidates — the caller's exact path answers."""
-    from incubator_predictionio_tpu.serving import ann
-
     ivf = model._ivf
     filtered = row_mask is not None or (
         exclude is not None and len(exclude) > 0)
-    backend = kernel_backend() if (
-        not filtered and ivf.device_ready
-        and model._device_users is not None
-        and ann.quant_coarse_enabled(True)) else None
-    if backend:
-        # which routine runs follows what is resident and what the batch
-        # carries, not a setting. The queries are the bfloat16 rows the
-        # exact path scores with: the fused float32 [U, D+1] tower lies
-        # column-major on the device, and a row gather from it copies all
-        # of it. A rule-filtered batch stays with the host routine below:
-        # search_device answers it alike, but a dense catalog-length mask
-        # a batch costs more to make and send (10-15 MB at a bucket of 8,
-        # 17 ms on the v5e host) than the host rerank it would save
+    if plan.pruned == serve_plan.DEVICE_LEG and not filtered:
+        # the queries are the bfloat16 rows the exact path scores with: the
+        # fused float32 [U, D+1] tower lies column-major on the device, and
+        # a row gather from it copies all of it. A rule-filtered batch stays
+        # with the host routine below: search_device answers it alike, but
+        # a dense catalog-length mask a batch costs more to make and send
+        # (10-15 MB at a bucket of 8, 17 ms on the v5e host) than the host
+        # rerank it would save
         return ivf.search_device(
             user_idx, model._device_users, model.mean, num,
-            k=model._serve_k, interpret=backend == "interpret")
+            k=plan.serve_k, nprobe=plan.nprobe,
+            interpret=plan.backend == "interpret")
     if not ivf.hydrated:
         # persisted slim and this model never ran _prepare_index (e.g. a
         # build_index=False prepare): rebuild the rerank tables lazily
@@ -1166,7 +1047,8 @@ def _recommend_batch_two_stage(
     q = np.asarray(model.user_emb, np.float32)[uidx]
     ub = np.asarray(model.user_bias, np.float32)[uidx]
     return ivf.search(
-        q, ub, model.mean, num, exclude=exclude, row_mask=row_mask)
+        q, ub, model.mean, num, nprobe=plan.nprobe, exclude=exclude,
+        row_mask=row_mask, backend=plan.backend)
 
 
 def _recommend_batch_host(

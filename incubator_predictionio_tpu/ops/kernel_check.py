@@ -91,7 +91,7 @@ def _catalog_cases(interpret: bool, rng) -> Iterator[dict]:
     int8 block ``(512, d)`` at d 32/64/128, the 100k catalog at rank 128."""
     import jax.numpy as jnp
 
-    from incubator_predictionio_tpu.models.two_tower import (
+    from incubator_predictionio_tpu.serving.plan import (
         ROW_MASK_MAX_ELEMENTS,
         SERVE_BUCKETS,
     )

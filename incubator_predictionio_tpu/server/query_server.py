@@ -622,9 +622,6 @@ class MicroBatcher:
             for _ in range(-delta):
                 await self._sem.acquire()
 
-    #: historical name, kept callable (pre-admission callers and tests)
-    set_max_in_flight = resize
-
     async def _drain(self) -> None:
         loop = asyncio.get_running_loop()
         sem = self._sem = asyncio.Semaphore(self.max_in_flight)

@@ -20,7 +20,7 @@ is the coarse-to-fine answer:
   :func:`~incubator_predictionio_tpu.ops.retrieval.quantize_rows`) and
   scored int8×int8→int32 with ONE fp32 rescale per candidate, grouped by
   partition across the batch so each probed int8 block is read once.
-  The coarse stage quantizes alongside it (``PIO_RETRIEVAL_QUANT_COARSE``).
+  The coarse stage follows the index's storage: an int8 index probes int8.
   ``PIO_RETRIEVAL_QUANTIZE=0`` opts a deployment back onto fp32 rows +
   exact serving math for the rerank (the recall-oracle path, always kept).
   Either way the shared serial-parity top-k chain picks the result.
@@ -35,7 +35,8 @@ oracle; tests assert a recall@k floor against it
 Mode selection is env-driven (``PIO_RETRIEVAL_MODE`` = ``exact`` |
 ``two_stage`` | ``auto``; auto keeps catalogs under
 ``PIO_RETRIEVAL_MIN_ITEMS`` on the exact path so small templates keep
-bitwise parity). See docs/serving.md ("Two-stage retrieval").
+bitwise parity), read once a deployment by serving/plan.resolve. See
+docs/serving.md ("How the serve path is chosen", "Two-stage retrieval").
 """
 
 from __future__ import annotations
@@ -78,7 +79,7 @@ FALLBACKS = REGISTRY.counter(
 INT8_COARSE = REGISTRY.counter(
     "pio_retrieval_int8_coarse_total",
     "Batches whose coarse (centroid) stage scored int8×int8→int32 "
-    "against the quantized centroid table (PIO_RETRIEVAL_QUANT_COARSE)")
+    "against the quantized centroid table (every batch of an int8 index)")
 INT8_RERANK = REGISTRY.counter(
     "pio_retrieval_int8_rerank_total",
     "Batches whose candidate rerank scored int8×int8→int32 over the "
@@ -101,25 +102,16 @@ DEVICE_BLOCK_SKEW = 4
 
 # -- env knobs ---------------------------------------------------------------
 
-def retrieval_mode() -> str:
-    """``PIO_RETRIEVAL_MODE``: ``exact`` | ``two_stage`` | ``auto``."""
+def two_stage_enabled(n_items: int) -> bool:
+    """Whether the environment says to prune a catalog of ``n_items``
+    (``two_stage``, or ``auto`` from ``PIO_RETRIEVAL_MIN_ITEMS`` rows on).
+    For serving/plan.resolve, once a prepare, and the train-time build."""
     mode = os.environ.get("PIO_RETRIEVAL_MODE", "auto").strip().lower()
     if mode not in ("exact", "two_stage", "auto"):
         raise ValueError(
             f"PIO_RETRIEVAL_MODE={mode!r} (want exact|two_stage|auto)")
-    return mode
-
-
-def min_items() -> int:
-    return int(os.environ.get("PIO_RETRIEVAL_MIN_ITEMS", "100000"))
-
-
-def two_stage_enabled(n_items: int) -> bool:
-    """Whether a catalog of ``n_items`` should serve two-stage right now."""
-    mode = retrieval_mode()
-    if mode == "two_stage":
-        return True
-    return mode == "auto" and n_items >= min_items()
+    return mode == "two_stage" or (mode == "auto" and n_items >= int(
+        os.environ.get("PIO_RETRIEVAL_MIN_ITEMS", "100000")))
 
 
 def default_partitions(n_items: int) -> int:
@@ -135,36 +127,23 @@ def resolved_partitions(n_items: int) -> int:
     return c if c > 0 else default_partitions(n_items)
 
 
-def resolved_nprobe(n_partitions: int) -> int:
-    """√C probes by default, clamped to the partition count."""
+def nprobe_override() -> Optional[int]:
+    """``PIO_RETRIEVAL_NPROBE`` when set to a positive count, else None."""
     p = int(os.environ.get("PIO_RETRIEVAL_NPROBE", "0"))
-    if p <= 0:
-        p = max(1, int(round(np.sqrt(n_partitions))))
-    return min(p, n_partitions)
+    return p if p > 0 else None
+
+
+def resolved_nprobe(n_partitions: int, nprobe: Optional[int] = None) -> int:
+    """``nprobe`` clamped to the partition count; √C probes without one."""
+    if nprobe is None:
+        nprobe = int(round(np.sqrt(n_partitions)))
+    return min(max(1, nprobe), n_partitions)
 
 
 def quantize_enabled() -> bool:
     """int8 rerank storage is the default; ``PIO_RETRIEVAL_QUANTIZE=0``
     opts a deployment back onto the fp32 exact-math rerank."""
     return os.environ.get("PIO_RETRIEVAL_QUANTIZE", "1") != "0"
-
-
-def quant_coarse_enabled(index_quantized: bool) -> bool:
-    """``PIO_RETRIEVAL_QUANT_COARSE``: ``auto`` | ``1`` | ``0``.
-
-    Whether the coarse (centroid) stage scores int8×int8→int32 against the
-    quantized centroid table. ``auto`` (default) follows the index's rerank
-    storage — a quantized index probes quantized, an fp32 index probes
-    fp32; ``1``/``0`` force it per deployment. int8 coarse always requires
-    a quantized index (the centroid tables quantize alongside the member
-    rows)."""
-    val = os.environ.get("PIO_RETRIEVAL_QUANT_COARSE", "auto").strip().lower()
-    if val not in ("auto", "1", "0"):
-        raise ValueError(
-            f"PIO_RETRIEVAL_QUANT_COARSE={val!r} (want auto|1|0)")
-    if not index_quantized:
-        return False
-    return val != "0"
 
 
 def _device_free_bytes() -> Optional[int]:
@@ -425,11 +404,13 @@ class IVFIndex:
             "size_skew": round(float(sizes.max()) / mean, 2) if mean else 0.0,
             "empty_partitions": int((sizes == 0).sum()),
             "quantized": self.quantized,
-            "quant_coarse": quant_coarse_enabled(self.quantized),
+            # the coarse stage follows the index's storage
+            "quant_coarse": self.quantized,
             "rerank_bytes": int(rerank_bytes),
             "rerank_bytes_fp32": int(fp32_bytes),
             "bytes_saved": int(fp32_bytes - rerank_bytes),
-            "default_nprobe": resolved_nprobe(self.n_partitions),
+            "default_nprobe": resolved_nprobe(
+                self.n_partitions, nprobe_override()),
             "index_bytes": int(nbytes),
             "build_seconds": round(self.build_seconds, 2),
             "stale_rows": self.stale_count,
@@ -438,24 +419,22 @@ class IVFIndex:
     # -- search -----------------------------------------------------------
 
     def probe(self, q: np.ndarray, nprobe: int,
-              q_quant: Optional[tuple] = None) -> np.ndarray:
+              q_quant: Optional[tuple] = None,
+              backend: Optional[str] = None) -> np.ndarray:
         """Top-``nprobe`` partition ids per query row (``[B, nprobe]``).
 
         With ``q_quant`` (the ``(q_q int8, q_scales f32)`` pair from
         ``quantize_rows``) the centroid scores run int8×int8→int32 with one
         fp32 rescale — the host-exact twin of the Pallas coarse kernel
-        (ops/retrieval.py ``score_centroids_quantized``); the fp32
-        mean-member-bias column is added after the rescale."""
+        (ops/retrieval.py ``score_centroids_quantized``), which runs them
+        instead where the caller's serve plan holds a kernel ``backend``;
+        the fp32 mean-member-bias column is added after the rescale."""
         if q_quant is not None:
             from incubator_predictionio_tpu.ops.retrieval import (
                 int8_matmul_exact,
             )
-            from incubator_predictionio_tpu.parallel.mesh import (
-                kernel_backend,
-            )
 
             q_q, q_scales = q_quant
-            backend = kernel_backend()
             if backend:
                 # the Pallas int8 coarse kernel (ops/retrieval.py). Same
                 # int8×int8→int32 + one-rescale contract as the host twin
@@ -693,8 +672,7 @@ class IVFIndex:
             return (np.zeros((b, 0), np.int64), np.zeros((b, 0), np.float32))
         if b == 0:
             return (np.zeros((0, num), np.int64), np.zeros((0, num), np.float32))
-        nprobe = resolved_nprobe(self.n_partitions) if nprobe is None \
-            else min(max(1, nprobe), self.n_partitions)
+        nprobe = resolved_nprobe(self.n_partitions, nprobe)
         position, tables = self._member_device
         length = int(tables[1].shape[1])
         # a scalar costs a host-to-device transfer of its own on every
@@ -771,6 +749,7 @@ class IVFIndex:
         exclude: Optional[np.ndarray] = None,
         row_mask: Optional[np.ndarray] = None,
         observe: bool = True,
+        backend: Optional[str] = None,
     ) -> Optional[tuple[np.ndarray, np.ndarray]]:
         """Two-stage top-``num``: returns ``(idx [B, num] int64, scores
         [B, num] f32)`` with the exact path's score semantics, or ``None``
@@ -792,8 +771,7 @@ class IVFIndex:
             return (np.zeros((b, 0), np.int64), np.zeros((b, 0), np.float32))
         if b == 0:
             return (np.zeros((0, num), np.int64), np.zeros((0, num), np.float32))
-        nprobe = resolved_nprobe(self.n_partitions) if nprobe is None \
-            else min(max(1, nprobe), self.n_partitions)
+        nprobe = resolved_nprobe(self.n_partitions, nprobe)
         # the two stages are spans (retrieval.batch.coarse|rerank); the
         # histograms read the same clock. Per-shard searches (observe=False)
         # are accounted once, by their caller
@@ -809,12 +787,11 @@ class IVFIndex:
                 # one per-row query quantization serves BOTH stages (the int8
                 # coarse probe and the int8 rerank share q_q/q_scales)
                 q_quant = quantize_rows(np.asarray(q, np.float32))
-            int8_coarse = q_quant is not None and quant_coarse_enabled(True)
-            probe = self.probe(q, nprobe, q_quant=q_quant if int8_coarse else None)
+            probe = self.probe(q, nprobe, q_quant=q_quant, backend=backend)
             counts = np.diff(self.offsets)[probe].sum(axis=1)
         if observe:
             COARSE_SEC.observe(sp.duration)
-            if int8_coarse:
+            if q_quant is not None:
                 INT8_COARSE.inc()
         if int(counts.min()) < num:
             if observe:

@@ -36,11 +36,8 @@ the merged (ids, scores) are bit-identical to the single-host oracle;
 score ties resolve to the earliest candidate position exactly like
 ``lax.top_k`` does on the full score row.
 
-Env knobs (docs/configuration.md): ``PIO_SHARD_SERVE`` = ``auto`` (shard
-when the model's tables are already model-axis sharded, or the simulated
-HBM budget says one chip can't hold the catalog) | ``1`` (always, host
-models get virtual shards) | ``0`` (never); ``PIO_SHARD_SERVE_SHARDS``
-overrides the shard count.
+Env knobs (docs/configuration.md): ``PIO_SHARD_SERVE`` and
+``PIO_SHARD_SERVE_SHARDS``, read by :func:`serving_shards_for` alone.
 """
 
 from __future__ import annotations
@@ -54,6 +51,7 @@ from typing import Any, Optional
 import numpy as np
 
 from incubator_predictionio_tpu.obs import profile as _profile
+from incubator_predictionio_tpu.serving import plan as serve_plan
 from incubator_predictionio_tpu.serving.topk import merge_topk
 from incubator_predictionio_tpu.sharding import shard_metrics as M
 from incubator_predictionio_tpu.sharding.table import (
@@ -63,60 +61,6 @@ from incubator_predictionio_tpu.sharding.table import (
 )
 
 SHARD_AXIS = "shard"
-
-
-# -- mode selection ----------------------------------------------------------
-
-def serve_mode() -> str:
-    """``PIO_SHARD_SERVE``: ``auto`` | ``on`` | ``off``."""
-    raw = os.environ.get("PIO_SHARD_SERVE", "auto").strip().lower()
-    mode = {"auto": "auto", "1": "on", "on": "on", "force": "on",
-            "0": "off", "off": "off"}.get(raw)
-    if mode is None:
-        raise ValueError(
-            f"PIO_SHARD_SERVE={raw!r} (want auto|1|0)")
-    return mode
-
-
-def forced_shards() -> Optional[int]:
-    raw = os.environ.get("PIO_SHARD_SERVE_SHARDS", "").strip()
-    if not raw:
-        return None
-    n = int(raw)
-    return n if n > 1 else None
-
-
-def requested_shards(n_items: int, rank: int, tables=None) -> int:
-    """How many shards serving should use for this model right now
-    (0/1 = stay on the single-host paths).
-
-    ``auto`` engages only when the layout already says sharded (the
-    restored device tables span >1 shards on the model axis) or the
-    simulated HBM budget says the single-chip serving residency does not
-    fit; ``on`` engages whenever more than one shard is realizable
-    (forced count, or one per local device)."""
-    mode = serve_mode()
-    if mode == "off":
-        return 0
-    import jax
-
-    ndev = len(jax.devices())
-    forced = forced_shards()
-    if mode == "on":
-        # at least 2: virtual host shards don't need devices, and "always"
-        # must mean always — a single-device box still gets the sharded
-        # host twin (device tables clamp to the device count at build)
-        return forced or max(ndev, 2)
-    # auto
-    if tables is not None and "ie" in tables:
-        if array_model_shards(tables["ie"]) > 1:
-            return forced or max(ndev, 1)
-    budget = hbm_budget()
-    if budget is not None:
-        one = ShardSpec("ie", n_items, rank + 1, 1)
-        if one.shard_table_bytes() > budget:
-            return forced or max(ndev, 1)
-    return 0
 
 
 def shard_build_key(n_local: int, shard: int) -> dict:
@@ -190,27 +134,55 @@ def model_shard_rows(model, spec: ShardSpec):
     return rows
 
 
-def serving_shards_for(model, host_max_elements: Optional[int] = None,
-                       ) -> int:
-    """How many shards SERVING will use for this model under the current
-    env (0 = the single-host paths). The ONE engage decision — shared by
-    ``_prepare_scoring``, the train-time hook (``ALSAlgorithm.train``
-    building the persisted per-shard IVF), and the deploy-time restore
-    path — so the layouts they pick cannot disagree."""
-    from incubator_predictionio_tpu.models.two_tower import (
-        HOST_SERVE_MAX_ELEMENTS,
-    )
+def layout_shards_of(model) -> int:
+    """How many ways a model's restored item table is split on the model
+    axis (1 for host towers)."""
+    if not model.device_resident or "ie" not in model._tables:
+        return 1
+    return array_model_shards(model._tables["ie"])
 
-    tables = model._tables if model.device_resident else None
-    s = requested_shards(model.n_items, model.config.rank, tables)
-    if s <= 1:
+
+def serving_shards_for(
+    n_items: int, rank: int, layout_shards: int = 1,
+    host_max_elements: int = serve_plan.HOST_SERVE_MAX_ELEMENTS,
+) -> int:
+    """How many shards SERVING will use for a model of these facts under
+    the current env (0 = the single-host paths). The ONE engage decision —
+    shared by the serve plan (serving/plan.resolve), the train-time hook
+    (``ALSAlgorithm.train`` building the persisted per-shard IVF), and the
+    deploy-time restore path — so the layouts they pick cannot disagree.
+
+    ``PIO_SHARD_SERVE`` = ``auto`` engages only for a catalog over the host
+    threshold whose layout already says sharded (the restored device tables
+    span ``layout_shards`` > 1 shards on the model axis) or whose
+    single-chip serving residency the simulated HBM budget refuses; ``1``
+    engages whenever more than one shard is realizable (forced count, or
+    one per local device); ``0`` never."""
+    raw = os.environ.get("PIO_SHARD_SERVE", "auto").strip().lower()
+    mode = {"auto": "auto", "1": "on", "on": "on", "force": "on",
+            "0": "off", "off": "off"}.get(raw)
+    if mode is None:
+        raise ValueError(f"PIO_SHARD_SERVE={raw!r} (want auto|1|0)")
+    if mode == "off":
         return 0
-    host_max = (HOST_SERVE_MAX_ELEMENTS if host_max_elements is None
-                else host_max_elements)
-    small = model.n_items * (model.config.rank + 1) <= host_max
-    if small and serve_mode() != "on":
+    import jax
+
+    ndev = len(jax.devices())
+    forced = int(os.environ.get("PIO_SHARD_SERVE_SHARDS", "").strip() or 0)
+    forced = forced if forced > 1 else None
+    if mode == "on":
+        # at least 2: virtual host shards don't need devices, and "always"
+        # must mean always — a single-device box still gets the sharded
+        # host twin (device tables clamp to the device count at build)
+        return forced or max(ndev, 2)
+    if n_items * (rank + 1) <= host_max_elements:
         return 0
-    return s
+    budget = hbm_budget()
+    if layout_shards > 1 or (budget is not None and ShardSpec(
+            "ie", n_items, rank + 1, 1).shard_table_bytes() > budget):
+        s = forced or ndev
+        return s if s > 1 else 0
+    return 0
 
 
 def restore_shards(n_items: int, rank: int, trained_shards: int = 1) -> int:
@@ -219,33 +191,15 @@ def restore_shards(n_items: int, rank: int, trained_shards: int = 1) -> int:
     the tables land straight in the serving layout — no host staging, no
     post-restore reshard. ``trained_shards`` comes from the persisted
     :class:`~incubator_predictionio_tpu.sharding.table.ShardSpec` record."""
-    mode = serve_mode()
-    if mode == "off":
-        return 0
-    import jax
+    s = serving_shards_for(n_items, rank, trained_shards)
+    if s > 1:
+        import jax
 
-    ndev = len(jax.devices())
-    # clamp forced counts like _build_sharded does: the restore template
-    # places DEVICE arrays, and a persisted model must redeploy under the
-    # same env that served it in-process
-    s = min(forced_shards() or ndev, ndev)
-    if s <= 1:
-        return 0
-    if mode == "on":
-        return s
-    from incubator_predictionio_tpu.models.two_tower import (
-        HOST_SERVE_MAX_ELEMENTS,
-    )
-
-    if n_items * (rank + 1) <= HOST_SERVE_MAX_ELEMENTS:
-        return 0
-    if trained_shards > 1:
-        return s
-    budget = hbm_budget()
-    if budget is not None and ShardSpec(
-            "ie", n_items, rank + 1, 1).shard_table_bytes() > budget:
-        return s
-    return 0
+        # clamp forced counts like prepare_for_serving does: the restore
+        # template places DEVICE arrays, and a persisted model must
+        # redeploy under the same env that served it in-process
+        s = min(s, len(jax.devices()))
+    return s if s > 1 else 0
 
 
 def train_time_shard_ivf(model, persisted: Optional[list] = None,
@@ -254,7 +208,8 @@ def train_time_shard_ivf(model, persisted: Optional[list] = None,
     sharded — persistence runs right after training, so the clustering
     ships with the model and redeploys skip the per-shard re-cluster.
     Returns None when sharded serving would not engage."""
-    s = serving_shards_for(model)
+    s = serving_shards_for(model.n_items, model.config.rank,
+                           layout_shards_of(model))
     if s <= 1:
         return None
     spec = ShardSpec("ie", model.n_items, model.config.rank + 1, s)
@@ -557,13 +512,12 @@ class ShardedServing:
 
         from incubator_predictionio_tpu.models.two_tower import (
             _row_mask_pad_buffer,
-            serve_bucket,
         )
 
         dev = self.device
         t_phase = time.perf_counter()
         b = len(user_idx)
-        bucket = serve_bucket(max(b, 1))
+        bucket = serve_plan.serve_bucket(max(b, 1))
         k = self.serve_k if 0 < num <= self.serve_k else num
         k = min(k, self.spec.n_rows)
         kl = min(k, self.spec.rows_per_shard)
@@ -659,6 +613,8 @@ class ShardedServing:
         return idx, scores
 
     def search_ivf(self, q, ub, num: int, exclude=None, row_mask=None,
+                   nprobe: Optional[int] = None,
+                   backend: Optional[str] = None,
                    ) -> Optional[tuple[np.ndarray, np.ndarray]]:
         """Composed two-stage over shards: each shard prunes its LOCAL
         partitions and reranks its candidates with the exact math; the
@@ -688,9 +644,9 @@ class ShardedServing:
             local_rm = row_mask[:, lo:hi] if row_mask is not None else None
             # observe=False: the batch is accounted ONCE in pio_shard_*,
             # not once per shard in pio_retrieval_*
-            res = idx_s.search(q, ub, self.mean, k_s,
+            res = idx_s.search(q, ub, self.mean, k_s, nprobe=nprobe,
                                exclude=local_excl, row_mask=local_rm,
-                               observe=False)
+                               observe=False, backend=backend)
             if res is None:
                 M.SHARD_FALLBACKS.inc()
                 return None
@@ -767,7 +723,9 @@ class ShardedServing:
         ``self.ivf`` in place)."""
         from incubator_predictionio_tpu.serving import ann
 
-        if not self.ivf or not ann.two_stage_enabled(self.spec.n_rows):
+        if not self.ivf:
+            # prepare builds the per-shard indexes only under a plan that
+            # prunes this catalog: none here, nothing to keep fresh
             return
         frac = float(os.environ.get("PIO_STREAM_STALE_REBUILD_FRAC", "0.25"))
         tables = getattr(model, "_tables", None) if model is not None else None

@@ -9,10 +9,10 @@ deeper schedule:
 - **norm bound** — a row whose norm exceeds ``max_norm_factor`` × the base
   tables' p99 row norm trips (legitimate learning moves rows, it does not
   detonate them);
-- **recall floor** — when the model serves two-stage retrieval, sampled
-  queries compare the pruned path against the exact oracle
-  (``_force_exact``); recall@k under ``recall_floor`` trips — the
-  "two-stage index stays honest" contract under streaming staleness;
+- **recall floor** — when the model's serve plan prunes the catalog,
+  sampled queries compare the pruned path against the exact oracle
+  (``recommend_batch(exact=True)``); recall@k under ``recall_floor`` trips
+  — the "two-stage index stays honest" contract under streaming staleness;
 - **reference bound** (tests/bench) — :func:`compare_to_reference` scores
   an incremental model against a full retrain the way the
   ``adam_moments_dtype`` parity suite bounds bf16 vs fp32 moments.
@@ -143,12 +143,10 @@ class DivergenceGuard:
             return None
         self._folds_since_recall = 0
         mf = getattr(model, "mf", model)
-        ivf = getattr(mf, "_ivf", None)
-        if ivf is None:
+        if getattr(mf, "_ivf", None) is None:
             return None
-        from incubator_predictionio_tpu.serving import ann
-
-        if not ann.two_stage_enabled(mf.n_items):
+        plan = mf._plan or mf.prepare_for_serving()._plan
+        if plan.pruned is None:
             return None
         from incubator_predictionio_tpu.models.two_tower import TwoTowerMF
 
@@ -162,7 +160,7 @@ class DivergenceGuard:
         k = min(cfg.recall_k, mf.n_items)
         pruned_idx, _ = TwoTowerMF.recommend_batch(mf, sample, k)
         exact_idx, _ = TwoTowerMF.recommend_batch(mf, sample, k,
-                                                  _force_exact=True)
+                                                  exact=True)
         hits = sum(
             len(set(p.tolist()) & set(e.tolist()))
             for p, e in zip(pruned_idx, exact_idx))
@@ -202,9 +200,9 @@ def compare_to_reference(inc_model, ref_model, sample_users: int = 64,
     rmse = float(np.sqrt(np.mean((s_inc - s_ref) ** 2)))
     k = min(k, n_items)
     top_inc, _ = TwoTowerMF.recommend_batch(inc, sample.astype(np.int32), k,
-                                            _force_exact=True)
+                                            exact=True)
     top_ref, _ = TwoTowerMF.recommend_batch(ref, sample.astype(np.int32), k,
-                                            _force_exact=True)
+                                            exact=True)
     overlap = sum(
         len(set(a.tolist()) & set(b.tolist()))
         for a, b in zip(top_inc, top_ref)) / float(top_ref.size)

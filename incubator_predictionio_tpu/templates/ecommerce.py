@@ -461,7 +461,7 @@ class ECommAlgorithm(PAlgorithm):
         # ROW_MASK_MAX_ELEMENTS (the device path's bound) by chunking the
         # batch — a deep micro-batch over a huge catalog must not balloon
         # host memory to O(B × N); chunking changes no result.
-        from incubator_predictionio_tpu.models.two_tower import (
+        from incubator_predictionio_tpu.serving.plan import (
             ROW_MASK_MAX_ELEMENTS,
         )
 

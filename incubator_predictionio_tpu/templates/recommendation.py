@@ -604,7 +604,7 @@ class ALSAlgorithm(PAlgorithm):
         # cluster it HERE — the trainer persists right after this (either the
         # device-model sidecar or default model pickling), so the index ships
         # with the model and redeploys skip the re-cluster. No-op below the
-        # auto threshold; prepare_for_serving still (re)builds on env drift.
+        # auto threshold; a deploy whose build key differs re-clusters.
         with span("train.verb.index"):
             mf._prepare_index()
         return RecModel(mf, user_map, item_map)
@@ -750,7 +750,7 @@ class ALSAlgorithm(PAlgorithm):
             for qi, q in queries if q.user not in model.user_map
         ]
         if known:
-            from incubator_predictionio_tpu.models.two_tower import (
+            from incubator_predictionio_tpu.serving.plan import (
                 ROW_MASK_MAX_ELEMENTS,
                 serve_bucket,
             )

@@ -1189,7 +1189,8 @@ def format_index_stats(models) -> list[str]:
         mode = info.get("retrieval_mode", "exact")
         lines.append(f"model {i} ({name}): path={info.get('path', '?')} "
                      f"catalog_rows={info.get('catalog_rows', '?')} "
-                     f"retrieval={mode}")
+                     f"retrieval={mode} "
+                     f"pruned={info.get('pruned') or 'none'}")
         stats = info.get("index")
         if isinstance(stats, list):
             # sharded serving: one IVF per shard (docs/sharding.md)
@@ -1235,8 +1236,7 @@ def format_index_stats(models) -> list[str]:
                 f"  quantization: int8 member rows "
                 f"({stats.get('rerank_bytes', '?')} bytes, saves "
                 f"{stats.get('bytes_saved', 0)} vs fp32) + "
-                f"{'int8' if stats.get('quant_coarse') else 'fp32'} coarse "
-                "(PIO_RETRIEVAL_QUANT_COARSE)")
+                f"{'int8' if stats.get('quant_coarse') else 'fp32'} coarse")
     return lines
 
 

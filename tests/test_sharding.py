@@ -435,9 +435,7 @@ def test_serve_shards_fewer_than_trained(shard_env):
     m = TwoTowerModel(mean=1.0, config=TwoTowerConfig(rank=RANK))
     m._tables = {"ue": ue, "ie": ie}
     m._n_users, m._n_items = n_users, n_items
-    m._sharded = sh
-    m._serve_k = 10
-    idx, sc = TwoTowerMF.recommend_batch(m, np.arange(5, dtype=np.int32), 10)
+    idx, sc = sh.search_exact(m, np.arange(5, dtype=np.int32), 10)
     assert idx.shape == (5, 10) and np.isfinite(np.asarray(sc)).all()
     assert int(np.asarray(idx).max()) < n_items
     del jax
@@ -566,7 +564,7 @@ def test_shard_info_and_cli_formatting(two_stage_sharded_env):
     info = m.shard_info()
     assert info["sharded"] and info["n_shards"] == 4
     assert info["items"]["n_rows"] == n_items
-    assert info["merge_fanin"] == 4 * min(m._serve_k, info["items"]["rows_per_shard"])
+    assert info["merge_fanin"] == 4 * min(m._plan.serve_k, info["items"]["rows_per_shard"])
 
     class FakeRec:
         def shard_info(self):

@@ -862,7 +862,7 @@ def test_two_stage_stale_rows_serve_current_embeddings(monkeypatch):
     uidx = np.asarray([0], np.int32)
     pruned_idx, pruned_scores = TwoTowerMF.recommend_batch(new.mf, uidx, 5)
     exact_idx, exact_scores = TwoTowerMF.recommend_batch(
-        new.mf, uidx, 5, _force_exact=True)
+        new.mf, uidx, 5, exact=True)
     # the pruned probe CANNOT miss the moved row, and it serves the
     # post-update score, not the pre-update embedding
     assert exact_idx[0][0] == target

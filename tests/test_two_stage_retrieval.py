@@ -508,7 +508,8 @@ def test_cli_index_stats_formatting(two_stage_env):
     plain = _exact_oracle()
     lines = format_index_stats([indexed, plain])
     text = "\n".join(lines)
-    assert "retrieval=two_stage" in text
+    assert "retrieval=two_stage pruned=host-routine" in text
+    assert "retrieval=exact pruned=none" in text
     assert f"over {indexed.n_items} items" in text
     assert "no partition index" in text  # the exact model's row
 
@@ -518,7 +519,6 @@ def test_cli_index_stats_formatting(two_stage_env):
 @pytest.fixture
 def int8_env(two_stage_env, monkeypatch):
     monkeypatch.setenv("PIO_RETRIEVAL_QUANTIZE", "1")
-    monkeypatch.delenv("PIO_RETRIEVAL_QUANT_COARSE", raising=False)
 
 
 @pytest.mark.parametrize(
@@ -533,7 +533,7 @@ def test_int8_end_to_end_recall_floor_all_mask_kinds(int8_env, kind):
     model.prepare_for_serving()
     ivf = model._ivf
     assert ivf.quantized and ivf.emb_m is None
-    assert ivf.stats()["quant_coarse"]  # auto follows the quantized index
+    assert ivf.stats()["quant_coarse"]  # the coarse stage follows storage
     users = np.arange(64, dtype=np.int32)
     exclude, row_mask = _filter_cases(oracle, users)[kind]
     coarse0 = ann.INT8_COARSE._default().value
@@ -594,28 +594,6 @@ def test_int8_fallbacks_answer_from_exact_path(int8_env, monkeypatch):
                                              row_mask=white)
     np.testing.assert_array_equal(gi, oi)
     np.testing.assert_allclose(gs, oscores, rtol=1e-5, atol=1e-5)
-
-
-def test_int8_coarse_knob_opt_out(int8_env, monkeypatch):
-    """PIO_RETRIEVAL_QUANT_COARSE=0: rerank stays int8, the coarse stage
-    scores fp32 — counted (and reported) accordingly."""
-    monkeypatch.setenv("PIO_RETRIEVAL_QUANT_COARSE", "0")
-    model = _clustered_model()
-    model.prepare_for_serving()
-    ivf = model._ivf
-    assert ivf.quantized and not ivf.stats()["quant_coarse"]
-    coarse0 = ann.INT8_COARSE._default().value
-    rerank0 = ann.INT8_RERANK._default().value
-    users = np.arange(16, dtype=np.int32)
-    gi, _ = TwoTowerMF.recommend_batch(model, users, 10)
-    assert gi.shape == (16, 10)
-    assert ann.INT8_COARSE._default().value == coarse0
-    assert ann.INT8_RERANK._default().value == rerank0 + 1
-    # an fp32 index can never opt IN to int8 coarse
-    assert not ann.quant_coarse_enabled(False)
-    with pytest.raises(ValueError, match="PIO_RETRIEVAL_QUANT_COARSE"):
-        monkeypatch.setenv("PIO_RETRIEVAL_QUANT_COARSE", "maybe")
-        ann.quant_coarse_enabled(True)
 
 
 def test_int8_stats_report_bytes_saved(int8_env):
@@ -786,13 +764,17 @@ def test_device_leg_matches_host_search(uneven, b, form, nprobe, num):
         assert (np.take_along_axis(row_mask, got[0], axis=1) == 0).all()
 
 
-def _device_model(monkeypatch, quantize_index="1"):
+def _device_model(monkeypatch, quantize_index="1", interpret=True,
+                  mode="two_stage"):
     """A model whose towers and kernels are 'on a device': the kernels under
     the Pallas interpreter, the int8 catalog + bf16 users resident."""
-    monkeypatch.setenv("PIO_RETRIEVAL_MODE", "two_stage")
+    monkeypatch.setenv("PIO_RETRIEVAL_MODE", mode)
     monkeypatch.setenv("PIO_RETRIEVAL_NPROBE", "16")
     monkeypatch.setenv("PIO_RETRIEVAL_QUANTIZE", quantize_index)
-    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "1")
+    if interpret:
+        monkeypatch.setenv("PIO_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PIO_PALLAS_INTERPRET", raising=False)
     model = _clustered_model(n_items=2048)
     model.prepare_for_serving(quantize=True, serve_k=16, host_max_elements=0)
     return model
@@ -800,20 +782,21 @@ def _device_model(monkeypatch, quantize_index="1"):
 
 @pytest.mark.parametrize(
     "case", ["resident", "stale_overlay", "float32_index",
-             "no_kernel_backend", "coarse_knob_off", "no_room",
-             "exclude", "row_mask"])
+             "no_kernel_backend", "no_room", "exclude", "row_mask"])
 def test_device_leg_runs_iff_resident(monkeypatch, case):
-    """Which routine answers follows what is resident and what the batch
-    carries (no setting): the device leg iff kernels, towers and a
-    quantized index without an overlay are on a device and the batch has no
-    rule filter (a dense mask a batch costs more to send than the host
-    rerank it would save); IVFIndex.search otherwise."""
+    """Which routine answers follows what prepare found resident (the serve
+    plan's ``pruned``) and what the batch carries (no setting): the device
+    leg iff kernels, towers and a quantized index without an overlay are on
+    a device and the batch has no rule filter (a dense mask a batch costs
+    more to send than the host rerank it would save); IVFIndex.search
+    otherwise."""
     if case == "no_room":  # the padded layout wants over half of what is free
         monkeypatch.setattr(ann, "_device_free_bytes", lambda: 1 << 16)
     model = _device_model(
-        monkeypatch, quantize_index="0" if case == "float32_index" else "1")
+        monkeypatch, quantize_index="0" if case == "float32_index" else "1",
+        interpret=case != "no_kernel_backend")
     assert model._ivf.device_ready == (
-        case not in ("float32_index", "no_room"))
+        case not in ("float32_index", "no_room", "no_kernel_backend"))
     if case == "stale_overlay":
         rows = {7: np.ones(model.config.rank + 1, np.float32)}
         moved = model.with_row_updates(item_rows=rows)
@@ -822,10 +805,10 @@ def test_device_leg_runs_iff_resident(monkeypatch, case):
         assert moved._ivf.stale_count == 1 and not moved._ivf.device_ready
         assert model._ivf.device_ready  # the live model's view is its own
         model = moved
-    elif case == "no_kernel_backend":
-        monkeypatch.delenv("PIO_PALLAS_INTERPRET")
-    elif case == "coarse_knob_off":
-        monkeypatch.setenv("PIO_RETRIEVAL_QUANT_COARSE", "0")
+    on_device = case in ("resident", "exclude", "row_mask")
+    assert model._plan.pruned == (
+        "device-leg" if on_device else "host-routine")
+    assert model.serving_info()["pruned"] == model._plan.pruned
     engaged = ann.DEVICE_RERANK._default().value
     batches = ann.TWO_STAGE_BATCHES._default().value
     users = np.arange(5, dtype=np.int32)
@@ -860,7 +843,7 @@ def test_device_leg_leaves_a_restored_tower_on_the_device(monkeypatch):
     assert ann.DEVICE_RERANK._default().value == engaged + 1
     assert fused.user_emb is None  # nothing was pulled to the host
     want = fused._ivf.search(host.user_emb[users], host.user_bias[users],
-                             host.mean, 10, observe=False)
+                             host.mean, 10, nprobe=16, observe=False)
     np.testing.assert_allclose(scores, want[1], rtol=5e-3, atol=0)
     assert (idx == want[0]).mean() > 0.8
 
@@ -878,32 +861,62 @@ def test_device_leg_spans_and_fallback_to_exact(monkeypatch):
               if s["name"].startswith("retrieval.batch.")}
     assert stages["retrieval.batch.coarse"]["where"] == "device"
     assert stages["retrieval.batch.rerank"]["where"] == "device"
+    # the plan is fixed at prepare: a narrower probe is a new prepare
     monkeypatch.setenv("PIO_RETRIEVAL_NPROBE", "1")
+    model.prepare_for_serving(quantize=True, serve_k=16, host_max_elements=0)
+    assert model._plan.nprobe == 1 and model._plan.pruned == "device-leg"
     num = int(np.diff(model._ivf.offsets).max()) + 1
     fallbacks = ann.FALLBACKS._default().value
     idx, _ = TwoTowerMF.recommend_batch(model, users, num)
     assert ann.FALLBACKS._default().value == fallbacks + 1
-    monkeypatch.setenv("PIO_RETRIEVAL_MODE", "exact")
-    exact, _ = TwoTowerMF.recommend_batch(model, users, num)
+    exact, _ = TwoTowerMF.recommend_batch(model, users, num, exact=True)
     np.testing.assert_array_equal(idx, exact)
+    assert ann.FALLBACKS._default().value == fallbacks + 1
 
 
-def test_device_leg_warmup_leaves_nothing_to_compile(monkeypatch):
-    """After the deploy's warm-up a dispatch at each coarse bucket builds no
-    executable: jitstats' keys and the jit caches themselves stay flat."""
+@pytest.mark.parametrize("kind", ["device-leg", "host-routine", "exact"])
+def test_device_leg_warmup_leaves_nothing_to_compile(monkeypatch, kind):
+    """Warm-up walks the plan's warm list, and after it a dispatch at every
+    serve bucket builds no executable, plain, rule-filtered or sent to the
+    full-catalog scorer (the pruned path's fallback): jitstats' keys and
+    the jit caches themselves stay flat."""
+    from incubator_predictionio_tpu.models import two_tower
     from incubator_predictionio_tpu.ops import retrieval
+    from incubator_predictionio_tpu.serving.plan import (
+        ROW_MASK_MAX_ELEMENTS,
+        SERVE_BUCKETS,
+        WarmShape,
+    )
     from incubator_predictionio_tpu.utils import jitstats
 
-    model = _device_model(monkeypatch)
-    model._warmup_buckets(max_batch=64, two_stage=True)
+    if kind == "host-routine":  # an int8 index the device has no room for
+        monkeypatch.setattr(ann, "_device_free_bytes", lambda: 1 << 16)
+    model = _device_model(
+        monkeypatch, mode="exact" if kind == "exact" else "two_stage")
+    plan = model._plan
+    assert plan.pruned == (None if kind == "exact" else kind)
+    max_batch = 16
+    buckets = [b for b in SERVE_BUCKETS if b <= max_batch]
+    # the coarse kernel's buckets past the prime's, then every exact bucket
+    coarse = [b for b in buckets if b > 8] if plan.pruned else []
+    assert plan.warm_shapes(max_batch) == (
+        [WarmShape(1, "two_stage")] if plan.pruned else []) + [
+        WarmShape(b, "two_stage") for b in coarse] + [
+        WarmShape(b, "exact", True) for b in buckets]
+    assert buckets[-1] * model.n_items <= ROW_MASK_MAX_ELEMENTS
+    assert model.warmup(max_batch) == len(coarse) + len(buckets)
     fns = (retrieval.quantize_user_rows, retrieval.score_centroids_quantized,
-           retrieval.two_stage_rerank)
+           retrieval.two_stage_rerank, two_tower._topk_quantized)
     keys, sizes = jitstats.count(), [f._cache_size() for f in fns]
     engaged = ann.DEVICE_RERANK._default().value
-    for b in (8, 16, 32, 64):
-        idx, _ = TwoTowerMF.recommend_batch(
-            model, np.arange(b, dtype=np.int32), 10)
-        assert idx.shape == (b, 10)
-    assert ann.DEVICE_RERANK._default().value == engaged + 4
+    for b in buckets:
+        users = np.arange(b, dtype=np.int32)
+        mask = np.zeros((b, model.n_items), np.float32)
+        for kw in ({}, {"row_mask": mask}, {"exact": True},
+                   {"exact": True, "row_mask": mask}):
+            idx, _ = TwoTowerMF.recommend_batch(model, users, 10, **kw)
+            assert idx.shape == (b, 10)
+    assert ann.DEVICE_RERANK._default().value == engaged + (
+        len(buckets) if kind == "device-leg" else 0)
     assert jitstats.count() == keys
     assert [f._cache_size() for f in fns] == sizes
