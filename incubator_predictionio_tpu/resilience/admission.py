@@ -376,18 +376,24 @@ class InflightGate:
 
 
 class AdaptiveConcurrencyLimiter:
-    """AIMD concurrency limit driven by observed latency vs. a target.
+    """AIMD concurrency limit driven by an observed time vs. a target.
+
+    What is observed is for the caller to choose and must be the time the
+    limited resource was HELD: the query server feeds one sample a clean
+    batch dispatch, the time that batch held its dispatch slot
+    (``AdmissionController.on_dispatch``), never a request's wait in the
+    queue in front of the slots, which fewer slots only lengthen.
 
     Additive increase / multiplicative decrease on a per-window median:
-    every ``window`` completions (rate-limited by ``cooldown_sec``), a
+    every ``window`` samples (rate-limited by ``cooldown_sec``), a
     median above the target shrinks the limit by ``backoff``; a median
     comfortably below it (< ``headroom`` × target) grows it by one slot.
 
     Gradient mode: with no explicit ``target_sec``, the target is
-    ``tolerance ×`` a rolling-minimum latency baseline — the window
-    minimum is adopted immediately when it improves and drifts up slowly
-    otherwise, so the "no-queue" latency the engine is capable of becomes
-    the yardstick the limit is judged against.
+    ``tolerance ×`` a rolling-minimum baseline — the window minimum is
+    adopted immediately when it improves and drifts up slowly otherwise,
+    so the uncontended time the engine is capable of becomes the
+    yardstick the limit is judged against.
     """
 
     def __init__(self, min_limit: int = 1, max_limit: int = 2,
@@ -431,7 +437,7 @@ class AdaptiveConcurrencyLimiter:
         return self.tolerance * self._baseline
 
     def observe(self, latency_sec: float) -> Optional[int]:
-        """Record one completion; returns the NEW limit iff it changed."""
+        """Record one sample; returns the NEW limit iff it changed."""
         with self._lock:
             self._samples.append(latency_sec)
             if len(self._samples) < self.window:
@@ -512,7 +518,8 @@ class AdmissionConfig:
     adaptive: bool = True
     min_inflight: int = 1
     max_inflight: int = 2
-    target_latency_sec: Optional[float] = None  # None = gradient mode
+    # target for the time a batch dispatch holds its slot; None = gradient
+    target_latency_sec: Optional[float] = None
 
 
 class AdmissionController:
@@ -628,20 +635,25 @@ class AdmissionController:
         return self._brownout
 
     # -- feedback ---------------------------------------------------------
-    def on_complete(self, latency_sec: float,
-                    observe_latency: bool = True) -> Optional[int]:
-        """Record a served request (feeds the service-rate estimate and
-        the adaptive limiter); returns the new concurrency limit iff it
-        changed. ``observe_latency=False`` feeds ONLY the rate estimate —
-        non-predict completions (binding 400s, degraded answers) drain
-        the queue like any other, but their near-instant latencies would
-        poison the limiter's gradient-mode rolling-min baseline (a ~1 ms
-        400 adopted as the "no-queue" floor makes every real prediction
-        read as congestion and pins the limit at its minimum)."""
+    def on_complete(self) -> None:
+        """Record a served request: one more unit of drain progress for the
+        service-rate estimate (the 429 gate and ``Retry-After`` read it).
+        Every request that left the queue counts, 400s and degraded answers
+        too. The adaptive limiter is NOT fed here: a request's latency is
+        mostly its wait in the batcher's queue, in FRONT of the dispatch
+        slots, which fewer slots can only lengthen (:meth:`on_dispatch`)."""
         self._completions.record(1)
-        if observe_latency and self.limiter is not None:
-            return self.limiter.observe(latency_sec)
-        return None
+
+    def on_dispatch(self, held_sec: float) -> Optional[int]:
+        """Record how long one clean batch dispatch held its slot (feeds
+        the adaptive limiter); returns the new concurrency limit iff it
+        changed. Failed dispatches and ones that rejected a query stay out:
+        a ~1 ms all-400 batch adopted as the gradient-mode "no-contention"
+        floor would make every real dispatch read as congestion and pin
+        the limit at its minimum."""
+        if self.limiter is None:
+            return None
+        return self.limiter.observe(held_sec)
 
     def on_shed_expired(self, n: int = 1) -> None:
         self.shed_expired += n

@@ -108,6 +108,11 @@ _G_BATCHES = REGISTRY.gauge(
     "pio_serving_batches", "Micro-batches dispatched to the device")
 _G_MAX_BATCH = REGISTRY.gauge(
     "pio_serving_max_batch_seen", "Largest micro-batch coalesced so far")
+_BATCH_SLOTS = REGISTRY.counter(
+    "pio_serving_batch_slots_total",
+    "Dispatch-slot bound in force at each micro-batch's assembly, summed: "
+    "its growth over pio_serving_batches' is the mean number of slots the "
+    "batches ran under (docs/resilience.md \"Adaptive concurrency\")")
 _G_LATENCY_Q = REGISTRY.gauge(
     "pio_serving_latency_seconds",
     "Serving latency split into its terms (exact reservoir quantiles)",
@@ -196,13 +201,14 @@ class ServerConfig:
     admission_max_queue: int = dataclasses.field(
         default_factory=lambda: int(
             os.environ.get("PIO_ADMISSION_MAX_QUEUE", "256")))
-    # adaptive concurrency limiter: AIMD on observed latency, live-resizes
-    # the micro-batcher's dispatch slots within [1, effective max]
+    # adaptive concurrency limiter: AIMD on the time a batch's dispatch
+    # holds its slot, live-resizes the micro-batcher's dispatch slots
+    # within [1, effective max]
     admission_adaptive: bool = dataclasses.field(
         default_factory=lambda: os.environ.get(
             "PIO_ADMISSION_ADAPTIVE", "1") != "0")
-    # explicit latency target for the limiter (ms); unset = gradient mode
-    # (the target tracks a rolling-minimum latency baseline)
+    # explicit target for a batch's dispatch time (ms); unset = gradient
+    # mode (the target tracks a rolling-minimum dispatch-time baseline)
     admission_target_ms: Optional[float] = dataclasses.field(
         default_factory=lambda: (
             float(os.environ["PIO_ADMISSION_TARGET_MS"])
@@ -529,7 +535,9 @@ class MicroBatcher:
         # ambient deadline so storage calls under predict inherit it
         self.deadline_sec = deadline_sec
         self._clock = clock
-        self._admission = admission  # shed bookkeeping only (may be None)
+        # shed bookkeeping, and the adaptive limiter that each clean
+        # dispatch's duration feeds (may be None)
+        self._admission = admission
         self.queue: asyncio.Queue = asyncio.Queue()
         self.batches_served = 0
         self.max_batch_seen = 0
@@ -539,6 +547,7 @@ class MicroBatcher:
         self._task: Optional[asyncio.Task] = None
         self._sem: Optional[asyncio.Semaphore] = None
         self._inflight: set[asyncio.Task] = set()
+        self._resizes: set[asyncio.Task] = set()  # strong refs
         self._stopped = False
 
     def start(self) -> None:
@@ -551,6 +560,10 @@ class MicroBatcher:
         """Cancel the drainer and fail everything still queued so callers
         don't hang until aiohttp force-cancels them."""
         self._stopped = True
+        # a shrink could be parked on the dispatch semaphore; nothing will
+        # ever need the smaller bound again
+        for task in list(self._resizes):
+            task.cancel()
         if self._task is not None:
             self._task.cancel()
             try:
@@ -664,6 +677,7 @@ class MicroBatcher:
                     sem.release()
                     continue
                 self.batches_served += 1
+                _BATCH_SLOTS.inc(self.max_in_flight)
                 self.max_batch_seen = max(self.max_batch_seen, len(batch))
                 task = loop.create_task(self._dispatch(loop, batch))
                 self._inflight.add(task)
@@ -747,13 +761,28 @@ class MicroBatcher:
         # predict_batch published its per-algorithm times inside ctx; writes
         # made under Context.run persist in the Context object
         algo_times = ctx.get(_DISPATCH_ALGO_TIMES, [])
+        delivered = False
         with _trace.trace_scope(lead), \
                 _trace.span("serve.batch.merge", **attrs):
             resolved_at = time.perf_counter()
             for entry, r in zip(batch, results):
                 if not entry[1].done():
+                    delivered = True
                     entry[1].set_result(
                         _Delivered(r, algo_times, resolved_at))
+        # the limiter sizes the dispatches in flight, so what it observes is
+        # how long this one held its slot. Only a clean dispatch somebody
+        # waited out says that: a failed one, one that rejected or healed a
+        # query, or one whose callers all gave up first feeds nothing
+        if (self._admission is not None and delivered
+                and not any(isinstance(r, Exception) for r in results)):
+            limit = self._admission.on_dispatch(sp.duration)
+            if limit is not None and limit != self.max_in_flight:
+                # off the dispatch's own path: a shrink waits out whichever
+                # dispatch holds the slot it takes away
+                task = loop.create_task(self.resize(limit))
+                self._resizes.add(task)
+                task.add_done_callback(self._resizes.discard)
 
 
 # LatencyReservoir moved to obs/metrics.py (it is a general primitive the
@@ -880,7 +909,6 @@ class QueryServer:
             deadline_sec=config.query_timeout_sec,
             clock=clock, admission=self._admission,
         )
-        self._resize_tasks: set[asyncio.Task] = set()  # strong refs
         self.request_count = 0
         self.avg_serving_sec = 0.0
         self.last_serving_sec = 0.0
@@ -1286,8 +1314,7 @@ class QueryServer:
                      for name, sec in algo_times)
         return ", ".join(parts)
 
-    def _feed_admission(self, dt: float,
-                        observe_latency: bool = True) -> None:
+    def _feed_admission(self) -> None:
         """Every request that consumed a batcher queue slot counts as drain
         progress — 400 binding rejections, timeout-degraded answers, and
         engine exceptions all drained the queue (and usually a dispatch)
@@ -1295,17 +1322,11 @@ class QueryServer:
         successes under-reads the true drain rate, shedding good traffic
         below capacity on mixed workloads. Brownout answers and abandoned
         entries never enter the queue, so they stay out; assembly-time
-        504-evictions are recorded by ``on_shed_expired`` instead. Only
-        clean predictions carry ``observe_latency`` — the AIMD limiter's
-        gradient baseline must track genuine predict latency, not a fast
-        400's — and a changed limit resizes the batcher's slots off the
-        hot path."""
-        new_limit = self._admission.on_complete(
-            dt, observe_latency=observe_latency)
-        if new_limit is not None and new_limit != self.batcher.max_in_flight:
-            task = asyncio.create_task(self.batcher.resize(new_limit))
-            self._resize_tasks.add(task)
-            task.add_done_callback(self._resize_tasks.discard)
+        504-evictions are recorded by ``on_shed_expired`` instead. No
+        request's latency goes to the adaptive limiter: that one sizes the
+        dispatch slots and hears from ``MicroBatcher._dispatch``, once a
+        batch."""
+        self._admission.on_complete()
 
     async def _serve_payload(
             self, body: bytes, t_entry: Optional[float] = None,
@@ -1393,8 +1414,7 @@ class QueryServer:
             # the engine answered (binding rejected the query): health-wise
             # a success — a half-open probe slot must never leak
             self._serving_breaker.record_success()
-            self._feed_admission(self._clock.monotonic() - t0,
-                                 observe_latency=False)
+            self._feed_admission()
             return 400, {"message": f"Invalid query: {e}"}, None
         except (asyncio.TimeoutError, ServingUnavailable, DeadlineExceeded,
                 CircuitOpenError) as e:
@@ -1405,8 +1425,7 @@ class QueryServer:
             # freshly swapped instance — restore the pinned previous one
             await self._maybe_probation_rollback(repr(e))
             self._ship_remote_log(f"query degraded: {e!r}")
-            self._feed_admission(self._clock.monotonic() - t0,
-                                 observe_latency=False)
+            self._feed_admission()
             return 200, await loop.run_in_executor(
                 None, self._degraded_result, payload, repr(e)), None
         except Exception as e:  # noqa: BLE001 - ship serving errors remotely
@@ -1417,8 +1436,7 @@ class QueryServer:
             # surfaces here as ServingUnavailable (counted above).
             self._serving_breaker.record_success()
             self._ship_remote_log(f"query failed: {e!r}")
-            self._feed_admission(self._clock.monotonic() - t0,
-                                 observe_latency=False)
+            self._feed_admission()
             raise
         # future resolved → answer built: the wait for the loop to reach
         # this request behind its batch-mates' answers, then the block (the
@@ -1437,7 +1455,7 @@ class QueryServer:
         self.last_serving_sec = dt
         self.avg_serving_sec += (dt - self.avg_serving_sec) / self.request_count
         self.latency.record(dt)
-        self._feed_admission(dt)
+        self._feed_admission()
         # camelCase field names: the reference's response shape
         # (CreateServer.scala:494's json4s serialization of e.g. ItemScore)
         result = to_jsonable(prediction, camelize_fields=True)
@@ -2049,10 +2067,6 @@ class QueryServer:
             self._front = None
         if self._runner is not None:
             await self._runner.cleanup()
-        # a shrink mid-shutdown could be parked on the dispatch semaphore;
-        # nothing will ever need the smaller bound again
-        for task in list(self._resize_tasks):
-            task.cancel()
         lag = getattr(self, "_loop_lag", None)
         if lag is not None:
             lag.cancel()

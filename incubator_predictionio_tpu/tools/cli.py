@@ -3057,7 +3057,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "(PIO_ADMISSION_MAX_QUEUE env, default 256 — "
                         "docs/resilience.md)")
     p.add_argument("--admission-target-ms", type=float,
-                   help="explicit latency target (ms) for the adaptive "
+                   help="explicit target (ms of a batch's dispatch, the "
+                        "time it holds a slot) for the adaptive "
                         "concurrency limiter; unset = gradient mode "
                         "(PIO_ADMISSION_TARGET_MS env)")
     p.add_argument("--no-adaptive-admission", action="store_true",

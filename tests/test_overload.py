@@ -6,7 +6,9 @@ Every timing-dependent decision runs on FakeClock — limit changes, sheds,
 brownout enter/exit, and Retry-After values are asserted exactly, with no
 wall-clock sleeps (the ISSUE 5 acceptance bar). The asyncio plumbing
 (futures resolving, semaphores resizing) uses the event loop but never
-waits out a timing window.
+waits out a timing window. The exception: what the limiter hears from a live
+batcher is a real dispatch's duration, so those tests drive a stub that
+sleeps 10-30 ms a batch, with margins of 2x and more around every threshold.
 """
 
 import asyncio
@@ -221,7 +223,7 @@ def test_admission_always_admits_empty_queue():
     ctrl = _controller(clk, max_queue=4, deadline_sec=0.1)
     # even with a painfully slow observed service rate, an empty queue
     # waits ~0 — the structural zero-sheds-below-capacity property
-    ctrl.on_complete(1.0)
+    ctrl.on_complete()
     clk.advance(10.0)
     for _ in range(20):
         decision, retry = ctrl.decide(0)
@@ -243,9 +245,9 @@ def test_admission_rejects_infeasible_deadline_with_derived_retry_after():
     ctrl = _controller(clk, max_queue=1000, deadline_sec=0.5)
     # establish 10/s service rate: 10 completions over 1s
     for _ in range(5):
-        ctrl.on_complete(0.01)
+        ctrl.on_complete()
         clk.advance(0.2)
-        ctrl.on_complete(0.01)
+        ctrl.on_complete()
     # depth 20 at 10/s → 2s predicted wait >> 0.5s deadline → reject,
     # and the client is told how long the queue actually takes to drain
     decision, retry = ctrl.decide(20)
@@ -592,17 +594,41 @@ def test_query_server_429_at_the_door_when_queue_saturates():
     storage.close()
 
 
-def test_query_server_invalid_queries_feed_service_rate():
-    """400 binding rejections drained the queue and rode a dispatch like
-    any 200 — they must feed the service-rate estimate, or a rate fed
-    only by clean successes under-reads the true drain rate and sheds
-    good traffic below capacity on mixed workloads."""
+class _ServingDown(_StubAlgo):
+    def predict(self, model, query):
+        from incubator_predictionio_tpu.resilience.policy import (
+            ServingUnavailable,
+        )
+        raise ServingUnavailable("every backend is down")
 
-    class _RejectingAlgo(_StubAlgo):
-        def predict(self, model, query):
-            raise TypeError("binding rejected")
 
-    server, storage = _mk_server(_RejectingAlgo())
+class _Rejecting(_StubAlgo):
+    def predict(self, model, query):
+        raise TypeError("binding rejected")
+
+
+class _Crashing(_StubAlgo):
+    def predict(self, model, query):
+        raise RuntimeError("engine bug")
+
+
+@pytest.mark.parametrize("algo_cls, status, degraded, samples", [
+    (_StubAlgo, 200, False, 2),    # the control: a clean dispatch IS heard
+    (_Rejecting, 400, False, 0),
+    (_ServingDown, 200, True, 0),
+    (_Crashing, 500, False, 0),
+], ids=["clean", "invalid-400", "degraded", "engine-error"])
+def test_query_server_every_outcome_feeds_rate_only_clean_dispatch_feeds_limiter(
+        algo_cls, status, degraded, samples):
+    """400 binding rejections, degraded answers and engine errors drained
+    the queue and rode a dispatch like any 200 — they must feed the
+    service-rate estimate, or a rate fed only by clean successes
+    under-reads the true drain rate and sheds good traffic below capacity
+    on mixed workloads. But only a CLEAN dispatch's duration reaches the
+    AIMD limiter, one observation a batch: a ~1ms all-400 dispatch adopted
+    as the gradient-mode "no-contention" baseline would make every real
+    dispatch read as congestion and pin the concurrency limit at 1."""
+    server, storage = _mk_server(algo_cls())
 
     async def t():
         client = TestClient(TestServer(server.make_app()))
@@ -611,14 +637,62 @@ def test_query_server_invalid_queries_feed_service_rate():
             for _ in range(2):
                 resp = await client.post("/queries.json",
                                          json={"features": [1]})
-                assert resp.status == 400
+                assert resp.status == status
+                if status == 200:
+                    body = await resp.json()
+                    assert body.get("degraded", False) is degraded
             assert server._admission.service_rate() > 0
-            # ...but the near-instant 400s must NOT have fed the AIMD
-            # latency window: a ~1ms 400 adopted as the gradient-mode
-            # "no-queue" baseline would make every real prediction read
-            # as congestion and pin the concurrency limit at 1
-            assert server._admission.limiter._samples == []
+            assert len(server._admission.limiter._samples) == samples
             assert server._admission.limiter._baseline is None
+        finally:
+            await client.close()
+            await server.shutdown()
+
+    asyncio.run(t())
+    storage.close()
+
+
+@pytest.mark.parametrize("failure", ["dispatch-raises", "callers-gave-up"])
+def test_query_server_failed_or_abandoned_dispatch_never_feeds_limiter(
+        failure, monkeypatch):
+    """The other two ways a dispatch ends without a verdict on the slots: it
+    raised as a whole, or every caller had already been answered from the
+    degraded path (budget blown) when it returned. Both count as drain
+    progress; neither is a dispatch time."""
+    algo = _StubAlgo()
+    algo.gate = threading.Event()
+    server, storage = _mk_server(algo, query_timeout_sec=0.05)
+    if failure == "dispatch-raises":
+        algo.gate.set()
+
+        def boom(payloads):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(server.deployed, "predict_batch", boom)
+
+    async def t():
+        client = TestClient(TestServer(server.make_app()))
+        await client.start_server()
+        try:
+            # two requests: the rate estimate needs two events for a signal
+            posts = [asyncio.create_task(client.post(
+                "/queries.json", json={"features": [1]})) for _ in range(2)]
+            for resp in await asyncio.gather(*posts):
+                if failure == "dispatch-raises":
+                    assert resp.status == 500
+                else:
+                    assert (await resp.json())["degraded"] is True
+            if failure == "callers-gave-up":
+                assert server.batcher._inflight  # still wedged in predict
+                algo.gate.set()
+            for _ in range(400):
+                if not server.batcher._inflight:
+                    break
+                await asyncio.sleep(0.005)
+            assert not server.batcher._inflight
+            assert server.batcher.batches_served >= 1
+            assert server._admission.service_rate() > 0
+            assert server._admission.limiter._samples == []
         finally:
             await client.close()
             await server.shutdown()
@@ -776,6 +850,168 @@ def test_query_server_adaptive_limiter_resizes_batcher_live():
                 await asyncio.sleep(0.005)
             assert server.batcher.max_in_flight == 1
             assert server._admission.current_limit() == 1
+        finally:
+            await client.close()
+            await server.shutdown()
+
+    asyncio.run(t())
+    storage.close()
+
+
+class _SleepAlgo(_StubAlgo):
+    """A device round trip: one sleep a BATCH, the GIL released."""
+
+    def __init__(self, delay):
+        super().__init__()
+        self.delay = delay
+
+    def batch_predict(self, model, pairs):
+        import time as _t
+
+        _t.sleep(self.delay)
+        return [(i, {"label": 1, "source": "live"}) for i, _q in pairs]
+
+
+def _short_window_limiter(server, window=8):
+    """The server's own limiter with a window a test can fill quickly (the
+    constants under test are the class defaults; only the sample count and
+    the cool-down shrink)."""
+    lim = AdaptiveConcurrencyLimiter(
+        min_limit=1, max_limit=2, window=window, cooldown_sec=0.0)
+    server._admission.limiter = lim
+    return lim
+
+
+async def _timed_post(client, payload):
+    import time as _t
+
+    t0 = _t.perf_counter()
+    resp = await client.post("/queries.json", json=payload)
+    assert resp.status == 200
+    return _t.perf_counter() - t0
+
+
+def test_query_server_queue_wait_in_front_of_slots_is_not_congestion():
+    """Healthy batching under load: bursts queue in FRONT of the dispatch
+    slots, so the median request takes several times the fastest one, while
+    every dispatch takes the same time. The limiter sizes the slots and
+    hears dispatch times only, so the limit stays at its bound window after
+    window. (Fed each request's whole latency, as it once was, it read the
+    batcher's own queue as congestion of the slots and cut 2 -> 1 in the
+    first window, which lengthened the very wait it was reacting to.)"""
+    algo = _SleepAlgo(0.015)
+    server, storage = _mk_server(algo, max_batch=2,
+                                 admission_max_queue=1000)
+    lim = _short_window_limiter(server)
+
+    async def t():
+        client = TestClient(TestServer(server.make_app()))
+        await client.start_server()
+        try:
+            payload = {"features": [1]}
+            assert server.batcher.max_in_flight == 2
+            latencies = []
+            while server.batcher.batches_served < 10 * lim.window:
+                latencies += await asyncio.gather(
+                    *(_timed_post(client, payload) for _ in range(32)))
+            latencies.sort()
+            # the premise: the median request waited behind other batches
+            assert latencies[len(latencies) // 2] > 2 * latencies[0]
+            assert lim.changes == 0
+            assert lim.limit == 2
+            assert server.batcher.max_in_flight == 2
+        finally:
+            await client.close()
+            await server.shutdown()
+
+    asyncio.run(t())
+    storage.close()
+
+
+def test_query_server_limiter_shrinks_when_overlapping_dispatches_slow():
+    """What the limiter is for survives the new feed: dispatches that take
+    three times the best dispatch while two are in flight (a lock inside
+    predict, a host out of cores, a full device queue) shrink the limit;
+    once they run at the old pace again it grows back."""
+    algo = _SleepAlgo(0.010)
+    server, storage = _mk_server(algo, max_batch=2,
+                                 admission_max_queue=1000)
+    lim = _short_window_limiter(server)
+
+    async def post_until(client, limit, n):
+        """Posts ``n`` at a time until the limiter reads ``limit`` (a pair
+        may coalesce into one dispatch, so windows fill at their own pace),
+        then waits for the resize, which lands via a background task."""
+        for _ in range(40):
+            if lim.limit == limit:
+                break
+            await asyncio.gather(
+                *(_timed_post(client, payload) for _ in range(n)))
+        for _ in range(400):
+            if server.batcher.max_in_flight == limit:
+                break
+            await asyncio.sleep(0.005)
+        assert lim.limit == limit
+        assert server.batcher.max_in_flight == limit
+
+    payload = {"features": [1]}
+
+    async def t():
+        client = TestClient(TestServer(server.make_app()))
+        await client.start_server()
+        try:
+            assert server.batcher.max_in_flight == 2
+            # learn the uncontended dispatch time, pairs in flight
+            while server.batcher.batches_served < lim.window:
+                await asyncio.gather(
+                    *(_timed_post(client, payload) for _ in range(2)))
+            assert lim.limit == 2 and lim.changes == 0
+            algo.delay = 0.030  # overlapping dispatches slow each other
+            await post_until(client, 1, 2)
+            algo.delay = 0.010  # one at a time they are fast again
+            await post_until(client, 2, 1)
+            assert lim.changes == 2
+        finally:
+            await client.close()
+            await server.shutdown()
+
+    asyncio.run(t())
+    storage.close()
+
+
+def test_query_server_batch_slots_counter_reads_the_bound_undisturbed():
+    """pio_serving_batch_slots_total adds the slot bound in force at each
+    batch's assembly: over pio_serving_batches it is the mean number of
+    slots the batches ran under, the bound itself on a server whose limiter
+    never engaged."""
+    server, storage = _mk_server(_StubAlgo())
+
+    async def scrape(client):
+        text = await (await client.get("/metrics")).text()
+        out = {}
+        for line in text.splitlines():
+            name, _, value = line.partition(" ")
+            if name in ("pio_serving_batch_slots_total",
+                        "pio_serving_batches"):
+                out[name] = float(value)
+        return out
+
+    async def t():
+        client = TestClient(TestServer(server.make_app()))
+        await client.start_server()
+        try:
+            before = await scrape(client)
+            for _ in range(5):
+                resp = await client.post("/queries.json",
+                                         json={"features": [1]})
+                assert resp.status == 200
+            after = await scrape(client)
+            batches = (after["pio_serving_batches"]
+                       - before["pio_serving_batches"])
+            slots = (after["pio_serving_batch_slots_total"]
+                     - before["pio_serving_batch_slots_total"])
+            assert batches == 5
+            assert slots / batches == server.batcher.max_in_flight == 2
         finally:
             await client.close()
             await server.shutdown()
