@@ -23,9 +23,19 @@ softmax are float32.
 
 Named scopes inside every executable, for the device trace: ``mla_proj``,
 ``mla_attn``, ``moe_router``, ``moe_experts``, ``moe_shared``, ``head_topk``.
+
+The layer is composed from the config (``layer_apply``): attention kind x
+router scoring x experts. ``attention_kind="gqa_sparse"`` takes its attention
+half from models/sparse_gqa.py (grouped-query heads behind a learned sparse
+index, a softmax router, no shared expert); the norms, the router, the
+grouped expert matmul, the head and the serving steps below are this
+module's for both kinds.
 """
 
 from __future__ import annotations
+
+import dataclasses
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +50,10 @@ from incubator_predictionio_tpu.models.reference.mla_moe import (
 F32 = jnp.float32
 NEG = -1e30          # finite: a row with no visible key stays finite
 Q_CHUNK = 512        # queries a chunk in the up-projected form
-SCOPES = ("mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared",
-          "head_topk")
+ATTENTION_SCOPES = ("mla_proj", "mla_attn")
+MOE_SCOPES = ("moe_router", "moe_experts", "moe_shared", "head_topk")
+SCOPES = ATTENTION_SCOPES + MOE_SCOPES
+DEFAULT_FORM = "up"
 #: per-layer device counters: held experts' routed picks, then picks that
 #: fell on absent experts, then held experts that got at least one pick
 #: (summed over dispatches)
@@ -76,18 +88,80 @@ def cache_width(cfg) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
+def row_layout(cfg) -> dict:
+    """Row kinds a token leaves in the serving cache, and their widths."""
+    return {"latent": cache_width(cfg)}
+
+
+@dataclasses.dataclass(frozen=True)
+class ServeShapes:
+    """What a block's serving ladder is made of: block lengths (the first is
+    the short, batched one; a block longer than the last is cut into pieces
+    of it), the batch sizes of short blocks (``batch_to_max``: the server's
+    ``max_batch`` tops the list), the contexts a short block may take, and
+    the attention forms."""
+    path: str             # the status page's name for the serve path
+    blocks: tuple
+    batches: tuple
+    batch_to_max: bool
+    short_contexts: tuple
+    short_form: str
+    long_form: str
+    whole_context: bool   # a long block may take a context of its own length
+
+    def long_contexts(self, block: int) -> tuple:
+        full = self.short_contexts[-1]
+        if self.whole_context:
+            return (block,) if block == full else (block, full)
+        return tuple(c for c in self.short_contexts if c >= block)
+
+
+BLOCK_LADDER = (16, 128, 512, 1024, 1536, 2048, 3072)
+BATCH_LADDER = (4, 16, 64)
+
+
+def serve_shapes(cfg) -> ServeShapes:
+    """The latent block's ladder: short blocks batch and attend over a
+    whole-length context in the absorbed form; a long block runs whole, one
+    session a dispatch, in the up-projected form."""
+    full = cfg.max_len
+    return ServeShapes(
+        "device-latent-cache",
+        tuple(b for b in BLOCK_LADDER if b < full) + (full,), BATCH_LADDER,
+        True, (full,), "absorbed", "up", True)
+
+
+def count_dispatch(cfg, extents) -> None:
+    """Host counters of one serving dispatch beyond the shared ``pio_seq_*``
+    (``extents``: ``(offset, new tokens)`` a session): this block has none."""
+
+
+def block_of(cfg):
+    """The module that holds the config's attention half: ``attention``,
+    ``attention_shapes``, ``row_layout``, ``cache_context``,
+    ``block_context``, ``serve_shapes``, ``count_dispatch``,
+    ``ATTENTION_SCOPES``, ``DEFAULT_FORM``."""
+    if cfg.attention_kind == "gqa_sparse":
+        from incubator_predictionio_tpu.models import sparse_gqa
+
+        return sparse_gqa
+    return sys.modules[__name__]
+
+
+def scopes(cfg) -> tuple:
+    """Every named scope the config's executables carry."""
+    return block_of(cfg).ATTENTION_SCOPES + MOE_SCOPES
+
+
 def experts_held(cfg) -> int:
     return cfg.experts_held or cfg.n_routed_experts
 
 
-def layer_shapes(cfg) -> dict:
-    """One layer's arrays: ``{name: (shape, float32-always?)}``."""
+def attention_shapes(cfg) -> dict:
+    """The latent attention half's arrays of one layer."""
     d, h = cfg.d_model, cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    f, e = cfg.moe_intermediate_size, experts_held(cfg)
-    fs = f * cfg.n_shared_experts
     return {
-        "norm1": ((d,), True), "norm2": ((d,), True),
         "norm_q": ((cfg.q_lora_rank,), True),
         "norm_kv": ((cfg.kv_lora_rank,), True),
         "w_dq": ((d, cfg.q_lora_rank), False),
@@ -95,13 +169,28 @@ def layer_shapes(cfg) -> dict:
         "w_dkv": ((d, cfg.kv_lora_rank + dr), False),
         "w_ukv": ((cfg.kv_lora_rank, h * (dn + dv)), False),
         "w_o": ((h * dv, d), False),
-        "w_r": ((d, cfg.n_routed_experts), True),
-        "b_r": ((cfg.n_routed_experts,), True),
-        "we1": ((e, d, f), False), "we3": ((e, d, f), False),
-        "we2": ((e, f, d), False),
-        "ws1": ((d, fs), False), "ws3": ((d, fs), False),
-        "ws2": ((fs, d), False),
     }
+
+
+def layer_shapes(cfg) -> dict:
+    """One layer's arrays: ``{name: (shape, float32-always?)}``: the two
+    norms, the attention half of the config's kind, the router (its
+    selection bias only where the scoring is sigmoid), the routed experts
+    held here and the shared ones where there are any."""
+    d = cfg.d_model
+    f, e = cfg.moe_intermediate_size, experts_held(cfg)
+    fs = f * cfg.n_shared_experts
+    out = {"norm1": ((d,), True), "norm2": ((d,), True),
+           **block_of(cfg).attention_shapes(cfg),
+           "w_r": ((d, cfg.n_routed_experts), True)}
+    if cfg.router_scoring == "sigmoid":
+        out["b_r"] = ((cfg.n_routed_experts,), True)
+    out.update({"we1": ((e, d, f), False), "we3": ((e, d, f), False),
+                "we2": ((e, f, d), False)})
+    if cfg.n_shared_experts:
+        out.update({"ws1": ((d, fs), False), "ws3": ((d, fs), False),
+                    "ws2": ((fs, d), False)})
+    return out
 
 
 def init_params(key, cfg) -> dict:
@@ -261,12 +350,18 @@ ATTEND = {"up": attend_up, "absorbed": attend_absorbed}
 
 
 def moe_router(x, lw, cfg):
-    """``x [N, d]`` → ``(idx [N, k], w [N, k])``. Sigmoid scoring with a
-    selection-only bias; a softmax router would change the one line that
-    makes ``g``."""
-    g = jax.nn.sigmoid(jnp.matmul(
-        x.astype(F32), lw["w_r"], precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(g + lw["b_r"], cfg.experts_per_token)
+    """``x [N, d]`` → ``(idx [N, k], w [N, k])``. The scoring is the
+    config's: sigmoid with a selection-only bias, or softmax over all the
+    experts with none; the weights are the picks' scores normalised over the
+    k picks."""
+    logits = jnp.matmul(
+        x.astype(F32), lw["w_r"], precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_scoring == "softmax":
+        g = ranked = jax.nn.softmax(logits, -1)
+    else:
+        g = jax.nn.sigmoid(logits)
+        ranked = g + lw["b_r"]
+    _, idx = jax.lax.top_k(ranked, cfg.experts_per_token)
     gi = jnp.take_along_axis(g, idx, -1)
     return idx, gi / gi.sum(-1, keepdims=True) * cfg.routed_scaling_factor
 
@@ -315,30 +410,60 @@ def moe_shared(x, lw):
                   _mm)
 
 
-def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form="up"):
-    """One block on ``h [B, T, d]`` (float32). ``context(latent)`` takes the
-    block's new latent rows and returns ``(ctx [B, Tc, kvr + dr], key_valid
-    [B, Tc], state)``: the block itself while training, the session cache
-    after the write while serving. Returns ``(h, counters, state)``."""
-    b, t, d = h.shape
-    eps = cfg.rms_norm_eps
-    x = rms_norm(h, lw["norm1"], eps)
+def attention(x, h, lw, cfg, pos, q_index, context, form):
+    """The latent attention half on the normed ``x``: ``context(latent)``
+    takes the block's new latent rows and returns ``(ctx [B, Tc, kvr + dr],
+    key_valid [B, Tc], state)``. Returns ``(h + attention, state)``."""
     with jax.named_scope("mla_proj"):
         q_nope, q_rope, latent = project(x, lw, cfg, pos)
     with jax.named_scope("mla_attn"):
         ctx, key_valid, state = context(latent)  # cache write and gather
         a = ATTEND[form](q_nope, q_rope, ctx, q_index, key_valid, lw, cfg)
     with jax.named_scope("mla_proj"):
-        h = h + _mm(a, lw["w_o"])
+        return h + _mm(a, lw["w_o"]), state
+
+
+def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form=None):
+    """One block on ``h [B, T, d]`` (float32): the attention half of the
+    config's kind, then router and experts. ``context(rows)`` takes the
+    block's new cache rows and returns ``(ctx, key_valid [B, Tc], state)``:
+    the block itself while training, the session cache after the write while
+    serving. Returns ``(h, counters, state)``."""
+    b, t, d = h.shape
+    eps = cfg.rms_norm_eps
+    block = block_of(cfg)
+    x = rms_norm(h, lw["norm1"], eps)
+    h, state = block.attention(
+        x, h, lw, cfg, pos, q_index, context, form or block.DEFAULT_FORM)
     x = rms_norm(h, lw["norm2"], eps).reshape(b * t, d)
     with jax.named_scope("moe_router"):
         idx, w = moe_router(x, lw, cfg)
     with jax.named_scope("moe_experts"):
         y, counters = moe_experts(x, idx, w, token_valid.reshape(b * t), lw,
                                   cfg)
-    with jax.named_scope("moe_shared"):
-        y = y + moe_shared(x, lw)
+    if "ws1" in lw:
+        with jax.named_scope("moe_shared"):
+            y = y + moe_shared(x, lw)
     return h + y.reshape(b, t, d), counters, state
+
+
+def block_context(valid, wdt):
+    """``context`` when the block is its own context (``fit``, ``forward``)."""
+    return lambda latent: (latent.astype(wdt), valid, None)
+
+
+def cache_context(cache, geometry, pages, cfg, form):
+    """``context`` for a serving step: the block's latent rows are written
+    to the sessions' pages, then the whole cached context is read back."""
+    _, _, write, read, key_valid = geometry
+
+    def context(latent):
+        rows = cache["latent"]
+        pad = rows.shape[-1] - latent.shape[-1]
+        new = rows.at[write].set(jnp.pad(latent, [(0, 0), (0, 0), (0, pad)]))
+        return new[read], key_valid, {"latent": new}
+
+    return context
 
 
 def forward(params, tokens, positions, cfg):
@@ -349,10 +474,9 @@ def forward(params, tokens, positions, cfg):
     h = params["item_emb"][tokens].astype(F32)
     valid = tokens != 0
     q_index = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    context = block_of(cfg).block_context(valid, wdt)
     for lw in params["layers"]:
-        h, _, _ = layer_apply(
-            lw, h, cfg, positions, q_index, valid,
-            lambda latent: (latent.astype(wdt), valid, None))
+        h, _, _ = layer_apply(lw, h, cfg, positions, q_index, valid, context)
     return rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
 
 
@@ -402,17 +526,13 @@ def embed_step(item_emb, tok_cache, tokens, pages, offsets, counts, *, page):
 
 def layer_step(lw, cache, counters, h, pages, offsets, counts, *, cfg, form):
     """One layer of "extend a batch of sessions by a block each": the
-    block's latent rows are written to the sessions' pages, then every query
-    attends over its session's whole cached context."""
-    page = cfg.cache_page
-    q_index, token_valid, write, read, key_valid = _block_geometry(
-        pages, offsets, counts, h.shape[1], page)
-
-    def context(latent):
-        pad = cache.shape[-1] - latent.shape[-1]
-        new = cache.at[write].set(jnp.pad(latent, [(0, 0), (0, 0), (0, pad)]))
-        return new[read], key_valid, new
-
+    block's new rows are written to the sessions' pages (``cache`` is the
+    layer's ``{row kind: array}``), then every query attends over its
+    session's cached context as the block's kind and ``form`` read it."""
+    geometry = _block_geometry(pages, offsets, counts, h.shape[1],
+                               cfg.cache_page)
+    q_index, token_valid = geometry[:2]
+    context = block_of(cfg).cache_context(cache, geometry, pages, cfg, form)
     h, layer_counters, cache = layer_apply(
         lw, h, cfg, q_index, q_index, token_valid, context, form)
     return h, cache, counters + layer_counters
@@ -428,6 +548,10 @@ def head_step(norm_f, head, tok_cache, h, pages, offsets, counts, *, cfg, k):
         last = jnp.clip(counts - 1, 0, t - 1)
         x = rms_norm(h[jnp.arange(b), last], norm_f, cfg.rms_norm_eps)
         logits = _mm(x, head.T)
-        seen = jnp.where(key_valid, tok_cache[read], 0)
+        # (the padding item has a column of its own: a context full of real
+        # keys has no invalid key to stand for it)
+        seen = jnp.concatenate([
+            jnp.where(key_valid, tok_cache[read], 0),
+            jnp.zeros((b, 1), tok_cache.dtype)], axis=1)
         logits = logits.at[jnp.arange(b)[:, None], seen].set(-jnp.inf)
         return jax.lax.top_k(logits, k)

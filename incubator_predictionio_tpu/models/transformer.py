@@ -88,7 +88,13 @@ class TransformerConfig:
     # "mla": models/latent_moe.py: RMSNorm, low-rank query / key-value
     # projections with a decoupled rotary part (latent attention), routed
     # gated-SiLU experts (sigmoid-scored, no dropped token) plus shared
-    # experts. The norm kind and the router's scoring follow from the block
+    # experts.
+    # "gqa_sparse": models/sparse_gqa.py: grouped-query heads (n_kv_heads of
+    # head_dim) with per-head RMSNorm on q and k and half-split rope at
+    # rope_theta, behind a learned index (index_n_heads x index_head_dim
+    # queries, one key head) that lets a query attend to its index_topk
+    # best-scored keys only; the routed experts and everything else of the
+    # "mla" block's other half (models/latent_moe.py), router_scoring softmax
     attention_kind: str = "mha"
     rms_norm_eps: float = 1e-6
     q_lora_rank: int = 0
@@ -103,6 +109,19 @@ class TransformerConfig:
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    # how the router scores: "sigmoid" (plus a selection-only bias) or
+    # "softmax" over all the experts (no bias); weights are normalised over
+    # the picks either way
+    router_scoring: str = "sigmoid"
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    rope_theta: float = 10000.0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # keys are read in tiles of index_kv_tile rows (the published
+    # kv_chunk_size); serving cuts a long block into pieces of 4 tiles
+    index_kv_tile: int = 512
     # one chip's share of an expert-parallel deployment: experts
     # [expert_offset, expert_offset + experts_held) live here (0 = all); the
     # router still scores every expert and normalises over all its picks
@@ -116,27 +135,50 @@ class TransformerConfig:
     cache_tokens: int = 0
 
     def __post_init__(self):
-        if self.attention_kind == "mla":
-            if (not self.n_routed_experts or not self.rope_parameters
-                    or self.n_experts):
+        if self.attention_kind in ("mla", "gqa_sparse"):
+            if not self.n_routed_experts or self.n_experts:
                 raise ValueError(
-                    "attention_kind='mla' is the latent-attention block: "
-                    "n_routed_experts > 0, rope_parameters set, n_experts "
-                    "(the old top-1 layer) 0")
+                    f"attention_kind={self.attention_kind!r} has routed "
+                    "experts: n_routed_experts > 0, n_experts (the old top-1 "
+                    "layer) 0")
+            if self.router_scoring not in ("sigmoid", "softmax"):
+                raise ValueError(
+                    f"unknown router_scoring {self.router_scoring!r}")
             if self.max_len % self.cache_page:
                 raise ValueError(
                     f"max_len={self.max_len} must be a multiple of "
                     f"cache_page={self.cache_page}")
+        if self.attention_kind == "mla":
+            if not self.rope_parameters:
+                raise ValueError(
+                    "attention_kind='mla' is the latent-attention block: "
+                    "rope_parameters set")
+        elif self.attention_kind == "gqa_sparse":
+            if (not self.n_kv_heads or self.n_heads % self.n_kv_heads
+                    or not self.head_dim or self.head_dim % 2
+                    or not self.index_n_heads or not self.index_head_dim
+                    or self.index_head_dim % 2 or self.index_topk < 1):
+                raise ValueError(
+                    "attention_kind='gqa_sparse' needs n_kv_heads dividing "
+                    "n_heads, an even head_dim and the indexer's "
+                    "index_n_heads, even index_head_dim and index_topk")
+            tile = self.index_kv_tile
+            if tile % self.cache_page or self.max_len % tile:
+                raise ValueError(
+                    f"index_kv_tile={tile} must be whole pages of "
+                    f"{self.cache_page} and divide max_len={self.max_len}")
         elif self.attention_kind != "mha":
             raise ValueError(f"unknown attention_kind {self.attention_kind!r}")
         elif self.n_routed_experts or not self.tie_head:
             raise ValueError(
                 "routed experts and an untied head belong to "
-                "attention_kind='mla'")
+                "attention_kind='mla' or 'gqa_sparse'")
 
     @property
     def latent(self) -> bool:
-        return self.attention_kind == "mla"
+        """The blocks of models/latent_moe.py (RMSNorm, routed experts),
+        served from the paged session cache: "mla" and "gqa_sparse"."""
+        return self.attention_kind != "mha"
 
 
 def _init_params(key, cfg: TransformerConfig):

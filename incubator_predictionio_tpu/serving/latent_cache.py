@@ -1,12 +1,15 @@
-"""Serving state of the latent-attention block (models/latent_moe.py): a
-device-resident, paged cache of per-token latent rows, the table of sessions
-that own its pages, and the one entry point that extends a batch of sessions
-by a block of new tokens each and returns each one's top-k next items.
+"""Serving state of the blocks of models/latent_moe.py (``attention_kind``
+"mla" and "gqa_sparse"): a device-resident, paged cache of per-token rows,
+the table of sessions that own its pages, and the one entry point that
+extends a batch of sessions by a block of new tokens each and returns each
+one's top-k next items.
 
-- The cache is ``n_layers`` arrays ``[pages * page, kv_lora_rank +
-  qk_rope_head_dim padded to whole 128-lane tiles]`` in the weights' dtype
-  plus one int32 array of the tokens themselves (the history mask reads it).
-  Page 0 belongs to nobody: padding writes land there.
+- The cache is, a layer, one array ``[pages * page, width]`` for each kind
+  of row the block keeps (``row_layout``: the latent block one latent row,
+  the sparse-index block a key/value row and an index row; widths are whole
+  128-lane tiles), in the weights' dtype, plus one int32 array of the tokens
+  themselves (the history mask reads it). Page 0 belongs to nobody: padding
+  writes land there.
 - A session is keyed by the query's ``user``. The table keeps the tokens it
   has cached; an incoming list reuses the longest prefix that equals them,
   token for token, and computes the rest (at least the last token, whose
@@ -20,6 +23,16 @@ by a block of new tokens each and returns each one's top-k next items.
   choice of form is by block length alone. Each bucket is three executables (embed, one
   layer, head + top-k), compiled once by ``warmup`` and called a layer at a
   time, so a bucket compiles one layer whatever the depth.
+- The ladder is the block's own (``block_of(cfg).serve_shapes``). The
+  sparse-index block's: a turn runs alone over a **context bucket** (powers
+  of two from twice ``index_topk`` to ``max_len``) that holds its session, in
+  the ``select`` form; anything longer is cut into pieces that run in the
+  ``chunk`` form, each writing its rows and attending to what the earlier
+  pieces cached; only the last piece runs the head.
+- One dispatch runs at a time (``_TurnLock``), and between the pieces of a
+  cut block the lock is offered to whoever waits: another batch's turns run
+  between a miss's pieces and do not wait for all of it. A batch that names
+  a session whose block is still being cut waits for that block.
 """
 
 from __future__ import annotations
@@ -64,18 +77,24 @@ _TOUCHED = REGISTRY.counter(
     "pio_moe_experts_touched_total",
     "Held experts that received at least one pick, summed over dispatches",
     ("layer",))
-
+_PREFILL_CHUNKS = REGISTRY.counter(
+    "pio_seq_prefill_chunks_total",
+    "Long-block dispatches: the pieces a long block was cut into (a block "
+    "the ladder holds whole is one)")
 TOP_K = 16                       # the head's k the ladder is warmed for
-BLOCK_LADDER = (16, 128, 512, 1024, 1536, 2048, 3072)
-BATCH_LADDER = (4, 16, 64)
 _INSTRUCTION = re.compile(
-    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', re.M)
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*)op_name="([^"]*)"', re.M)
+#: control flow has no device time of its own: a trace shows a loop's
+#: operations inside the loop's own event, and a sum over both counts the
+#: body twice
+_CONTAINER = re.compile(r" (?:while|conditional|call)\(")
 
 
 @dataclasses.dataclass
 class _Session:
     tokens: np.ndarray            # what the cache holds for it, in order
     pages: list
+    given: Optional[Sequence] = None   # the list ``tokens`` was encoded from
 
 
 @dataclasses.dataclass
@@ -91,17 +110,50 @@ def _bucket(ladder: Sequence[int], n: int) -> int:
     return next(b for b in ladder if b >= n)
 
 
+class _TurnLock:
+    """A lock handed on in arrival order, which its holder can offer to those
+    who wait (``offer``: they all run before the holder goes on). A plain
+    ``threading.Lock`` released and taken again goes back to the thread that
+    released it."""
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._next = self._serving = 0
+
+    def __enter__(self):
+        with self._cond:
+            mine, self._next = self._next, self._next + 1
+            while self._serving != mine:
+                self._cond.wait()
+        return self
+
+    def __exit__(self, *exc):
+        with self._cond:
+            self._serving += 1
+            self._cond.notify_all()
+
+    def offer(self) -> bool:
+        """Lets every thread that waits now run first; false if none does."""
+        if self._next - self._serving < 2:
+            return False
+        self.__exit__()
+        self.__enter__()
+        return True
+
+
 class LatentServing:
     def __init__(self, params: dict, cfg):
         self.cfg, self.params = cfg, params
         self.page = cfg.cache_page
-        self.blocks = tuple(
-            b for b in BLOCK_LADDER if b < cfg.max_len) + (cfg.max_len,)
-        self.batches = BATCH_LADDER
+        self.block = latent_moe.block_of(cfg)
+        self.shapes = self.block.serve_shapes(cfg)
+        self.blocks = self.shapes.blocks
+        self.batches = self.shapes.batches
         self.device = next(iter(params["item_emb"].devices()))
-        width = latent_moe.cache_width(cfg)
+        self.layout = self.block.row_layout(cfg)
         wdt = params["item_emb"].dtype
-        self.bytes_per_token = cfg.n_layers * width * wdt.itemsize + 4
+        self.bytes_per_token = cfg.n_layers * sum(self.layout.values()) \
+            * wdt.itemsize + 4
         # ``cache_tokens`` is the operator's: live sessions x the length they
         # may reach (default: 16 sessions of ``max_len``); never less than
         # two whole sessions. Page 0 belongs to nobody.
@@ -109,7 +161,8 @@ class LatentServing:
         n_pages = max(tokens, 2 * cfg.max_len) // self.page + 1
         rows = n_pages * self.page
         with jax.default_device(self.device):
-            self.cache = [jnp.zeros((rows, width), wdt)
+            self.cache = [{kind: jnp.zeros((rows, width), wdt)
+                           for kind, width in self.layout.items()}
                           for _ in range(cfg.n_layers)]
             self.tok_cache = jnp.zeros((rows,), jnp.int32)
             self.counters = [
@@ -121,7 +174,8 @@ class LatentServing:
         self._sessions: "collections.OrderedDict[str, _Session]" = \
             collections.OrderedDict()
         self._exe: dict = {}
-        self._lock = threading.Lock()
+        self._lock = _TurnLock()
+        self._cutting: set = set()   # sessions of an ``extend`` under way
         self._published = np.zeros(
             (cfg.n_layers, self.counters[0].shape[0]), np.int64)
         _CACHE_TOKENS.labels(state="capacity").set(self.capacity_tokens)
@@ -134,17 +188,20 @@ class LatentServing:
 
     # -- executables --------------------------------------------------------------
     def form(self, block: int) -> str:
-        return "absorbed" if block == self.blocks[0] else "up"
+        return self.shapes.short_form if block == self.blocks[0] \
+            else self.shapes.long_form
 
     def ladder(self) -> list:
-        """Every (batch, block, context) bucket a dispatch can take. Short
-        blocks attend over a whole-length context; a long block whose
-        session fits the block itself (a cold session does) attends over
-        just that, else over the whole length."""
-        full = self.cfg.max_len
-        out = [(b, self.blocks[0], full) for b in self.batches]
+        """Every (batch, block, context) bucket a dispatch can take. The
+        latent block: short blocks attend over a whole-length context; a
+        long block whose session fits the block itself (a cold session does)
+        attends over just that, else over the whole length. The sparse-index
+        block: short blocks and pieces of long ones over every context
+        bucket that holds them."""
+        out = [(b, self.blocks[0], c) for c in self.shapes.short_contexts
+               for b in self.batches]
         for t in self.blocks[1:]:
-            out += [(1, t, t)] + ([(1, t, full)] if t < full else [])
+            out += [(1, t, c) for c in self.shapes.long_contexts(t)]
         return out
 
     @staticmethod
@@ -152,6 +209,14 @@ class LatentServing:
         return f"{batch}x{block}@{ctx}"
 
     def _compile(self, batch: int, block: int, ctx: int) -> dict:
+        exe = {k: v.compile() for k, v in self._lower(batch, block,
+                                                      ctx).items()}
+        return {**exe, "head": {
+            TOP_K: self._compile_head(batch, block, ctx, TOP_K)}}
+
+    def _lower(self, batch: int, block: int, ctx: int) -> dict:
+        """The bucket's embed and layer programs, lowered under the names a
+        device trace shows (``jit_seq_<kind>_b<B>_t<T>_c<C>``)."""
         cfg, page = self.cfg, self.page
         tag = f"b{batch}_t{block}_c{ctx}"
 
@@ -175,16 +240,15 @@ class LatentServing:
                                       counts, page=page), "embed"),
                 donate_argnums=() if keep else (1,)).lower(
                 self.params["item_emb"], self.tok_cache,
-                spec((batch, block), jnp.int32), *small).compile()
+                spec((batch, block), jnp.int32), *small)
             layer = jax.jit(named(
                 lambda lw, cache, counters, h, pages, offsets, counts:
                 latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
                                       counts, cfg=cfg, form=form), "layer"),
                 donate_argnums=() if keep else (1, 2, 3)).lower(
                 self.params["layers"][0], self.cache[0], self.counters[0], h,
-                *small).compile()
-        return {"embed": embed, "layer": layer,
-                "head": {TOP_K: self._compile_head(batch, block, ctx, TOP_K)}}
+                *small)
+        return {"embed": embed, "layer": layer}
 
     def _compile_head(self, batch: int, block: int, ctx: int, k: int):
         cfg = self.cfg
@@ -207,10 +271,11 @@ class LatentServing:
 
     def warmup(self, max_batch: int = 64) -> int:
         """Compiles every bucket of the ladder and runs it once on page 0
-        (one ``deploy.warmup.bucket`` span each). Batches of short blocks go
-        up to ``max_batch``."""
-        self.batches = tuple(
-            b for b in BATCH_LADDER if b < max_batch) + (max_batch,)
+        (one ``deploy.warmup.bucket`` span each). Where the block batches its
+        short blocks, batches go up to ``max_batch``."""
+        if self.shapes.batch_to_max:
+            self.batches = tuple(
+                b for b in self.shapes.batches if b < max_batch) + (max_batch,)
         with span("deploy.warmup", buckets=len(self.ladder())):
             for bucket in self.ladder():
                 with span("deploy.warmup.bucket", bucket=self.label(*bucket),
@@ -224,15 +289,17 @@ class LatentServing:
         """``{executable name: {HLO instruction: named scope}}`` from the
         compiled programs' own metadata: a device trace names operations by
         instruction, and this is what tells ``moe_experts`` from
-        ``mla_attn`` inside one executable."""
-        out = {}
+        ``mla_attn`` inside one executable. Loops and branches are left out:
+        their time is their bodies' operations', which are in the map."""
+        out, scopes = {}, latent_moe.scopes(self.cfg)
         for (batch, block, ctx), exes in self._exe.items():
             for kind in ("layer", "head"):
                 found = {}
                 exe = exes[kind][TOP_K] if kind == "head" else exes[kind]
-                for name, op in _INSTRUCTION.findall(exe.as_text()):
-                    parts = [p for p in op.split("/")
-                             if p in latent_moe.SCOPES]
+                for name, body, op in _INSTRUCTION.findall(exe.as_text()):
+                    if _CONTAINER.search(body):
+                        continue
+                    parts = [p for p in op.split("/") if p in scopes]
                     if parts:
                         found[name] = parts[-1]
                     elif op.startswith("ragged-dot"):
@@ -255,9 +322,22 @@ class LatentServing:
             _EVICTIONS.inc()
         return [self._free.pop() for _ in range(n)]
 
+    def _tokens(self, key: Optional[str], given: tuple, encode) -> np.ndarray:
+        """The session as int32 tokens. A turn sends again a list the table
+        has seen: what equals the list as it was last given is not encoded a
+        second time (``encode`` maps item by item and keeps the last
+        ``max_len`` tokens, so the tokens of a list's head are the head of
+        its tokens)."""
+        sess = self._sessions.get(key) if key is not None else None
+        n = len(sess.given) if sess is not None and sess.given else 0
+        if n and given[:n] == sess.given:
+            return np.concatenate([sess.tokens, np.asarray(
+                encode(given[n:]), np.int32)])[-self.cfg.max_len:]
+        return np.asarray(encode(given), np.int32)
+
     def _match(self, row: int, key: Optional[str], tokens: np.ndarray,
-               busy: set, release: list) -> _Block:
-        sess = self._sessions.pop(key, None) if key is not None else None
+               given: Optional[tuple], release: list) -> _Block:
+        sess = self._sessions.get(key) if key is not None else None
         if sess is None:
             sess, reuse = _Session(tokens[:0], []), 0
         else:
@@ -267,13 +347,14 @@ class LatentServing:
         reuse = min(reuse, len(tokens) - 1)
         need = -(-len(tokens) // self.page) - len(sess.pages)
         if need > 0:
-            sess.pages = sess.pages + self._take_pages(need, busy)
+            sess.pages = sess.pages + self._take_pages(need, self._cutting)
         elif need < 0:
             release.extend(sess.pages[need:])
             sess.pages = sess.pages[:need]
-        sess.tokens = tokens
+        sess.tokens, sess.given = tokens, given
         if key is not None:
-            self._sessions[key] = sess        # most recently used
+            self._sessions[key] = sess
+            self._sessions.move_to_end(key)   # most recently used
         else:
             release.extend(sess.pages)        # nobody can come back to it
         _TOKENS_REUSED.inc(reuse)
@@ -286,41 +367,65 @@ class LatentServing:
         """``[(key or None, session)]`` → ``(scores [R, k], tokens [R, k])``
         of each session's last position, best first, padding and the
         session's own tokens masked; ``k >= num``. ``encode`` turns a session
-        as given into its int32 tokens (default: it is them already). A
-        session with no token gets a row of ``-inf``."""
+        as given (a sequence of items) into its int32 tokens, item by item
+        (default: it is them already). A session with no token gets a row of
+        ``-inf``. Safe to call from several threads: dispatches run one at a
+        time, and between the pieces of a cut block the other callers' run
+        (module docstring)."""
         k = TOP_K if num <= TOP_K else min(
             1 << (num - 1).bit_length(), self.cfg.vocab_size)
+        busy = {key for key, _ in requests if key is not None}
         with self._lock:
+            # a session whose block another caller is still cutting: after it
+            while busy & self._cutting and self._lock.offer():
+                pass
+            self._cutting |= busy
             release: list = []
-            with span("seq.batch.match", sessions=len(requests)) as sp:
-                busy = {key for key, _ in requests if key is not None}
-                blocks = []
-                for row, (key, session) in enumerate(requests):
-                    tokens = np.asarray(
-                        encode(session) if encode else session, np.int32)
-                    if len(tokens):
-                        blocks.append(
-                            self._match(row, key, tokens, busy, release))
-                hits = sum(b.offset > 0 for b in blocks)
-                sp.set_attr("hits", hits)
-                sp.set_attr("misses", len(blocks) - hits)
-                sp.set_attr("reused", sum(b.offset for b in blocks))
-            scores = np.full((len(requests), k), -np.inf, np.float32)
-            items = np.zeros((len(requests), k), np.int32)
-            for group, bucket in self._plan(
-                    [requests[b.row][0] for b in blocks], blocks):
-                vals, idx = self._dispatch(group, *bucket, k)
-                rows = [b.row for b in group]
-                scores[rows], items[rows] = vals[:len(rows)], idx[:len(rows)]
-            self._free.extend(release)
-            used = sum(len(s.pages) for s in self._sessions.values())
-            _CACHE_TOKENS.labels(state="used").set(used * self.page)
+            try:
+                with span("seq.batch.match", sessions=len(requests)) as sp:
+                    blocks = []
+                    for row, (key, session) in enumerate(requests):
+                        if encode is None:
+                            given, tokens = None, np.asarray(session, np.int32)
+                        else:
+                            given = tuple(session)
+                            tokens = self._tokens(key, given, encode)
+                        if len(tokens):
+                            blocks.append(
+                                self._match(row, key, tokens, given, release))
+                    hits = sum(b.offset > 0 for b in blocks)
+                    sp.set_attr("hits", hits)
+                    sp.set_attr("misses", len(blocks) - hits)
+                    sp.set_attr("reused", sum(b.offset for b in blocks))
+                scores = np.full((len(requests), k), -np.inf, np.float32)
+                items = np.zeros((len(requests), k), np.int32)
+                for group, bucket, last in self._plan(
+                        [requests[b.row][0] for b in blocks], blocks):
+                    out = self._dispatch(group, *bucket, k, head=last)
+                    if last:
+                        rows = [b.row for b in group]
+                        scores[rows], items[rows] = \
+                            out[0][:len(rows)], out[1][:len(rows)]
+                    else:
+                        self._lock.offer()   # between a cut block's pieces
+            except BaseException:
+                # the table says more of these sessions than the cache holds
+                for sess in map(self._sessions.pop, busy & set(self._sessions)):
+                    release.extend(sess.pages)
+                raise
+            finally:
+                self._cutting -= busy
+                self._free.extend(release)
+                used = sum(len(s.pages) for s in self._sessions.values())
+                _CACHE_TOKENS.labels(state="used").set(used * self.page)
         return scores, items
 
     def _plan(self, keys, blocks):
-        """Dispatches in order. Two requests of one session never share a
-        dispatch (they would write the same pages): the later one waits for
-        the next round."""
+        """Dispatches in order, as ``(group, bucket, last)``: ``last`` is
+        false for every piece of a cut block but its final one, whose head
+        alone answers. Two requests of one session never share a dispatch
+        (they would write the same pages): the later one waits for the next
+        round."""
         rounds, depth = [], {}
         for key, blk in zip(keys, blocks):
             r = depth.get(key, 0) if key is not None else 0
@@ -329,18 +434,29 @@ class LatentServing:
             while len(rounds) <= r:
                 rounds.append([])
             rounds[r].append(blk)
-        short, full = self.blocks[0], self.cfg.max_len
+        short, longest = self.blocks[0], self.blocks[-1]
         for members in rounds:
             quick = [b for b in members if len(b.tokens) - b.offset <= short]
             for i in range(0, len(quick), self.batches[-1]):
                 group = quick[i:i + self.batches[-1]]
-                yield group, (_bucket(self.batches, len(group)), short, full)
+                ctx = _bucket(self.shapes.short_contexts,
+                              max(len(b.tokens) for b in group))
+                yield group, (_bucket(self.batches, len(group)), short,
+                              ctx), True
             for b in members:
-                n = len(b.tokens) - b.offset
-                if n > short:
-                    block = _bucket(self.blocks, n)
-                    yield [b], (1, block,
-                                block if len(b.tokens) <= block else full)
+                if len(b.tokens) - b.offset <= short:
+                    continue
+                for start in range(b.offset, len(b.tokens), longest):
+                    end = min(start + longest, len(b.tokens))
+                    block = _bucket(self.blocks, end - start)
+                    piece = _Block(b.row, b.tokens[:end], start, b.pages)
+                    if block == short:   # a cut block's tail, as a turn
+                        bucket = (self.batches[0], short, _bucket(
+                            self.shapes.short_contexts, end))
+                    else:
+                        bucket = (1, block, _bucket(
+                            self.shapes.long_contexts(block), end))
+                    yield [piece], bucket, end == len(b.tokens)
 
     def _head(self, batch: int, block: int, ctx: int, k: int):
         """The head + top-k executable of a bucket at ``k``; the ladder is
@@ -352,7 +468,10 @@ class LatentServing:
         return exe["head"][k]
 
     def _dispatch(self, group: list, batch: int, block: int, ctx: int,
-                  k: int = TOP_K, count: bool = True) -> tuple:
+                  k: int = TOP_K, count: bool = True, head: bool = True):
+        """Embed, every layer, and with ``head`` the head + top-k, whose
+        ``(values, tokens)`` come back; a piece that is not its block's last
+        only leaves its rows in the cache."""
         exe = self._exe[batch, block, ctx]
         form = self.form(block)
         n_new = sum(len(b.tokens) - b.offset for b in group)
@@ -365,7 +484,8 @@ class LatentServing:
             for i, b in enumerate(group):
                 new = b.tokens[b.offset:]
                 tokens[i, :len(new)] = new
-                pages[i, :len(b.pages)] = b.pages
+                own = b.pages[:pages.shape[1]]   # a piece's context ends with it
+                pages[i, :len(own)] = own
                 offsets[i], counts[i] = b.offset, len(new)
             small = (pages, offsets, counts)
             h, self.tok_cache = exe["embed"](
@@ -373,13 +493,16 @@ class LatentServing:
             for i, lw in enumerate(self.params["layers"]):
                 h, self.cache[i], self.counters[i] = exe["layer"](
                     lw, self.cache[i], self.counters[i], h, *small)
-            out = self._head(batch, block, ctx, k)(
+            out = jax.device_get(self._head(batch, block, ctx, k)(
                 self.params["norm_f"], latent_moe.head_matrix(self.params),
-                self.tok_cache, h, *small)
-            vals, idx = jax.device_get(out)
+                self.tok_cache, h, *small)) if head else None
         if count:
             _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
-        return vals, idx
+            if block != self.blocks[0]:
+                _PREFILL_CHUNKS.inc()
+            self.block.count_dispatch(self.cfg, [
+                (b.offset, len(b.tokens) - b.offset) for b in group])
+        return out
 
     # -- what the status page and /metrics show ----------------------------------------
     def _collect(self) -> None:
@@ -400,10 +523,11 @@ class LatentServing:
     def info(self) -> dict:
         cfg = self.cfg
         return {
-            "path": "device-latent-cache",
+            "path": self.shapes.path,
             "cache_capacity_tokens": self.capacity_tokens,
             "cache_page": self.page,
             "cache_bytes_per_token": self.bytes_per_token,
+            "cache_row_widths": dict(self.layout),
             "sessions": len(self._sessions),
             "buckets": [f"{self.label(*b)}:{self.form(b[1])}"
                         for b in self.ladder()],

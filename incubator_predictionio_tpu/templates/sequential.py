@@ -12,8 +12,9 @@ explicit session, or ``{"user": U, "num": N}`` reads the user's recent
 view/buy events live from the event store (LEventStore, like the ecommerce
 template's serving-time reads).
 
-With the latent-attention block (``attentionKind: "mla"``) the deployed
-model keeps a device-resident latent cache per session
+With the latent-attention block (``attentionKind: "mla"``) or the
+sparse-index block (``"gqa_sparse"``) the deployed model keeps a
+device-resident cache of per-token rows per session
 (serving/latent_cache.py). ``recent_items`` is still the whole session as the
 application knows it; ``user``, when given WITH it, is the **cache key**: the
 server reuses the longest prefix of the incoming list that equals what it has
@@ -261,9 +262,11 @@ class TransformerAlgorithmParams(Params):
     recent_events: tuple[str, ...] = ("view", "buy")
     checkpoint_dir: Optional[str] = None   # mid-training resume (utils/checkpoint.py)
     checkpoint_every: int = 0
-    # the block (models/transformer.py TransformerConfig): "mha" or "mla",
-    # the latent-attention / routed-expert block, whose sizes follow under
-    # the published config's names (d_model / n_heads / n_layers above)
+    # the block (models/transformer.py TransformerConfig): "mha", "mla" (the
+    # latent-attention / routed-expert block) or "gqa_sparse" (grouped-query
+    # heads behind a learned sparse index, the same routed experts), whose
+    # sizes follow under the published configs' names (d_model / n_heads /
+    # n_layers above)
     attention_kind: str = "mha"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -277,6 +280,14 @@ class TransformerAlgorithmParams(Params):
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    router_scoring: str = "sigmoid"   # or "softmax" (no selection bias)
+    num_key_value_heads: int = 0      # "gqa_sparse": grouped-query heads ...
+    head_dim: int = 0
+    rope_theta: float = 10000.0
+    indexer_num_heads: int = 0        # ... and its indexer (sa_config)
+    indexer_head_dim: int = 0
+    index_topk: int = 0
+    index_kv_tile: int = 512
     experts_held: int = 0         # this chip's share (0 = all), from expert_offset
     expert_offset: int = 0
     tie_head: bool = True
@@ -299,12 +310,21 @@ class TransformerAlgorithm(PAlgorithm):
         latent = {}
         if p.attention_kind == "mla":
             latent = dict(
-                rms_norm_eps=p.rms_norm_eps,
                 q_lora_rank=p.q_lora_rank, kv_lora_rank=p.kv_lora_rank,
                 qk_nope_head_dim=p.qk_nope_head_dim,
                 qk_rope_head_dim=p.qk_rope_head_dim, v_head_dim=p.v_head_dim,
                 rope_parameters=tuple(sorted(
-                    (p.rope_parameters or {}).items())),
+                    (p.rope_parameters or {}).items())))
+        elif p.attention_kind == "gqa_sparse":
+            latent = dict(
+                n_kv_heads=p.num_key_value_heads, head_dim=p.head_dim,
+                rope_theta=p.rope_theta, index_n_heads=p.indexer_num_heads,
+                index_head_dim=p.indexer_head_dim, index_topk=p.index_topk,
+                index_kv_tile=p.index_kv_tile)
+        if p.attention_kind != "mha":
+            latent.update(
+                rms_norm_eps=p.rms_norm_eps,
+                router_scoring=p.router_scoring,
                 n_routed_experts=p.n_routed_experts,
                 experts_per_token=p.num_experts_per_tok,
                 moe_intermediate_size=p.moe_intermediate_size,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import hashlib
 import datetime as dt
 import json
 import math
@@ -201,15 +202,36 @@ def test_fit_loss_and_gradients_match_the_reference(sessions):
                                    err_msg=jax.tree_util.keystr(path))
 
 
-def test_four_shares_add_up_to_the_uncut_layer(sessions):
-    """Four chips of four experts each: the routed parts of the four shares
-    plus ONE shared expert are the uncut reference's expert layer."""
+def _latent_kind():
     cfg = config()
-    lw = seeded_params(cfg)["layers"][0]
+    return (cfg, seeded_params(cfg)["layers"][0], ref.experts, lm.published,
+            lambda x, lw: lm.moe_shared(x, lw))
+
+
+def _sparse_kind():
+    """The sparse-index block's expert half: the same ``moe_experts``, a
+    softmax router, no shared expert."""
+    from benchmarks.reference import gqa_sparse_moe_ref
+    from incubator_predictionio_tpu.models import sparse_gqa
+    from tests.fixtures import sparse_tiny
+
+    cfg = sparse_tiny.config(n_routed_experts=16, experts_per_token=4)
+    return (cfg, sparse_tiny.seeded_params(cfg)["layers"][0],
+            gqa_sparse_moe_ref.experts, sparse_gqa.published,
+            lambda x, lw: 0.0)
+
+
+@pytest.mark.parametrize("kind", [_latent_kind, _sparse_kind],
+                         ids=["mla", "gqa_sparse"])
+def test_four_shares_add_up_to_the_uncut_layer(sessions, kind):
+    """Four chips of four experts each: the routed parts of the four shares
+    plus what every chip computes alike (ONE shared expert, where the block
+    has one) are the uncut reference's expert layer."""
+    cfg, lw, experts, published, shared = kind()
     x = jax.random.normal(jax.random.key(7), (96, cfg.d_model))
-    want = ref.experts(x, lw, lm.published(cfg))
+    want = experts(x, lw, published(cfg))
     valid = jnp.ones(96, bool)
-    total = lm.moe_shared(x, lw)
+    total = shared(x, lw)
     unheld = 0
     for share in range(4):
         part = dataclasses.replace(cfg, experts_held=4, expert_offset=4 * share)
@@ -221,8 +243,8 @@ def test_four_shares_add_up_to_the_uncut_layer(sessions):
         unheld += int(counters[4])
         # the share alone is the reference's share alone
         np.testing.assert_allclose(
-            y + lm.moe_shared(x, lw),
-            ref.experts(x, mine, lm.published(part)), atol=TOL, rtol=0)
+            y + shared(x, lw), experts(x, mine, published(part)),
+            atol=TOL, rtol=0)
     np.testing.assert_allclose(total, want, atol=TOL, rtol=0)
     assert unheld == 3 * 96 * 4  # every pick is held by exactly one share
 
@@ -397,9 +419,12 @@ def test_expert_counters_are_read_when_metrics_are(served, sessions):
     unheld = total(after, "pio_moe_tokens_unheld_total") \
         - total(before, "pio_moe_tokens_unheld_total")
     assert held + unheld == 50 * 4 * cfg.n_layers
-    labels = {tuple(sorted(l.items())) for _, l, _ in
-              after["pio_moe_expert_tokens_total"]["samples"]}
-    assert labels <= {(("expert", str(e)), ("layer", str(layer)))
+    was = {tuple(sorted(l.items())): v for _, l, v in before.get(
+        "pio_moe_expert_tokens_total", {"samples": []})["samples"]}
+    labels = {tuple(sorted(l.items())) for _, l, v in
+              after["pio_moe_expert_tokens_total"]["samples"]
+              if v != was.get(tuple(sorted(l.items())), 0)}   # that moved
+    assert labels and labels <= {(("expert", str(e)), ("layer", str(layer)))
                       for e in range(4, 8) for layer in range(3)}
     assert total(after, "pio_moe_experts_touched_total") \
         > total(before, "pio_moe_experts_touched_total")
@@ -462,6 +487,32 @@ def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
         assert set(found.values()) == want, module
     text = serving._exe[4, 16, 256]["layer"].as_text()
     assert re.search(r"HloModule jit_seq_layer_b4_t16_c256\b", text)
+    # the block's scope list is what it was before the sparse-index block
+    # came to share this module
+    assert lm.scopes(cfg) == lm.SCOPES == (
+        "mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared",
+        "head_topk")
+
+
+@pytest.mark.parametrize("bucket, digest", [
+    ((4, 16, 256),
+     "b515fe347c07554a8cfe7f231c5a949a400e1d1b34d54f104b0342292bb31d2e"),
+    ((1, 128, 256),
+     "dc3c9934591449568dc16b153d613c3f9ae844f68b06601856ccc63110d5ea9c"),
+], ids=["absorbed", "up"])
+def test_the_latent_layer_lowers_to_the_parents_program(served, bucket, digest):
+    """ISSUE 30 made ``layer_apply`` compose the attention half from the
+    config and the cache a mapping of row kinds, and had to leave the latent
+    block's programs alone: the layer's lowered text (no debug info: line
+    numbers move) is commit 7398cde's, but for the name of its cache result
+    (``result[1]`` there, ``result[1]['latent']`` now)."""
+    serving, _, _ = served
+    text = serving._lower(*bucket)["layer"].as_text().replace(
+        "result[1]['latent']", "result[1]")
+    assert hashlib.sha256(text.encode()).hexdigest() == digest, (
+        f"the digest was taken under jax 0.9.0 and this is jax "
+        f"{jax.__version__}: after a JAX upgrade, or a deliberate change to "
+        f"the latent block, pin the new digest")
 
 
 # ---------------------------------------------------------------------------

@@ -613,7 +613,50 @@ def _rerank_lowered():
         nprobe=3, k=16, interpret=True)
 
 
+def _seq_layer_lowered(kind: str, bucket: tuple):
+    """A layer executable of the sequence template's paged-cache serving,
+    by the block's kind, as ``LatentServing`` names and lowers it."""
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.models.transformer import TransformerConfig
+    from incubator_predictionio_tpu.serving.latent_cache import LatentServing
+
+    shared = dict(vocab_size=64, max_len=64, d_model=32, n_heads=2,
+                  n_layers=1, n_routed_experts=4, experts_per_token=2,
+                  moe_intermediate_size=16, tie_head=False, cache_page=8,
+                  cache_tokens=128)
+    if kind == "mla":
+        cfg = TransformerConfig(
+            attention_kind="mla", q_lora_rank=8, kv_lora_rank=8,
+            qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            n_shared_experts=1, rope_parameters=tuple(sorted({
+                "beta_fast": 32, "beta_slow": 1, "factor": 128,
+                "original_max_position_embeddings": 8192,
+                "rope_theta": 10000}.items())), **shared)
+    else:
+        cfg = TransformerConfig(
+            attention_kind="gqa_sparse", n_kv_heads=1, head_dim=16,
+            index_n_heads=2, index_head_dim=16, index_topk=8,
+            index_kv_tile=8, router_scoring="softmax", **shared)
+    serving = LatentServing(
+        latent_moe.init_params(jax.random.key(0), cfg), cfg)
+    try:
+        return serving._lower(*bucket)["layer"]
+    finally:
+        serving.close()
+
+
 @pytest.mark.parametrize("lower, module, scopes", [
+    (lambda: _seq_layer_lowered("mla", (4, 16, 64)),
+     "jit_seq_layer_b4_t16_c64",
+     ("mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared")),
+    (lambda: _seq_layer_lowered("gqa_sparse", (1, 16, 64)),
+     "jit_seq_layer_b1_t16_c64",
+     ("gqa_proj", "idx_score", "idx_select", "sparse_attn", "moe_router",
+      "moe_experts")),
+    (lambda: _seq_layer_lowered("gqa_sparse", (1, 32, 32)),
+     "jit_seq_layer_b1_t32_c32",
+     ("gqa_proj", "idx_score", "idx_select", "sparse_attn", "moe_router",
+      "moe_experts")),
     (_train_lowered, "jit__train_epochs",
      ("gather", "loss_grad", "scatter", "adam_user", "adam_item")),
     (_topk_lowered, "jit__topk_quantized", ("score", "topk")),
@@ -624,12 +667,15 @@ def _rerank_lowered():
      ("gather", "quantize")),
     (_rerank_lowered, "jit_two_stage_rerank",
      ("probe_select", "rerank", "gather", "topk")),
-], ids=["train_epochs", "topk_quantized", "score_centroids", "init",
+], ids=["seq_layer_latent", "seq_layer_sparse_turn", "seq_layer_sparse_piece",
+        "train_epochs", "topk_quantized", "score_centroids", "init",
         "order_batches", "quantize_user_rows", "two_stage_rerank"])
 def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
     """``benchmarks/layer_metrics/*_roofline.py`` find these executables by
-    name in a device trace's ``XLA Modules`` line: a rename has to fail
-    here, not read as a missing roofline on the chip."""
+    name in a device trace's ``XLA Modules`` line (the sequence template's
+    by the ``jit_seq_layer_`` names and the scopes inside them, through
+    ``benchmarks/seq_trace.py``): a rename has to fail here, not read as a
+    missing roofline on the chip."""
     lowered = lower()
     assert re.search(rf"\bmodule @{module}\b", lowered.as_text())
     text = lowered.as_text(debug_info=True)

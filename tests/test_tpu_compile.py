@@ -1,6 +1,9 @@
 """The TPU's own compiler, without the chip: the device leg's executables at
 the benchmark's widths (476,002 items × rank 128: 690 partitions in blocks of
-4,096 rows, nprobe 26, serve_k 128) compile for a described v5e. What the
+4,096 rows, nprobe 26, serve_k 128) and the sparse-index block's layer
+executables at the lifelong cell's (hidden 2048, 32 / 4 heads of 128,
+indexer 16 x 64 top-2048, 128 experts top-8 of width 768, 720,896 cache rows)
+compile for a described v5e. What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
 a time or a result.
@@ -72,3 +75,71 @@ def test_quantize_user_rows_compiles_for_v5e(one_chip):
     # a row gather, not a copy of the tower (the fused [U, 129] float32
     # layout made one: 10 ms a call on the chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- the sparse-index block's layer executables (serving/latent_cache.py) ----------------
+
+def _sparse_cfg():
+    from incubator_predictionio_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=151_936, max_len=32_768, d_model=2048, n_heads=32,
+        n_layers=4, attention_kind="gqa_sparse", n_kv_heads=4, head_dim=128,
+        rope_theta=1e7, index_n_heads=16, index_head_dim=64, index_topk=2048,
+        router_scoring="softmax", n_routed_experts=128, experts_per_token=8,
+        moe_intermediate_size=768, tie_head=False, weight_dtype="bfloat16",
+        cache_page=128, cache_tokens=720_896)
+
+
+@pytest.mark.parametrize("batch, block, form", [
+    (1, 16, "select"),      # the largest turn bucket of the block's ladder
+    (1, 2048, "chunk"),     # a piece of a miss
+])
+def test_sparse_index_layer_compiles_for_v5e_at_context_32768(
+        one_chip, batch, block, form):
+    """The layer executable over the whole 32,768-row context, with the cell's
+    own cache (22 x 32,768 tokens in two kinds of row) as its argument: it
+    fits the chip beside the 6.25 GB of weights, donates the cache instead of
+    copying it, and holds no gathered or scored array larger than the ladder
+    was sized for."""
+    from incubator_predictionio_tpu.models import latent_moe, sparse_gqa
+
+    cfg = _sparse_cfg()
+    ladder = sparse_gqa.serve_shapes(cfg)
+    assert (ladder.batches, ladder.blocks) == ((1,), (16, 2048))
+    assert ladder.short_contexts == (4096, 8192, 16384, 32768)
+    assert (batch, block) in ((ladder.batches[-1], ladder.blocks[0]),
+                              (1, ladder.blocks[-1]))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16, ctx = jnp.bfloat16, cfg.max_len
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    lw = {k: s(shape, jnp.float32 if f32 else bf16)
+          for k, (shape, f32) in latent_moe.layer_shapes(cfg).items()}
+    cache = {kind: s((rows, width), bf16)
+             for kind, width in sparse_gqa.row_layout(cfg).items()}
+    assert {k: v.shape[1] for k, v in cache.items()} == {
+        "kv": 1024, "idx": 128}
+
+    def layer(lw, cache, counters, h, pages, offsets, counts):
+        return latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
+                                     counts, cfg=cfg, form=form)
+
+    compiled = jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
+        lw, cache, s((130,), jnp.int32), s((batch, block, 2048), jnp.float32),
+        s((batch, ctx // cfg.cache_page), jnp.int32), s((batch,), jnp.int32),
+        s((batch,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = rows * (1024 + 128) * 2
+    assert mem.alias_size_in_bytes >= cache_bytes       # donated, not copied
+    # weights of a layer + both caches + temporaries: well inside 16 GB
+    # beside the other three layers' 3.75 GB and the 1.25 GB of embeddings
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + 3 * 1.26e9 + 1.25e9 + 3 * cache_bytes < 15.5e9
+    text = compiled.as_text()
+    for scope in ("gqa_proj", "idx_score", "idx_select", "sparse_attn",
+                  "moe_router", "moe_experts"):
+        assert f"/{scope}/" in text, scope
