@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import sys
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +109,15 @@ class ServeShapes:
     short_form: str
     long_form: str
     whole_context: bool   # a long block may take a context of its own length
+    # the widest batch whose short blocks take a context under the full one
+    # (None: every batch does)
+    context_batch: Optional[int] = None
+
+    def contexts(self, batch: int) -> tuple:
+        """The contexts a short dispatch of ``batch`` sessions may take."""
+        if self.context_batch is None or batch <= self.context_batch:
+            return self.short_contexts
+        return self.short_contexts[-1:]
 
     def long_contexts(self, block: int) -> tuple:
         full = self.short_contexts[-1]
@@ -117,18 +127,29 @@ class ServeShapes:
 
 
 BLOCK_LADDER = (16, 128, 512, 1024, 1536, 2048, 3072)
-BATCH_LADDER = (4, 16, 64)
+BATCH_LADDER = (1, 4, 16, 64)
+#: batches up to this take a context bucket: a wider one nearly always holds
+#: a long session, and every (batch, context) pair is a program to compile
+CONTEXT_BATCH = 4
 
 
 def serve_shapes(cfg) -> ServeShapes:
-    """The latent block's ladder: short blocks batch and attend over a
-    whole-length context in the absorbed form; a long block runs whole, one
-    session a dispatch, in the up-projected form."""
-    full = cfg.max_len
+    """The latent block's ladder: short blocks batch and attend in the
+    absorbed form over the smallest of a quarter, a half and the whole of
+    ``max_len`` (whole pages, no shorter than the block) that holds the
+    longest session of the dispatch; a long block runs whole, one session a
+    dispatch, in the up-projected form."""
+    full, page = cfg.max_len, cfg.cache_page
+    blocks = tuple(b for b in BLOCK_LADDER if b < full) + (full,)
+
+    def paged(rows):
+        return -(-rows // page) * page
+
+    contexts = sorted({max(paged(full // part), paged(blocks[0]))
+                       for part in (4, 2, 1)})
     return ServeShapes(
-        "device-latent-cache",
-        tuple(b for b in BLOCK_LADDER if b < full) + (full,), BATCH_LADDER,
-        True, (full,), "absorbed", "up", True)
+        "device-latent-cache", blocks, BATCH_LADDER, True, tuple(contexts),
+        "absorbed", "up", True, CONTEXT_BATCH)
 
 
 def count_dispatch(cfg, extents) -> None:

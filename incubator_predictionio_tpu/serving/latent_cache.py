@@ -23,9 +23,13 @@ one's top-k next items.
   choice of form is by block length alone. Each bucket is three executables (embed, one
   layer, head + top-k), compiled once by ``warmup`` and called a layer at a
   time, so a bucket compiles one layer whatever the depth.
-- The ladder is the block's own (``block_of(cfg).serve_shapes``). The
-  sparse-index block's: a turn runs alone over a **context bucket** (powers
-  of two from twice ``index_topk`` to ``max_len``) that holds its session, in
+- The ladder is the block's own (``block_of(cfg).serve_shapes``), the rule
+  is one: a short dispatch takes the smallest (batch, **context bucket**)
+  that holds its sessions, so a lone turn reads its own session's rows and
+  not a batch of whole-length contexts. The latent block's contexts are a
+  quarter, a half and the whole of ``max_len``, for batches of up to 4 (a
+  wider batch reads the whole length). The sparse-index block's: a turn runs
+  alone over powers of two from twice ``index_topk`` to ``max_len``, in
   the ``select`` form; anything longer is cut into pieces that run in the
   ``chunk`` form, each writing its rows and attending to what the earlier
   pieces cached; only the last piece runs the head.
@@ -77,6 +81,12 @@ _TOUCHED = REGISTRY.counter(
     "pio_moe_experts_touched_total",
     "Held experts that received at least one pick, summed over dispatches",
     ("layer",))
+_CONTEXT_HELD = REGISTRY.counter(
+    "pio_seq_context_rows_held_total",
+    "Tokens of the sessions of short-block dispatches, their blocks included")
+_CONTEXT_READ = REGISTRY.counter(
+    "pio_seq_context_rows_read_total",
+    "Cache rows short-block dispatches read: batch x context of the bucket")
 _PREFILL_CHUNKS = REGISTRY.counter(
     "pio_seq_prefill_chunks_total",
     "Long-block dispatches: the pieces a long block was cut into (a block "
@@ -193,13 +203,14 @@ class LatentServing:
 
     def ladder(self) -> list:
         """Every (batch, block, context) bucket a dispatch can take. The
-        latent block: short blocks attend over a whole-length context; a
-        long block whose session fits the block itself (a cold session does)
-        attends over just that, else over the whole length. The sparse-index
-        block: short blocks and pieces of long ones over every context
-        bucket that holds them."""
-        out = [(b, self.blocks[0], c) for c in self.shapes.short_contexts
-               for b in self.batches]
+        latent block: short blocks attend over a context bucket in batches
+        of up to 4 and over the whole length in wider ones; a long block
+        whose session fits the block itself (a cold session does) attends
+        over just that, else over the whole length. The sparse-index block:
+        short blocks and pieces of long ones over every context bucket that
+        holds them."""
+        out = [(b, self.blocks[0], c) for b in self.batches
+               for c in self.shapes.contexts(b)]
         for t in self.blocks[1:]:
             out += [(1, t, c) for c in self.shapes.long_contexts(t)]
         return out
@@ -439,10 +450,8 @@ class LatentServing:
             quick = [b for b in members if len(b.tokens) - b.offset <= short]
             for i in range(0, len(quick), self.batches[-1]):
                 group = quick[i:i + self.batches[-1]]
-                ctx = _bucket(self.shapes.short_contexts,
-                              max(len(b.tokens) for b in group))
-                yield group, (_bucket(self.batches, len(group)), short,
-                              ctx), True
+                yield group, self._short_bucket(
+                    len(group), max(len(b.tokens) for b in group)), True
             for b in members:
                 if len(b.tokens) - b.offset <= short:
                     continue
@@ -451,12 +460,18 @@ class LatentServing:
                     block = _bucket(self.blocks, end - start)
                     piece = _Block(b.row, b.tokens[:end], start, b.pages)
                     if block == short:   # a cut block's tail, as a turn
-                        bucket = (self.batches[0], short, _bucket(
-                            self.shapes.short_contexts, end))
+                        bucket = self._short_bucket(1, end)
                     else:
                         bucket = (1, block, _bucket(
                             self.shapes.long_contexts(block), end))
                     yield [piece], bucket, end == len(b.tokens)
+
+    def _short_bucket(self, sessions: int, longest: int) -> tuple:
+        """The smallest (batch, context) a short dispatch of ``sessions``
+        fits, the longest of them ``longest`` tokens with its block."""
+        batch = _bucket(self.batches, sessions)
+        return batch, self.blocks[0], _bucket(
+            self.shapes.contexts(batch), longest)
 
     def _head(self, batch: int, block: int, ctx: int, k: int):
         """The head + top-k executable of a bucket at ``k``; the ladder is
@@ -500,6 +515,9 @@ class LatentServing:
             _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
             if block != self.blocks[0]:
                 _PREFILL_CHUNKS.inc()
+            else:
+                _CONTEXT_HELD.inc(sum(len(b.tokens) for b in group))
+                _CONTEXT_READ.inc(batch * ctx)
             self.block.count_dispatch(self.cfg, [
                 (b.offset, len(b.tokens) - b.offset) for b in group])
         return out

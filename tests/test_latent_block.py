@@ -302,18 +302,24 @@ def test_the_tolerance_catches_a_dropped_pick_and_bfloat16_sums():
 # the latent cache: extend == full forward
 # ---------------------------------------------------------------------------
 
+#: the short half of the ladder at max_len 256, page 16, max_batch 8
+SHORT_BUCKETS = [(1, 16, 64), (1, 16, 128), (1, 16, 256), (4, 16, 64),
+                 (4, 16, 128), (4, 16, 256), (8, 16, 256)]
+
+
 @pytest.fixture(scope="module")
 def served():
     """One chip's share (experts 4-7 of 16) behind the latent cache; the
-    ladder at max_len 256 is 4x16 / 8x16 absorbed over the whole length, and
-    up-projected 1x128 over 128 or 256 and 1x256."""
+    ladder at max_len 256 is 1x16 / 4x16 absorbed over a context of 64, 128
+    or the whole length, 8x16 over the whole length alone, and up-projected
+    1x128 over 128 or 256 and 1x256."""
     cfg = config(experts_held=4, expert_offset=4)
     params = seeded_params(cfg)   # four experts a layer: the share's own
     serving = LatentServing(params, cfg)
-    assert serving.warmup(8) == 5
+    assert serving.warmup(8) == len(SHORT_BUCKETS) + 3
     assert serving.info()["buckets"] == [
-        "4x16@256:absorbed", "8x16@256:absorbed", "1x128@128:up",
-        "1x128@256:up", "1x256@256:up"]
+        f"{LatentServing.label(*b)}:absorbed" for b in SHORT_BUCKETS] + [
+        "1x128@128:up", "1x128@256:up", "1x256@256:up"]
     yield serving, params, cfg
     serving.close()
 
@@ -327,7 +333,7 @@ def _dispatched() -> dict:
 @pytest.mark.parametrize("first, growth", [
     (40, (1, 7, 16)),        # miss in 1x128@128, three absorbed extensions
     (200, (17, 20, 19)),     # miss in 1x256, three extensions in 1x128@256
-    (3, (100, 5, 148)),      # miss absorbed; 1x128@128, 4x16, 1x256
+    (3, (100, 5, 148)),      # miss absorbed; 1x128@128, 1x16@128, 1x256
 ], ids=["absorbed", "up", "mixed"])
 def test_miss_then_three_extensions_equal_the_full_forward(
         served, sessions, first, growth):
@@ -342,14 +348,106 @@ def test_miss_then_three_extensions_equal_the_full_forward(
     after = _dispatched()
     used = {b for b in after if after[b] > before.get(b, 0)}
     assert used == {
-        "absorbed": {"1x128@128", "4x16@256"},
+        "absorbed": {"1x128@128", "1x16@64"},   # 41, 48, 64 of 64 rows
         "up": {"1x256@256", "1x128@256"},
-        "mixed": {"4x16@256", "1x128@128", "1x256@256"}}[
+        "mixed": {"1x16@64", "1x128@128", "1x16@128", "1x256@256"}}[
         "absorbed" if first == 40 else "up" if first == 200 else "mixed"]
 
 
+def _fills(ask, s):
+    """A session that fills the 64-row context exactly: no invalid key of
+    the context stands for the padding item (``head_step``'s own column)."""
+    ask([("a", s[0, :60])])
+    return [ask([("a", s[0, :64])])]
+
+
+def _crosses(ask, s):
+    """A session that crosses from the 64-row context into the 128-row one
+    between two turns: the rows the first wrote are the second's context."""
+    ask([("a", s[1, :60])])
+    return [ask([("a", s[1, :64])]), ask([("a", s[1, :70])])]
+
+
+def _pair(ask, s):
+    """Two sessions a dispatch: the longer one sets the context."""
+    ask([("a", s[2, :30])]), ask([("b", s[3, :100])])
+    return [ask([("a", s[2, :33]), ("b", s[3, :105])])]
+
+
+def _tail(ask, s):
+    """A block cut into a piece of 128 and a tail of 12, which runs as a
+    turn over what the piece cached (the play shortens the block ladder: the
+    latent block's own holds every block whole)."""
+    return [ask([("a", s[4, :140])])]
+
+
+@pytest.mark.parametrize("play, natural, reused, blocks", [
+    (_fills, ["1x128@128", "1x16@64"], 60, None),
+    (_crosses, ["1x128@128", "1x16@64", "1x16@128"], 60 + 64, None),
+    (_pair, ["1x128@128", "1x128@128", "4x16@128"], 30 + 100, None),
+    (_tail, ["1x128@128", "1x16@256"], 0, (16, 128)),
+], ids=["fills", "crosses", "pair", "tail"])
+def test_every_short_bucket_that_holds_a_group_answers_alike(
+        served, sessions, monkeypatch, play, natural, reused, blocks):
+    """The rows a smaller context leaves out are rows the mask had zeroed:
+    the same sessions through the whole-length 4x16 bucket (the parent's
+    only one), through the buckets the plan picks and through every other
+    short bucket that holds them give one answer, the reference's."""
+    serving, params, cfg = served
+    if blocks:
+        monkeypatch.setattr(serving, "blocks", blocks)
+    asked, runs = [], []
+
+    def ask(requests):
+        asked.append([tokens for _, tokens in requests])
+        return serving.extend(
+            [(f"{play.__name__}{len(runs)}-{key}", tokens)
+             for key, tokens in requests])
+
+    def run(bucket=None):
+        """The play with fresh session keys; every short dispatch in
+        ``bucket`` (default: the plan's own choice)."""
+        runs.append(bucket)
+        del asked[:]
+        if bucket:   # shadows the method
+            serving._short_bucket = lambda sessions, longest: bucket
+        try:
+            return play(ask, sessions)
+        finally:
+            vars(serving).pop("_short_bucket", None)
+
+    def extends():
+        return [s["attrs"]["bucket"] for s in trace.TRACES.spans()
+                if s["name"] == "seq.batch.extend"]
+
+    want = run((4, 16, 256))
+    r0 = REGISTRY.get("pio_seq_tokens_reused_total").value
+    before = len(extends())
+    got = run()
+    assert REGISTRY.get("pio_seq_tokens_reused_total").value - r0 == reused
+    assert extends()[before:] == natural
+    answered = asked[-len(got):]
+    for batch, (scores, items) in zip(answered, got):
+        for tokens, s, i in zip(batch, scores, items):
+            want_s, want_i = masked_reference(params, cfg, tokens)
+            np.testing.assert_array_equal(i, want_i)
+            np.testing.assert_allclose(s, want_s, atol=TOL, rtol=0)
+    holds = [b for b in SHORT_BUCKETS
+             if b[0] >= max(map(len, asked))
+             and b[2] >= max(len(t) for batch in asked for t in batch)]
+    assert len(holds) >= 3
+    for bucket, answers in [(None, got)] + [(b, run(b)) for b in holds]:
+        for (ws, wi), (gs, gi) in zip(want, answers):
+            np.testing.assert_array_equal(gi, wi, err_msg=str(bucket))
+            np.testing.assert_allclose(gs, ws, atol=TOL, rtol=0,
+                                       err_msg=str(bucket))
+    # the next tests count free pages
+    for key in [k for k in serving._sessions if k.startswith(play.__name__)]:
+        serving._free.extend(serving._sessions.pop(key).pages)
+
+
 def test_a_batch_of_warm_cold_and_repeated_sessions(served, sessions):
-    """Eight short blocks share the 8x16 bucket; a session asked twice in
+    """Eight short blocks share the 8x16@256 bucket; a session asked twice in
     one batch is extended in two rounds; a keyless session leaves nothing."""
     serving, params, cfg = served
     warm = [(f"w{i}", sessions[i, :30 + i]) for i in range(6)]
@@ -473,14 +571,14 @@ def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
                               "reused": 0}
     extends = [s["attrs"] for s in spans if s["name"] == "seq.batch.extend"]
     assert extends == [
-        {"bucket": "4x16@256", "tokens": 12, "form": "absorbed"},
+        {"bucket": "1x16@64", "tokens": 12, "form": "absorbed"},
         {"bucket": "1x256@256", "tokens": 140, "form": "up"}]
 
     scopes = serving.device_scopes()
     assert set(scopes) == {
         f"jit_seq_{kind}_b{b}_t{t}_c{c}" for kind in ("layer", "head")
-        for b, t, c in ((4, 16, 256), (8, 16, 256), (1, 128, 128),
-                        (1, 128, 256), (1, 256, 256))}
+        for b, t, c in SHORT_BUCKETS + [(1, 128, 128), (1, 128, 256),
+                                        (1, 256, 256)]}
     for module, found in scopes.items():
         want = {"head_topk"} if "_head_" in module else {
             "mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared"}
@@ -633,8 +731,9 @@ def test_train_persist_deploy_query_through_the_query_server(
     assert info["path"] == "device-latent-cache"
     assert info["experts_held"] == info["n_routed_experts"] == 8
     assert info["cache_capacity_tokens"] >= 512 - 8
-    assert info["buckets"] == ["4x16@32:absorbed", "8x16@32:absorbed",
-                               "1x32@32:up"]
+    assert info["buckets"] == [
+        "1x16@16:absorbed", "1x16@32:absorbed", "4x16@16:absorbed",
+        "4x16@32:absorbed", "8x16@32:absorbed", "1x32@32:up"]
     pub = lm.published(model.config)
     for n, body in zip((8, 10, 12), answers):
         tokens = np.asarray([model.item_map[i] for i in session[:n]], np.int32)

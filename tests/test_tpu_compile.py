@@ -3,7 +3,10 @@ the benchmark's widths (476,002 items × rank 128: 690 partitions in blocks of
 4,096 rows, nprobe 26, serve_k 128) and the sparse-index block's layer
 executables at the lifelong cell's (hidden 2048, 32 / 4 heads of 128,
 indexer 16 x 64 top-2048, 128 experts top-8 of width 768, 720,896 cache rows)
-compile for a described v5e. What the
+compile for a described v5e, as do the latent block's at the Mistral cell's
+(hidden 4096, 32 heads over a 256 + 64 latent row, 32 of 128 experts top-4 of
+width 2048, 786,560 cache rows) in its smallest and its largest turn bucket.
+What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
 a time or a result.
@@ -12,7 +15,10 @@ All such compiles live in THIS file, and the topology is described inside a
 fixture: only the worker that is given this file loads the TPU's library.
 """
 
+import json
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +149,110 @@ def test_sparse_index_layer_compiles_for_v5e_at_context_32768(
     for scope in ("gqa_proj", "idx_score", "idx_select", "sparse_attn",
                   "moe_router", "moe_experts"):
         assert f"/{scope}/" in text, scope
+
+
+# -- the latent block's layer in a lone turn's bucket and in the parent's one ------
+
+def _latent_cfg():
+    """The Mistral cell's block, from the cell's own configuration file."""
+    from incubator_predictionio_tpu.models.transformer import TransformerConfig
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmarks", "configs", "seq-mistral-small4-ep4.json")
+    with open(path) as f:
+        c = json.load(f)
+    return TransformerConfig(
+        vocab_size=c["vocab_size"], max_len=c["serve"]["max_len"],
+        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
+        n_layers=c["num_hidden_layers"], attention_kind="mla",
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_parameters=tuple(sorted(c["rope_parameters"].items())),
+        n_routed_experts=c["n_routed_experts"],
+        experts_per_token=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        experts_held=c["experts_held"], tie_head=False,
+        weight_dtype="bfloat16", cache_page=c["serve"]["cache_page"],
+        cache_tokens=c["serve"]["cache_tokens"])
+
+
+@pytest.fixture(scope="module")
+def latent_turn_layers(one_chip):
+    """The absorbed-form layer at ``1x16@1024`` and ``4x16@4096``, compiled
+    with the cell's own cache as the donated argument."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    cfg = _latent_cfg()
+    ladder = latent_moe.serve_shapes(cfg)
+    assert ladder.batches == (1, 4, 16, 64) and ladder.blocks[0] == 16
+    assert ladder.contexts(1) == ladder.contexts(4) == (1024, 2048, 4096)
+    assert ladder.contexts(16) == ladder.contexts(64) == (4096,)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    assert rows == 786_560
+    lw = {k: s(shape, jnp.float32 if f32 else bf16)
+          for k, (shape, f32) in latent_moe.layer_shapes(cfg).items()}
+    cache = {"latent": s((rows, latent_moe.cache_width(cfg)), bf16)}
+
+    def layer(lw, cache, counters, h, pages, offsets, counts):
+        return latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
+                                     counts, cfg=cfg, form=ladder.short_form)
+
+    assert (cfg.d_model, cache["latent"].shape[1]) == (4096, 384)
+    return rows, {
+        (batch, ctx): jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
+            lw, cache, s((34,), jnp.int32), s((batch, 16, 4096), jnp.float32),
+            s((batch, ctx // cfg.cache_page), jnp.int32),
+            s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
+        for batch, ctx in ((1, 1024), (4, 4096))}
+
+
+@pytest.mark.parametrize("batch, ctx", [(1, 1024), (4, 4096)])
+def test_latent_turn_layer_compiles_for_v5e(latent_turn_layers, batch, ctx):
+    rows, compiled = latent_turn_layers
+    cache_bytes = rows * 384 * 2
+    mem = compiled[batch, ctx].memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes       # donated, not copied
+    # a layer's 1.72 GB of weights + the cache + temporaries beside the
+    # other five layers and 0.54 GB of embeddings
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + 5 * 1.72e9 + 0.54e9 + 5 * cache_bytes < 15.5e9
+    text = compiled[batch, ctx].as_text()
+    for scope in ("mla_proj", "mla_attn", "moe_router", "moe_experts",
+                  "moe_shared"):
+        assert f"/{scope}/" in text, scope
+
+
+def _scope_array_bytes(text: str, scope: str, but_rows: int) -> int:
+    """Bytes of every array an instruction under ``scope`` produces (fast
+    memory included: ``temp_size_in_bytes`` counts only what spills out of
+    it), but for those as long as the cache."""
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    total = 0
+    for dtype, dims in re.findall(
+            r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]+)\][^\n]*"
+            rf'op_name="[^"]*/{scope}/', text, re.M):
+        dims = [int(d) for d in dims.split(",")]
+        if dims[0] != but_rows:
+            total += math.prod(dims) * width[dtype]
+    return total
+
+
+def test_a_lone_turns_bucket_holds_a_tenth_of_the_widest_ones_attention_arrays(
+        latent_turn_layers):
+    """What the context bucket is for: the gathered rows, the float32 scores
+    and the probabilities of ``1x16@1024`` against ``4x16@4096``'s."""
+    rows, compiled = latent_turn_layers
+    small, large = (
+        _scope_array_bytes(compiled[b].as_text(), "mla_attn", rows)
+        for b in ((1, 1024), (4, 4096)))
+    assert 1e6 < small < large / 10
+    # the cache itself stays where it is in both
+    assert all(c.memory_analysis().temp_size_in_bytes < 16e6
+               for c in compiled.values())
