@@ -30,6 +30,13 @@ half from models/sparse_gqa.py (grouped-query heads behind a learned sparse
 index, a softmax router, no shared expert); the norms, the router, the
 grouped expert matmul, the head and the serving steps below are this
 module's for both kinds.
+
+A config with a ``layer_pattern`` (``attention_kind="gqa"``) makes each layer
+ONE of three things behind one norm (``layer_kinds``): a state-space mixer
+(``"S"``, models/state_space.py), dense grouped-query attention (``"A"``,
+models/sparse_gqa.py's plain part) or this module's router and experts alone
+(``"E"``, ``expert_layer``). The experts' activation is the config's:
+gated SiLU (three matrices) or squared ReLU (two).
 """
 
 from __future__ import annotations
@@ -166,7 +173,21 @@ def block_of(cfg):
         from incubator_predictionio_tpu.models import sparse_gqa
 
         return sparse_gqa
+    if cfg.layer_pattern:
+        from incubator_predictionio_tpu.models import state_space
+
+        return state_space
     return sys.modules[__name__]
+
+
+#: the layer of a config without a pattern: an attention half, then experts
+LAYER = "layer"
+
+
+def layer_kinds(cfg) -> tuple:
+    """What each layer is: the pattern's letters (``"S"`` a state-space
+    mixer, ``"A"`` attention, ``"E"`` experts), or ``LAYER`` for each."""
+    return tuple(cfg.layer_pattern) or (LAYER,) * cfg.n_layers
 
 
 def scopes(cfg) -> tuple:
@@ -193,25 +214,54 @@ def attention_shapes(cfg) -> dict:
     }
 
 
-def layer_shapes(cfg) -> dict:
-    """One layer's arrays: ``{name: (shape, float32-always?)}``: the two
-    norms, the attention half of the config's kind, the router (its
-    selection bias only where the scoring is sigmoid), the routed experts
-    held here and the shared ones where there are any."""
+def expert_shapes(cfg) -> dict:
+    """The expert half's arrays: its norm, the router (its selection bias
+    only where the scoring is sigmoid), the routed experts held here and the
+    shared ones where there are any; gated experts have a third matrix. The
+    routed experts' first matrices are STORED with their width padded to
+    whole 128-lane tiles, the padding zeros (``pad_stored``): the TPU
+    compiler lays a ``[e, d, f]`` array whose ``f`` is no multiple of 128 out
+    column-major, and the grouped matmul then copies all of it at every call
+    (638 MB a layer at 64 x 2688 x 1856; PERF.md PR 34). The width of the
+    mathematics is ``moe_intermediate_size``: ``we2`` has that many rows. (A
+    width under one tile is stored as it is: the toy sizes of the CPU tests,
+    whose pinned programs stay what they were.)"""
     d = cfg.d_model
     f, e = cfg.moe_intermediate_size, experts_held(cfg)
-    fs = f * cfg.n_shared_experts
-    out = {"norm1": ((d,), True), "norm2": ((d,), True),
-           **block_of(cfg).attention_shapes(cfg),
-           "w_r": ((d, cfg.n_routed_experts), True)}
+    fs = cfg.shared_intermediate_size or f * cfg.n_shared_experts
+    gated = cfg.expert_activation == "gated_silu"
+    out = {"norm2": ((d,), True), "w_r": ((d, cfg.n_routed_experts), True)}
     if cfg.router_scoring == "sigmoid":
         out["b_r"] = ((cfg.n_routed_experts,), True)
-    out.update({"we1": ((e, d, f), False), "we3": ((e, d, f), False),
-                "we2": ((e, f, d), False)})
+    out["we1"] = ((e, d, f if f < LANES else -(-f // LANES) * LANES), False)
+    if gated:
+        out["we3"] = out["we1"]
+    out["we2"] = ((e, f, d), False)
     if cfg.n_shared_experts:
-        out.update({"ws1": ((d, fs), False), "ws3": ((d, fs), False),
-                    "ws2": ((fs, d), False)})
+        out["ws1"] = ((d, fs), False)
+        if gated:
+            out["ws3"] = ((d, fs), False)
+        out["ws2"] = ((fs, d), False)
     return out
+
+
+def layer_shapes(cfg, kind: str = LAYER) -> dict:
+    """One layer's arrays: ``{name: (shape, float32-always?)}``. ``LAYER``:
+    the two norms, the attention half of the config's kind and the expert
+    half; a pattern's layer: one norm and its own part."""
+    norm = {"norm1": ((cfg.d_model,), True)}
+    if kind == "E":
+        return expert_shapes(cfg)
+    if kind != LAYER:
+        return {**norm, **block_of(cfg).mixer_shapes(cfg, kind)}
+    experts = expert_shapes(cfg)
+    return {**norm, "norm2": experts.pop("norm2"),
+            **block_of(cfg).attention_shapes(cfg), **experts}
+
+
+def pad_stored(array, shape):
+    """``array`` with zeros up to the stored ``shape`` (``expert_shapes``)."""
+    return jnp.pad(array, [(0, n - m) for n, m in zip(shape, array.shape)])
 
 
 def init_params(key, cfg) -> dict:
@@ -224,16 +274,22 @@ def init_params(key, cfg) -> dict:
         return (jax.random.normal(next(keys), shape, F32) * scale).astype(dtype)
 
     layers = []
-    for _ in range(cfg.n_layers):
+    special = getattr(block_of(cfg), "INIT", {})
+    for kind in layer_kinds(cfg):
         lw = {}
-        for name, (shape, f32) in layer_shapes(cfg).items():
-            if name.startswith("norm"):
+        for name, (shape, f32) in layer_shapes(cfg, kind).items():
+            if name in special:
+                lw[name] = special[name](next(keys), shape)
+            elif name.startswith("norm"):
                 lw[name] = jnp.ones(shape, F32)
             elif name == "b_r":
                 lw[name] = jnp.zeros(shape, F32)
             else:
                 lw[name] = normal(shape, shape[-2] ** -0.5,
                                   F32 if f32 else wdt)
+            if name in ("we1", "we3"):     # the stored padding is zeros
+                lw[name] = pad_stored(
+                    lw[name][..., :cfg.moe_intermediate_size], shape)
         layers.append(lw)
     params = {"item_emb": normal((cfg.vocab_size, cfg.d_model), 0.02),
               "norm_f": jnp.ones((cfg.d_model,), F32), "layers": layers}
@@ -387,48 +443,125 @@ def moe_router(x, lw, cfg):
     return idx, gi / gi.sum(-1, keepdims=True) * cfg.routed_scaling_factor
 
 
-def _gated(x, w1, w3, w2, dot):
-    a = jax.nn.silu(dot(x, w1)) * dot(x, w3)
-    return dot(a.astype(w2.dtype), w2)
+def _expert_hidden(x, lw, names, dot):
+    """``silu(w1 x) * w3 x``, or ``relu(w1 x)^2`` where the layer has no
+    third matrix (``expert_activation="relu2"``), at the width of the
+    mathematics (``w2``'s rows: ``w1`` may be stored wider, with zeros)."""
+    w1, w3, w2 = (lw.get(n) for n in names)
+    a = dot(x, w1)
+    a = jnp.square(jax.nn.relu(a)) if w3 is None \
+        else jax.nn.silu(a) * dot(x, w3)
+    if a.shape[-1] != w2.shape[-2]:
+        a = a[..., :w2.shape[-2]]
+    return a
+
+
+def _expert(x, lw, names, dot):
+    """``w2 (silu(w1 x) * w3 x)`` or ``w2 relu(w1 x)^2``."""
+    w2 = lw[names[2]]
+    return dot(_expert_hidden(x, lw, names, dot).astype(w2.dtype), w2)
+
+
+#: the dense form's float32 activations ``[experts, N, f]`` a slice of experts
+DENSE_SLICE_BYTES = 256 << 20
+#: the lanes of a tile: the grouped matmul walks an expert's matrices in
+#: tiles this wide unless both their widths are whole multiples of twice it
+LANES = 128
+#: token slots from which the dense form is the faster one over matrices the
+#: grouped matmul walks 128 lanes at a time (``dense_experts``)
+DENSE_FROM_SLOTS = 64
+
+
+def dense_experts(n: int, stored) -> bool:
+    """Whether a block of ``n`` token slots runs every held expert on every
+    token: the code's own choice, from the block's static size and the
+    stored ``[held, d, f]`` of the experts' first matrices. The grouped
+    matmul reads only the experts a block touches and does only the picks'
+    operations, so it is the form wherever its kernel's tiles fit the widths
+    (4096 x 2048 and 2048 x 768, the other two served blocks: at 2.3 x and
+    4 x the floor of the bytes it reads). At 2688 x 1920, which admit no
+    tile wider than 128 lanes, it runs 7-13 x over that floor, and the dense
+    form's 21 x the operations at the matrix unit's own tiling cost what
+    reading all 64 experts costs (1.3 GB: 1.86 ms a layer) up to 128 slots.
+    One layer alone on the v5e, grouped / dense ms (PERF.md, PR 34): 16
+    slots 1.28 / 1.86 with 2 real tokens and 5.61 / 1.86 with 16; 64 slots
+    4.00 / 1.88 with 8 real and 11.78 / 1.88 with 64; 128 slots 13.3 / 2.0;
+    512 slots 15.9 / 4.0. So from 64 slots on the dense form wins whatever
+    the block holds, and at 16 the grouped form wins the lone turn of a few
+    items (what a 16-slot block nearly always is) and loses a full one: its
+    time follows the experts touched, which a static size cannot see. A
+    width under one tile (the toy sizes of the CPU tests) is a single
+    partial tile either way and keeps the grouped form."""
+    _, d, f = stored
+    narrow = d % (2 * LANES) != 0 or f % (2 * LANES) != 0
+    return n >= DENSE_FROM_SLOTS and f >= LANES and narrow
+
+
+def _experts_dense(x, gate, lw):
+    """Every held expert on every token, as batched matmuls over slices of
+    the experts, each token's output weighted by ``gate [N, held]`` (zero
+    where the token did not pick the expert): the same sum as the grouped
+    form, with ``held / picks`` times its operations at the matrix unit's own
+    tiling."""
+    wdt = lw["we1"].dtype
+    (held, d, fp), n = lw["we1"].shape, x.shape[0]
+    xs = x.astype(wdt)
+    step = max(1, min(held, DENSE_SLICE_BYTES // (4 * n * fp)))
+    y = jnp.zeros((n, d), F32)
+    for lo in range(0, held, step):
+        part = {name: m[lo:lo + step] for name, m in lw.items()
+                if name in ("we1", "we3", "we2")}
+        a = _expert_hidden(xs, part, ("we1", "we3", "we2"), lambda v, m: _einsum(
+            "nd,edf->enf", v, m, wdt)) * gate.T[lo:lo + step, :, None]
+        y = y + _einsum("enf,efd->nd", a, part["we2"], wdt)
+    return y
 
 
 def moe_experts(x, idx, w, token_valid, lw, cfg):
-    """The routed experts held here: token-pick pairs sorted by expert, one
-    grouped matmul per expert matrix, unsorted, weighted, summed per token.
-    Pairs of padding tokens or of experts held elsewhere sort behind the
-    last group, where the grouped matmul does no work. Returns ``(y [N, d],
-    counters [held + 2])``."""
+    """The routed experts held here. Grouped form: token-pick pairs sorted
+    by expert, one grouped matmul per expert matrix, unsorted, weighted,
+    summed per token; pairs of padding tokens or of experts held elsewhere
+    sort behind the last group, where the grouped matmul does no work. Dense
+    form (where ``dense_experts`` says so): ``_experts_dense``. Returns
+    ``(y [N, d], counters [held + 2])``."""
     n, k = idx.shape
     held = experts_held(cfg)
     wdt = lw["we1"].dtype
     local = idx - cfg.expert_offset
     here = (local >= 0) & (local < held) & token_valid[:, None]
     key = jnp.where(here, local, held).reshape(n * k)
-    order = jnp.argsort(key)                      # stable
+    dense = dense_experts(n, lw["we1"].shape)
+    order = None if dense else jnp.argsort(key)   # stable
     group_sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
-    xs = x.astype(wdt)[order // k]
+    if dense:
+        gate = jnp.zeros((n, held + 1), F32).at[
+            jnp.arange(n)[:, None], key.reshape(n, k)].add(
+                jnp.where(here, w, 0.0))[:, :held]
+        y = _experts_dense(x, gate, lw)
+    else:
+        xs = x.astype(wdt)[order // k]
 
-    def dot(a, m):
-        return jax.lax.ragged_dot(a, m, group_sizes,
-                                  preferred_element_type=F32,
-                                  precision=_precision(wdt))
+        def dot(a, m):
+            return jax.lax.ragged_dot(a, m, group_sizes,
+                                      preferred_element_type=F32,
+                                      precision=_precision(wdt))
 
-    out = _gated(xs, lw["we1"], lw["we3"], lw["we2"], dot)
-    weight = jnp.where(here, w, 0.0).reshape(n * k)[order]
-    out = jnp.where((key[order] < held)[:, None], out, 0.0) * weight[:, None]
-    y = jnp.zeros((n * k, x.shape[-1]), F32).at[order].set(
-        out, unique_indices=True)
+        out = _expert(xs, lw, ("we1", "we3", "we2"), dot)
+        weight = jnp.where(here, w, 0.0).reshape(n * k)[order]
+        out = jnp.where((key[order] < held)[:, None], out, 0.0) \
+            * weight[:, None]
+        y = jnp.zeros((n * k, x.shape[-1]), F32).at[order].set(
+            out, unique_indices=True)
     picks = token_valid.sum() * k
     counters = jnp.concatenate([
         group_sizes,
         jnp.stack([picks - group_sizes.sum(),
                    (group_sizes > 0).sum()]).astype(jnp.int32)])
-    return y.reshape(n, k, -1).sum(1), counters
+    return y if dense else y.reshape(n, k, -1).sum(1), counters
 
 
 def moe_shared(x, lw):
-    return _gated(x.astype(lw["ws1"].dtype), lw["ws1"], lw["ws3"], lw["ws2"],
-                  _mm)
+    return _expert(x.astype(lw["ws1"].dtype), lw, ("ws1", "ws3", "ws2"), _mm)
 
 
 def attention(x, h, lw, cfg, pos, q_index, context, form):
@@ -444,19 +577,11 @@ def attention(x, h, lw, cfg, pos, q_index, context, form):
         return h + _mm(a, lw["w_o"]), state
 
 
-def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form=None):
-    """One block on ``h [B, T, d]`` (float32): the attention half of the
-    config's kind, then router and experts. ``context(rows)`` takes the
-    block's new cache rows and returns ``(ctx, key_valid [B, Tc], state)``:
-    the block itself while training, the session cache after the write while
-    serving. Returns ``(h, counters, state)``."""
+def expert_layer(lw, h, cfg, token_valid):
+    """The expert half on ``h [B, T, d]``: norm, router, the routed experts
+    held here and the shared ones. Returns ``(h, counters)``."""
     b, t, d = h.shape
-    eps = cfg.rms_norm_eps
-    block = block_of(cfg)
-    x = rms_norm(h, lw["norm1"], eps)
-    h, state = block.attention(
-        x, h, lw, cfg, pos, q_index, context, form or block.DEFAULT_FORM)
-    x = rms_norm(h, lw["norm2"], eps).reshape(b * t, d)
+    x = rms_norm(h, lw["norm2"], cfg.rms_norm_eps).reshape(b * t, d)
     with jax.named_scope("moe_router"):
         idx, w = moe_router(x, lw, cfg)
     with jax.named_scope("moe_experts"):
@@ -465,7 +590,21 @@ def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form=None):
     if "ws1" in lw:
         with jax.named_scope("moe_shared"):
             y = y + moe_shared(x, lw)
-    return h + y.reshape(b, t, d), counters, state
+    return h + y.reshape(b, t, d), counters
+
+
+def layer_apply(lw, h, cfg, pos, q_index, token_valid, context, form=None):
+    """One block on ``h [B, T, d]`` (float32): the attention half of the
+    config's kind, then router and experts. ``context(rows)`` takes the
+    block's new cache rows and returns ``(ctx, key_valid [B, Tc], state)``:
+    the block itself while training, the session cache after the write while
+    serving. Returns ``(h, counters, state)``."""
+    block = block_of(cfg)
+    x = rms_norm(h, lw["norm1"], cfg.rms_norm_eps)
+    h, state = block.attention(
+        x, h, lw, cfg, pos, q_index, context, form or block.DEFAULT_FORM)
+    h, counters = expert_layer(lw, h, cfg, token_valid)
+    return h, counters, state
 
 
 def block_context(valid, wdt):
@@ -495,9 +634,17 @@ def forward(params, tokens, positions, cfg):
     h = params["item_emb"][tokens].astype(F32)
     valid = tokens != 0
     q_index = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
-    context = block_of(cfg).block_context(valid, wdt)
-    for lw in params["layers"]:
-        h, _, _ = layer_apply(lw, h, cfg, positions, q_index, valid, context)
+    block = block_of(cfg)
+    context = block.block_context(valid, wdt)
+    for kind, lw in zip(layer_kinds(cfg), params["layers"]):
+        if kind == LAYER:
+            h, _, _ = layer_apply(lw, h, cfg, positions, q_index, valid,
+                                  context)
+        elif kind == "E":
+            h, _ = expert_layer(lw, h, cfg, valid)
+        else:
+            h, _ = block.mixer_layer(kind, lw, h, cfg, q_index, valid,
+                                     context)
     return rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
 
 
@@ -557,6 +704,25 @@ def layer_step(lw, cache, counters, h, pages, offsets, counts, *, cfg, form):
     h, layer_counters, cache = layer_apply(
         lw, h, cfg, q_index, q_index, token_valid, context, form)
     return h, cache, counters + layer_counters
+
+
+def expert_step(lw, cache, counters, h, slots, offsets, counts, *, cfg, form):
+    """An ``"E"`` layer of a pattern: no context, nothing cached."""
+    token_valid = jnp.arange(h.shape[1])[None, :] < counts[:, None]
+    h, layer_counters = expert_layer(lw, h, cfg, token_valid)
+    return h, cache, counters + layer_counters
+
+
+def step_of(kind: str, cfg):
+    """The serving step of one layer kind: ``(lw, cache, counters, h, own,
+    offsets, counts, *, cfg, form) -> (h, cache, counters)``; ``cache`` is
+    what the kind keeps and ``own`` where the batch's sessions keep it
+    (per-token rows by ``pages [B, P]``; a mixer's per-session state by
+    ``slots [B]``; the experts' nothing) and ``counters`` the experts' (``()``
+    for a layer without any)."""
+    if kind == LAYER:
+        return layer_step
+    return expert_step if kind == "E" else block_of(cfg).STEPS[kind]
 
 
 def head_step(norm_f, head, tok_cache, h, pages, offsets, counts, *, cfg, k):

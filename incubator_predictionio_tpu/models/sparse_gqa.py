@@ -41,6 +41,12 @@ Named scopes, for the device trace: ``gqa_proj`` (projections, head norms,
 rope, output projection), ``idx_score`` (index rows written and read, index
 scores), ``idx_select`` (top-k or threshold), ``sparse_attn`` (key/value rows
 written and read, attention).
+
+The plain part, for the ``"A"`` layers of a layer pattern
+(``attention_kind="gqa"``, composed in models/state_space.py): the same
+grouped heads and key/value rows with no index, no head norms and no
+positional encoding, every query attending to all it sees (``dense_*``;
+scopes ``gqa_proj``, ``gqa_attn``).
 """
 
 from __future__ import annotations
@@ -52,7 +58,9 @@ import numpy as np
 from incubator_predictionio_tpu.models.latent_moe import (
     F32,
     NEG,
+    Q_CHUNK,
     ServeShapes,
+    _block_geometry,
     _einsum,
     _mm,
     rms_norm,
@@ -376,6 +384,77 @@ def block_context(valid, wdt):
     """``context`` when the block is its own context (``fit``, ``forward``)."""
     return lambda rows: (
         {k: v.astype(wdt) for k, v in rows.items()}, valid, None)
+
+
+# -- the plain part: dense grouped-query attention, an "A" layer of a pattern -----------------
+
+def dense_shapes(cfg) -> dict:
+    d, h, kv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    return {"w_q": ((d, h * dh), False), "w_k": ((d, kv * dh), False),
+            "w_v": ((d, kv * dh), False), "w_o": ((h * dh, d), False)}
+
+
+def dense_row_layout(cfg) -> dict:
+    return {"kv": _lanes(2 * cfg.n_kv_heads * cfg.head_dim)}
+
+
+def attend_dense(q, rows, q_index, key_valid, cfg, wdt):
+    """``q [B, T, H, dh]`` (scaled) over the context's key/value ``rows [B,
+    Tc, W]``: causal softmax over every visible key, queries in chunks."""
+    b, t, h, dh = q.shape
+    k, v = _split_kv(rows, cfg)
+
+    def chunk(args):
+        qc, qi = args
+        s = _einsum("btngd,bsnd->bngts", _grouped(qc, cfg), k, wdt)
+        seen = _visible(qi, key_valid, 0, key_valid.shape[1])
+        p = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG), axis=-1)
+        return _einsum("bngts,bsnd->btngd", p, v, wdt).reshape(
+            b, qc.shape[1], h * dh)
+
+    if t <= Q_CHUNK:
+        return chunk((q, q_index))
+    n = t // Q_CHUNK
+    out = jax.lax.map(chunk, (
+        jnp.moveaxis(q.reshape(b, n, Q_CHUNK, h, dh), 1, 0),
+        jnp.moveaxis(q_index.reshape(b, n, Q_CHUNK), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h * dh)
+
+
+def dense_layer(lw, h, cfg, q_index, context):
+    """``h + W_o attention(norm(h))``; ``context(rows)`` takes the block's
+    new key/value rows and returns ``(rows of the context, key_valid,
+    state)``. Returns ``(h, state)``."""
+    b, t, _ = h.shape
+    wdt = lw["w_q"].dtype
+    with jax.named_scope("gqa_proj"):
+        x = rms_norm(h, lw["norm1"], cfg.rms_norm_eps)
+        q = _mm(x, lw["w_q"]).reshape(b, t, cfg.n_heads, cfg.head_dim) \
+            * cfg.head_dim ** -0.5
+        rows = jnp.concatenate(
+            [_mm(x, lw["w_k"]), _mm(x, lw["w_v"])], -1).astype(wdt)
+    with jax.named_scope("gqa_attn"):
+        ctx, key_valid, state = context(rows)   # cache write and gather
+        a = attend_dense(q, ctx, q_index, key_valid, cfg, wdt)
+    with jax.named_scope("gqa_proj"):
+        return h + _mm(a, lw["w_o"]), state
+
+
+def dense_step(lw, cache, counters, h, pages, offsets, counts, *, cfg, form):
+    """An ``"A"`` layer of "extend a batch of sessions by a block each": the
+    block's key/value rows go to the sessions' pages, every query attends
+    over its session's cached rows."""
+    q_index, _, write, read, key_valid = _block_geometry(
+        pages, offsets, counts, h.shape[1], cfg.cache_page)
+
+    def context(rows):
+        pad = cache["kv"].shape[-1] - rows.shape[-1]
+        kv = cache["kv"].at[write].set(
+            jnp.pad(rows, [(0, 0), (0, 0), (0, pad)]))
+        return kv[read], key_valid, {"kv": kv}
+
+    h, cache = dense_layer(lw, h, cfg, q_index, context)
+    return h, cache, counters
 
 
 # -- the serving ladder, and what a dispatch did, from the equations ------------------------

@@ -95,7 +95,28 @@ class TransformerConfig:
     # queries, one key head) that lets a query attend to its index_topk
     # best-scored keys only; the routed experts and everything else of the
     # "mla" block's other half (models/latent_moe.py), router_scoring softmax
+    # "gqa": dense grouped-query attention (n_kv_heads of head_dim, no
+    # positional encoding) as the "A" layers of a layer_pattern, below
     attention_kind: str = "mha"
+    # one letter a layer (n_layers of them), each layer a mixer OR a
+    # feed-forward part alone behind one norm: "S" a selective state-space
+    # mixer with a causal depthwise convolution before it
+    # (models/state_space.py), "A" the attention of attention_kind="gqa",
+    # "E" the router and experts of models/latent_moe.py. "" (every other
+    # attention_kind): every layer is an attention half, then the experts
+    layer_pattern: str = ""
+    # the "S" layers: ssm_heads x ssm_head_dim inner values a token, a state
+    # of ssm_head_dim x ssm_state a head, B and C shared by the heads of each
+    # of ssm_groups groups; the convolution sees conv_kernel inputs;
+    # ssm_chunk is the tile of the chunked scan (it changes no result)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # what a session's recurrent state is kept in between requests
+    state_dtype: str = "float32"
     rms_norm_eps: float = 1e-6
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -108,6 +129,11 @@ class TransformerConfig:
     experts_per_token: int = 0
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0
+    # the shared experts' width together (0: moe_intermediate_size each)
+    shared_intermediate_size: int = 0
+    # an expert is w2 (silu(w1 x) * w3 x) ("gated_silu", three matrices) or
+    # w2 relu(w1 x)^2 ("relu2", two)
+    expert_activation: str = "gated_silu"
     routed_scaling_factor: float = 1.0
     # how the router scores: "sigmoid" (plus a selection-only bias) or
     # "softmax" over all the experts (no bias); weights are normalised over
@@ -133,9 +159,12 @@ class TransformerConfig:
     # sessions x the length they may reach; 0 = 16 sessions of max_len)
     cache_page: int = 128
     cache_tokens: int = 0
+    # serving, "S" layers: sessions whose recurrent state the device keeps
+    # (0 = what cache_tokens / max_len sessions need)
+    state_slots: int = 0
 
     def __post_init__(self):
-        if self.attention_kind in ("mla", "gqa_sparse"):
+        if self.attention_kind in ("mla", "gqa_sparse", "gqa"):
             if not self.n_routed_experts or self.n_experts:
                 raise ValueError(
                     f"attention_kind={self.attention_kind!r} has routed "
@@ -144,10 +173,18 @@ class TransformerConfig:
             if self.router_scoring not in ("sigmoid", "softmax"):
                 raise ValueError(
                     f"unknown router_scoring {self.router_scoring!r}")
+            if self.expert_activation not in ("gated_silu", "relu2"):
+                raise ValueError(
+                    f"unknown expert_activation {self.expert_activation!r}")
             if self.max_len % self.cache_page:
                 raise ValueError(
                     f"max_len={self.max_len} must be a multiple of "
                     f"cache_page={self.cache_page}")
+        if (self.attention_kind == "gqa") != bool(self.layer_pattern):
+            raise ValueError(
+                "a layer_pattern takes its 'A' layers from "
+                "attention_kind='gqa', and that kind is served in a pattern "
+                "only")
         if self.attention_kind == "mla":
             if not self.rope_parameters:
                 raise ValueError(
@@ -167,17 +204,40 @@ class TransformerConfig:
                 raise ValueError(
                     f"index_kv_tile={tile} must be whole pages of "
                     f"{self.cache_page} and divide max_len={self.max_len}")
+        elif self.attention_kind == "gqa":
+            if (set(self.layer_pattern) - set("SAE")
+                    or len(self.layer_pattern) != self.n_layers):
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} is one of 'S' "
+                    "(state-space mixer), 'A' (attention), 'E' (experts) a "
+                    f"layer, n_layers={self.n_layers} of them")
+            if (not self.n_kv_heads or self.n_heads % self.n_kv_heads
+                    or not self.head_dim):
+                raise ValueError(
+                    "attention_kind='gqa' needs n_kv_heads dividing n_heads "
+                    "and a head_dim")
+            inner = self.ssm_heads * self.ssm_head_dim
+            if "S" in self.layer_pattern and (
+                    not inner or not self.ssm_state or self.ssm_groups < 1
+                    or self.ssm_heads % self.ssm_groups
+                    or inner % self.ssm_groups or self.conv_kernel < 2
+                    or self.ssm_chunk < 1):
+                raise ValueError(
+                    "an 'S' layer needs ssm_heads x ssm_head_dim, an "
+                    "ssm_state, ssm_groups dividing ssm_heads, conv_kernel "
+                    ">= 2 and an ssm_chunk")
         elif self.attention_kind != "mha":
             raise ValueError(f"unknown attention_kind {self.attention_kind!r}")
         elif self.n_routed_experts or not self.tie_head:
             raise ValueError(
                 "routed experts and an untied head belong to "
-                "attention_kind='mla' or 'gqa_sparse'")
+                "attention_kind='mla', 'gqa_sparse' or 'gqa'")
 
     @property
     def latent(self) -> bool:
         """The blocks of models/latent_moe.py (RMSNorm, routed experts),
-        served from the paged session cache: "mla" and "gqa_sparse"."""
+        served from the session cache: "mla", "gqa_sparse" and the layer
+        patterns of "gqa"."""
         return self.attention_kind != "mha"
 
 

@@ -1,8 +1,10 @@
 """Serving state of the blocks of models/latent_moe.py (``attention_kind``
-"mla" and "gqa_sparse"): a device-resident, paged cache of per-token rows,
-the table of sessions that own its pages, and the one entry point that
-extends a batch of sessions by a block of new tokens each and returns each
-one's top-k next items.
+"mla", "gqa_sparse" and the layer patterns of "gqa"): a device-resident cache
+of what each layer keeps for a session (per-token rows in pages, a
+per-session recurrent state in a slot, or nothing), the table of sessions
+that own its pages and slots, and the one entry point that extends a batch of
+sessions by a block of new tokens each and returns each one's top-k next
+items.
 
 - The cache is, a layer, one array ``[pages * page, width]`` for each kind
   of row the block keeps (``row_layout``: the latent block one latent row,
@@ -33,6 +35,26 @@ one's top-k next items.
   the ``select`` form; anything longer is cut into pieces that run in the
   ``chunk`` form, each writing its rows and attending to what the earlier
   pieces cached; only the last piece runs the head.
+- A layer pattern (models/state_space.py) has three kinds of layer. An
+  ``"A"`` layer keeps per-token key/value rows in pages, as above. An ``"S"``
+  layer keeps a **per-session state** of fixed size (the recurrent state in
+  ``state_dtype`` and the convolution's last inputs, one row each of
+  ``[slots, values]`` arrays a layer): a session owns its pages AND one
+  slot, eviction frees both, slot 0 belongs to nobody (padding lands
+  there), and a block that starts at offset 0 starts from zeros whatever
+  its slot held. An ``"E"`` layer keeps nothing. A state stands at exactly
+  one position, the length last computed, so the **reuse rule** is: a
+  session's state stands at n tokens; an incoming list whose first n tokens
+  equal the cached ones and which is LONGER continues from the state
+  (tokens n.. are computed); any other list (it diverges before n, it slid
+  past ``max_len``, it is shorter, or it is the same list again) is computed
+  from position 0, and ``pio_seq_state_restarts_total`` counts it when a
+  prefix did match. (An unchanged list sent again is a restart: nothing of
+  the last answer is kept, and the last token cannot be recomputed from a
+  state that already holds it.) A bucket compiles one program a layer kind
+  (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``: no context, shared by the
+  bucket's contexts; ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern order;
+  the pieces of a cut block hand the state on through the slot.
 - One dispatch runs at a time (``_TurnLock``), and between the pieces of a
   cut block the lock is offered to whoever waits: another batch's turns run
   between a miss's pieces and do not wait for all of it. A batch that names
@@ -91,7 +113,28 @@ _PREFILL_CHUNKS = REGISTRY.counter(
     "pio_seq_prefill_chunks_total",
     "Long-block dispatches: the pieces a long block was cut into (a block "
     "the ladder holds whole is one)")
+_STATE_SLOTS = REGISTRY.gauge(
+    "pio_seq_state_slots",
+    "Per-session state slots by state (used, capacity)", ("state",))
+_STATE_EVICTIONS = REGISTRY.counter(
+    "pio_seq_state_evictions_total",
+    "Sessions evicted whose state slot was freed with their pages")
+_STATE_RESTARTS = REGISTRY.counter(
+    "pio_seq_state_restarts_total",
+    "Lists computed from position 0 although a prefix matched: the state "
+    "stood elsewhere (a diverging, shorter or unchanged list)")
+_STATE_TOKENS = REGISTRY.counter(
+    "pio_seq_state_tokens_total",
+    "Tokens run through the state-space layers by form (step: short blocks "
+    "from cached states; scan: long blocks)", ("form",))
+_STATE_STEP_SESSIONS = REGISTRY.counter(
+    "pio_seq_state_step_sessions_total",
+    "Sessions in short-block dispatches of a stateful pattern")
 TOP_K = 16                       # the head's k the ladder is warmed for
+#: a pattern's layer kinds: the names of their programs, and which take no
+#: context (one program a (batch, block), shared by the bucket's contexts)
+PROGRAM = {"S": "ssm", "A": "gqa", "E": "moe"}
+CONTEXT_FREE = ("S", "E")
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*)op_name="([^"]*)"', re.M)
 #: control flow has no device time of its own: a trace shows a loop's
@@ -105,6 +148,7 @@ class _Session:
     tokens: np.ndarray            # what the cache holds for it, in order
     pages: list
     given: Optional[Sequence] = None   # the list ``tokens`` was encoded from
+    slot: int = 0                 # its per-session state (0: none kept)
 
 
 @dataclasses.dataclass
@@ -114,6 +158,7 @@ class _Block:
     tokens: np.ndarray
     offset: int
     pages: list
+    slot: int = 0
 
 
 def _bucket(ladder: Sequence[int], n: int) -> int:
@@ -160,35 +205,64 @@ class LatentServing:
         self.blocks = self.shapes.blocks
         self.batches = self.shapes.batches
         self.device = next(iter(params["item_emb"].devices()))
+        self.kinds = latent_moe.layer_kinds(cfg)
         self.layout = self.block.row_layout(cfg)
         wdt = params["item_emb"].dtype
-        self.bytes_per_token = cfg.n_layers * sum(self.layout.values()) \
+        paged = sum(k in (latent_moe.LAYER, "A") for k in self.kinds)
+        self.bytes_per_token = paged * sum(self.layout.values()) \
             * wdt.itemsize + 4
+        # what an "S" layer keeps for a session, {name: (values, dtype)}
+        self.state_layout = self.block.state_layout(cfg) \
+            if "S" in self.kinds else {}
+        self.state_bytes_per_session = self.kinds.count("S") * sum(
+            n * dt.itemsize for n, dt in self.state_layout.values())
         # ``cache_tokens`` is the operator's: live sessions x the length they
         # may reach (default: 16 sessions of ``max_len``); never less than
         # two whole sessions. Page 0 belongs to nobody.
         tokens = cfg.cache_tokens or 16 * cfg.max_len
         n_pages = max(tokens, 2 * cfg.max_len) // self.page + 1
         rows = n_pages * self.page
+        # ``state_slots`` is the operator's too: sessions whose state the
+        # device keeps (default: what ``tokens / max_len`` sessions need).
+        # Slot 0 belongs to nobody.
+        self.n_slots = (cfg.state_slots or max(tokens // cfg.max_len, 2)) \
+            if self.state_layout else 0
+
+        def kept(kind):
+            if kind == "S":
+                return {name: jnp.zeros((self.n_slots + 1, n), dt)
+                        for name, (n, dt) in self.state_layout.items()}
+            if kind == "E":
+                return {}
+            return {name: jnp.zeros((rows, width), wdt)
+                    for name, width in self.layout.items()}
+
+        self.moe_layers = [i for i, k in enumerate(self.kinds)
+                           if k in (latent_moe.LAYER, "E")]
         with jax.default_device(self.device):
-            self.cache = [{kind: jnp.zeros((rows, width), wdt)
-                           for kind, width in self.layout.items()}
-                          for _ in range(cfg.n_layers)]
+            self.cache = [kept(kind) for kind in self.kinds]
             self.tok_cache = jnp.zeros((rows,), jnp.int32)
+            # (the experts' device counters; ``()`` where a layer has none)
             self.counters = [
                 jnp.zeros((latent_moe.experts_held(cfg)
                            + latent_moe.N_EXTRA_COUNTERS,), jnp.int32)
-                for _ in range(cfg.n_layers)]
+                if i in self.moe_layers else ()
+                for i in range(cfg.n_layers)]
         self.capacity_tokens = (n_pages - 1) * self.page
         self._free = list(range(n_pages - 1, 0, -1))
+        self._free_slots = list(range(self.n_slots, 0, -1))
         self._sessions: "collections.OrderedDict[str, _Session]" = \
             collections.OrderedDict()
         self._exe: dict = {}
+        self._shared: dict = {}      # (kind, batch, block) -> executable
         self._lock = _TurnLock()
         self._cutting: set = set()   # sessions of an ``extend`` under way
         self._published = np.zeros(
-            (cfg.n_layers, self.counters[0].shape[0]), np.int64)
+            (len(self.moe_layers), latent_moe.experts_held(cfg)
+             + latent_moe.N_EXTRA_COUNTERS), np.int64)
         _CACHE_TOKENS.labels(state="capacity").set(self.capacity_tokens)
+        if self.n_slots:
+            _STATE_SLOTS.labels(state="capacity").set(self.n_slots)
         # weakly: the registry must not keep a retired deployment's cache
         # and weights on the device
         me, key = weakref.ref(self), f"latent_serving:{id(self)}"
@@ -206,9 +280,9 @@ class LatentServing:
         latent block: short blocks attend over a context bucket in batches
         of up to 4 and over the whole length in wider ones; a long block
         whose session fits the block itself (a cold session does) attends
-        over just that, else over the whole length. The sparse-index block:
-        short blocks and pieces of long ones over every context bucket that
-        holds them."""
+        over just that, else over the whole length; a layer pattern's
+        attention layers the same. The sparse-index block: short blocks and
+        pieces of long ones over every context bucket that holds them."""
         out = [(b, self.blocks[0], c) for b in self.batches
                for c in self.shapes.contexts(b)]
         for t in self.blocks[1:]:
@@ -220,19 +294,33 @@ class LatentServing:
         return f"{batch}x{block}@{ctx}"
 
     def _compile(self, batch: int, block: int, ctx: int) -> dict:
-        exe = {k: v.compile() for k, v in self._lower(batch, block,
-                                                      ctx).items()}
-        return {**exe, "head": {
+        # a context-free program is compiled once a (batch, block)
+        done = {k: self._shared[k, batch, block] for k in CONTEXT_FREE
+                if (k, batch, block) in self._shared}
+        exe = {k: v.compile() for k, v in self._lower(
+            batch, block, ctx, skip=tuple(done)).items()}
+        self._shared.update({(k, batch, block): exe[k]
+                             for k in CONTEXT_FREE if k in exe})
+        return {**exe, **done, "head": {
             TOP_K: self._compile_head(batch, block, ctx, TOP_K)}}
 
-    def _lower(self, batch: int, block: int, ctx: int) -> dict:
-        """The bucket's embed and layer programs, lowered under the names a
-        device trace shows (``jit_seq_<kind>_b<B>_t<T>_c<C>``)."""
-        cfg, page = self.cfg, self.page
-        tag = f"b{batch}_t{block}_c{ctx}"
+    def program(self, kind: str, batch: int, block: int, ctx: int) -> str:
+        """The name a device trace shows a bucket's program under, less the
+        ``jit_``: ``seq_<embed|layer|head>_b<B>_t<T>_c<C>``, and for a
+        pattern's kinds ``seq_<ssm|moe>_b<B>_t<T>``, ``seq_gqa_b<B>_t<T>_c<C>``."""
+        tag = f"b{batch}_t{block}" + (
+            "" if kind in CONTEXT_FREE else f"_c{ctx}")
+        return f"seq_{PROGRAM.get(kind, kind)}_{tag}"
 
-        def named(fn, name):
-            fn.__name__ = fn.__qualname__ = f"seq_{name}_{tag}"
+    def _lower(self, batch: int, block: int, ctx: int, skip=()) -> dict:
+        """The bucket's embed program and its layer programs, one a layer
+        kind (``"layer"`` where every layer is the same), lowered under the
+        names a device trace shows."""
+        cfg, page = self.cfg, self.page
+
+        def named(fn, kind):
+            fn.__name__ = fn.__qualname__ = self.program(
+                kind, batch, block, ctx)
             return fn
 
         def spec(shape, dtype):
@@ -244,22 +332,33 @@ class LatentServing:
         form = self.form(block)
         # (the CPU backend cannot reuse a donated buffer and says so)
         keep = self.device.platform == "cpu"
+        out = {}
         with jax.default_device(self.device):
-            embed = jax.jit(named(
+            out["embed"] = jax.jit(named(
                 lambda emb, toks, tokens, pages, offsets, counts:
                 latent_moe.embed_step(emb, toks, tokens, pages, offsets,
                                       counts, page=page), "embed"),
                 donate_argnums=() if keep else (1,)).lower(
                 self.params["item_emb"], self.tok_cache,
                 spec((batch, block), jnp.int32), *small)
-            layer = jax.jit(named(
-                lambda lw, cache, counters, h, pages, offsets, counts:
-                latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
-                                      counts, cfg=cfg, form=form), "layer"),
-                donate_argnums=() if keep else (1, 2, 3)).lower(
-                self.params["layers"][0], self.cache[0], self.counters[0], h,
-                *small)
-        return {"embed": embed, "layer": layer}
+            for kind in dict.fromkeys(self.kinds):
+                if kind in skip:
+                    continue
+                first = self.kinds.index(kind)
+                # (a context-free kind takes the sessions' slots where the
+                # others take their pages; the argument keeps the name the
+                # accepted programs' texts were pinned under)
+                own = small[1] if kind in CONTEXT_FREE else small[0]
+                fn = (lambda step: lambda lw, cache, counters, h, pages,
+                      offsets, counts: step(
+                          lw, cache, counters, h, pages, offsets, counts,
+                          cfg=cfg, form=form))(latent_moe.step_of(kind, cfg))
+                out[kind] = jax.jit(
+                    named(fn, kind),
+                    donate_argnums=() if keep else (1, 2, 3)).lower(
+                    self.params["layers"][first], self.cache[first],
+                    self.counters[first], h, own, *small[1:])
+        return out
 
     def _compile_head(self, batch: int, block: int, ctx: int, k: int):
         cfg = self.cfg
@@ -268,8 +367,8 @@ class LatentServing:
             return latent_moe.head_step(norm_f, head, toks, h, pages, offsets,
                                         counts, cfg=cfg, k=k)
 
-        head.__name__ = head.__qualname__ = \
-            f"seq_head_b{batch}_t{block}_c{ctx}"
+        head.__name__ = head.__qualname__ = self.program(
+            "head", batch, block, ctx)
         int32 = jnp.int32
         with jax.default_device(self.device):
             return jax.jit(head).lower(
@@ -292,7 +391,7 @@ class LatentServing:
                 with span("deploy.warmup.bucket", bucket=self.label(*bucket),
                           path=f"latent-{self.form(bucket[1])}"):
                     self._exe[bucket] = self._compile(*bucket)
-                    empty = _Block(0, np.zeros(0, np.int32), 0, [])
+                    empty = _Block(0, np.zeros(0, np.int32), 0, [])   # slot 0
                     self._dispatch([empty], *bucket, count=False)
         return len(self._exe)
 
@@ -304,7 +403,7 @@ class LatentServing:
         their time is their bodies' operations', which are in the map."""
         out, scopes = {}, latent_moe.scopes(self.cfg)
         for (batch, block, ctx), exes in self._exe.items():
-            for kind in ("layer", "head"):
+            for kind in (*dict.fromkeys(self.kinds), "head"):
                 found = {}
                 exe = exes[kind][TOP_K] if kind == "head" else exes[kind]
                 for name, body, op in _INSTRUCTION.findall(exe.as_text()):
@@ -318,20 +417,42 @@ class LatentServing:
                         # back without the scope it was traced under; the
                         # routed experts are the only grouped matmul here
                         found[name] = "moe_experts"
-                out[f"jit_seq_{kind}_b{batch}_t{block}_c{ctx}"] = found
+                out["jit_" + self.program(kind, batch, block, ctx)] = found
         return out
 
     # -- the session table ---------------------------------------------------------
+    def _evict(self, busy: set) -> None:
+        """Frees the least recently used session's pages and slot."""
+        victim = next((k for k in self._sessions if k not in busy), None)
+        if victim is None:
+            raise RuntimeError(
+                "the session cache is too small for this batch "
+                f"({self.capacity_tokens} tokens, {self.n_slots} state "
+                "slots)")
+        self._drop(self._sessions.pop(victim), self._free, self._free_slots)
+        _EVICTIONS.inc()
+        if self.n_slots:
+            _STATE_EVICTIONS.inc()
+
+    @staticmethod
+    def _drop(sess: _Session, pages: list, slots: list) -> None:
+        """A session leaves the table: its pages and its slot go to the
+        free lists, or to those a dispatch under way frees when it ends."""
+        pages.extend(sess.pages)
+        if sess.slot:
+            slots.append(sess.slot)
+
     def _take_pages(self, n: int, busy: set) -> list:
         while len(self._free) < n:
-            victim = next((k for k in self._sessions if k not in busy), None)
-            if victim is None:
-                raise RuntimeError(
-                    "the latent cache is too small for this batch "
-                    f"({self.capacity_tokens} tokens)")
-            self._free.extend(self._sessions.pop(victim).pages)
-            _EVICTIONS.inc()
+            self._evict(busy)
         return [self._free.pop() for _ in range(n)]
+
+    def _take_slot(self, busy: set) -> int:
+        if not self.n_slots:
+            return 0
+        while not self._free_slots:
+            self._evict(busy)
+        return self._free_slots.pop()
 
     def _tokens(self, key: Optional[str], given: tuple, encode) -> np.ndarray:
         """The session as int32 tokens. A turn sends again a list the table
@@ -347,30 +468,47 @@ class LatentServing:
         return np.asarray(encode(given), np.int32)
 
     def _match(self, row: int, key: Optional[str], tokens: np.ndarray,
-               given: Optional[tuple], release: list) -> _Block:
+               given: Optional[tuple], release: tuple) -> _Block:
+        """``release``: the (pages, slots) to free after the dispatch."""
         sess = self._sessions.get(key) if key is not None else None
-        if sess is None:
+        new = sess is None
+        if new:
             sess, reuse = _Session(tokens[:0], []), 0
         else:
             n = min(len(sess.tokens), len(tokens))
             differ = np.flatnonzero(sess.tokens[:n] != tokens[:n])
             reuse = int(differ[0]) if len(differ) else n
-        reuse = min(reuse, len(tokens) - 1)
+        if not self.n_slots:
+            reuse = min(reuse, len(tokens) - 1)
+        elif reuse and not reuse == len(sess.tokens) < len(tokens):
+            # the state stands at len(sess.tokens) and nowhere else: only a
+            # longer list that begins with all of it continues from there
+            reuse = 0
+            _STATE_RESTARTS.inc()
         need = -(-len(tokens) // self.page) - len(sess.pages)
-        if need > 0:
-            sess.pages = sess.pages + self._take_pages(need, self._cutting)
-        elif need < 0:
-            release.extend(sess.pages[need:])
+        try:
+            if need > 0:
+                sess.pages = sess.pages + self._take_pages(
+                    need, self._cutting)
+            if new:
+                sess.slot = self._take_slot(self._cutting)
+        except RuntimeError:
+            if new:   # the table does not know it yet: ``extend`` cannot
+                self._drop(sess, *release)
+            raise
+        if need < 0:
+            release[0].extend(sess.pages[need:])
             sess.pages = sess.pages[:need]
         sess.tokens, sess.given = tokens, given
+        block = _Block(row, tokens, reuse, list(sess.pages), sess.slot)
         if key is not None:
             self._sessions[key] = sess
             self._sessions.move_to_end(key)   # most recently used
         else:
-            release.extend(sess.pages)        # nobody can come back to it
+            self._drop(sess, *release)        # nobody can come back to it
         _TOKENS_REUSED.inc(reuse)
         _TOKENS_COMPUTED.inc(len(tokens) - reuse)
-        return _Block(row, tokens, reuse, list(sess.pages))
+        return block
 
     # -- the entry point -------------------------------------------------------------
     def extend(self, requests: Sequence[tuple], encode=None,
@@ -391,10 +529,10 @@ class LatentServing:
             while busy & self._cutting and self._lock.offer():
                 pass
             self._cutting |= busy
-            release: list = []
+            release: tuple = ([], [])
             try:
                 with span("seq.batch.match", sessions=len(requests)) as sp:
-                    blocks = []
+                    blocks, slots_before = [], len(self._free_slots)
                     for row, (key, session) in enumerate(requests):
                         if encode is None:
                             given, tokens = None, np.asarray(session, np.int32)
@@ -408,6 +546,9 @@ class LatentServing:
                     sp.set_attr("hits", hits)
                     sp.set_attr("misses", len(blocks) - hits)
                     sp.set_attr("reused", sum(b.offset for b in blocks))
+                    if self.n_slots:
+                        sp.set_attr("slots_taken", max(
+                            slots_before - len(self._free_slots), 0))
                 scores = np.full((len(requests), k), -np.inf, np.float32)
                 items = np.zeros((len(requests), k), np.int32)
                 for group, bucket, last in self._plan(
@@ -422,13 +563,17 @@ class LatentServing:
             except BaseException:
                 # the table says more of these sessions than the cache holds
                 for sess in map(self._sessions.pop, busy & set(self._sessions)):
-                    release.extend(sess.pages)
+                    self._drop(sess, *release)
                 raise
             finally:
                 self._cutting -= busy
-                self._free.extend(release)
+                self._free.extend(release[0])
+                self._free_slots.extend(release[1])
                 used = sum(len(s.pages) for s in self._sessions.values())
                 _CACHE_TOKENS.labels(state="used").set(used * self.page)
+                if self.n_slots:
+                    _STATE_SLOTS.labels(state="used").set(
+                        self.n_slots - len(self._free_slots))
         return scores, items
 
     def _plan(self, keys, blocks):
@@ -458,7 +603,8 @@ class LatentServing:
                 for start in range(b.offset, len(b.tokens), longest):
                     end = min(start + longest, len(b.tokens))
                     block = _bucket(self.blocks, end - start)
-                    piece = _Block(b.row, b.tokens[:end], start, b.pages)
+                    piece = _Block(b.row, b.tokens[:end], start, b.pages,
+                                   b.slot)
                     if block == short:   # a cut block's tail, as a turn
                         bucket = self._short_bucket(1, end)
                     else:
@@ -496,18 +642,27 @@ class LatentServing:
             pages = np.zeros((batch, ctx // self.page), np.int32)
             offsets = np.zeros((batch,), np.int32)
             counts = np.zeros((batch,), np.int32)
+            slots = np.zeros((batch,), np.int32)
             for i, b in enumerate(group):
                 new = b.tokens[b.offset:]
                 tokens[i, :len(new)] = new
-                own = b.pages[:pages.shape[1]]   # a piece's context ends with it
-                pages[i, :len(own)] = own
-                offsets[i], counts[i] = b.offset, len(new)
+                held = b.pages[:pages.shape[1]]  # a piece's context ends with it
+                pages[i, :len(held)] = held
+                offsets[i], counts[i], slots[i] = b.offset, len(new), b.slot
             small = (pages, offsets, counts)
+            if self.cfg.layer_pattern:
+                # (one transfer for the whole stack's launches, not one a
+                # layer: a pattern is many thin layers)
+                small = jax.device_put(small, self.device)
+                slots = jax.device_put(slots, self.device)
+            own = {kind: (slots if kind in CONTEXT_FREE else small[0],
+                          *small[1:]) for kind in exe}
             h, self.tok_cache = exe["embed"](
                 self.params["item_emb"], self.tok_cache, tokens, *small)
-            for i, lw in enumerate(self.params["layers"]):
-                h, self.cache[i], self.counters[i] = exe["layer"](
-                    lw, self.cache[i], self.counters[i], h, *small)
+            for i, (kind, lw) in enumerate(
+                    zip(self.kinds, self.params["layers"])):
+                h, self.cache[i], self.counters[i] = exe[kind](
+                    lw, self.cache[i], self.counters[i], h, *own[kind])
             out = jax.device_get(self._head(batch, block, ctx, k)(
                 self.params["norm_f"], latent_moe.head_matrix(self.params),
                 self.tok_cache, h, *small)) if head else None
@@ -518,6 +673,10 @@ class LatentServing:
             else:
                 _CONTEXT_HELD.inc(sum(len(b.tokens) for b in group))
                 _CONTEXT_READ.inc(batch * ctx)
+            if self.n_slots:
+                _STATE_TOKENS.labels(form=form).inc(n_new)
+                if block == self.blocks[0]:
+                    _STATE_STEP_SESSIONS.inc(len(group))
             self.block.count_dispatch(self.cfg, [
                 (b.offset, len(b.tokens) - b.offset) for b in group])
         return out
@@ -527,10 +686,11 @@ class LatentServing:
         """The device's per-layer expert counters, read when ``/metrics`` is
         read: the counters families advance by what was added since."""
         with self._lock:
-            now = np.stack(jax.device_get(self.counters)).astype(np.int64)
+            now = np.stack(jax.device_get(
+                [self.counters[i] for i in self.moe_layers])).astype(np.int64)
         delta, self._published = now - self._published, now
         held = latent_moe.experts_held(self.cfg)
-        for layer, row in enumerate(delta):
+        for layer, row in zip(self.moe_layers, delta):
             for j in np.flatnonzero(row[:held]):
                 _EXPERT_TOKENS.labels(
                     layer=str(layer),
@@ -546,6 +706,9 @@ class LatentServing:
             "cache_page": self.page,
             "cache_bytes_per_token": self.bytes_per_token,
             "cache_row_widths": dict(self.layout),
+            **({"state_slots": self.n_slots,
+                "state_bytes_per_session": self.state_bytes_per_session,
+                "layer_pattern": cfg.layer_pattern} if self.n_slots else {}),
             "sessions": len(self._sessions),
             "buckets": [f"{self.label(*b)}:{self.form(b[1])}"
                         for b in self.ladder()],
@@ -557,6 +720,21 @@ class LatentServing:
             "weight_dtype": cfg.weight_dtype,
         }
 
+    def session_state(self, key: str, layer: int) -> Optional[tuple]:
+        """What an ``"S"`` layer keeps for a session the table holds: ``(the
+        tokens its state stands at, {name: its slot's row})``; ``None`` for
+        a session that is not held or whose block is still being cut. Waits
+        for the dispatch under way. (For tests and for a comparison of the
+        served state with a reference's: nothing on the serve path reads
+        it.)"""
+        with self._lock:
+            sess = self._sessions.get(key)
+            if sess is None or not sess.slot or key in self._cutting:
+                return None
+            return sess.tokens.copy(), {
+                name: np.asarray(rows[sess.slot])
+                for name, rows in self.cache[layer].items()}
+
     def close(self) -> None:
         """Gives the device back: the cache, the counters, the executables
         and this object's hold on the weights."""
@@ -564,4 +742,5 @@ class LatentServing:
         with self._lock:
             self.cache = self.counters = self.tok_cache = self.params = None
             self._exe.clear()
+            self._shared.clear()
             self._sessions.clear()

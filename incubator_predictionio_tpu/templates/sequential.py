@@ -12,10 +12,13 @@ explicit session, or ``{"user": U, "num": N}`` reads the user's recent
 view/buy events live from the event store (LEventStore, like the ecommerce
 template's serving-time reads).
 
-With the latent-attention block (``attentionKind: "mla"``) or the
-sparse-index block (``"gqa_sparse"``) the deployed model keeps a
-device-resident cache of per-token rows per session
-(serving/latent_cache.py). ``recent_items`` is still the whole session as the
+With the latent-attention block (``attentionKind: "mla"``), the
+sparse-index block (``"gqa_sparse"``) or a layer pattern (``"gqa"`` with
+``layerPattern``: state-space, attention and expert layers in a given order)
+the deployed model keeps a device-resident cache per session
+(serving/latent_cache.py): per-token rows, and for state-space layers a
+recurrent state, which stands at one position: it is reused only by a list
+that CONTINUES the cached one. ``recent_items`` is still the whole session as the
 application knows it; ``user``, when given WITH it, is the **cache key**: the
 server reuses the longest prefix of the incoming list that equals what it has
 cached under that key, token for token, and computes only the rest. The
@@ -266,8 +269,18 @@ class TransformerAlgorithmParams(Params):
     # latent-attention / routed-expert block) or "gqa_sparse" (grouped-query
     # heads behind a learned sparse index, the same routed experts), whose
     # sizes follow under the published configs' names (d_model / n_heads /
-    # n_layers above)
+    # n_layers above); "gqa" with a layer_pattern: one letter a layer, "S" a
+    # state-space mixer (ssm_*, conv_kernel), "A" dense grouped-query
+    # attention (num_key_value_heads, head_dim), "E" the routed experts
     attention_kind: str = "mha"
+    layer_pattern: str = ""
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk_size: int = 128
+    state_dtype: str = "float32"  # what a session's state is kept in
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -279,6 +292,8 @@ class TransformerAlgorithmParams(Params):
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     n_shared_experts: int = 0
+    shared_intermediate_size: int = 0   # 0: moe_intermediate_size each
+    expert_activation: str = "gated_silu"   # or "relu2" (two matrices)
     routed_scaling_factor: float = 1.0
     router_scoring: str = "sigmoid"   # or "softmax" (no selection bias)
     num_key_value_heads: int = 0      # "gqa_sparse": grouped-query heads ...
@@ -294,6 +309,8 @@ class TransformerAlgorithmParams(Params):
     weight_dtype: str = "float32"
     cache_page: int = 128         # latent cache: tokens a page, and its size
     cache_tokens: int = 0         # in tokens (0 = 16 sessions of max_len)
+    state_slots: int = 0          # sessions whose state is kept (0 = what
+    #                               cache_tokens / max_len sessions need)
 
 
 class TransformerAlgorithm(PAlgorithm):
@@ -321,9 +338,19 @@ class TransformerAlgorithm(PAlgorithm):
                 rope_theta=p.rope_theta, index_n_heads=p.indexer_num_heads,
                 index_head_dim=p.indexer_head_dim, index_topk=p.index_topk,
                 index_kv_tile=p.index_kv_tile)
+        elif p.attention_kind == "gqa":
+            latent = dict(
+                n_kv_heads=p.num_key_value_heads, head_dim=p.head_dim,
+                layer_pattern=p.layer_pattern, ssm_heads=p.ssm_num_heads,
+                ssm_head_dim=p.ssm_head_dim, ssm_state=p.ssm_state_size,
+                ssm_groups=p.ssm_groups, conv_kernel=p.conv_kernel,
+                ssm_chunk=p.ssm_chunk_size, state_dtype=p.state_dtype,
+                state_slots=p.state_slots)
         if p.attention_kind != "mha":
             latent.update(
                 rms_norm_eps=p.rms_norm_eps,
+                shared_intermediate_size=p.shared_intermediate_size,
+                expert_activation=p.expert_activation,
                 router_scoring=p.router_scoring,
                 n_routed_experts=p.n_routed_experts,
                 experts_per_token=p.num_experts_per_tok,

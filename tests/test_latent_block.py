@@ -623,7 +623,7 @@ def test_train_persist_deploy_query_through_the_query_server(
 
     from incubator_predictionio_tpu.core.workflow import run_train
     from incubator_predictionio_tpu.data import Event
-    from incubator_predictionio_tpu.data.storage import App, Storage
+    from incubator_predictionio_tpu.data.storage import App, Storage, registry
     from incubator_predictionio_tpu.data.storage.base import EngineInstance
     from incubator_predictionio_tpu.server.query_server import (
         QueryServer,
@@ -650,6 +650,9 @@ def test_train_persist_deploy_query_through_the_query_server(
     for k, v in env.items():
         monkeypatch.setenv(k, v)
     storage = Storage(env)
+    # (the DataSource reads through the process's Storage: this one,
+    # whatever an earlier test file of this worker left there)
+    monkeypatch.setattr(registry, "_storage_singleton", storage)
     app_id = storage.get_meta_data_apps().insert(App(0, "latent-seq"))
     events = storage.get_events()
     events.init(app_id)

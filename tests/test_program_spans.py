@@ -632,15 +632,22 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
                 "beta_fast": 32, "beta_slow": 1, "factor": 128,
                 "original_max_position_embeddings": 8192,
                 "rope_theta": 10000}.items())), **shared)
-    else:
+    elif kind == "gqa_sparse":
         cfg = TransformerConfig(
             attention_kind="gqa_sparse", n_kv_heads=1, head_dim=16,
             index_n_heads=2, index_head_dim=16, index_topk=8,
             index_kv_tile=8, router_scoring="softmax", **shared)
+    else:   # a letter of a layer pattern
+        cfg = TransformerConfig(**{
+            **shared, "n_layers": 3, "attention_kind": "gqa",
+            "layer_pattern": "SAE", "n_kv_heads": 1, "head_dim": 16,
+            "ssm_heads": 4, "ssm_head_dim": 8, "ssm_state": 8,
+            "ssm_groups": 2, "ssm_chunk": 8, "n_shared_experts": 1,
+            "expert_activation": "relu2"})
     serving = LatentServing(
         latent_moe.init_params(jax.random.key(0), cfg), cfg)
     try:
-        return serving._lower(*bucket)["layer"]
+        return serving._lower(*bucket)[kind if kind in "SAE" else "layer"]
     finally:
         serving.close()
 
@@ -657,6 +664,14 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
      "jit_seq_layer_b1_t32_c32",
      ("gqa_proj", "idx_score", "idx_select", "sparse_attn", "moe_router",
       "moe_experts")),
+    (lambda: _seq_layer_lowered("S", (4, 16, 64)), "jit_seq_ssm_b4_t16",
+     ("ssm_proj", "ssm_conv", "ssm_scan")),
+    (lambda: _seq_layer_lowered("S", (1, 64, 64)), "jit_seq_ssm_b1_t64",
+     ("ssm_proj", "ssm_conv", "ssm_scan")),
+    (lambda: _seq_layer_lowered("A", (4, 16, 32)), "jit_seq_gqa_b4_t16_c32",
+     ("gqa_proj", "gqa_attn")),
+    (lambda: _seq_layer_lowered("E", (4, 16, 32)), "jit_seq_moe_b4_t16",
+     ("moe_router", "moe_experts", "moe_shared")),
     (_train_lowered, "jit__train_epochs",
      ("gather", "loss_grad", "scatter", "adam_user", "adam_item")),
     (_topk_lowered, "jit__topk_quantized", ("score", "topk")),
@@ -668,12 +683,14 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
     (_rerank_lowered, "jit_two_stage_rerank",
      ("probe_select", "rerank", "gather", "topk")),
 ], ids=["seq_layer_latent", "seq_layer_sparse_turn", "seq_layer_sparse_piece",
+        "seq_ssm_step", "seq_ssm_scan", "seq_gqa", "seq_moe",
         "train_epochs", "topk_quantized", "score_centroids", "init",
         "order_batches", "quantize_user_rows", "two_stage_rerank"])
 def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
     """``benchmarks/layer_metrics/*_roofline.py`` find these executables by
     name in a device trace's ``XLA Modules`` line (the sequence template's
-    by the ``jit_seq_layer_`` names and the scopes inside them, through
+    by the ``jit_seq_layer_`` names, a layer pattern's ``jit_seq_ssm_`` /
+    ``jit_seq_gqa_`` / ``jit_seq_moe_``, and the scopes inside them, through
     ``benchmarks/seq_trace.py``): a rename has to fail here, not read as a
     missing roofline on the chip."""
     lowered = lower()

@@ -16,6 +16,7 @@ agree to a few 1e-6, ``TOL`` = 1e-4 as ISSUE 30 asks.
 
 from __future__ import annotations
 
+import hashlib
 import re
 import threading
 
@@ -436,3 +437,23 @@ def test_scopes_in_the_programs(served):
     loops = re.findall(r"^\s*%?([\w.\-]+) = [^\n]* while\(", text, re.M)
     assert loops and not set(loops) & set(
         scopes["jit_seq_layer_b1_t32_c96"])
+
+
+@pytest.mark.parametrize("bucket, digest", [
+    ((1, 16, 32),
+     "cc75a8353c516859cf3fcb2f69da17f92f35ad8bcc4d0bb66eb114e8ad748e8a"),
+    ((1, 32, 96),
+     "4832938d8b210ce28d9a09094e10c5007840b124c56f023e99597decfaea5385"),
+], ids=["select", "chunk"])
+def test_the_sparse_index_layer_lowers_to_the_parents_program(
+        served, bucket, digest):
+    """ISSUE 34 gave the config a layer pattern, the cache a per-session
+    state and the experts a second activation, and had to leave this block's
+    programs alone: the layer's lowered text (no debug info) is commit
+    38e73b2's, taken before that change was made."""
+    serving, _, _ = served
+    text = serving._lower(*bucket)["layer"].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest, (
+        f"the digest was taken under jax 0.9.0 and this is jax "
+        f"{jax.__version__}: after a JAX upgrade, or a deliberate change to "
+        f"the sparse-index block, pin the new digest")

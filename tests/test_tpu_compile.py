@@ -5,7 +5,10 @@ executables at the lifelong cell's (hidden 2048, 32 / 4 heads of 128,
 indexer 16 x 64 top-2048, 128 experts top-8 of width 768, 720,896 cache rows)
 compile for a described v5e, as do the latent block's at the Mistral cell's
 (hidden 4096, 32 heads over a 256 + 64 latent row, 32 of 128 experts top-4 of
-width 2048, 786,560 cache rows) in its smallest and its largest turn bucket.
+width 2048, 786,560 cache rows) in its smallest and its largest turn bucket,
+and the state-space pattern's three layer kinds at the visitor cell's (hidden
+2688, 64 state-space heads of 64 x 128, 32 / 2 heads of 128, 64 of 128 relu2
+experts top-6 of width 1856, 257 state slots, 262,272 cache rows).
 What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
@@ -256,3 +259,114 @@ def test_a_lone_turns_bucket_holds_a_tenth_of_the_widest_ones_attention_arrays(
     # the cache itself stays where it is in both
     assert all(c.memory_analysis().temp_size_in_bytes < 16e6
                for c in compiled.values())
+
+
+# -- the state-space pattern's layer kinds at the visitor cell's widths ------------------
+
+def _pattern_cfg():
+    """The Nemotron cell's stack, from the cell's own configuration file, as
+    ``benchmarks/engines/seeded_ssm.algorithm_params`` binds it."""
+    from incubator_predictionio_tpu.models.transformer import TransformerConfig
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmarks", "configs", "seq-nemotron3-nano-ep2.json")
+    with open(path) as f:
+        c = json.load(f)
+    return TransformerConfig(
+        vocab_size=c["vocab_size"], max_len=c["serve"]["max_len"],
+        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
+        n_layers=c["num_hidden_layers"], attention_kind="gqa",
+        layer_pattern=c["hybrid_override_pattern"].translate(
+            str.maketrans("M*", "SA")),
+        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        ssm_heads=c["mamba_num_heads"], ssm_head_dim=c["mamba_head_dim"],
+        ssm_state=c["ssm_state_size"], ssm_groups=c["n_groups"],
+        conv_kernel=c["conv_kernel"], ssm_chunk=c["chunk_size"],
+        rms_norm_eps=c["layer_norm_epsilon"],
+        n_routed_experts=c["n_routed_experts"],
+        experts_per_token=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        shared_intermediate_size=c["moe_shared_expert_intermediate_size"],
+        expert_activation=c["mlp_hidden_act"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        experts_held=c["experts_held"], tie_head=False,
+        weight_dtype="bfloat16", cache_page=c["serve"]["cache_page"],
+        cache_tokens=c["serve"]["cache_tokens"],
+        state_slots=c["serve"]["state_slots"])
+
+
+PATTERN_BUCKETS = [(1, 16, 512), (16, 16, 2048), (1, 2048, 2048)]
+
+
+@pytest.fixture(scope="module")
+def pattern_layers(one_chip):
+    """Each layer kind's serving step in a lone turn's bucket, the widest
+    batch of turns and the longest block, compiled with the cell's own state
+    and cache as the donated arguments."""
+    from incubator_predictionio_tpu.models import latent_moe, state_space
+
+    cfg = _pattern_cfg()
+    assert cfg.layer_pattern == "SESESAESESESAE"
+    ladder = state_space.serve_shapes(cfg)
+    assert ladder.path == "device-state-kv-cache"
+    assert ladder.blocks == (16, 128, 512, 1024, 1536, 2048)
+    assert ladder.contexts(1) == ladder.contexts(4) == (512, 1024, 2048)
+    assert ladder.contexts(16) == (2048,)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    kept = {
+        "S": {name: s((cfg.state_slots + 1, n), dt)
+              for name, (n, dt) in state_space.state_layout(cfg).items()},
+        "A": {"kv": s((rows, 512), jnp.bfloat16)}, "E": {}}
+    assert kept["S"]["state"].shape == (257, 64 * 64 * 128)
+    assert kept["S"]["conv"].shape == (257, 3 * 6144)
+    counters = {"S": (), "A": (), "E": s((66,), jnp.int32)}
+    out = {}
+    for batch, block, ctx in PATTERN_BUCKETS:
+        for kind in "SAE":
+            lw = {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
+                  for k, (shape, f32) in latent_moe.layer_shapes(
+                      cfg, kind).items()}
+            step = latent_moe.step_of(kind, cfg)
+            own = s((batch, ctx // cfg.cache_page) if kind == "A"
+                    else (batch,), jnp.int32)
+            out[kind, batch, block] = jax.jit(
+                lambda lw, cache, counters, h, own, offsets, counts,
+                step=step: step(lw, cache, counters, h, own, offsets, counts,
+                                cfg=cfg, form=""),
+                donate_argnums=(1, 2, 3)).lower(
+                lw, kept[kind], counters[kind],
+                s((batch, block, cfg.d_model), jnp.float32), own,
+                s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
+    return out
+
+
+@pytest.mark.parametrize("batch, block, ctx", PATTERN_BUCKETS)
+@pytest.mark.parametrize("kind", ["S", "A", "E"])
+def test_pattern_layer_compiles_for_v5e(pattern_layers, kind, batch, block,
+                                        ctx):
+    compiled = pattern_layers[kind, batch, block]
+    mem = compiled.memory_analysis()
+    kept = {"S": 257 * (64 * 64 * 128 * 4 + 3 * 6144 * 2),
+            "A": 262_272 * 512 * 2, "E": 0}[kind]
+    assert mem.alias_size_in_bytes >= kept        # donated, not copied
+    # beside 9.3 GB of weights, 3.29 GB of states and 0.54 GB of rows
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    scopes = {"S": ("ssm_proj", "ssm_conv", "ssm_scan"),
+              "A": ("gqa_proj", "gqa_attn"),
+              "E": ("moe_router", "moe_experts", "moe_shared")}[kind]
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+    if kind == "E":
+        # a lone turn keeps the grouped matmul; wider blocks run every held
+        # expert on every token
+        assert ("ragged-dot" in text) == (batch * block < 64)
+        # the routed experts' first matrices are stored lane-aligned: no
+        # copy of all 64 of them in front of the grouped matmul (at
+        # [64, 2688, 1856] the compiler made one of 638 MB at every call)
+        assert not re.search(r"bf16\[64,2688,\d+\][^\n]* copy\(", text)
