@@ -12,10 +12,13 @@ models/reference/mla_moe.py, which this module is held to).
   sum back out of it (short blocks against a long context);
 - sigmoid-scored top-k routing over ``n_routed_experts`` with a
   selection-only bias and weights normalised over all k picks; gated-SiLU
-  experts as one grouped matmul (``jax.lax.ragged_dot``) over the
-  ``experts_held`` experts this chip holds, token-pick pairs sorted by
-  expert, no capacity and no dropped token; picks that fall on an expert
-  held elsewhere add nothing here. One shared expert beside them.
+  experts as one grouped matmul a matrix over the ``experts_held`` experts
+  this chip holds, token-pick pairs sorted by expert, no capacity and no
+  dropped token; picks that fall on an expert held elsewhere add nothing
+  here. The grouped matmul is ``jax.lax.ragged_dot``, or, where the experts'
+  stored widths admit none of its wide tiles and the backend runs the
+  package's kernels, ops/grouped_matmul.py (``expert_form``: the code's own
+  choice, the same sum either way). One shared expert beside them.
 
 Weights live in ``weight_dtype`` (bfloat16 when served, float32 when ``fit``
 trains a small instance); products accumulate in float32; norms, router and
@@ -54,6 +57,7 @@ from incubator_predictionio_tpu.models.reference.mla_moe import (
     softmax_scale,
     yarn_inv_freq,
 )
+from incubator_predictionio_tpu.parallel.mesh import kernel_backend
 
 F32 = jnp.float32
 NEG = -1e30          # finite: a row with no visible key stays finite
@@ -218,14 +222,17 @@ def expert_shapes(cfg) -> dict:
     """The expert half's arrays: its norm, the router (its selection bias
     only where the scoring is sigmoid), the routed experts held here and the
     shared ones where there are any; gated experts have a third matrix. The
-    routed experts' first matrices are STORED with their width padded to
-    whole 128-lane tiles, the padding zeros (``pad_stored``): the TPU
-    compiler lays a ``[e, d, f]`` array whose ``f`` is no multiple of 128 out
+    routed experts' matrices are STORED with their width padded to whole
+    128-lane tiles, the padding zeros (``pad_stored``): the TPU compiler
+    lays a ``[e, d, f]`` array whose ``f`` is no multiple of 128 out
     column-major, and the grouped matmul then copies all of it at every call
-    (638 MB a layer at 64 x 2688 x 1856; PERF.md PR 34). The width of the
-    mathematics is ``moe_intermediate_size``: ``we2`` has that many rows. (A
-    width under one tile is stored as it is: the toy sizes of the CPU tests,
-    whose pinned programs stay what they were.)"""
+    (638 MB a layer at 64 x 2688 x 1856; PERF.md PR 34); ``we2`` has the
+    same number of zero rows, so the hidden activation is never cut to a
+    width that is no whole number of tiles either (the zero columns of
+    ``we1`` make zero activations, which meet zero rows). The width of the
+    mathematics is ``moe_intermediate_size``. (A width under one tile is
+    stored as it is: the toy sizes of the CPU tests, whose pinned programs
+    stay what they were.)"""
     d = cfg.d_model
     f, e = cfg.moe_intermediate_size, experts_held(cfg)
     fs = cfg.shared_intermediate_size or f * cfg.n_shared_experts
@@ -233,10 +240,11 @@ def expert_shapes(cfg) -> dict:
     out = {"norm2": ((d,), True), "w_r": ((d, cfg.n_routed_experts), True)}
     if cfg.router_scoring == "sigmoid":
         out["b_r"] = ((cfg.n_routed_experts,), True)
-    out["we1"] = ((e, d, f if f < LANES else -(-f // LANES) * LANES), False)
+    stored = f if f < LANES else -(-f // LANES) * LANES
+    out["we1"] = ((e, d, stored), False)
     if gated:
         out["we3"] = out["we1"]
-    out["we2"] = ((e, f, d), False)
+    out["we2"] = ((e, stored, d), False)
     if cfg.n_shared_experts:
         out["ws1"] = ((d, fs), False)
         if gated:
@@ -287,9 +295,11 @@ def init_params(key, cfg) -> dict:
             else:
                 lw[name] = normal(shape, shape[-2] ** -0.5,
                                   F32 if f32 else wdt)
-            if name in ("we1", "we3"):     # the stored padding is zeros
-                lw[name] = pad_stored(
-                    lw[name][..., :cfg.moe_intermediate_size], shape)
+            f = cfg.moe_intermediate_size  # the stored padding is zeros
+            if name in ("we1", "we3"):
+                lw[name] = pad_stored(lw[name][..., :f], shape)
+            elif name == "we2":
+                lw[name] = pad_stored(lw[name][:, :f], shape)
         layers.append(lw)
     params = {"item_emb": normal((cfg.vocab_size, cfg.d_model), 0.02),
               "norm_f": jnp.ones((cfg.d_model,), F32), "layers": layers}
@@ -445,15 +455,12 @@ def moe_router(x, lw, cfg):
 
 def _expert_hidden(x, lw, names, dot):
     """``silu(w1 x) * w3 x``, or ``relu(w1 x)^2`` where the layer has no
-    third matrix (``expert_activation="relu2"``), at the width of the
-    mathematics (``w2``'s rows: ``w1`` may be stored wider, with zeros)."""
-    w1, w3, w2 = (lw.get(n) for n in names)
+    third matrix (``expert_activation="relu2"``), at the stored width (the
+    columns past the width of the mathematics are zeros, and stay zeros)."""
+    w1, w3 = (lw.get(n) for n in names[:2])
     a = dot(x, w1)
-    a = jnp.square(jax.nn.relu(a)) if w3 is None \
+    return jnp.square(jax.nn.relu(a)) if w3 is None \
         else jax.nn.silu(a) * dot(x, w3)
-    if a.shape[-1] != w2.shape[-2]:
-        a = a[..., :w2.shape[-2]]
-    return a
 
 
 def _expert(x, lw, names, dot):
@@ -462,102 +469,71 @@ def _expert(x, lw, names, dot):
     return dot(_expert_hidden(x, lw, names, dot).astype(w2.dtype), w2)
 
 
-#: the dense form's float32 activations ``[experts, N, f]`` a slice of experts
-DENSE_SLICE_BYTES = 256 << 20
-#: the lanes of a tile: the grouped matmul walks an expert's matrices in
+#: the lanes of a tile: XLA's grouped matmul walks an expert's matrices in
 #: tiles this wide unless both their widths are whole multiples of twice it
 LANES = 128
-#: token slots from which the dense form is the faster one over matrices the
-#: grouped matmul walks 128 lanes at a time (``dense_experts``)
-DENSE_FROM_SLOTS = 64
 
 
-def dense_experts(n: int, stored) -> bool:
-    """Whether a block of ``n`` token slots runs every held expert on every
-    token: the code's own choice, from the block's static size and the
-    stored ``[held, d, f]`` of the experts' first matrices. The grouped
-    matmul reads only the experts a block touches and does only the picks'
-    operations, so it is the form wherever its kernel's tiles fit the widths
-    (4096 x 2048 and 2048 x 768, the other two served blocks: at 2.3 x and
-    4 x the floor of the bytes it reads). At 2688 x 1920, which admit no
-    tile wider than 128 lanes, it runs 7-13 x over that floor, and the dense
-    form's 21 x the operations at the matrix unit's own tiling cost what
-    reading all 64 experts costs (1.3 GB: 1.86 ms a layer) up to 128 slots.
-    One layer alone on the v5e, grouped / dense ms (PERF.md, PR 34): 16
-    slots 1.28 / 1.86 with 2 real tokens and 5.61 / 1.86 with 16; 64 slots
-    4.00 / 1.88 with 8 real and 11.78 / 1.88 with 64; 128 slots 13.3 / 2.0;
-    512 slots 15.9 / 4.0. So from 64 slots on the dense form wins whatever
-    the block holds, and at 16 the grouped form wins the lone turn of a few
-    items (what a 16-slot block nearly always is) and loses a full one: its
-    time follows the experts touched, which a static size cannot see. A
-    width under one tile (the toy sizes of the CPU tests) is a single
-    partial tile either way and keeps the grouped form."""
+def _narrow(stored) -> bool:
+    """Whether XLA's grouped matmul walks the stored ``[held, d, f]`` of the
+    experts' first matrices one lane tile at a time: a width that is no
+    multiple of two lane tiles (and at least one: under that, the toy sizes
+    of the CPU tests, a matrix is a single partial tile either way)."""
     _, d, f = stored
-    narrow = d % (2 * LANES) != 0 or f % (2 * LANES) != 0
-    return n >= DENSE_FROM_SLOTS and f >= LANES and narrow
+    return f >= LANES and (d % (2 * LANES) != 0 or f % (2 * LANES) != 0)
 
 
-def _experts_dense(x, gate, lw):
-    """Every held expert on every token, as batched matmuls over slices of
-    the experts, each token's output weighted by ``gate [N, held]`` (zero
-    where the token did not pick the expert): the same sum as the grouped
-    form, with ``held / picks`` times its operations at the matrix unit's own
-    tiling."""
-    wdt = lw["we1"].dtype
-    (held, d, fp), n = lw["we1"].shape, x.shape[0]
-    xs = x.astype(wdt)
-    step = max(1, min(held, DENSE_SLICE_BYTES // (4 * n * fp)))
-    y = jnp.zeros((n, d), F32)
-    for lo in range(0, held, step):
-        part = {name: m[lo:lo + step] for name, m in lw.items()
-                if name in ("we1", "we3", "we2")}
-        a = _expert_hidden(xs, part, ("we1", "we3", "we2"), lambda v, m: _einsum(
-            "nd,edf->enf", v, m, wdt)) * gate.T[lo:lo + step, :, None]
-        y = y + _einsum("enf,efd->nd", a, part["we2"], wdt)
-    return y
+def expert_form(stored) -> str:
+    """Which grouped matmul the routed experts run, from what the code can
+    see: ``"kernel"`` (ops/grouped_matmul.py, whose tiles are divisors of
+    the widths themselves) where the widths are ``_narrow`` and the backend
+    runs the package's kernels, ``"ragged"`` (``jax.lax.ragged_dot``)
+    everywhere else."""
+    return "kernel" if _narrow(stored) and kernel_backend() else "ragged"
 
 
 def moe_experts(x, idx, w, token_valid, lw, cfg):
-    """The routed experts held here. Grouped form: token-pick pairs sorted
-    by expert, one grouped matmul per expert matrix, unsorted, weighted,
-    summed per token; pairs of padding tokens or of experts held elsewhere
-    sort behind the last group, where the grouped matmul does no work. Dense
-    form (where ``dense_experts`` says so): ``_experts_dense``. Returns
-    ``(y [N, d], counters [held + 2])``."""
+    """The routed experts held here: token-pick pairs sorted by expert, one
+    grouped matmul per expert matrix (``expert_form`` says which), unsorted,
+    weighted, summed per token; pairs of padding tokens or of experts held
+    elsewhere sort behind the last group, where the grouped matmul does no
+    work. Returns ``(y [N, d], counters [held + 2])``."""
     n, k = idx.shape
     held = experts_held(cfg)
     wdt = lw["we1"].dtype
     local = idx - cfg.expert_offset
     here = (local >= 0) & (local < held) & token_valid[:, None]
     key = jnp.where(here, local, held).reshape(n * k)
-    dense = dense_experts(n, lw["we1"].shape)
-    order = None if dense else jnp.argsort(key)   # stable
+    order = jnp.argsort(key)   # stable
     group_sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
-    if dense:
-        gate = jnp.zeros((n, held + 1), F32).at[
-            jnp.arange(n)[:, None], key.reshape(n, k)].add(
-                jnp.where(here, w, 0.0))[:, :held]
-        y = _experts_dense(x, gate, lw)
-    else:
-        xs = x.astype(wdt)[order // k]
+    xs = x.astype(wdt)[order // k]
+    form = expert_form(lw["we1"].shape)
 
-        def dot(a, m):
-            return jax.lax.ragged_dot(a, m, group_sizes,
-                                      preferred_element_type=F32,
-                                      precision=_precision(wdt))
+    def dot(a, m):
+        if form == "kernel":
+            # (imported here: a process whose experts keep ``ragged_dot``
+            # loads no Pallas and stays, module for module, what it was)
+            from incubator_predictionio_tpu.ops.grouped_matmul import (
+                grouped_matmul,
+            )
 
-        out = _expert(xs, lw, ("we1", "we3", "we2"), dot)
-        weight = jnp.where(here, w, 0.0).reshape(n * k)[order]
-        out = jnp.where((key[order] < held)[:, None], out, 0.0) \
-            * weight[:, None]
-        y = jnp.zeros((n * k, x.shape[-1]), F32).at[order].set(
-            out, unique_indices=True)
+            return grouped_matmul(a, m, group_sizes,
+                                  interpret=kernel_backend() == "interpret")
+        return jax.lax.ragged_dot(a, m, group_sizes,
+                                  preferred_element_type=F32,
+                                  precision=_precision(wdt))
+
+    out = _expert(xs, lw, ("we1", "we3", "we2"), dot)
+    weight = jnp.where(here, w, 0.0).reshape(n * k)[order]
+    out = jnp.where((key[order] < held)[:, None], out, 0.0) * weight[:, None]
+    y = jnp.zeros((n * k, x.shape[-1]), F32).at[order].set(
+        out, unique_indices=True)
     picks = token_valid.sum() * k
     counters = jnp.concatenate([
         group_sizes,
         jnp.stack([picks - group_sizes.sum(),
                    (group_sizes > 0).sum()]).astype(jnp.int32)])
-    return y if dense else y.reshape(n, k, -1).sum(1), counters
+    return y.reshape(n, k, -1).sum(1), counters
 
 
 def moe_shared(x, lw):

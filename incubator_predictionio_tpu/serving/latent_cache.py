@@ -103,6 +103,11 @@ _TOUCHED = REGISTRY.counter(
     "pio_moe_experts_touched_total",
     "Held experts that received at least one pick, summed over dispatches",
     ("layer",))
+_EXPERT_LAYERS = REGISTRY.counter(
+    "pio_moe_expert_layers_total",
+    "Expert layers run by dispatches, by the grouped matmul their programs "
+    "were compiled with (kernel: ops/grouped_matmul.py; ragged: XLA's "
+    "ragged_dot)", ("form",))
 _CONTEXT_HELD = REGISTRY.counter(
     "pio_seq_context_rows_held_total",
     "Tokens of the sessions of short-block dispatches, their blocks included")
@@ -239,6 +244,9 @@ class LatentServing:
 
         self.moe_layers = [i for i, k in enumerate(self.kinds)
                            if k in (latent_moe.LAYER, "E")]
+        # (the grouped matmul every bucket's expert layers are compiled with)
+        self.expert_form = latent_moe.expert_form(
+            params["layers"][self.moe_layers[0]]["we1"].shape)
         with jax.default_device(self.device):
             self.cache = [kept(kind) for kind in self.kinds]
             self.tok_cache = jnp.zeros((rows,), jnp.int32)
@@ -668,6 +676,8 @@ class LatentServing:
                 self.tok_cache, h, *small)) if head else None
         if count:
             _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
+            _EXPERT_LAYERS.labels(form=self.expert_form).inc(
+                len(self.moe_layers))
             if block != self.blocks[0]:
                 _PREFILL_CHUNKS.inc()
             else:

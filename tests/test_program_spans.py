@@ -700,6 +700,40 @@ def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
         assert re.search(rf'[/"]{scope}[/"]', text), scope
 
 
+def test_the_expert_kernel_lies_under_the_moe_experts_scope(monkeypatch):
+    """Where the routed experts run through the Pallas grouped matmul
+    (stored widths that are no multiples of 256 lanes, on a TPU), its two
+    calls are lowered under ``moe_experts``: ``LatentServing.
+    device_scopes()`` places a trace's operations by that path, and the
+    experts' roofline reads the scope. Lowered for the TPU from here; no
+    ``ragged_dot`` is left, and no activation over every held expert."""
+    from incubator_predictionio_tpu.models import latent_moe
+    from tests.fixtures.ssm_tiny import config
+
+    cfg = config(moe_intermediate_size=384, d_model=128)
+    lw = latent_moe.init_params(jax.random.key(0), cfg)["layers"][1]
+    assert lw["we1"].shape == (8, 128, 384)
+    monkeypatch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+
+    def seq_moe_b4_t16(lw, counters, h, counts):
+        return latent_moe.expert_step(lw, {}, counters, h, None, None, counts,
+                                      cfg=cfg, form="")
+
+    text = jax.jit(seq_moe_b4_t16).trace(
+        lw, jnp.zeros(10, jnp.int32), jnp.zeros((4, 16, 128)),
+        jnp.zeros(4, jnp.int32)).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    assert len(re.findall(r"custom_call @tpu_custom_call", text)) == 2
+    # (the kernel's entry is a jit of its own: the scope is on its call)
+    sites = re.findall(r"call @grouped_matmul\w*\(.*loc\((#loc\d+)\)", text)
+    where = [line for line in text.splitlines()
+             if any(line.startswith(site + " =") for site in sites)]
+    assert len(where) == 2 and all(
+        "/moe_experts/jit(grouped_matmul)" in line for line in where), where
+    assert "chlo.ragged_dot" not in text
+    assert "tensor<8x64x384xf32>" not in text     # [held, slots, f]
+
+
 def test_train_schedule_lowers_to_the_parents_program():
     """ISSUE 25 moved the per-batch sort in front of ``_train_epochs`` and
     left the schedule alone: its lowered text (no debug info: line numbers

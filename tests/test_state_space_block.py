@@ -187,9 +187,11 @@ def test_relu2_experts_have_two_matrices_stored_lane_aligned():
     cfg = config()
     lw = seeded_params(cfg)["layers"][1]
     assert "we3" not in lw and "ws3" not in lw
-    assert lw["we1"].shape == (8, 64, 256) and lw["we2"].shape == (8, 160, 64)
+    assert lw["we1"].shape == (8, 64, 256) and lw["we2"].shape == (8, 256, 64)
     assert not np.asarray(lw["we1"][..., 160:]).any()  # the padding is zeros
+    assert not np.asarray(lw["we2"][:, 160:]).any()
     assert np.asarray(lw["we1"][..., :160]).all()
+    assert np.asarray(lw["we2"][:, :160]).all()
     # (a width under one lane tile is stored as it is)
     assert lm.expert_shapes(config(moe_intermediate_size=32))["we1"][0] \
         == (8, 64, 32)
@@ -203,46 +205,6 @@ def test_relu2_experts_have_two_matrices_stored_lane_aligned():
     np.testing.assert_allclose(
         y + lm.moe_shared(x, lw), ref.experts(x, plain, ssm.published(cfg)),
         atol=TOL, rtol=0)
-
-
-@pytest.mark.parametrize("activation", ["relu2", "gated_silu"])
-def test_the_dense_expert_form_is_the_grouped_one(activation, monkeypatch):
-    """Every held expert on every token, gated, against sorted picks through
-    the grouped matmul: the same sum, the same counters, also for a share
-    (picks on experts held elsewhere) with padding tokens, and in slices."""
-    cfg = config(expert_activation=activation, experts_held=4, expert_offset=2)
-    lw = lm.init_params(jax.random.key(2), cfg)["layers"][1]
-    lw["b_r"] = 0.1 * jax.random.normal(jax.random.key(3), lw["b_r"].shape)
-    x = jax.random.normal(jax.random.key(7), (70, cfg.d_model))
-    valid = jnp.arange(70) < 61
-    idx, w = lm.moe_router(x, lw, cfg)
-    with monkeypatch.context() as m:
-        m.setattr(lm, "DENSE_FROM_SLOTS", 71)
-        grouped = lm.moe_experts(x, idx, w, valid, lw, cfg)
-    for slice_bytes in (lm.DENSE_SLICE_BYTES, 4 * 70 * 256):   # 4 | 1 a slice
-        monkeypatch.setattr(lm, "DENSE_SLICE_BYTES", slice_bytes)
-        dense = lm.moe_experts(x, idx, w, valid, lw, cfg)
-        np.testing.assert_allclose(dense[0], grouped[0], atol=TOL, rtol=0)
-        np.testing.assert_array_equal(dense[1], grouped[1])
-    assert np.abs(grouped[0][:61]).max() > 0.1
-    assert not np.asarray(grouped[0][61:]).any()
-    assert int(grouped[1][4]) > 0        # picks that fell on absent experts
-
-
-@pytest.mark.parametrize("n, stored, dense", [
-    (16, (64, 2688, 1920), False),     # a lone turn reads the experts it touches
-    (63, (64, 2688, 1920), False),
-    (64, (64, 2688, 1920), True),      # no tile wider than 128 lanes fits
-    (2048, (64, 2688, 1920), True),
-    (2048, (32, 4096, 2048), False),   # the latent block's experts
-    (2048, (128, 2048, 768), False),   # the sparse-index block's
-    (2048, (64, 2048, 1920), True),    # one width is enough
-    (64, (8, 64, 256), True),          # this file's size
-    (4096, (8, 64, 32), False),        # under one tile: the pinned toy programs
-], ids=str)
-def test_the_expert_form_is_chosen_from_the_block_and_the_widths(
-        n, stored, dense):
-    assert lm.dense_experts(n, stored) is dense
 
 
 def test_two_shares_add_up_to_the_uncut_layer():

@@ -326,22 +326,26 @@ def pattern_layers(one_chip):
     assert kept["S"]["conv"].shape == (257, 3 * 6144)
     counters = {"S": (), "A": (), "E": s((66,), jnp.int32)}
     out = {}
-    for batch, block, ctx in PATTERN_BUCKETS:
-        for kind in "SAE":
-            lw = {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
-                  for k, (shape, f32) in latent_moe.layer_shapes(
-                      cfg, kind).items()}
-            step = latent_moe.step_of(kind, cfg)
-            own = s((batch, ctx // cfg.cache_page) if kind == "A"
-                    else (batch,), jnp.int32)
-            out[kind, batch, block] = jax.jit(
-                lambda lw, cache, counters, h, own, offsets, counts,
-                step=step: step(lw, cache, counters, h, own, offsets, counts,
-                                cfg=cfg, form=""),
-                donate_argnums=(1, 2, 3)).lower(
-                lw, kept[kind], counters[kind],
-                s((batch, block, cfg.d_model), jnp.float32), own,
-                s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
+    # (the code asks the backend which grouped matmul to build: this
+    # process's is the CPU, the programs are the chip's)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+        for batch, block, ctx in PATTERN_BUCKETS:
+            for kind in "SAE":
+                lw = {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
+                      for k, (shape, f32) in latent_moe.layer_shapes(
+                          cfg, kind).items()}
+                step = latent_moe.step_of(kind, cfg)
+                own = s((batch, ctx // cfg.cache_page) if kind == "A"
+                        else (batch,), jnp.int32)
+                out[kind, batch, block] = jax.jit(
+                    lambda lw, cache, counters, h, own, offsets, counts,
+                    step=step: step(lw, cache, counters, h, own, offsets,
+                                    counts, cfg=cfg, form=""),
+                    donate_argnums=(1, 2, 3)).lower(
+                    lw, kept[kind], counters[kind],
+                    s((batch, block, cfg.d_model), jnp.float32), own,
+                    s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
     return out
 
 
@@ -363,10 +367,22 @@ def test_pattern_layer_compiles_for_v5e(pattern_layers, kind, batch, block,
     for scope in scopes:
         assert f"/{scope}/" in text, scope
     if kind == "E":
-        # a lone turn keeps the grouped matmul; wider blocks run every held
-        # expert on every token
-        assert ("ragged-dot" in text) == (batch * block < 64)
-        # the routed experts' first matrices are stored lane-aligned: no
-        # copy of all 64 of them in front of the grouped matmul (at
-        # [64, 2688, 1856] the compiler made one of 638 MB at every call)
-        assert not re.search(r"bf16\[64,2688,\d+\][^\n]* copy\(", text)
+        # the routed experts' two grouped matmuls are the Pallas kernel at
+        # every block size, under the scope the trace's readers sum
+        calls = re.findall(r'^.*custom_call_target="tpu_custom_call".*$',
+                           text, re.M)
+        assert len(calls) == 2, len(calls)
+        assert all("/moe_experts/" in call for call in calls)
+        # ... and so does all that prepares them (the visits' arithmetic)
+        names = re.findall(r'op_name="([^"]*grouped_matmul[^"]*)"', text)
+        assert names and all("/moe_experts/" in name for name in names)
+        assert "ragged-dot" not in text
+        # no held expert runs on a token that did not pick it: no
+        # [64, N, 1920] activation
+        assert not re.search(r"f32\[64,\d+,1920\]", text)
+        # the routed experts' matrices are stored lane-aligned ([64, 2688,
+        # 1920] and [64, 1920, 2688]): no copy of all 64 in front of the
+        # kernel, in whatever layout it asks for (at [64, 2688, 1856] the
+        # compiler made one of 638 MB at every call)
+        assert not re.search(
+            r"bf16\[64,(?:2688|1920),\d+\][^\n]* copy\(", text)
