@@ -371,30 +371,79 @@ def _trace_annotation():
     return cls
 
 
-def _finish(sp: Span) -> None:
+def _finish(sp: Span, export: bool = True) -> None:
     """Every finished span, however it was timed: ring, aggregate, export."""
     (sp._buffer or TRACES).add(sp)
     _profile.record_span(sp.name, sp.duration)
     exporter = _EXPORTER
-    if exporter is not None:
+    if export and exporter is not None:
         exporter(sp)
 
 
 def record_span(name: str, start: float, duration: float,
                 context: Optional[Any] = None,
                 service: Optional[str] = None,
-                buffer: Optional[TraceBuffer] = None, **attrs: Any) -> Span:
+                buffer: Optional[TraceBuffer] = None, export: bool = True,
+                **attrs: Any) -> Span:
     """Record a span whose interval was timed by the caller: ``start`` is a
     ``time.perf_counter()`` reading, ``duration`` seconds. For intervals no
     ``with`` block can hold — they cross an ``await`` or begin on another
     task (a request's wait in the batcher's queue). A child of ``context``
-    (default: the current one); lands in the ring, the aggregate and the
-    exporter, never on the profiler's timeline."""
+    (default: the current one); lands in the ring, the aggregate and
+    (``export``) the exporter, never on the profiler's timeline."""
     sp = Span(name, service, buffer, False, start, **attrs)
     sp._begin(context if context is not None else _CURRENT.get())
     sp.duration = max(0.0, duration)
-    _finish(sp)
+    _finish(sp, export)
     return sp
+
+
+class Track:
+    """Back-to-back intervals of one state machine that no ``with`` block
+    can hold: both ends of each lie on ONE thread (the event loop's) but in
+    different tasks, outside every other span's block. ``switch(phase)``
+    closes the open interval, recording it as the span ``<scope>.<phase>``
+    (ring and aggregate, through :func:`record_span`), and opens the next;
+    ``switch(None)`` only closes. The intervals are sibling roots of one
+    trace of the track's own: they are nobody's request, and they never
+    reach the exporter (every long interval of a quiet server would pass
+    the spool's slow rule).
+
+    A phase listed in ``timeline`` also lies on the jax profiler's timeline
+    as ``pio.<scope>.<phase>``: a bare annotation held open between the two
+    switches, entered only while a profiler session runs. An interval that
+    was already open when the session started has no annotation and is
+    simply missing from that capture. The ambient context is never touched
+    (a :class:`Span` resets a contextvar token on exit, which another
+    task's context would refuse)."""
+
+    __slots__ = ("_scope", "_timeline", "_ctx", "phase", "_t0", "_ann")
+
+    def __init__(self, scope: str, timeline: tuple = ()):
+        self._scope = scope
+        self._timeline = frozenset(timeline)
+        # (no span id: the intervals are roots, not children of one)
+        self._ctx = SpanContext(_new_id(), None, False)
+        #: the open interval's phase, None before the first switch and
+        #: after ``switch(None)`` (read only)
+        self.phase: Optional[str] = None
+        self._t0 = 0.0
+        self._ann = None
+
+    def switch(self, phase: Optional[str]) -> None:
+        now = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self.phase is not None:
+            record_span(f"{self._scope}.{self.phase}", self._t0,
+                        now - self._t0, context=self._ctx, export=False)
+        self.phase, self._t0 = phase, now
+        if phase in self._timeline:
+            cls = _TRACE_ANNOTATION or _trace_annotation()
+            if cls is not None and cls.is_enabled():
+                self._ann = cls(f"pio.{self._scope}.{phase}")
+                self._ann.__enter__()
 
 
 class trace_scope:

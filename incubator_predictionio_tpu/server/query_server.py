@@ -515,6 +515,16 @@ class MicroBatcher:
     ``dispatch`` (assembly → results) reservoirs split the latency into its
     two terms; both are exposed on the status page.
 
+    Occupancy: the batcher counts the requests it holds, from the enqueue
+    until the entry leaves it (its batch merged or failed, shed or dropped
+    at assembly, drained by ``stop()``; a waiter that gave up is held until
+    then too: its entry still rides the queue or the dispatch), and books
+    the time it holds none as span ``serve.server.empty`` and the rest as
+    ``serve.server.occupied`` (``obs/trace.Track``; the first also lies on
+    the profiler's timeline). A request pays one integer add, a batch one
+    subtraction. ``occupied ÷ (occupied + empty)`` between two scrapes of
+    ``pio_profile_phase_seconds_total`` is the server's utilisation.
+
     Overload protection (resilience/admission.py): each request is tagged
     with its deadline at enqueue; batch assembly evicts entries whose
     deadline already expired (their futures resolve :class:`ShedExpired`
@@ -549,12 +559,17 @@ class MicroBatcher:
         self._inflight: set[asyncio.Task] = set()
         self._resizes: set[asyncio.Task] = set()  # strong refs
         self._stopped = False
+        #: requests held: enqueued, entry not yet merged, shed or drained
+        #: (the loop's thread only)
+        self.held = 0
+        self._occupancy = _trace.Track("serve.server", timeline=("empty",))
 
     def start(self) -> None:
         if self._stopped:
             raise RuntimeError("server shutting down")
         if self._task is None:
             self._task = asyncio.get_running_loop().create_task(self._drain())
+            self._occupancy.switch("empty")
 
     async def stop(self) -> None:
         """Cancel the drainer and fail everything still queued so callers
@@ -571,14 +586,26 @@ class MicroBatcher:
             except asyncio.CancelledError:
                 pass
             self._task = None
+        drained = 0
         while True:
             try:
                 entry = self.queue.get_nowait()
             except asyncio.QueueEmpty:
                 break
+            drained += 1
             fut = entry[1]
             if not fut.done():
                 fut.set_result(RuntimeError("server shutting down"))
+        # (the dispatches in flight released theirs as the drainer ended)
+        self._release(drained)
+        self._occupancy.switch(None)
+
+    def _release(self, n: int) -> None:
+        """``n`` held entries left the batcher (after ``stop()`` closed the
+        track, nothing reopens it)."""
+        self.held -= n
+        if n and not self.held and self._occupancy.phase is not None:
+            self._occupancy.switch("empty")
 
     async def submit(self, payload: dict) -> Any:
         return (await self.submit_timed(payload))[0]
@@ -600,6 +627,9 @@ class MicroBatcher:
         # caller's trace (coalesced followers share that dispatch span)
         await self.queue.put((payload, fut, time.perf_counter(),
                               contextvars.copy_context(), deadline_at))
+        self.held += 1
+        if self.held == 1:
+            self._occupancy.switch("occupied")
         try:
             got = await fut
         except asyncio.CancelledError:
@@ -661,16 +691,15 @@ class MicroBatcher:
                                 batch.append(self.queue.get_nowait())
                             except asyncio.QueueEmpty:
                                 break
-                        attrs = _batch_attrs(len(batch))
-                        sp.attrs.update(attrs)
-                    now = time.perf_counter()
-                    for entry in batch:
+                        sp.attrs.update(_batch_attrs(len(batch)))
+                        now = time.perf_counter()
+                        taken, batch = batch, self._evict_expired(batch)
+                    self._release(len(taken) - len(batch))
+                    for entry in taken:
                         self.queue_delay.record(now - entry[2])
                         _trace.record_span(
                             "serve.request.queue", entry[2], now - entry[2],
                             context=_trace.context_of(entry[3]))
-                    with _trace.span("serve.batch.mask", **attrs):
-                        batch = self._evict_expired(batch)
                 if not batch:
                     # the whole assembly was dead on arrival: no dispatch,
                     # hand the slot back and keep draining
@@ -754,6 +783,7 @@ class MicroBatcher:
             for entry in batch:
                 if not entry[1].done():
                     entry[1].set_result(RuntimeError("server shutting down"))
+            self._release(len(batch))
             raise
         except Exception as e:  # noqa: BLE001 - keep serving
             results = [e] * len(batch)
@@ -770,6 +800,9 @@ class MicroBatcher:
                     delivered = True
                     entry[1].set_result(
                         _Delivered(r, algo_times, resolved_at))
+        # (after merge's block: the empty interval's annotation must not
+        # open inside another span's)
+        self._release(len(batch))
         # the limiter sizes the dispatches in flight, so what it observes is
         # how long this one held its slot. Only a clean dispatch somebody
         # waited out says that: a failed one, one that rejected or healed a

@@ -64,6 +64,7 @@ items.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import re
 import threading
@@ -192,9 +193,14 @@ class _TurnLock:
             self._serving += 1
             self._cond.notify_all()
 
+    def ahead(self) -> int:
+        """Tickets not yet served, the holder's included (read unlocked: a
+        span's attribute, nothing decides by it)."""
+        return self._next - self._serving
+
     def offer(self) -> bool:
         """Lets every thread that waits now run first; false if none does."""
-        if self._next - self._serving < 2:
+        if self.ahead() < 2:
             return False
         self.__exit__()
         self.__enter__()
@@ -532,10 +538,13 @@ class LatentServing:
         k = TOP_K if num <= TOP_K else min(
             1 << (num - 1).bit_length(), self.cfg.vocab_size)
         busy = {key for key, _ in requests if key is not None}
-        with self._lock:
-            # a session whose block another caller is still cutting: after it
-            while busy & self._cutting and self._lock.offer():
-                pass
+        with contextlib.ExitStack() as held:
+            with span("seq.batch.lock", ahead=self._lock.ahead()):
+                held.enter_context(self._lock)
+                # a session whose block another caller is still cutting:
+                # after it
+                while busy & self._cutting and self._lock.offer():
+                    pass
             self._cutting |= busy
             release: tuple = ([], [])
             try:
@@ -566,8 +575,9 @@ class LatentServing:
                         rows = [b.row for b in group]
                         scores[rows], items[rows] = \
                             out[0][:len(rows)], out[1][:len(rows)]
-                    else:
-                        self._lock.offer()   # between a cut block's pieces
+                    else:   # between a cut block's pieces
+                        with span("seq.batch.lock", why="offer"):
+                            self._lock.offer()
             except BaseException:
                 # the table says more of these sessions than the cache holds
                 for sess in map(self._sessions.pop, busy & set(self._sessions)):
@@ -640,40 +650,56 @@ class LatentServing:
                   k: int = TOP_K, count: bool = True, head: bool = True):
         """Embed, every layer, and with ``head`` the head + top-k, whose
         ``(values, tokens)`` come back; a piece that is not its block's last
-        only leaves its rows in the cache."""
+        only leaves its rows in the cache. Three child spans cover the
+        dispatch, ``seq.turn.*`` for the short block (turns, a cut block's
+        tail, a short miss) and ``seq.miss.*`` for any other: ``stage`` (the
+        operands), ``launch`` (what the host spends issuing the programs)
+        and, with ``head``, ``wait`` (the device finishing and the transfer
+        back; without it nothing is waited for, and the piece's device work
+        runs on under whatever comes next)."""
         exe = self._exe[batch, block, ctx]
         form = self.form(block)
         n_new = sum(len(b.tokens) - b.offset for b in group)
+        scope = "seq.turn" if block == self.blocks[0] else "seq.miss"
         with span("seq.batch.extend", bucket=self.label(batch, block, ctx),
                   tokens=n_new, form=form):
-            tokens = np.zeros((batch, block), np.int32)
-            pages = np.zeros((batch, ctx // self.page), np.int32)
-            offsets = np.zeros((batch,), np.int32)
-            counts = np.zeros((batch,), np.int32)
-            slots = np.zeros((batch,), np.int32)
-            for i, b in enumerate(group):
-                new = b.tokens[b.offset:]
-                tokens[i, :len(new)] = new
-                held = b.pages[:pages.shape[1]]  # a piece's context ends with it
-                pages[i, :len(held)] = held
-                offsets[i], counts[i], slots[i] = b.offset, len(new), b.slot
-            small = (pages, offsets, counts)
-            if self.cfg.layer_pattern:
-                # (one transfer for the whole stack's launches, not one a
-                # layer: a pattern is many thin layers)
-                small = jax.device_put(small, self.device)
-                slots = jax.device_put(slots, self.device)
-            own = {kind: (slots if kind in CONTEXT_FREE else small[0],
-                          *small[1:]) for kind in exe}
-            h, self.tok_cache = exe["embed"](
-                self.params["item_emb"], self.tok_cache, tokens, *small)
-            for i, (kind, lw) in enumerate(
-                    zip(self.kinds, self.params["layers"])):
-                h, self.cache[i], self.counters[i] = exe[kind](
-                    lw, self.cache[i], self.counters[i], h, *own[kind])
-            out = jax.device_get(self._head(batch, block, ctx, k)(
-                self.params["norm_f"], latent_moe.head_matrix(self.params),
-                self.tok_cache, h, *small)) if head else None
+            with span(scope + ".stage", sessions=len(group)):
+                tokens = np.zeros((batch, block), np.int32)
+                pages = np.zeros((batch, ctx // self.page), np.int32)
+                offsets = np.zeros((batch,), np.int32)
+                counts = np.zeros((batch,), np.int32)
+                slots = np.zeros((batch,), np.int32)
+                for i, b in enumerate(group):
+                    new = b.tokens[b.offset:]
+                    tokens[i, :len(new)] = new
+                    # a piece's context ends with it
+                    held = b.pages[:pages.shape[1]]
+                    pages[i, :len(held)] = held
+                    offsets[i], counts[i], slots[i] = \
+                        b.offset, len(new), b.slot
+                small = (pages, offsets, counts)
+                if self.cfg.layer_pattern:
+                    # (one transfer for the whole stack's launches, not one
+                    # a layer: a pattern is many thin layers)
+                    small = jax.device_put(small, self.device)
+                    slots = jax.device_put(slots, self.device)
+                own = {kind: (slots if kind in CONTEXT_FREE else small[0],
+                              *small[1:]) for kind in exe}
+            with span(scope + ".launch",
+                      launches=len(self.kinds) + 1 + head):
+                h, self.tok_cache = exe["embed"](
+                    self.params["item_emb"], self.tok_cache, tokens, *small)
+                for i, (kind, lw) in enumerate(
+                        zip(self.kinds, self.params["layers"])):
+                    h, self.cache[i], self.counters[i] = exe[kind](
+                        lw, self.cache[i], self.counters[i], h, *own[kind])
+                out = self._head(batch, block, ctx, k)(
+                    self.params["norm_f"],
+                    latent_moe.head_matrix(self.params),
+                    self.tok_cache, h, *small) if head else None
+            if head:
+                with span(scope + ".wait"):
+                    out = jax.device_get(out)
         if count:
             _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
             _EXPERT_LAYERS.labels(form=self.expert_form).inc(

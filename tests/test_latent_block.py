@@ -538,10 +538,11 @@ def test_expert_counters_are_read_when_metrics_are(served, sessions):
 
 def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
         served, sessions, tmp_path):
-    """A recorded profiler trace of one batch holds the three ``seq.batch.*``
-    spans as ``pio.*`` events; the compiled programs carry the six named
-    scopes, by which ``device_scopes`` tells a device trace's operations
-    apart."""
+    """A recorded profiler trace of one batch (a short block and a long one)
+    holds its ``seq.*`` spans as ``pio.*`` events: the lock, the match, and
+    under each ``seq.batch.extend`` its stage, launch and wait, named by the
+    dispatch's kind; the compiled programs carry the six named scopes, by
+    which ``device_scopes`` tells a device trace's operations apart."""
     import glob
 
     serving, params, cfg = served
@@ -559,20 +560,36 @@ def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
              if plane.name == "/host:CPU"
              for line in plane.lines for e in line.events
              if e.name.startswith("pio.seq.")]
-    assert sorted(names) == ["pio.seq.batch.extend", "pio.seq.batch.extend",
-                             "pio.seq.batch.match"]
+    parts = ("stage", "launch", "wait")
+    assert sorted(names) == sorted(
+        ["pio.seq.batch.lock", "pio.seq.batch.match"]
+        + 2 * ["pio.seq.batch.extend"]
+        + [f"pio.seq.{kind}.{part}" for kind in ("turn", "miss")
+           for part in parts])
     # the call's own spans are the ring's newest (a full ring keeps its
     # length, so they are counted from its end)
     spans = [s for s in trace.TRACES.spans()
-             if s["name"].startswith("seq.batch.")][-3:]
-    assert [s["name"] for s in spans].count("seq.batch.match") == 1
-    match = next(s for s in spans if s["name"] == "seq.batch.match")
+             if s["name"].startswith("seq.")][-10:]
+    assert [s["name"] for s in spans] == [
+        "seq.batch.lock", "seq.batch.match",
+        *(f"seq.turn.{part}" for part in parts), "seq.batch.extend",
+        *(f"seq.miss.{part}" for part in parts), "seq.batch.extend"]
+    assert spans[0]["attrs"] == {"ahead": 0}
+    match = spans[1]
     assert match["attrs"] == {"sessions": 2, "hits": 0, "misses": 2,
                               "reused": 0}
-    extends = [s["attrs"] for s in spans if s["name"] == "seq.batch.extend"]
-    assert extends == [
+    extends = [s for s in spans if s["name"] == "seq.batch.extend"]
+    assert [s["attrs"] for s in extends] == [
         {"bucket": "1x16@64", "tokens": 12, "form": "absorbed"},
         {"bucket": "1x256@256", "tokens": 140, "form": "up"}]
+    # stage, launch and wait hang under their dispatch and cover it
+    own = trace.self_seconds(spans)
+    for kind, parent in zip(("turn", "miss"), extends):
+        kids = [s for s in spans if s["name"].startswith(f"seq.{kind}.")]
+        assert all(s["parentId"] == parent["spanId"] for s in kids)
+        assert own[parent["spanId"]] <= 0.05 * parent["durationSec"]
+        assert kids[0]["attrs"] == {"sessions": 1}
+        assert kids[1]["attrs"] == {"launches": cfg.n_layers + 2}
 
     scopes = serving.device_scopes()
     assert set(scopes) == {

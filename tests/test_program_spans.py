@@ -53,6 +53,10 @@ FACTORY = "tests.test_program_spans.SpanEngine"
 # the primitive
 # ---------------------------------------------------------------------------
 
+def _occupancy(spans: list) -> list:
+    return [s for s in spans if s["name"].startswith("serve.server.")]
+
+
 def _phase_row(family: str, scope: str, phase: str) -> float:
     """One row of the aggregate as a scrape of ``/metrics`` reads it."""
     samples = parse_prometheus_text(REGISTRY.expose())[family]["samples"]
@@ -301,6 +305,234 @@ def test_span_marks_errors_and_respects_a_classified_status():
 
 
 # ---------------------------------------------------------------------------
+# the server's occupancy (MicroBatcher, ISSUE 36)
+# ---------------------------------------------------------------------------
+
+class _Echo:
+    """``predict_batch`` stub: sleeps, then echoes or raises."""
+
+    def __init__(self, block_s: float = 0.0, fail: bool = False):
+        self.block_s, self.fail = block_s, fail
+
+    def predict_batch(self, payloads):
+        time.sleep(self.block_s)
+        if self.fail:
+            raise ValueError("the engine failed")
+        return [{"echo": p["id"]} for p in payloads]
+
+
+def _assert_alternates_and_ends_empty(batcher, spans: list) -> None:
+    """No hold leaked: the count is back at 0 and the booked intervals
+    alternate from ``empty`` to ``empty`` (the one ``stop()`` closed)."""
+    names = [s["name"].rsplit(".", 1)[1] for s in _occupancy(spans)]
+    assert batcher.held == 0
+    assert len(names) >= 3 and len(names) % 2 == 1, names
+    assert names == ["empty", "occupied"] * (len(names) // 2) + ["empty"]
+
+
+def test_two_spaced_requests_book_alternating_occupancy():
+    from incubator_predictionio_tpu.server.query_server import MicroBatcher
+
+    async def drive():
+        batcher = MicroBatcher(_Echo(block_s=0.02), max_batch=4)
+        t0 = time.perf_counter()
+        batcher.start()
+        await asyncio.sleep(0.03)
+        first = await batcher.submit({"id": 1})
+        assert batcher.held == 0
+        await asyncio.sleep(0.04)
+        second = await batcher.submit({"id": 2})
+        await asyncio.sleep(0.01)
+        await batcher.stop()
+        return batcher, time.perf_counter() - t0, (first, second)
+
+    trace.TRACES.clear()
+    before = prof.phase_snapshot().get("serve.server", {}).get("phases", {})
+    exported = []
+    trace.set_exporter(exported.append)
+    try:
+        batcher, wall, answers = asyncio.run(drive())
+    finally:
+        trace.set_exporter(None)
+    # occupancy is the server's state, nobody's request: a quiet server's
+    # long empty intervals must not pass the spool's slow rule
+    assert not [s.name for s in exported
+                if s.name.startswith("serve.server.")]
+    assert "serve.batch.assemble" in {s.name for s in exported}
+    assert [a["echo"] for a in answers] == [1, 2]
+    spans = _occupancy(trace.TRACES.spans())
+    _assert_alternates_and_ends_empty(batcher, spans)
+    assert len(spans) == 5
+    dur = [s["durationSec"] for s in spans]
+    assert sum(dur) == pytest.approx(wall, rel=0.02)
+    # each interval is what the schedule made it: the waits empty, a
+    # dispatch's 20 ms (and the hand-over around it) occupied
+    assert dur[0] >= 0.03 and dur[2] >= 0.04 and dur[4] >= 0.01
+    assert 0.02 <= dur[1] < 0.03 and 0.02 <= dur[3] < 0.03
+    # back to back on one clock: each starts where the last one ended
+    for a, b in zip(spans, spans[1:]):
+        assert b["startUnix"] == pytest.approx(
+            a["startUnix"] + a["durationSec"], abs=1e-3)
+    # one trace of sibling roots, and rows of the aggregate with no family
+    # of their own: utilisation = occupied / (occupied + empty)
+    assert len({s["traceId"] for s in spans}) == 1
+    assert all(s["parentId"] is None for s in spans)
+    phases = prof.phase_snapshot()["serve.server"]["phases"]
+    grew = {k: phases[k]["seconds"] - before.get(k, {}).get("seconds", 0.0)
+            for k in ("empty", "occupied")}
+    assert grew["empty"] == pytest.approx(dur[0] + dur[2] + dur[4])
+    assert grew["occupied"] == pytest.approx(dur[1] + dur[3])
+    assert _phase_row("pio_profile_phase_seconds_total", "serve.server",
+                      "occupied") == pytest.approx(
+        phases["occupied"]["seconds"])
+
+
+async def _coalesced(MicroBatcher):
+    """Eight requests at once, batches of four: one occupied interval."""
+    batcher = MicroBatcher(_Echo(block_s=0.005), max_batch=4)
+    got = await asyncio.gather(*(batcher.submit({"id": i}) for i in range(8)))
+    assert [g["echo"] for g in got] == list(range(8))
+    return batcher, 1
+
+
+async def _shed(MicroBatcher):
+    """Deadlines that pass while the one slot is held: ``ShedExpired``."""
+    from incubator_predictionio_tpu.resilience.admission import ShedExpired
+    from incubator_predictionio_tpu.resilience.clock import FakeClock
+
+    clk = FakeClock()
+    batcher = MicroBatcher(_Echo(block_s=0.03), max_batch=1, max_in_flight=1,
+                           deadline_sec=0.5, clock=clk)
+    tasks = [asyncio.create_task(batcher.submit({"id": i})) for i in range(3)]
+    await asyncio.sleep(0.01)      # the first is in its dispatch
+    assert batcher.held == 3
+    clk.advance(1.0)               # the other two expire in the queue
+    got = await asyncio.gather(*tasks, return_exceptions=True)
+    assert got[0] == {"echo": 0}
+    assert all(isinstance(g, ShedExpired) for g in got[1:])
+    assert batcher.shed_expired == 2
+    return batcher, 1
+
+
+async def _cancelled(MicroBatcher):
+    """A waiter that gives up while queued, one while in its dispatch: each
+    entry is held until the batcher is rid of it (the dispatch over, the
+    queued one dropped at the next assembly), not until its waiter left."""
+    batcher = MicroBatcher(_Echo(block_s=0.03), max_batch=1, max_in_flight=1)
+    tasks = [asyncio.create_task(batcher.submit({"id": i})) for i in range(3)]
+    await asyncio.sleep(0.01)
+    tasks[0].cancel(), tasks[2].cancel()
+    await asyncio.sleep(0)
+    assert batcher.held == 3
+    got = await asyncio.gather(*tasks, return_exceptions=True)
+    assert isinstance(got[0], asyncio.CancelledError)
+    assert got[1] == {"echo": 1}
+    assert isinstance(got[2], asyncio.CancelledError)
+    await asyncio.sleep(0.005)     # the drainer meets the abandoned entry
+    return batcher, 1
+
+
+async def _failed(MicroBatcher):
+    """``predict_batch`` raises: every caller of the batch gets the error."""
+    batcher = MicroBatcher(_Echo(fail=True), max_batch=4)
+    for _ in range(2):
+        with pytest.raises(ValueError):
+            await batcher.submit({"id": 0})
+        assert batcher.held == 0
+        await asyncio.sleep(0.005)
+    return batcher, 2
+
+
+async def _stopped(MicroBatcher):
+    """``stop()`` with one request in its dispatch and two queued."""
+    batcher = MicroBatcher(_Echo(block_s=0.05), max_batch=1, max_in_flight=1)
+    tasks = [asyncio.create_task(batcher.submit({"id": i})) for i in range(3)]
+    await asyncio.sleep(0.01)
+    assert batcher.held == 3
+    await batcher.stop()
+    assert batcher.held == 0
+    got = await asyncio.gather(*tasks, return_exceptions=True)
+    assert all(isinstance(g, RuntimeError) for g in got)
+    return batcher, 1
+
+
+@pytest.mark.parametrize("path", [_coalesced, _shed, _cancelled, _failed,
+                                  _stopped],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_no_path_out_of_the_batcher_leaks_a_hold(path):
+    """A leaked hold would pin the server ``occupied`` for ever: after each
+    way a request can leave (answered in a coalesced batch, shed, cancelled,
+    failed, shut down) the count is 0 and the last interval is ``empty``."""
+    from incubator_predictionio_tpu.server.query_server import MicroBatcher
+
+    async def drive():
+        batcher, occupied = await path(MicroBatcher)
+        assert batcher.held == 0
+        await asyncio.sleep(0.005)
+        await batcher.stop()
+        return batcher, occupied
+
+    trace.TRACES.clear()
+    batcher, occupied = asyncio.run(drive())
+    spans = trace.TRACES.spans()
+    _assert_alternates_and_ends_empty(batcher, spans)
+    assert len(_occupancy(spans)) == 2 * occupied + 1
+
+
+def test_empty_intervals_lie_on_the_profilers_timeline(tmp_path):
+    """Under a capture every ``serve.server.empty`` interval that began
+    inside it is a ``pio.serve.server.empty`` event on ``/host:CPU``, as
+    long as the ring's span and clear of every ``pio.serve.batch.predict``
+    (the worker's thread, the same clock); the interval that was open when
+    the capture started has no event; ``occupied`` never has one."""
+    from incubator_predictionio_tpu.server.query_server import MicroBatcher
+
+    class Predicts(_Echo):
+        def predict_batch(self, payloads):
+            with trace.span("serve.batch.predict"):
+                return super().predict_batch(payloads)
+
+    async def drive():
+        batcher = MicroBatcher(Predicts(block_s=0.01), max_batch=4)
+        batcher.start()
+        await asyncio.sleep(0.01)
+        _capture(str(tmp_path))
+        try:
+            for i in range(3):
+                await asyncio.sleep(0.01)
+                await batcher.submit({"id": i})
+            await asyncio.sleep(0.01)
+            await batcher.stop()
+        finally:
+            jax.profiler.stop_trace()
+        return batcher
+
+    jnp.ones(()).block_until_ready()
+    trace.TRACES.clear()
+    batcher = asyncio.run(drive())
+    spans = trace.TRACES.spans()
+    _assert_alternates_and_ends_empty(batcher, spans)
+    ours, _ = _pio_events(str(tmp_path))
+    empty = sorted((s, e) for n, s, e in ours
+                   if n == "pio.serve.server.empty")
+    predict = sorted((s, e) for n, s, e in ours
+                     if n == "pio.serve.batch.predict")
+    assert not [n for n, _, _ in ours if n == "pio.serve.server.occupied"]
+    # four empty intervals in the ring; the first began before the capture
+    ring = [s for s in _occupancy(spans) if s["name"].endswith(".empty")]
+    assert len(ring) == 4 and len(empty) == 3 and len(predict) == 3
+    for (s, e), row in zip(empty, ring[1:]):
+        assert (e - s) / 1e9 == pytest.approx(row["durationSec"], abs=1e-3)
+        assert (s - empty[0][0]) / 1e9 == pytest.approx(
+            row["startUnix"] - ring[1]["startUnix"], abs=1e-3)
+    # empty, predict, empty, predict, empty, predict, empty: none overlaps
+    for ps, pe in predict:
+        assert not [1 for s, e in empty if s < pe and ps < e]
+    edges = sorted(empty + predict)
+    assert edges == [x for pair in zip(predict, empty) for x in pair]
+
+
+# ---------------------------------------------------------------------------
 # the two measured paths: one run_train, one POST /queries.json
 # ---------------------------------------------------------------------------
 
@@ -497,8 +729,7 @@ def test_one_query_yields_request_and_batch_spans(trained):
     assert route["parentId"] is None
     for name in ("serve.request.parse", "serve.request.queue",
                  "serve.request.respond", "serve.batch.assemble",
-                 "serve.batch.mask", "serve.batch.dispatch",
-                 "serve.batch.merge"):
+                 "serve.batch.dispatch", "serve.batch.merge"):
         assert by_name[name]["parentId"] == route["spanId"], name
     dispatch = by_name["serve.batch.dispatch"]
     assert dispatch["attrs"] == {"batch": 1, "bucket": 1}
@@ -522,9 +753,14 @@ def test_one_query_yields_request_and_batch_spans(trained):
     assert starts == sorted(starts)
     assert sum(by_name[n]["durationSec"] for n in order) \
         <= route["durationSec"]
-    # nothing of this request is left outside its trace
-    assert not [s for s in spans if s["traceId"] != trace_id
-                and s["name"].startswith(("serve.", "retrieval."))]
+    # nothing of this request is left outside its trace; the server's
+    # occupancy is nobody's request and keeps a trace of its own
+    outside = [s for s in spans if s["traceId"] != trace_id
+               and s["name"].startswith(("serve.", "retrieval."))]
+    assert [s["name"] for s in outside] == [
+        "serve.server.empty", "serve.server.occupied", "serve.server.empty"]
+    assert len({s["traceId"] for s in outside}) == 1
+    assert all(s["parentId"] is None for s in outside)
 
 
 def test_exact_path_opens_the_device_span(trained):

@@ -28,6 +28,7 @@ import pytest
 from benchmarks.reference import gqa_sparse_moe_ref as ref
 from incubator_predictionio_tpu.models import latent_moe as lm
 from incubator_predictionio_tpu.models import sparse_gqa as sg
+from incubator_predictionio_tpu.obs import trace
 from incubator_predictionio_tpu.obs.metrics import REGISTRY, parse_prometheus_text
 from incubator_predictionio_tpu.serving.latent_cache import LatentServing
 from tests.fixtures.sparse_tiny import (
@@ -303,6 +304,51 @@ def test_another_callers_turn_runs_between_the_pieces_of_a_miss(
     _assert_answer(params, cfg, sessions[1, :94], answers["miss"])
     _assert_answer(params, cfg, sessions[2, :43], answers["other"])
     assert not serving._cutting
+
+
+def test_a_cut_miss_and_the_turn_beside_it_in_spans(served, sessions):
+    """A miss of 94 tokens is two head-less pieces and a last one; a turn of
+    another caller waits at the lock through the first piece and runs after
+    it. Pieces are ``seq.miss.*`` and only the last waits for an answer; the
+    turn is ``seq.turn.*``; the miss's hand-overs are ``seq.batch.lock``
+    spans with ``why="offer"``, the first as long as the turn it let in."""
+    serving, params, cfg = served
+    assert_answers(serving, params, cfg, [("u", sessions[4, :40])])
+    trace.TRACES.clear()
+    _, order = _cut_beside(
+        serving, [("n", sessions[5, :94])], [("u", sessions[4, :43])])
+    assert [who for who, _ in order] == ["miss", "other", "miss", "miss"]
+    spans = trace.TRACES.spans()
+    by_id = {s["spanId"]: s for s in spans}
+    named = lambda name: [s for s in spans if s["name"] == name]
+    extends = named("seq.batch.extend")
+    assert [s["attrs"]["bucket"] for s in extends] == [b for _, b in order]
+    first, turn = extends[0], extends[1]
+    # stage and launch a dispatch, a wait only where a head answers
+    for part, n in (("stage", 3), ("launch", 3), ("wait", 1)):
+        assert len(named(f"seq.miss.{part}")) == n, part
+        assert len(named(f"seq.turn.{part}")) == 1, part
+    assert [s["attrs"]["launches"] for s in named("seq.miss.launch")] == [
+        cfg.n_layers + 1, cfg.n_layers + 1, cfg.n_layers + 2]
+    assert by_id[named("seq.miss.wait")[0]["parentId"]] is extends[3]
+    assert by_id[named("seq.turn.wait")[0]["parentId"]] is turn
+    # the children cover a dispatch that waits for its answer (a head-less
+    # piece of this size is over in 0.3 ms: three spans' own cost shows)
+    for s in (turn, extends[3]):
+        assert trace.self_seconds(spans)[s["spanId"]] \
+            <= 0.05 * s["durationSec"], s["attrs"]
+    # the locks: each caller's entry, and the miss's two hand-overs
+    locks = named("seq.batch.lock")
+    entries = [s for s in locks if "ahead" in s["attrs"]]
+    offers = [s for s in locks if s["attrs"].get("why") == "offer"]
+    assert len(entries) == 2 and len(offers) == 2
+    # the turn stood at the lock through the miss's whole first piece ...
+    waited = max(entries, key=lambda s: s["durationSec"])
+    assert waited["attrs"]["ahead"] == 1
+    assert waited["durationSec"] >= first["durationSec"]
+    # ... and the miss stood aside for the whole turn, then for nobody
+    assert offers[0]["durationSec"] >= turn["durationSec"]
+    assert offers[1]["durationSec"] < turn["durationSec"]
 
 
 def test_a_caller_that_names_the_session_being_cut_waits_for_it(

@@ -480,6 +480,28 @@ def test_the_match_span_says_how_many_slots_were_taken(served, sessions):
     assert [e["form"] for e in extend] == ["scan", "step"]
 
 
+def test_a_scan_and_a_step_in_spans(served, sessions):
+    """A pattern's dispatch stages its operands on the device once
+    (``stage``), issues embed + one program a layer + the head (``launch``)
+    and fetches the answer (``wait``): ``seq.miss.*`` for the scan form's
+    long block, ``seq.turn.*`` for the step from the cached state."""
+    serving, params, cfg = served
+    trace.TRACES.clear()
+    serving.extend([("sp", sessions[2, :20]), ("sp", sessions[2, :22])])
+    spans = [s for s in trace.TRACES.spans() if s["name"].startswith("seq.")]
+    parts = ("stage", "launch", "wait")
+    assert [s["name"] for s in spans] == [
+        "seq.batch.lock", "seq.batch.match",
+        *(f"seq.miss.{p}" for p in parts), "seq.batch.extend",
+        *(f"seq.turn.{p}" for p in parts), "seq.batch.extend"]
+    for kids, parent in ((spans[2:5], spans[5]), (spans[6:9], spans[9])):
+        assert all(s["parentId"] == parent["spanId"] for s in kids)
+        assert kids[0]["attrs"] == {"sessions": 1}
+        assert kids[1]["attrs"] == {"launches": len(serving.kinds) + 2}
+    assert [s["attrs"]["form"] for s in (spans[5], spans[9])] \
+        == ["scan", "step"]
+
+
 def test_a_state_kept_in_bfloat16_is_outside_the_tolerance(served, sessions):
     """The precision step on the mechanism itself: turns through a state
     rounded to bfloat16 between requests move the logits by far more than
