@@ -639,8 +639,9 @@ def real_positions(tokens: np.ndarray) -> np.ndarray:
         np.int32)
 
 
-# -- serving steps: one executable a (batch, block) bucket, called a layer at a
-# time, so a bucket compiles one layer whatever the depth -------------------------
+# -- serving steps: serving/latent_cache.py compiles each as an executable of
+# its own a long bucket (called a layer at a time: one layer compiled whatever
+# the depth) and all of them into one a short bucket (``turn_step`` there) -------
 
 def _block_geometry(pages, offsets, counts, t, page):
     """From a dispatch's page table: the absolute index of every block

@@ -22,9 +22,15 @@ items.
   batches sessions and uses the absorbed attention form; longer blocks go
   one session a dispatch in the up-projected form, over a context no longer
   than the block when the whole session fits it (a cold session does). The
-  choice of form is by block length alone. Each bucket is three executables (embed, one
-  layer, head + top-k), compiled once by ``warmup`` and called a layer at a
-  time, so a bucket compiles one layer whatever the depth.
+  choice of form is by block length alone, and so is what a bucket compiles
+  to. A bucket of the SHORT block is ONE executable (``turn_step``: embed,
+  every layer in order with its own weights, cache and counters, head +
+  top-k; ``seq_turn_b<B>_t<T>_c<C>``): a turn is a few milliseconds of
+  device work, and a launch a layer made the host's issuing its pace. A
+  bucket of a longer block is three executables (embed, one layer, head +
+  top-k) called a layer at a time: it compiles one layer whatever the depth,
+  its device work is long beside its launches, and a piece that is not its
+  block's last returns without a head. Both are compiled once by ``warmup``.
 - The ladder is the block's own (``block_of(cfg).serve_shapes``), the rule
   is one: a short dispatch takes the smallest (batch, **context bucket**)
   that holds its sessions, so a lone turn reads its own session's rows and
@@ -51,10 +57,10 @@ items.
   from position 0, and ``pio_seq_state_restarts_total`` counts it when a
   prefix did match. (An unchanged list sent again is a restart: nothing of
   the last answer is kept, and the last token cannot be recomputed from a
-  state that already holds it.) A bucket compiles one program a layer kind
-  (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``: no context, shared by the
-  bucket's contexts; ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern order;
-  the pieces of a cut block hand the state on through the slot.
+  state that already holds it.) A long bucket compiles one program a layer
+  kind (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``: no context, shared by
+  the bucket's contexts; ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern
+  order; the pieces of a cut block hand the state on through the slot.
 - One dispatch runs at a time (``_TurnLock``), and between the pieces of a
   cut block the lock is offered to whoever waits: another batch's turns run
   between a miss's pieces and do not wait for all of it. A batch that names
@@ -66,6 +72,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import re
 import threading
 import weakref
@@ -94,6 +101,11 @@ _CACHE_TOKENS = REGISTRY.gauge(
 _DISPATCHES = REGISTRY.counter(
     "pio_seq_dispatches_total",
     "Extend dispatches by (batch x block) bucket", ("bucket",))
+_LAUNCHES = REGISTRY.counter(
+    "pio_seq_launches_total",
+    "Executables called by extend dispatches, by block (short: one turn "
+    "program a dispatch; long: embed, one a layer, the head where it answers)",
+    ("block",))
 _EXPERT_TOKENS = REGISTRY.counter(
     "pio_moe_expert_tokens_total",
     "Token-picks routed to each expert held on this chip", ("layer", "expert"))
@@ -169,6 +181,74 @@ class _Block:
 
 def _bucket(ladder: Sequence[int], n: int) -> int:
     return next(b for b in ladder if b >= n)
+
+
+@functools.lru_cache(maxsize=None)
+def _traced_once(step):
+    """A layer kind's step under a jit of its own: inside ``turn_step``'s
+    one program a kind is then traced and lowered once a bucket, not once a
+    layer (a deploy pays that for every bucket, compile cache or not); the
+    compiler inlines the calls, the executable is the same."""
+    return jax.jit(step, static_argnames=("cfg", "form"))
+
+
+def instruction_scopes(text: str, scopes: Sequence[str]) -> dict:
+    """``{HLO instruction: named scope}`` of one compiled program's text, by
+    the innermost of ``scopes`` on each instruction's ``op_name`` path. Loops
+    and branches are left out: their time is their bodies' operations',
+    which are in the map."""
+    found = {}
+    for name, body, op in _INSTRUCTION.findall(text):
+        if _CONTAINER.search(body):
+            continue
+        path = op.split("/")
+        parts = [p for p in path if p in scopes]
+        if parts:
+            found[name] = parts[-1]
+        elif path[-1].startswith("ragged-dot"):
+            # the TPU compiler's grouped-matmul kernel comes back without
+            # the scope it was traced under (under a jit inside the program
+            # it keeps that jit's name in front); the routed experts are the
+            # only grouped matmul here
+            found[name] = "moe_experts"
+    return found
+
+
+def split_operands(operands, block: int) -> tuple:
+    """A short dispatch's ONE int32 operand ``[B, block + P + 3]`` as its
+    parts ``(tokens [B, block], pages [B, P], offsets [B], counts [B],
+    slots [B])``: views to fill of the host's array, slices inside the
+    program. (One array because every numpy operand of a launch is a
+    transfer of its own, ~0.14 ms each on the chip: PERF.md PR 37.)"""
+    return (operands[:, :block], operands[:, block:-3], operands[:, -3],
+            operands[:, -2], operands[:, -1])
+
+
+#: ``turn_step``'s arguments that come back: the token cache, every layer's
+#: cache and every layer's counters (donated where the backend reuses them)
+TURN_KEPT = (1, 3, 4)
+
+
+def turn_step(item_emb, tok_cache, layers, caches, counters, norm_f, head,
+              tokens, pages, offsets, counts, slots, *, cfg, form, k):
+    """A whole dispatch of the short block as one function: ``embed_step``,
+    every layer's own step (``latent_moe.step_of``) in ``layer_kinds`` order
+    on its own weights, cache and counters, ``head_step`` at ``k``: the same
+    functions in the same order as a long block's chain calls one by one.
+    Returns ``((values, tokens), tok_cache, caches, counters)``."""
+    h, tok_cache = latent_moe.embed_step(
+        item_emb, tok_cache, tokens, pages, offsets, counts,
+        page=cfg.cache_page)
+    caches, counters = list(caches), list(counters)
+    for i, kind in enumerate(latent_moe.layer_kinds(cfg)):
+        step = _traced_once(latent_moe.step_of(kind, cfg))
+        h, caches[i], counters[i] = step(
+            layers[i], caches[i], counters[i], h,
+            slots if kind in CONTEXT_FREE else pages, offsets, counts,
+            cfg=cfg, form=form)
+    out = latent_moe.head_step(norm_f, head, tok_cache, h, pages, offsets,
+                               counts, cfg=cfg, k=k)
+    return out, tok_cache, caches, counters
 
 
 class _TurnLock:
@@ -308,6 +388,11 @@ class LatentServing:
         return f"{batch}x{block}@{ctx}"
 
     def _compile(self, batch: int, block: int, ctx: int) -> dict:
+        """A bucket's executables: the one turn program for the short block
+        (``{"turn": {k: executable}}``), the chain for any other."""
+        if block == self.blocks[0]:
+            return {"turn": {
+                TOP_K: self._lower_turn(batch, block, ctx, TOP_K).compile()}}
         # a context-free program is compiled once a (batch, block)
         done = {k: self._shared[k, batch, block] for k in CONTEXT_FREE
                 if (k, batch, block) in self._shared}
@@ -320,14 +405,40 @@ class LatentServing:
 
     def program(self, kind: str, batch: int, block: int, ctx: int) -> str:
         """The name a device trace shows a bucket's program under, less the
-        ``jit_``: ``seq_<embed|layer|head>_b<B>_t<T>_c<C>``, and for a
-        pattern's kinds ``seq_<ssm|moe>_b<B>_t<T>``, ``seq_gqa_b<B>_t<T>_c<C>``."""
+        ``jit_``: ``seq_turn_b<B>_t<T>_c<C>`` (the short block's one
+        program); a longer block's ``seq_<embed|layer|head>_b<B>_t<T>_c<C>``,
+        and for a pattern's kinds ``seq_<ssm|moe>_b<B>_t<T>``,
+        ``seq_gqa_b<B>_t<T>_c<C>``."""
         tag = f"b{batch}_t{block}" + (
             "" if kind in CONTEXT_FREE else f"_c{ctx}")
         return f"seq_{PROGRAM.get(kind, kind)}_{tag}"
 
+    def _lower_turn(self, batch: int, block: int, ctx: int, k: int):
+        """``turn_step`` at the bucket's shapes, its five small operands as
+        one array (``split_operands``), lowered under the name a device
+        trace shows; the token cache, the caches and the counters are
+        donated and come back."""
+        cfg, form = self.cfg, self.form(block)
+
+        def turn(*args):
+            return turn_step(*args[:-1], *split_operands(args[-1], block),
+                             cfg=cfg, form=form, k=k)
+
+        turn.__name__ = turn.__qualname__ = self.program(
+            "turn", batch, block, ctx)
+        # (the CPU backend cannot reuse a donated buffer and says so)
+        keep = self.device.platform == "cpu"
+        with jax.default_device(self.device):
+            return jax.jit(
+                turn, donate_argnums=() if keep else TURN_KEPT).lower(
+                self.params["item_emb"], self.tok_cache,
+                self.params["layers"], self.cache, self.counters,
+                self.params["norm_f"], latent_moe.head_matrix(self.params),
+                jax.ShapeDtypeStruct(
+                    (batch, block + ctx // self.page + 3), jnp.int32))
+
     def _lower(self, batch: int, block: int, ctx: int, skip=()) -> dict:
-        """The bucket's embed program and its layer programs, one a layer
+        """A long bucket's embed program and its layer programs, one a layer
         kind (``"layer"`` where every layer is the same), lowered under the
         names a device trace shows."""
         cfg, page = self.cfg, self.page
@@ -411,27 +522,18 @@ class LatentServing:
 
     def device_scopes(self) -> dict:
         """``{executable name: {HLO instruction: named scope}}`` from the
-        compiled programs' own metadata: a device trace names operations by
-        instruction, and this is what tells ``moe_experts`` from
-        ``mla_attn`` inside one executable. Loops and branches are left out:
-        their time is their bodies' operations', which are in the map."""
+        compiled programs' own metadata (``instruction_scopes``): a device
+        trace names operations by instruction, and this is what tells
+        ``moe_experts`` from ``mla_attn`` inside one executable."""
         out, scopes = {}, latent_moe.scopes(self.cfg)
         for (batch, block, ctx), exes in self._exe.items():
-            for kind in (*dict.fromkeys(self.kinds), "head"):
-                found = {}
-                exe = exes[kind][TOP_K] if kind == "head" else exes[kind]
-                for name, body, op in _INSTRUCTION.findall(exe.as_text()):
-                    if _CONTAINER.search(body):
-                        continue
-                    parts = [p for p in op.split("/") if p in scopes]
-                    if parts:
-                        found[name] = parts[-1]
-                    elif op.startswith("ragged-dot"):
-                        # the TPU compiler's grouped-matmul kernel comes
-                        # back without the scope it was traced under; the
-                        # routed experts are the only grouped matmul here
-                        found[name] = "moe_experts"
-                out["jit_" + self.program(kind, batch, block, ctx)] = found
+            kinds = ("turn",) if "turn" in exes \
+                else (*dict.fromkeys(self.kinds), "head")
+            for kind in kinds:
+                exe = exes[kind][TOP_K] if kind in ("turn", "head") \
+                    else exes[kind]
+                out["jit_" + self.program(kind, batch, block, ctx)] = \
+                    instruction_scopes(exe.as_text(), scopes)
         return out
 
     # -- the session table ---------------------------------------------------------
@@ -637,11 +739,17 @@ class LatentServing:
         return batch, self.blocks[0], _bucket(
             self.shapes.contexts(batch), longest)
 
-    def _head(self, batch: int, block: int, ctx: int, k: int):
-        """The head + top-k executable of a bucket at ``k``; the ladder is
-        warmed at ``TOP_K``, a larger ``num`` compiles its own when first
+    def _at_k(self, batch: int, block: int, ctx: int, k: int):
+        """The executable of a bucket that holds the head + top-k (the short
+        block's turn program, a longer block's head), at ``k``; the ladder
+        is warmed at ``TOP_K``, a larger ``num`` compiles its own when first
         asked for."""
         exe = self._exe[batch, block, ctx]
+        if "turn" in exe:
+            if k not in exe["turn"]:
+                exe["turn"][k] = self._lower_turn(
+                    batch, block, ctx, k).compile()
+            return exe["turn"][k]
         if k not in exe["head"]:
             exe["head"][k] = self._compile_head(batch, block, ctx, k)
         return exe["head"][k]
@@ -650,25 +758,35 @@ class LatentServing:
                   k: int = TOP_K, count: bool = True, head: bool = True):
         """Embed, every layer, and with ``head`` the head + top-k, whose
         ``(values, tokens)`` come back; a piece that is not its block's last
-        only leaves its rows in the cache. Three child spans cover the
-        dispatch, ``seq.turn.*`` for the short block (turns, a cut block's
-        tail, a short miss) and ``seq.miss.*`` for any other: ``stage`` (the
-        operands), ``launch`` (what the host spends issuing the programs)
-        and, with ``head``, ``wait`` (the device finishing and the transfer
-        back; without it nothing is waited for, and the piece's device work
-        runs on under whatever comes next)."""
+        only leaves its rows in the cache. The short block (turns, a cut
+        block's tail, a short miss: always its block's last piece) is ONE
+        launch of the bucket's turn program; any other block is the chain,
+        a launch a layer. Three child spans cover the dispatch,
+        ``seq.turn.*`` for the short block and ``seq.miss.*`` for any other:
+        ``stage`` (the operands), ``launch`` (what the host spends issuing
+        the programs) and, with ``head``, ``wait`` (the device finishing and
+        the transfer back; without it nothing is waited for, and the piece's
+        device work runs on under whatever comes next)."""
         exe = self._exe[batch, block, ctx]
         form = self.form(block)
+        short = block == self.blocks[0]
         n_new = sum(len(b.tokens) - b.offset for b in group)
-        scope = "seq.turn" if block == self.blocks[0] else "seq.miss"
+        scope = "seq.turn" if short else "seq.miss"
+        launches = 1 if short else len(self.kinds) + 1 + head
         with span("seq.batch.extend", bucket=self.label(batch, block, ctx),
                   tokens=n_new, form=form):
             with span(scope + ".stage", sessions=len(group)):
-                tokens = np.zeros((batch, block), np.int32)
-                pages = np.zeros((batch, ctx // self.page), np.int32)
-                offsets = np.zeros((batch,), np.int32)
-                counts = np.zeros((batch,), np.int32)
-                slots = np.zeros((batch,), np.int32)
+                n_pages = ctx // self.page
+                if short:   # the turn program's one operand, filled by part
+                    operands = np.zeros(
+                        (batch, block + n_pages + 3), np.int32)
+                    tokens, pages, offsets, counts, slots = split_operands(
+                        operands, block)
+                else:
+                    tokens, pages, offsets, counts, slots = (
+                        np.zeros(shape, np.int32) for shape in (
+                            (batch, block), (batch, n_pages), (batch,),
+                            (batch,), (batch,)))
                 for i, b in enumerate(group):
                     new = b.tokens[b.offset:]
                     tokens[i, :len(new)] = new
@@ -678,40 +796,49 @@ class LatentServing:
                     offsets[i], counts[i], slots[i] = \
                         b.offset, len(new), b.slot
                 small = (pages, offsets, counts)
-                if self.cfg.layer_pattern:
+                if self.cfg.layer_pattern and not short:
                     # (one transfer for the whole stack's launches, not one
                     # a layer: a pattern is many thin layers)
                     small = jax.device_put(small, self.device)
                     slots = jax.device_put(slots, self.device)
-                own = {kind: (slots if kind in CONTEXT_FREE else small[0],
-                              *small[1:]) for kind in exe}
-            with span(scope + ".launch",
-                      launches=len(self.kinds) + 1 + head):
-                h, self.tok_cache = exe["embed"](
-                    self.params["item_emb"], self.tok_cache, tokens, *small)
-                for i, (kind, lw) in enumerate(
-                        zip(self.kinds, self.params["layers"])):
-                    h, self.cache[i], self.counters[i] = exe[kind](
-                        lw, self.cache[i], self.counters[i], h, *own[kind])
-                out = self._head(batch, block, ctx, k)(
-                    self.params["norm_f"],
-                    latent_moe.head_matrix(self.params),
-                    self.tok_cache, h, *small) if head else None
+            with span(scope + ".launch", launches=launches):
+                if short:
+                    out, self.tok_cache, self.cache, self.counters = \
+                        self._at_k(batch, block, ctx, k)(
+                            self.params["item_emb"], self.tok_cache,
+                            self.params["layers"], self.cache, self.counters,
+                            self.params["norm_f"],
+                            latent_moe.head_matrix(self.params), operands)
+                else:
+                    h, self.tok_cache = exe["embed"](
+                        self.params["item_emb"], self.tok_cache, tokens,
+                        *small)
+                    for i, (kind, lw) in enumerate(
+                            zip(self.kinds, self.params["layers"])):
+                        own = slots if kind in CONTEXT_FREE else small[0]
+                        h, self.cache[i], self.counters[i] = exe[kind](
+                            lw, self.cache[i], self.counters[i], h, own,
+                            *small[1:])
+                    out = self._at_k(batch, block, ctx, k)(
+                        self.params["norm_f"],
+                        latent_moe.head_matrix(self.params),
+                        self.tok_cache, h, *small) if head else None
             if head:
                 with span(scope + ".wait"):
                     out = jax.device_get(out)
         if count:
             _DISPATCHES.labels(bucket=self.label(batch, block, ctx)).inc()
+            _LAUNCHES.labels(block="short" if short else "long").inc(launches)
             _EXPERT_LAYERS.labels(form=self.expert_form).inc(
                 len(self.moe_layers))
-            if block != self.blocks[0]:
+            if not short:
                 _PREFILL_CHUNKS.inc()
             else:
                 _CONTEXT_HELD.inc(sum(len(b.tokens) for b in group))
                 _CONTEXT_READ.inc(batch * ctx)
             if self.n_slots:
                 _STATE_TOKENS.labels(form=form).inc(n_new)
-                if block == self.blocks[0]:
+                if short:
                     _STATE_STEP_SESSIONS.inc(len(group))
             self.block.count_dispatch(self.cfg, [
                 (b.offset, len(b.tokens) - b.offset) for b in group])

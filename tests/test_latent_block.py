@@ -541,8 +541,10 @@ def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
     """A recorded profiler trace of one batch (a short block and a long one)
     holds its ``seq.*`` spans as ``pio.*`` events: the lock, the match, and
     under each ``seq.batch.extend`` its stage, launch and wait, named by the
-    dispatch's kind; the compiled programs carry the six named scopes, by
-    which ``device_scopes`` tells a device trace's operations apart."""
+    dispatch's kind; the compiled programs (the short block's one turn
+    program a bucket, a longer block's layer and head) carry the six named
+    scopes, by which ``device_scopes`` tells a device trace's operations
+    apart."""
     import glob
 
     serving, params, cfg = served
@@ -589,19 +591,25 @@ def test_spans_lie_on_the_profilers_timeline_and_scopes_in_the_programs(
         assert all(s["parentId"] == parent["spanId"] for s in kids)
         assert own[parent["spanId"]] <= 0.05 * parent["durationSec"]
         assert kids[0]["attrs"] == {"sessions": 1}
-        assert kids[1]["attrs"] == {"launches": cfg.n_layers + 2}
+        # the short block is one program, a longer one a launch a layer
+        assert kids[1]["attrs"] == {
+            "launches": 1 if kind == "turn" else cfg.n_layers + 2}
 
     scopes = serving.device_scopes()
     assert set(scopes) == {
+        f"jit_seq_turn_b{b}_t{t}_c{c}" for b, t, c in SHORT_BUCKETS} | {
         f"jit_seq_{kind}_b{b}_t{t}_c{c}" for kind in ("layer", "head")
-        for b, t, c in SHORT_BUCKETS + [(1, 128, 128), (1, 128, 256),
-                                        (1, 256, 256)]}
+        for b, t, c in [(1, 128, 128), (1, 128, 256), (1, 256, 256)]}
+    layer = {"mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared"}
     for module, found in scopes.items():
-        want = {"head_topk"} if "_head_" in module else {
-            "mla_proj", "mla_attn", "moe_router", "moe_experts", "moe_shared"}
+        want = {"head_topk"} if "_head_" in module else layer \
+            if "_layer_" in module else layer | {"head_topk"}
         assert set(found.values()) == want, module
-    text = serving._exe[4, 16, 256]["layer"].as_text()
-    assert re.search(r"HloModule jit_seq_layer_b4_t16_c256\b", text)
+    assert set(serving._exe[4, 16, 256]) == {"turn"}
+    text = serving._exe[4, 16, 256]["turn"][TOP_K].as_text()
+    assert re.search(r"HloModule jit_seq_turn_b4_t16_c256\b", text)
+    text = serving._exe[1, 128, 256]["layer"].as_text()
+    assert re.search(r"HloModule jit_seq_layer_b1_t128_c256\b", text)
     # the block's scope list is what it was before the sparse-index block
     # came to share this module
     assert lm.scopes(cfg) == lm.SCOPES == (

@@ -469,13 +469,18 @@ def test_the_tolerance_catches_a_wrong_selection(served, sessions, monkeypatch):
 def test_scopes_in_the_programs(served):
     serving, _, _ = served
     scopes = serving.device_scopes()
+    # the short block's buckets are one turn program each, the pieces'
+    # their layer and their head
     assert set(scopes) == {
+        f"jit_seq_turn_b{b}_t{t}_c{c}" for b, t, c in serving.ladder()
+        if t == serving.blocks[0]} | {
         f"jit_seq_{kind}_b{b}_t{t}_c{c}" for kind in ("layer", "head")
-        for b, t, c in serving.ladder()}
+        for b, t, c in serving.ladder() if t != serving.blocks[0]}
+    layer = {"gqa_proj", "idx_score", "idx_select", "sparse_attn",
+             "moe_router", "moe_experts"}
     for module, found in scopes.items():
-        want = {"head_topk"} if "_head_" in module else {
-            "gqa_proj", "idx_score", "idx_select", "sparse_attn",
-            "moe_router", "moe_experts"}
+        want = {"head_topk"} if "_head_" in module else layer \
+            if "_layer_" in module else layer | {"head_topk"}
         assert set(found.values()) == want, module
     # a trace shows a loop's operations inside the loop's own event: a map
     # with the loop in it counts the body twice

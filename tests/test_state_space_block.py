@@ -481,10 +481,11 @@ def test_the_match_span_says_how_many_slots_were_taken(served, sessions):
 
 
 def test_a_scan_and_a_step_in_spans(served, sessions):
-    """A pattern's dispatch stages its operands on the device once
+    """A pattern's long block stages its operands on the device once
     (``stage``), issues embed + one program a layer + the head (``launch``)
-    and fetches the answer (``wait``): ``seq.miss.*`` for the scan form's
-    long block, ``seq.turn.*`` for the step from the cached state."""
+    and fetches the answer (``wait``): ``seq.miss.*``, the scan form. The
+    step from the cached state is ``seq.turn.*``: its operands stay numpy
+    and ride the ONE launch of the bucket's turn program."""
     serving, params, cfg = served
     trace.TRACES.clear()
     serving.extend([("sp", sessions[2, :20]), ("sp", sessions[2, :22])])
@@ -494,10 +495,12 @@ def test_a_scan_and_a_step_in_spans(served, sessions):
         "seq.batch.lock", "seq.batch.match",
         *(f"seq.miss.{p}" for p in parts), "seq.batch.extend",
         *(f"seq.turn.{p}" for p in parts), "seq.batch.extend"]
-    for kids, parent in ((spans[2:5], spans[5]), (spans[6:9], spans[9])):
+    for kids, parent, launches in (
+            (spans[2:5], spans[5], len(serving.kinds) + 2),
+            (spans[6:9], spans[9], 1)):
         assert all(s["parentId"] == parent["spanId"] for s in kids)
         assert kids[0]["attrs"] == {"sessions": 1}
-        assert kids[1]["attrs"] == {"launches": len(serving.kinds) + 2}
+        assert kids[1]["attrs"] == {"launches": launches}
     assert [s["attrs"]["form"] for s in (spans[5], spans[9])] \
         == ["scan", "step"]
 
@@ -521,24 +524,33 @@ def test_a_state_kept_in_bfloat16_is_outside_the_tolerance(served, sessions):
 def test_programs_scopes_and_what_a_bucket_shares(served):
     serving, _, _ = served
     scopes = serving.device_scopes()
+    short = [b for b in serving.ladder() if b[1] == serving.blocks[0]]
+    long = [b for b in serving.ladder() if b[1] != serving.blocks[0]]
+    assert len(short) == 6 and long == [(1, 96, 96)]
+    # a short bucket is ONE program, a long one a program a layer kind
     assert set(scopes) == (
-        {f"jit_seq_{kind}_b{b}_t{t}_c{c}" for kind in ("gqa", "head")
-         for b, t, c in serving.ladder()}
+        {f"jit_seq_turn_b{b}_t{t}_c{c}" for b, t, c in short}
+        | {f"jit_seq_{kind}_b{b}_t{t}_c{c}" for kind in ("gqa", "head")
+           for b, t, c in long}
         | {f"jit_seq_{kind}_b{b}_t{t}" for kind in ("ssm", "moe")
-           for b, t, _ in serving.ladder()})
+           for b, t, _ in long})
     want = {"ssm": {"ssm_proj", "ssm_conv", "ssm_scan"},
             "gqa": {"gqa_proj", "gqa_attn"},
             "moe": {"moe_router", "moe_experts", "moe_shared"},
             "head": {"head_topk"}}
+    want["turn"] = set().union(*want.values())
     for module, found in scopes.items():
         assert set(found.values()) == want[module.split("_")[2]], module
     assert lm.scopes(serving.cfg) == (
         "ssm_proj", "ssm_conv", "ssm_scan", "gqa_proj", "gqa_attn",
         "moe_router", "moe_experts", "moe_shared", "head_topk")
-    # a context-free kind is ONE program a (batch, block)
-    assert serving._exe[1, 16, 24]["S"] is serving._exe[1, 16, 96]["S"]
-    assert serving._exe[4, 16, 24]["E"] is serving._exe[4, 16, 48]["E"]
-    assert serving._exe[1, 16, 24]["A"] is not serving._exe[1, 16, 48]["A"]
+    # the short buckets hold the turn program and nothing a layer
+    assert all(set(serving._exe[b]) == {"turn"} for b in short)
+    assert serving._exe[1, 16, 24]["turn"] is not serving._exe[1, 16, 96]["turn"]
+    assert set(serving._exe[1, 96, 96]) == {"embed", "S", "A", "E", "head"}
+    # a long bucket's context-free kinds are ONE program a (batch, block)
+    assert set(serving._shared) == {("S", 1, 96), ("E", 1, 96)}
+    assert serving._shared["S", 1, 96] is serving._exe[1, 96, 96]["S"]
     text = serving._exe[1, 96, 96]["S"].as_text()
     assert re.search(r"HloModule jit_seq_ssm_b1_t96\b", text)
     # the tiles' loop is in a trace as a `while` around its own operations

@@ -8,7 +8,9 @@ compile for a described v5e, as do the latent block's at the Mistral cell's
 width 2048, 786,560 cache rows) in its smallest and its largest turn bucket,
 and the state-space pattern's three layer kinds at the visitor cell's (hidden
 2688, 64 state-space heads of 64 x 128, 32 / 2 heads of 128, 64 of 128 relu2
-experts top-6 of width 1856, 257 state slots, 262,272 cache rows).
+experts top-6 of width 1856, 257 state slots, 262,272 cache rows), and that
+cell's whole turn program (``latent_cache.turn_step``: embed, the 14 layers,
+head + top-k in one executable) in a lone turn's bucket.
 What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
@@ -386,3 +388,130 @@ def test_pattern_layer_compiles_for_v5e(pattern_layers, kind, batch, block,
         # compiler made one of 638 MB at every call)
         assert not re.search(
             r"bf16\[64,(?:2688|1920),\d+\][^\n]* copy\(", text)
+
+
+# -- the visitor cell's turn program: a short dispatch as ONE executable ----------------
+
+@pytest.fixture(scope="module")
+def pattern_turn(one_chip):
+    """``turn_step`` at ``1x16@512`` with the cell's 14 layers of weights,
+    its 8 cache and 12 state arrays, its 6 counters and its token cache, the
+    kept ones donated as ``LatentServing._lower_turn`` donates them."""
+    from incubator_predictionio_tpu.models import latent_moe, state_space
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        TURN_KEPT,
+        turn_step,
+    )
+
+    cfg = _pattern_cfg()
+    kinds = latent_moe.layer_kinds(cfg)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    kept = {
+        "S": {name: s((cfg.state_slots + 1, n), dt)
+              for name, (n, dt) in state_space.state_layout(cfg).items()},
+        "A": {"kv": s((rows, 512), jnp.bfloat16)}, "E": {}}
+    counters = {"S": (), "A": (), "E": s((66,), jnp.int32)}
+    layers = [{k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
+               for k, (shape, f32) in latent_moe.layer_shapes(
+                   cfg, kind).items()} for kind in kinds]
+    emb = s((cfg.vocab_size, cfg.d_model), jnp.bfloat16)
+    batch, block, ctx = 1, 16, 512
+
+    def seq_turn_b1_t16_c512(*args):
+        return turn_step(*args, cfg=cfg, form="step", k=16)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+        compiled = jax.jit(
+            seq_turn_b1_t16_c512, donate_argnums=TURN_KEPT).lower(
+            emb, s((rows,), jnp.int32), layers, [kept[k] for k in kinds],
+            [counters[k] for k in kinds], s((cfg.d_model,), jnp.float32),
+            emb, s((batch, block), jnp.int32),
+            s((batch, ctx // cfg.cache_page), jnp.int32),
+            s((batch,), jnp.int32), s((batch,), jnp.int32),
+            s((batch,), jnp.int32)).compile()
+    return cfg, kinds, rows, compiled
+
+
+def test_pattern_turn_program_keeps_every_cache_in_place(pattern_turn):
+    cfg, kinds, rows, compiled = pattern_turn
+    mem = compiled.memory_analysis()
+    kept = kinds.count("S") * 257 * (64 * 64 * 128 * 4 + 3 * 6144 * 2) \
+        + kinds.count("A") * rows * 512 * 2 + rows * 4 \
+        + kinds.count("E") * 66 * 4
+    assert kept > 3.8e9
+    assert mem.alias_size_in_bytes >= kept       # all donated, none copied
+    # 14 layers' temporaries are no more than a layer's own programs held
+    assert mem.temp_size_in_bytes < 64e6
+    text = compiled.as_text()
+    # no instruction copies an array as long as the states or the rows
+    assert not re.search(
+        rf"= \w+\[(?:257|{rows}),[\d,]*\]\S* copy(?:-start)?\(", text)
+    assert re.search(r"HloModule jit_seq_turn_b1_t16_c512\b", text)
+
+
+def test_pattern_turn_program_carries_every_scope(pattern_turn):
+    """The rooflines' readers find the turn program's operations by the
+    same named scopes as the chain's; the routed experts' twelve grouped
+    matmuls (two a layer, six expert layers) are the Pallas kernel, under
+    ``moe_experts``."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    cfg, kinds, _, compiled = pattern_turn
+    text = compiled.as_text()
+    for scope in latent_moe.scopes(cfg):
+        assert f"/{scope}/" in text, scope
+    calls = re.findall(r'^.*custom_call_target="tpu_custom_call".*$', text,
+                       re.M)
+    assert len(calls) == 2 * kinds.count("E")
+    assert all("/moe_experts/" in call for call in calls)
+    assert "ragged-dot" not in text
+
+
+def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(one_chip):
+    """The Mistral cell's turn program at ``1x16@1024``: the TPU compiler's
+    own grouped-matmul kernel (``ragged_dot``: these widths are multiples of
+    256) comes back named ``…/ragged-dot-none`` with no scope on its path,
+    and ``instruction_scopes`` books all 18 (three a layer) to
+    ``moe_experts``: the experts' roofline reads them through that map."""
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        TURN_KEPT,
+        instruction_scopes,
+        turn_step,
+    )
+
+    cfg = _latent_cfg()
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    layers = [{k: s(shape, jnp.float32 if f32 else bf16)
+               for k, (shape, f32) in latent_moe.layer_shapes(cfg).items()}
+              for _ in range(cfg.n_layers)]
+    caches = [{"latent": s((rows, latent_moe.cache_width(cfg)), bf16)}
+              for _ in range(cfg.n_layers)]
+    emb = s((cfg.vocab_size, cfg.d_model), bf16)
+
+    def seq_turn_b1_t16_c1024(*args):
+        return turn_step(*args, cfg=cfg, form="absorbed", k=16)
+
+    compiled = jax.jit(seq_turn_b1_t16_c1024, donate_argnums=TURN_KEPT).lower(
+        emb, s((rows,), jnp.int32), layers, caches,
+        [s((34,), jnp.int32)] * cfg.n_layers, s((cfg.d_model,), jnp.float32),
+        emb, s((1, 16), jnp.int32), s((1, 1024 // cfg.cache_page), jnp.int32),
+        s((1,), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cfg.n_layers * rows * 384 * 2
+    assert mem.temp_size_in_bytes < 64e6
+    found = instruction_scopes(compiled.as_text(), latent_moe.scopes(cfg))
+    kernels = [name for name in found if name.startswith("ragged-dot-none")]
+    assert len(kernels) == 3 * cfg.n_layers
+    assert all(found[name] == "moe_experts" for name in kernels)
+    assert set(found.values()) == set(latent_moe.scopes(cfg))
