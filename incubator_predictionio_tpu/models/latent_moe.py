@@ -35,11 +35,12 @@ grouped expert matmul, the head and the serving steps below are this
 module's for both kinds.
 
 A config with a ``layer_pattern`` (``attention_kind="gqa"``) makes each layer
-ONE of three things behind one norm (``layer_kinds``): a state-space mixer
-(``"S"``, models/state_space.py), dense grouped-query attention (``"A"``,
-models/sparse_gqa.py's plain part) or this module's router and experts alone
-(``"E"``, ``expert_layer``). The experts' activation is the config's:
-gated SiLU (three matrices) or squared ReLU (two).
+ONE thing behind one norm (``layer_kinds``): a state-space mixer (``"S"``,
+models/state_space.py), dense grouped-query attention (``"A"``,
+models/sparse_gqa.py's plain part), this module's router and experts alone
+(``"E"``, ``expert_layer``), a gated short convolution (``"C"``) or a dense
+gated feed-forward part (``"D"``, both models/short_conv.py). The experts'
+activation is the config's: gated SiLU (three matrices) or squared ReLU (two).
 """
 
 from __future__ import annotations
@@ -172,7 +173,9 @@ def block_of(cfg):
     """The module that holds the config's attention half: ``attention``,
     ``attention_shapes``, ``row_layout``, ``cache_context``,
     ``block_context``, ``serve_shapes``, ``count_dispatch``,
-    ``ATTENTION_SCOPES``, ``DEFAULT_FORM``."""
+    ``ATTENTION_SCOPES``, ``DEFAULT_FORM``; a pattern's block has its
+    letters' ``mixer_shapes``, ``mixer_layer``, ``STEPS``, ``STATEFUL``,
+    ``state_layout`` and ``pattern_scopes`` instead of an attention half."""
     if cfg.attention_kind == "gqa_sparse":
         from incubator_predictionio_tpu.models import sparse_gqa
 
@@ -190,13 +193,17 @@ LAYER = "layer"
 
 def layer_kinds(cfg) -> tuple:
     """What each layer is: the pattern's letters (``"S"`` a state-space
-    mixer, ``"A"`` attention, ``"E"`` experts), or ``LAYER`` for each."""
+    mixer, ``"A"`` attention, ``"E"`` experts, ``"C"`` a gated short
+    convolution, ``"D"`` a dense feed-forward part), or ``LAYER`` for each."""
     return tuple(cfg.layer_pattern) or (LAYER,) * cfg.n_layers
 
 
 def scopes(cfg) -> tuple:
     """Every named scope the config's executables carry."""
-    return block_of(cfg).ATTENTION_SCOPES + MOE_SCOPES
+    block = block_of(cfg)
+    own = block.pattern_scopes(cfg) if cfg.layer_pattern \
+        else block.ATTENTION_SCOPES
+    return own + MOE_SCOPES
 
 
 def experts_held(cfg) -> int:
@@ -620,7 +627,7 @@ def forward(params, tokens, positions, cfg):
             h, _ = expert_layer(lw, h, cfg, valid)
         else:
             h, _ = block.mixer_layer(kind, lw, h, cfg, q_index, valid,
-                                     context)
+                                     context, positions)
     return rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
 
 
@@ -659,6 +666,22 @@ def _block_geometry(pages, offsets, counts, t, page):
     read = (pages[:, :, None] * page + jnp.arange(page)).reshape(b, pc * page)
     key_valid = jnp.arange(pc * page)[None, :] < (offsets + counts)[:, None]
     return q_index, token_valid, write, read, key_valid
+
+
+def slot_rows(kept, slots):
+    """``kept[slots]`` of a per-session ``[slots, values]`` array as one
+    slice a session: a gather over rows of 2 MB made the TPU compiler pass
+    over the WHOLE array (539 MB at 257 slots; PERF.md PR 34)."""
+    return jnp.concatenate([
+        jax.lax.dynamic_slice_in_dim(kept, slots[i], 1)
+        for i in range(slots.shape[0])])
+
+
+def put_slot_rows(kept, slots, rows):
+    for i in range(slots.shape[0]):
+        kept = jax.lax.dynamic_update_slice_in_dim(
+            kept, rows[i:i + 1].astype(kept.dtype), slots[i], 0)
+    return kept
 
 
 def embed_step(item_emb, tok_cache, tokens, pages, offsets, counts, *, page):

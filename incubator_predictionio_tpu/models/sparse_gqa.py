@@ -390,7 +390,10 @@ def block_context(valid, wdt):
 
 def dense_shapes(cfg) -> dict:
     d, h, kv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    return {"w_q": ((d, h * dh), False), "w_k": ((d, kv * dh), False),
+    norms = {"norm_qh": ((dh,), True), "norm_kh": ((dh,), True)} \
+        if cfg.qk_norm else {}
+    return {**norms,
+            "w_q": ((d, h * dh), False), "w_k": ((d, kv * dh), False),
             "w_v": ((d, kv * dh), False), "w_o": ((h * dh, d), False)}
 
 
@@ -421,18 +424,31 @@ def attend_dense(q, rows, q_index, key_valid, cfg, wdt):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, h * dh)
 
 
-def dense_layer(lw, h, cfg, q_index, context):
+def dense_layer(lw, h, cfg, q_index, context, pos=None):
     """``h + W_o attention(norm(h))``; ``context(rows)`` takes the block's
     new key/value rows and returns ``(rows of the context, key_valid,
-    state)``. Returns ``(h, state)``."""
+    state)``. Returns ``(h, state)``. With the config's ``qk_norm`` each
+    head's q and k are RMS-normed (a gain each), with ``attention_rope`` both
+    are rotated at ``pos`` (default ``q_index``: a token's index in its
+    session); the rows kept are the normed, rotated keys and the values."""
     b, t, _ = h.shape
     wdt = lw["w_q"].dtype
     with jax.named_scope("gqa_proj"):
         x = rms_norm(h, lw["norm1"], cfg.rms_norm_eps)
-        q = _mm(x, lw["w_q"]).reshape(b, t, cfg.n_heads, cfg.head_dim) \
-            * cfg.head_dim ** -0.5
-        rows = jnp.concatenate(
-            [_mm(x, lw["w_k"]), _mm(x, lw["w_v"])], -1).astype(wdt)
+        q = _mm(x, lw["w_q"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q = rms_norm(q, lw["norm_qh"], cfg.rms_norm_eps)
+        q = q * cfg.head_dim ** -0.5
+        k = _mm(x, lw["w_k"])
+        if cfg.qk_norm or cfg.attention_rope:
+            k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+            if cfg.qk_norm:
+                k = rms_norm(k, lw["norm_kh"], cfg.rms_norm_eps)
+            if cfg.attention_rope:
+                pos = q_index if pos is None else pos
+                q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
+            k = k.reshape(b, t, -1)
+        rows = jnp.concatenate([k, _mm(x, lw["w_v"])], -1).astype(wdt)
     with jax.named_scope("gqa_attn"):
         ctx, key_valid, state = context(rows)   # cache write and gather
         a = attend_dense(q, ctx, q_index, key_valid, cfg, wdt)

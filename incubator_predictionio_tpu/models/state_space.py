@@ -1,9 +1,11 @@
 """Layer patterns with a selective state-space mixer: the block module of
 ``TransformerConfig(attention_kind="gqa", layer_pattern=...)``. Each layer is
-``x <- x + f(norm(x))`` with ``f`` one of three, in the pattern's order: this
+``x <- x + f(norm(x))`` with ``f`` one thing, in the pattern's order: this
 module's mixer (``"S"``), models/sparse_gqa.py's dense grouped-query
-attention (``"A"``) or models/latent_moe.py's router and experts (``"E"``);
-the norms, the head and the serving steps' geometry are latent_moe's.
+attention (``"A"``), models/latent_moe.py's router and experts (``"E"``) or
+models/short_conv.py's gated short convolution (``"C"``) and dense
+feed-forward part (``"D"``); the norms, the head and the serving steps'
+geometry are latent_moe's.
 
 The mixer (``inner = ssm_heads * ssm_head_dim``, ``G = ssm_groups``, ``N =
 ssm_state``)::
@@ -46,11 +48,32 @@ import math
 import jax
 import jax.numpy as jnp
 
-from incubator_predictionio_tpu.models import latent_moe, sparse_gqa
-from incubator_predictionio_tpu.models.latent_moe import F32, _mm, rms_norm
+from incubator_predictionio_tpu.models import (
+    latent_moe,
+    short_conv,
+    sparse_gqa,
+)
+from incubator_predictionio_tpu.models.latent_moe import (
+    F32,
+    _mm,
+    put_slot_rows,
+    rms_norm,
+    slot_rows,
+)
 
-ATTENTION_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan", "gqa_proj", "gqa_attn")
+#: the named scopes of each letter beside the experts'
+KIND_SCOPES = {"S": ("ssm_proj", "ssm_conv", "ssm_scan"),
+               "A": ("gqa_proj", "gqa_attn"), **short_conv.SCOPES}
+#: the letters whose layers keep a per-session state in a slot
+STATEFUL = ("S", "C")
 HI = jax.lax.Precision.HIGHEST
+
+
+def pattern_scopes(cfg) -> tuple:
+    """The named scopes of the pattern's letters, in the order the letters
+    first appear."""
+    return tuple(s for kind in dict.fromkeys(cfg.layer_pattern)
+                 for s in KIND_SCOPES.get(kind, ()))
 
 
 def published(cfg) -> dict:
@@ -84,9 +107,12 @@ def _conv_width(cfg) -> int:
 
 
 def mixer_shapes(cfg, kind: str) -> dict:
-    """The arrays of an ``"S"`` or an ``"A"`` layer beside its norm."""
+    """The arrays of an ``"S"``, ``"A"``, ``"C"`` or ``"D"`` layer beside
+    its norm."""
     if kind == "A":
         return sparse_gqa.dense_shapes(cfg)
+    if kind in short_conv.STEPS:
+        return short_conv.shapes(cfg, kind)
     d, inner, c, heads = cfg.d_model, _inner(cfg), _conv_width(cfg), \
         cfg.ssm_heads
     return {
@@ -120,9 +146,12 @@ def row_layout(cfg) -> dict:
     return sparse_gqa.dense_row_layout(cfg)
 
 
-def state_layout(cfg) -> dict:
-    """What an ``"S"`` layer keeps for a session, ``{name: (values,
-    dtype)}``: the recurrent state and the convolution's last inputs."""
+def state_layout(cfg, kind: str = "S") -> dict:
+    """What a layer of a ``STATEFUL`` kind keeps for a session, ``{name:
+    (values, dtype)}``: an ``"S"`` layer the recurrent state and its
+    convolution's last inputs, a ``"C"`` layer its convolution's alone."""
+    if kind == "C":
+        return short_conv.state_layout(cfg)
     return {
         "state": (_inner(cfg) * cfg.ssm_state, jnp.dtype(cfg.state_dtype)),
         "conv": ((cfg.conv_kernel - 1) * _conv_width(cfg),
@@ -223,11 +252,15 @@ def mixer(lw, h, cfg, token_valid, counts=None, carried=None):
             (state, kept)
 
 
-def mixer_layer(kind: str, lw, h, cfg, q_index, token_valid, context):
-    """An ``"S"`` or ``"A"`` layer when the block is its own context
-    (``fit``, ``forward``). Returns ``(h, None)``."""
+def mixer_layer(kind: str, lw, h, cfg, q_index, token_valid, context,
+                pos=None):
+    """A pattern's layer other than ``"E"`` when the block is its own
+    context (``fit``, ``forward``; ``pos``: the tokens' positions in their
+    sessions where they are not ``q_index``). Returns ``(h, None)``."""
     if kind == "A":
-        return sparse_gqa.dense_layer(lw, h, cfg, q_index, context)
+        return sparse_gqa.dense_layer(lw, h, cfg, q_index, context, pos)
+    if kind in short_conv.STEPS:
+        return short_conv.layer(kind, lw, h, cfg, token_valid), None
     return mixer(lw, h, cfg, token_valid)[0], None
 
 
@@ -235,22 +268,6 @@ block_context = latent_moe.block_context   # the block is its own context
 
 
 # -- the serving side ----------------------------------------------------------------
-
-def _rows(kept, slots):
-    """``kept[slots]`` as one slice a session: a gather over rows of 2 MB
-    made the TPU compiler pass over the WHOLE array (539 MB at 257 slots;
-    PERF.md PR 34)."""
-    return jnp.concatenate([
-        jax.lax.dynamic_slice_in_dim(kept, slots[i], 1)
-        for i in range(slots.shape[0])])
-
-
-def _put_rows(kept, slots, rows):
-    for i in range(slots.shape[0]):
-        kept = jax.lax.dynamic_update_slice_in_dim(
-            kept, rows[i:i + 1].astype(kept.dtype), slots[i], 0)
-    return kept
-
 
 def mixer_step(lw, cache, counters, h, slots, offsets, counts, *, cfg, form):
     """An ``"S"`` layer of "extend a batch of sessions by a block each": each
@@ -261,20 +278,20 @@ def mixer_step(lw, cache, counters, h, slots, offsets, counts, *, cfg, form):
     token_valid = jnp.arange(h.shape[1])[None, :] < counts[:, None]
     fresh = (offsets == 0)[:, None]
     with jax.named_scope("ssm_scan"):
-        state = jnp.where(fresh, 0.0, _rows(cache["state"], slots)).reshape(
+        state = jnp.where(fresh, 0.0, slot_rows(cache["state"], slots)).reshape(
             b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
     with jax.named_scope("ssm_conv"):
-        kept = jnp.where(fresh, 0.0, _rows(cache["conv"], slots)).reshape(
+        kept = jnp.where(fresh, 0.0, slot_rows(cache["conv"], slots)).reshape(
             b, cfg.conv_kernel - 1, -1).astype(cache["conv"].dtype)
     h, (state, kept) = mixer(lw, h, cfg, token_valid, counts, (state, kept))
     with jax.named_scope("ssm_scan"):
-        new_state = _put_rows(cache["state"], slots, state.reshape(b, -1))
+        new_state = put_slot_rows(cache["state"], slots, state.reshape(b, -1))
     with jax.named_scope("ssm_conv"):
-        new_conv = _put_rows(cache["conv"], slots, kept.reshape(b, -1))
+        new_conv = put_slot_rows(cache["conv"], slots, kept.reshape(b, -1))
     return h, {"state": new_state, "conv": new_conv}, counters
 
 
-STEPS = {"S": mixer_step, "A": sparse_gqa.dense_step}
+STEPS = {"S": mixer_step, "A": sparse_gqa.dense_step, **short_conv.STEPS}
 
 
 def serve_shapes(cfg) -> latent_moe.ServeShapes:

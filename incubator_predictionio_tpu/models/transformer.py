@@ -95,16 +95,26 @@ class TransformerConfig:
     # queries, one key head) that lets a query attend to its index_topk
     # best-scored keys only; the routed experts and everything else of the
     # "mla" block's other half (models/latent_moe.py), router_scoring softmax
-    # "gqa": dense grouped-query attention (n_kv_heads of head_dim, no
-    # positional encoding) as the "A" layers of a layer_pattern, below
+    # "gqa": dense grouped-query attention (n_kv_heads of head_dim; no
+    # positional encoding unless attention_rope, no per-head norm unless
+    # qk_norm) as the "A" layers of a layer_pattern, below
     attention_kind: str = "mha"
     # one letter a layer (n_layers of them), each layer a mixer OR a
     # feed-forward part alone behind one norm: "S" a selective state-space
     # mixer with a causal depthwise convolution before it
     # (models/state_space.py), "A" the attention of attention_kind="gqa",
-    # "E" the router and experts of models/latent_moe.py. "" (every other
-    # attention_kind): every layer is an attention half, then the experts
+    # "E" the router and experts of models/latent_moe.py, "C" a gated short
+    # convolution over conv_kernel taps and "D" a dense gated-SiLU
+    # feed-forward part of intermediate_size (models/short_conv.py). "" (every
+    # other attention_kind): every layer is an attention half, then the experts
     layer_pattern: str = ""
+    # the "A" layers of a pattern: RMSNorm over each head's head_dim values
+    # of q and k (a gain each), and half-split rotary pairs at rope_theta
+    # over the whole head, at a token's index in its session
+    qk_norm: bool = False
+    attention_rope: bool = False
+    # the "D" layers' width
+    intermediate_size: int = 0
     # the "S" layers: ssm_heads x ssm_head_dim inner values a token, a state
     # of ssm_head_dim x ssm_state a head, B and C shared by the heads of each
     # of ssm_groups groups; the convolution sees conv_kernel inputs;
@@ -185,6 +195,11 @@ class TransformerConfig:
                 "a layer_pattern takes its 'A' layers from "
                 "attention_kind='gqa', and that kind is served in a pattern "
                 "only")
+        if (self.qk_norm or self.attention_rope or self.intermediate_size) \
+                and not self.layer_pattern:
+            raise ValueError(
+                "qk_norm, attention_rope and intermediate_size belong to the "
+                "'A' and 'D' layers of a layer_pattern")
         if self.attention_kind == "mla":
             if not self.rope_parameters:
                 raise ValueError(
@@ -205,17 +220,29 @@ class TransformerConfig:
                     f"index_kv_tile={tile} must be whole pages of "
                     f"{self.cache_page} and divide max_len={self.max_len}")
         elif self.attention_kind == "gqa":
-            if (set(self.layer_pattern) - set("SAE")
+            if (set(self.layer_pattern) - set("SAECD")
                     or len(self.layer_pattern) != self.n_layers):
                 raise ValueError(
                     f"layer_pattern={self.layer_pattern!r} is one of 'S' "
-                    "(state-space mixer), 'A' (attention), 'E' (experts) a "
-                    f"layer, n_layers={self.n_layers} of them")
+                    "(state-space mixer), 'A' (attention), 'E' (experts), "
+                    "'C' (gated short convolution), 'D' (dense feed-forward) "
+                    f"a layer, n_layers={self.n_layers} of them")
             if (not self.n_kv_heads or self.n_heads % self.n_kv_heads
                     or not self.head_dim):
                 raise ValueError(
                     "attention_kind='gqa' needs n_kv_heads dividing n_heads "
                     "and a head_dim")
+            if self.attention_rope and self.head_dim % 2:
+                raise ValueError(
+                    "attention_rope turns pairs of a head's values: an even "
+                    "head_dim")
+            if "C" in self.layer_pattern and self.conv_kernel < 2:
+                raise ValueError(
+                    "a 'C' layer needs conv_kernel >= 2 (the taps of its "
+                    "convolution)")
+            if "D" in self.layer_pattern and self.intermediate_size < 1:
+                raise ValueError(
+                    "a 'D' layer needs an intermediate_size")
             inner = self.ssm_heads * self.ssm_head_dim
             if "S" in self.layer_pattern and (
                     not inner or not self.ssm_state or self.ssm_groups < 1

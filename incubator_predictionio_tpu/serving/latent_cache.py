@@ -41,14 +41,16 @@ items.
   the ``select`` form; anything longer is cut into pieces that run in the
   ``chunk`` form, each writing its rows and attending to what the earlier
   pieces cached; only the last piece runs the head.
-- A layer pattern (models/state_space.py) has three kinds of layer. An
+- A layer pattern (models/state_space.py) has five kinds of layer. An
   ``"A"`` layer keeps per-token key/value rows in pages, as above. An ``"S"``
   layer keeps a **per-session state** of fixed size (the recurrent state in
   ``state_dtype`` and the convolution's last inputs, one row each of
-  ``[slots, values]`` arrays a layer): a session owns its pages AND one
-  slot, eviction frees both, slot 0 belongs to nobody (padding lands
-  there), and a block that starts at offset 0 starts from zeros whatever
-  its slot held. An ``"E"`` layer keeps nothing. A state stands at exactly
+  ``[slots, values]`` arrays a layer), a ``"C"`` layer its convolution's
+  last inputs alone (the block's ``state_layout`` a kind sizes a slot): a
+  session owns its pages AND one slot, eviction frees both, slot 0 belongs
+  to nobody (padding lands there), and a block that starts at offset 0
+  starts from zeros whatever its slot held. An ``"E"`` or a ``"D"`` layer
+  keeps nothing. A state stands at exactly
   one position, the length last computed, so the **reuse rule** is: a
   session's state stands at n tokens; an incoming list whose first n tokens
   equal the cached ones and which is LONGER continues from the state
@@ -58,8 +60,9 @@ items.
   prefix did match. (An unchanged list sent again is a restart: nothing of
   the last answer is kept, and the last token cannot be recomputed from a
   state that already holds it.) A long bucket compiles one program a layer
-  kind (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``: no context, shared by
-  the bucket's contexts; ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern
+  kind (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``, ``seq_conv_b<B>_t<T>``,
+  ``seq_ffn_b<B>_t<T>``: no context, shared by the bucket's contexts;
+  ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern
   order; the pieces of a cut block hand the state on through the slot.
 - One dispatch runs at a time (``_TurnLock``), and between the pieces of a
   cut block the lock is offered to whoever waits: another batch's turns run
@@ -151,8 +154,8 @@ _STATE_STEP_SESSIONS = REGISTRY.counter(
 TOP_K = 16                       # the head's k the ladder is warmed for
 #: a pattern's layer kinds: the names of their programs, and which take no
 #: context (one program a (batch, block), shared by the bucket's contexts)
-PROGRAM = {"S": "ssm", "A": "gqa", "E": "moe"}
-CONTEXT_FREE = ("S", "E")
+PROGRAM = {"S": "ssm", "A": "gqa", "E": "moe", "C": "conv", "D": "ffn"}
+CONTEXT_FREE = ("S", "E", "C", "D")
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*)op_name="([^"]*)"', re.M)
 #: control flow has no device time of its own: a trace shows a loop's
@@ -302,11 +305,16 @@ class LatentServing:
         paged = sum(k in (latent_moe.LAYER, "A") for k in self.kinds)
         self.bytes_per_token = paged * sum(self.layout.values()) \
             * wdt.itemsize + 4
-        # what an "S" layer keeps for a session, {name: (values, dtype)}
-        self.state_layout = self.block.state_layout(cfg) \
-            if "S" in self.kinds else {}
-        self.state_bytes_per_session = self.kinds.count("S") * sum(
-            n * dt.itemsize for n, dt in self.state_layout.values())
+        # what a layer of a stateful kind ("S", "C") keeps for a session:
+        # {kind: {name: (values, dtype)}}
+        self.state_layout = {
+            kind: self.block.state_layout(cfg, kind)
+            for kind in (self.block.STATEFUL if cfg.layer_pattern else ())
+            if kind in self.kinds}
+        self.state_bytes_per_session = sum(
+            self.kinds.count(kind) * n * dt.itemsize
+            for kind, layout in self.state_layout.items()
+            for n, dt in layout.values())
         # ``cache_tokens`` is the operator's: live sessions x the length they
         # may reach (default: 16 sessions of ``max_len``); never less than
         # two whole sessions. Page 0 belongs to nobody.
@@ -320,10 +328,10 @@ class LatentServing:
             if self.state_layout else 0
 
         def kept(kind):
-            if kind == "S":
+            if kind in self.state_layout:
                 return {name: jnp.zeros((self.n_slots + 1, n), dt)
-                        for name, (n, dt) in self.state_layout.items()}
-            if kind == "E":
+                        for name, (n, dt) in self.state_layout[kind].items()}
+            if kind not in (latent_moe.LAYER, "A"):
                 return {}
             return {name: jnp.zeros((rows, width), wdt)
                     for name, width in self.layout.items()}
@@ -884,10 +892,10 @@ class LatentServing:
         }
 
     def session_state(self, key: str, layer: int) -> Optional[tuple]:
-        """What an ``"S"`` layer keeps for a session the table holds: ``(the
-        tokens its state stands at, {name: its slot's row})``; ``None`` for
-        a session that is not held or whose block is still being cut. Waits
-        for the dispatch under way. (For tests and for a comparison of the
+        """What an ``"S"`` or ``"C"`` layer keeps for a session the table
+        holds: ``(the tokens its state stands at, {name: its slot's row})``;
+        ``None`` for a session that is not held or whose block is still being
+        cut. Waits for the dispatch under way. (For tests and for a comparison of the
         served state with a reference's: nothing on the serve path reads
         it.)"""
         with self._lock:

@@ -271,9 +271,15 @@ class TransformerAlgorithmParams(Params):
     # sizes follow under the published configs' names (d_model / n_heads /
     # n_layers above); "gqa" with a layer_pattern: one letter a layer, "S" a
     # state-space mixer (ssm_*, conv_kernel), "A" dense grouped-query
-    # attention (num_key_value_heads, head_dim), "E" the routed experts
+    # attention (num_key_value_heads, head_dim; qk_norm: per-head RMSNorm of
+    # q and k; attention_rope: rotary pairs at rope_theta), "E" the routed
+    # experts, "C" a gated short convolution (conv_kernel taps), "D" a dense
+    # gated feed-forward part (intermediate_size)
     attention_kind: str = "mha"
     layer_pattern: str = ""
+    qk_norm: bool = False
+    attention_rope: bool = False
+    intermediate_size: int = 0
     ssm_num_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state_size: int = 0
@@ -345,7 +351,9 @@ class TransformerAlgorithm(PAlgorithm):
                 ssm_head_dim=p.ssm_head_dim, ssm_state=p.ssm_state_size,
                 ssm_groups=p.ssm_groups, conv_kernel=p.conv_kernel,
                 ssm_chunk=p.ssm_chunk_size, state_dtype=p.state_dtype,
-                state_slots=p.state_slots)
+                state_slots=p.state_slots, qk_norm=p.qk_norm,
+                attention_rope=p.attention_rope, rope_theta=p.rope_theta,
+                intermediate_size=p.intermediate_size)
         if p.attention_kind != "mha":
             latent.update(
                 rms_norm_eps=p.rms_norm_eps,

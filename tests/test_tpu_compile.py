@@ -10,7 +10,12 @@ and the state-space pattern's three layer kinds at the visitor cell's (hidden
 2688, 64 state-space heads of 64 x 128, 32 / 2 heads of 128, 64 of 128 relu2
 experts top-6 of width 1856, 257 state slots, 262,272 cache rows), and that
 cell's whole turn program (``latent_cache.turn_step``: embed, the 14 layers,
-head + top-k in one executable) in a lone turn's bucket.
+head + top-k in one executable) in a lone turn's bucket, and the
+short-convolution pattern's at the feed cell's (hidden 2048, 18 convolution
+layers of 3 taps, 6 rotary 32 / 8-head attention layers of 64, 2 dense parts
+of 7168, 16 of 32 experts top-4 of width 1792, 2,049 carry slots, 327,808
+cache rows): its whole 48-sub-block turn program in the widest turn bucket and
+each letter's step over the longest block.
 What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
@@ -515,3 +520,133 @@ def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(one_chip):
     assert len(kernels) == 3 * cfg.n_layers
     assert all(found[name] == "moe_experts" for name in kernels)
     assert set(found.values()) == set(latent_moe.scopes(cfg))
+
+
+# -- the feed cell's stack: the short-convolution pattern at its full depth --------------
+
+def _feed_cfg():
+    """The LFM2 cell's stack, from the cell's own configuration file, as
+    ``benchmarks/engines/seeded_conv.algorithm_params`` binds it."""
+    from benchmarks.engines import seeded_conv
+    from incubator_predictionio_tpu.utils.params import params_from_json
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmarks", "configs", "seq-lfm2-8b-a1b-ep2.json")
+    with open(path) as f:
+        c = json.load(f)
+    algo = seeded_conv.SeededShortConvAlgorithm(params_from_json(
+        seeded_conv.SeededShortConvParams,
+        seeded_conv.algorithm_params(c, 1)))
+    return algo.model_config(c["vocab_size"])
+
+
+def _feed_arguments(one_chip, cfg):
+    from incubator_predictionio_tpu.models import latent_moe, state_space
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    kept = {
+        "C": {name: s((cfg.state_slots + 1, n), dt) for name, (n, dt)
+              in state_space.state_layout(cfg, "C").items()},
+        "A": {"kv": s((rows, 1024), jnp.bfloat16)}, "D": {}, "E": {}}
+    counters = {"C": (), "D": (), "A": (), "E": s((18,), jnp.int32)}
+    layers = {kind: {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
+                     for k, (shape, f32) in latent_moe.layer_shapes(
+                         cfg, kind).items()} for kind in "CDAE"}
+    return s, rows, kept, counters, layers
+
+
+@pytest.fixture(scope="module")
+def feed_turn(one_chip):
+    """``turn_step`` at ``16x16@4096``, the widest turn bucket, with the
+    cell's 48 sub-blocks of weights, its 6 key/value caches, 18 carry arrays
+    and 22 counters, the kept ones donated."""
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        TURN_KEPT,
+        turn_step,
+    )
+
+    cfg = _feed_cfg()
+    kinds = latent_moe.layer_kinds(cfg)
+    s, rows, kept, counters, layers = _feed_arguments(one_chip, cfg)
+    emb = s((cfg.vocab_size, cfg.d_model), jnp.bfloat16)
+    batch, block, ctx = 16, 16, 4096
+
+    def seq_turn_b16_t16_c4096(*args):
+        return turn_step(*args, cfg=cfg, form="step", k=16)
+
+    compiled = jax.jit(
+        seq_turn_b16_t16_c4096, donate_argnums=TURN_KEPT).lower(
+        emb, s((rows,), jnp.int32), [layers[k] for k in kinds],
+        [kept[k] for k in kinds], [counters[k] for k in kinds],
+        s((cfg.d_model,), jnp.float32), emb, s((batch, block), jnp.int32),
+        s((batch, ctx // cfg.cache_page), jnp.int32),
+        s((batch,), jnp.int32), s((batch,), jnp.int32),
+        s((batch,), jnp.int32)).compile()
+    return cfg, kinds, rows, compiled
+
+
+def test_feed_turn_program_holds_the_whole_depth_in_place(feed_turn):
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        instruction_scopes,
+    )
+
+    cfg, kinds, rows, compiled = feed_turn
+    assert "".join(kinds) == "CDCDAE" + "CECECEAE" * 4 + "CECEAECECE"
+    assert (kinds.count("C"), kinds.count("A"), kinds.count("D"),
+            kinds.count("E")) == (18, 6, 2, 22)
+    mem = compiled.memory_analysis()
+    caches = 6 * rows * 1024 * 2 + 18 * (cfg.state_slots + 1) * 4096 * 2
+    assert mem.alias_size_in_bytes >= caches          # donated, not copied
+    # 8.93 GB of weights + 4.33 GB of rows and carries come in; beside them
+    # the widest turn's temporaries stay under half a gigabyte
+    assert 13.4e9 < mem.argument_size_in_bytes < 13.7e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    found = instruction_scopes(text, latent_moe.scopes(cfg))
+    assert set(found.values()) == {
+        "conv_proj", "conv_mix", "ffn_dense", "gqa_proj", "gqa_attn",
+        "moe_router", "moe_experts", "head_topk"}
+    # both expert widths are multiples of 256: XLA's grouped matmul, three a
+    # layer, and no copy of a layer's 16 experts in front of it
+    kernels = [name for name in found if name.startswith("ragged-dot-none")]
+    assert len(kernels) == 3 * 22
+    assert all(found[name] == "moe_experts" for name in kernels)
+    assert "grouped_matmul" not in text       # no Pallas kernel here
+    assert not re.search(r"bf16\[16,(?:2048|1792),\d+\][^\n]* copy\(", text)
+    # what makes a convolution's scopes read under their bytes' floor in a
+    # trace: its weights are copied to fast memory ahead of their use
+    assert re.search(r"bf16\[2048,6144\]\{[^}]*S\(1\)\}", text)
+
+
+@pytest.mark.parametrize("kind", ["C", "D", "A", "E"])
+def test_feed_long_block_letters_compile_for_v5e(one_chip, kind):
+    """Each letter's serving step over the longest block (one session of
+    4,096 items), its cache donated."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    cfg = _feed_cfg()
+    s, rows, kept, counters, layers = _feed_arguments(one_chip, cfg)
+    step = latent_moe.step_of(kind, cfg)
+    own = s((1, 4096 // cfg.cache_page) if kind == "A" else (1,), jnp.int32)
+    compiled = jax.jit(
+        lambda lw, cache, counters, h, own, offsets, counts: step(
+            lw, cache, counters, h, own, offsets, counts, cfg=cfg,
+            form="scan"), donate_argnums=(1, 2, 3)).lower(
+        layers[kind], kept[kind], counters[kind],
+        s((1, 4096, cfg.d_model), jnp.float32), own, s((1,), jnp.int32),
+        s((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    held = {"C": (cfg.state_slots + 1) * 4096 * 2, "A": rows * 1024 * 2,
+            "D": 0, "E": 0}[kind]
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    for scope in {"C": ("conv_proj", "conv_mix"), "D": ("ffn_dense",),
+                  "A": ("gqa_proj", "gqa_attn"),
+                  "E": ("moe_router", "moe_experts")}[kind]:
+        assert f"/{scope}/" in text, scope
