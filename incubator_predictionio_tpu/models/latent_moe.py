@@ -15,9 +15,9 @@ models/reference/mla_moe.py, which this module is held to).
   experts as one grouped matmul a matrix over the ``experts_held`` experts
   this chip holds, token-pick pairs sorted by expert, no capacity and no
   dropped token; picks that fall on an expert held elsewhere add nothing
-  here. The grouped matmul is ``jax.lax.ragged_dot``, or, where the experts'
-  stored widths admit none of its wide tiles and the backend runs the
-  package's kernels, ops/grouped_matmul.py (``expert_form``: the code's own
+  here. The grouped matmul is ops/grouped_matmul.py wherever the backend
+  runs the package's kernels (one form on a TPU, at every served width) and
+  ``jax.lax.ragged_dot`` where it runs none (``expert_form``: the code's own
   choice, the same sum either way). One shared expert beside them.
 
 Weights live in ``weight_dtype`` (bfloat16 when served, float32 when ``fit``
@@ -476,27 +476,19 @@ def _expert(x, lw, names, dot):
     return dot(_expert_hidden(x, lw, names, dot).astype(w2.dtype), w2)
 
 
-#: the lanes of a tile: XLA's grouped matmul walks an expert's matrices in
-#: tiles this wide unless both their widths are whole multiples of twice it
+#: the lanes of a tile: a stored width under it is one of the toy sizes
 LANES = 128
-
-
-def _narrow(stored) -> bool:
-    """Whether XLA's grouped matmul walks the stored ``[held, d, f]`` of the
-    experts' first matrices one lane tile at a time: a width that is no
-    multiple of two lane tiles (and at least one: under that, the toy sizes
-    of the CPU tests, a matrix is a single partial tile either way)."""
-    _, d, f = stored
-    return f >= LANES and (d % (2 * LANES) != 0 or f % (2 * LANES) != 0)
 
 
 def expert_form(stored) -> str:
     """Which grouped matmul the routed experts run, from what the code can
     see: ``"kernel"`` (ops/grouped_matmul.py, whose tiles are divisors of
-    the widths themselves) where the widths are ``_narrow`` and the backend
-    runs the package's kernels, ``"ragged"`` (``jax.lax.ragged_dot``)
-    everywhere else."""
-    return "kernel" if _narrow(stored) and kernel_backend() else "ragged"
+    the stored ``[held, d, f]`` widths themselves) wherever the backend runs
+    the package's kernels and ``f`` is at least one lane tile (under that a
+    matrix is a single partial tile: the toy sizes whose programs the CPU
+    tests pin); ``"ragged"`` (``jax.lax.ragged_dot``) everywhere else: a
+    process with no such backend, the kernel's reference and its backward."""
+    return "kernel" if stored[2] >= LANES and kernel_backend() else "ragged"
 
 
 def moe_experts(x, idx, w, token_valid, lw, cfg):

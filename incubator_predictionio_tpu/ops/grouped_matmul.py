@@ -5,18 +5,21 @@ end to end in ``lhs`` by ``group_sizes``; rows past the last group come back
 zero. This is ``jax.lax.ragged_dot``, which is the reference here and the
 kernel's backward.
 
-The routed experts call it (models/latent_moe.py) where XLA's own grouped
-kernel has no tile that fits: it walks an expert's matrix 128 lanes at a
-time unless both widths are multiples of 256, and at 2688 x 1920 (21 and 15
-lane tiles) that costs 7-14 x the time of reading the matrices it touches
-(measured on the v5e, PERF.md PR 35). Here a tile is a divisor of the width
-itself (384, 640, 896 ... up to the whole matrix), chosen from ``(m, k, n)``
-by ``tiles``: each grid step streams one ``[tk, tn]`` piece of ONE group's
-matrix HBM→VMEM and multiplies the row tile that holds the group's rows
-against it; a group that has no row is never visited, and a row tile that
-several groups share is visited once a group, each visit keeping only its
-own rows (the grid's middle dimension is the number of such visits, known
-on the device only).
+The routed experts call it (models/latent_moe.py) at every served width on
+a backend that runs the package's kernels. XLA's own grouped kernel walks an
+expert's matrix 128 lanes at a time unless both widths are multiples of 256,
+and at 2688 x 1920 (21 and 15 lane tiles) that costs 7-14 x the time of
+reading the matrices it touches (measured on the v5e, PERF.md PR 35); where
+both are (2048 x 1792, 4096 x 2048, 2048 x 768) it still takes 1.7-3.1 x
+this kernel's time from 256 to 4,096 sorted rows, 1.1-2.6 x at 12,288 and
+16,384 (where the operations bind both) and 1.1-1.7 x on a lone turn's
+(PERF.md PR 40). Here a tile is a divisor of the width itself (384, 640,
+896 ... up to the whole matrix), chosen from ``(m, k, n)`` by ``tiles``:
+each grid step streams one ``[tk, tn]`` piece of ONE group's matrix HBM→VMEM
+and multiplies the row tile that holds the group's rows against it; a group
+that has no row is never visited, and a row tile that several groups share
+is visited once a group, each visit keeping only its own rows (the grid's
+middle dimension is the number of such visits, known on the device only).
 """
 
 from __future__ import annotations
@@ -69,7 +72,13 @@ def tiles(m: int, k: int, n: int, itemsize: int = 2) -> tuple:
     reading the touched experts once; pieces of a third 1.28 x, of a fifth
     1.39 x), a third under a lone turn's 96 rows with a few experts touched;
     row tiles of 64 to 256 read alike, 512 is 1.5 x slower (every visit
-    multiplies the whole row tile)."""
+    multiplies the whole row tile). At the other three served widths
+    (PERF.md PR 40) the same rule gives the whole ``[2048, 1792]`` and
+    ``[2048, 768]`` matrix and a half of ``[4096, 2048]`` a piece: 1.15-1.45
+    x the touched experts' bytes up to 2,048 rows, and under a lone row tile
+    (halves of ``[2048, 1792]``, eighths of ``[4096, 2048]``) 1.2-1.45 x;
+    the whole matrix under a lone tile and a row tile of 256 read the
+    same."""
     tm = min(m, LANES)
     budget = LONE_TILE_PIECE_BYTES if m <= LANES else PIECE_BYTES
     tk = _divisor_tile(k, max(LANES, budget // (3 * LANES * itemsize)))

@@ -245,12 +245,22 @@ def _attention_cases(interpret: bool, rng) -> Iterator[dict]:
         rtol=5e-2, atol=5e-2)
 
 
+#: ``(held, d, f, [(sorted rows, experts touched)])`` of the four sequence
+#: cells' routed experts as served: a lone turn's picks, then a long block's
+GROUPED_MATMUL_WIDTHS = (
+    (64, 2688, 1920, [(96, 12), (768, 64), (12_288, 64)]),    # visitor cell
+    (16, 2048, 1792, [(64, 4), (16_384, 16)]),                 # feed cell
+    (32, 4096, 2048, [(64, 4), (12_288, 32)]),                 # Mistral cell
+    (128, 2048, 768, [(128, 24), (16_384, 128)]),              # lifelong cell
+)
+
+
 def _grouped_matmul_cases(interpret: bool, rng) -> Iterator[dict]:
     """``grouped_matmul`` as models/latent_moe.moe_experts calls it at the
-    visitor cell's widths (64 held experts, both matrices, bfloat16): a lone
-    turn's 96 sorted picks on at most 12 experts, a 128-token block's 768
-    and a 2,048-token block's 12,288 over all 64, about half of the rows
-    past the groups (picks on experts held elsewhere sort behind them)."""
+    four sequence cells' widths (the held experts, both matrices, bfloat16):
+    a lone turn's sorted picks on a few experts and a long block's over all
+    of them, about half of the rows past the groups (picks on experts held
+    elsewhere sort behind them)."""
     import jax.numpy as jnp
 
     from incubator_predictionio_tpu.ops.grouped_matmul import (
@@ -258,36 +268,39 @@ def _grouped_matmul_cases(interpret: bool, rng) -> Iterator[dict]:
         grouped_matmul_reference,
     )
 
-    held, d, f = 64, 2688, 1920
-    grid = [(96, 12), (768, held), (12_288, held)]
+    widths = GROUPED_MATMUL_WIDTHS
     if interpret:
-        held, d, f = 8, 256, 384
-        grid = [(96, 3), (300, held)]
-    w1, w2 = (jnp.asarray(rng.normal(size=s) * s[1] ** -0.5, jnp.bfloat16)
-              for s in ((held, d, f), (held, f, d)))
-    for m, touched in grid:
-        sizes = np.zeros(held, np.int32)
-        picks = rng.choice(held, touched, replace=False)
-        np.add.at(sizes, rng.choice(picks, m // 2), 1)
-        for rhs in (w1, w2):
-            k, n = rhs.shape[1:]
-            lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
-            args = (lhs, rhs, jnp.asarray(sizes))
-            total = int(sizes.sum())
+        widths = ((8, 256, 384, [(96, 3), (300, 8)]),
+                  (8, 256, 512, [(64, 2), (300, 8)]))
+    for held, d, f, grid in widths:
+        w1, w2 = (jnp.asarray(
+            rng.standard_normal(s, np.float32) * s[1] ** -0.5, jnp.bfloat16)
+            for s in ((held, d, f), (held, f, d)))
+        for m, touched in grid:
+            sizes = np.zeros(held, np.int32)
+            picks = rng.choice(held, touched, replace=False)
+            np.add.at(sizes, rng.choice(picks, m // 2), 1)
+            for rhs in (w1, w2):
+                k, n = rhs.shape[1:]
+                lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
+                args = (lhs, rhs, jnp.asarray(sizes))
+                total = int(sizes.sum())
 
-            def run(args=args, total=total):
-                got = grouped_matmul(*args, interpret=interpret)
-                want = grouped_matmul_reference(*args)
-                # (past the groups the kernel leaves zeros and XLA's
-                # grouped matmul, on a TPU, whatever it finds)
-                return ((got[:total], got[total:]),
-                        (want[:total], jnp.zeros_like(got[total:])))
+                def run(args=args, total=total):
+                    got = grouped_matmul(*args, interpret=interpret)
+                    want = grouped_matmul_reference(*args)
+                    # (past the groups the kernel leaves zeros and XLA's
+                    # grouped matmul, on a TPU, whatever it finds)
+                    return ((got[:total], got[total:]),
+                            (want[:total], jnp.zeros_like(got[total:])))
 
-            # float32 sums of the same bfloat16 products, in another order
-            yield _case(
-                "grouped_matmul",
-                {"m": m, "k": k, "n": n, "groups": held, "touched": touched},
-                run, rtol=1e-3, atol=1e-3)
+                # float32 sums of the same bfloat16 products, in another
+                # order
+                yield _case(
+                    "grouped_matmul",
+                    {"m": m, "k": k, "n": n, "groups": held,
+                     "touched": touched},
+                    run, rtol=1e-3, atol=1e-3)
 
 
 FAMILIES = (_coarse_cases, _catalog_cases, _adam_cases, _attention_cases,

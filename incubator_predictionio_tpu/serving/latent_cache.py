@@ -122,8 +122,9 @@ _TOUCHED = REGISTRY.counter(
 _EXPERT_LAYERS = REGISTRY.counter(
     "pio_moe_expert_layers_total",
     "Expert layers run by dispatches, by the grouped matmul their programs "
-    "were compiled with (kernel: ops/grouped_matmul.py; ragged: XLA's "
-    "ragged_dot)", ("form",))
+    "were compiled with (kernel: ops/grouped_matmul.py, the one form on a "
+    "TPU; ragged: jax.lax.ragged_dot, a process with no kernel backend)",
+    ("form",))
 _CONTEXT_HELD = REGISTRY.counter(
     "pio_seq_context_rows_held_total",
     "Tokens of the sessions of short-block dispatches, their blocks included")

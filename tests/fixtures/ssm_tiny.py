@@ -18,9 +18,10 @@ def config(**over) -> TransformerConfig:
     """d 64; the pattern SESEAE; 8 state-space heads of 8 with a state of 16
     in 2 groups, convolution over 4, tiles of 8; 4 query / 2 key-value heads
     of 16; 8 sigmoid-routed relu2 experts top-2 of width 160 (stored 256
-    wide: widths that take the Pallas grouped matmul on a backend that runs
-    kernels, ``latent_moe.expert_form``, and ``ragged_dot`` here) and a
-    shared one of 48; pages of 8, max_len 96: context buckets 24 / 48 / 96."""
+    wide: at least one lane tile, so the Pallas grouped matmul on a backend
+    that runs kernels, ``latent_moe.expert_form``, and ``ragged_dot`` here)
+    and a shared one of 48; pages of 8, max_len 96: context buckets 24 / 48
+    / 96."""
     base = dict(
         vocab_size=512, max_len=96, d_model=64, n_heads=4, n_layers=6,
         attention_kind="gqa", layer_pattern="SESEAE", n_kv_heads=2,

@@ -1,10 +1,12 @@
 """ops/grouped_matmul.py under the Pallas interpreter against
 ``jax.lax.ragged_dot``: the widths of the visitor cell's routed experts cut
 to CPU size with the same divisibility (2688 = 21 lane tiles, 1920 = 15, and
-1856 = 14.5: a contraction that is no whole number of them), every group
-layout the sorted picks of a serving block make, the routed experts through
-it with padding tokens and picks on experts held elsewhere, the gradient,
-and the rule that chooses it (``latent_moe.expert_form``)."""
+1856 = 14.5: a contraction that is no whole number of them) and the other
+three sequence cells' (whole multiples of 256 lanes, 16 groups of which half
+have no row), every group layout the sorted picks of a serving block make,
+the routed experts through it with padding tokens and picks on experts held
+elsewhere, the gradient, and the rule that chooses it
+(``latent_moe.expert_form``: the backend, and a floor of one lane tile)."""
 
 import jax
 import jax.numpy as jnp
@@ -52,10 +54,21 @@ def _operands(m, k, n, groups, dtype=jnp.float32, seed=0):
     (300, 256, 384, [1, 1, 1, 1, 1, 200], (128, 128, 384)),
     # bfloat16 operands, float32 sums (what serving runs)
     (288, 256, 640, [17, 0, 60, 3, 0, 90], None),
+    # widths that are whole multiples of 256 lanes (the feed, Mistral and
+    # lifelong cells' kind): 16 groups, half of them with no row, half of
+    # the rows past the groups (picks on the absent chip's experts)
+    (64, 256, 512, [0, 9, 0, 0, 1, 0, 14, 0, 2, 0, 3, 0, 0, 2, 0, 1], None),
+    (64, 512, 256, [0, 9, 0, 0, 1, 0, 14, 0, 2, 0, 3, 0, 0, 2, 0, 1], None),
+    (512, 256, 768, [40, 0, 0, 31, 0, 70, 0, 2, 0, 55, 0, 1, 30, 0, 27, 0],
+     None),
+    (512, 768, 256, [40, 0, 0, 31, 0, 70, 0, 2, 0, 55, 0, 1, 30, 0, 27, 0],
+     (128, 256, 256)),
+    (576, 512, 512, [40, 0, 0, 31, 0, 70, 0, 2, 0, 55, 0, 1, 30, 0, 59, 0],
+     None),
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, (list, tuple))
     else str(v))
 def test_the_kernel_is_ragged_dot(m, k, n, sizes, tiling):
-    dtype = jnp.bfloat16 if m == 288 else jnp.float32
+    dtype = jnp.bfloat16 if m in (288, 576) else jnp.float32
     lhs, rhs = _operands(m, k, n, len(sizes), dtype)
     sizes = jnp.asarray(sizes, jnp.int32)
     got = grouped_matmul(lhs, rhs, sizes, tiling=tiling, interpret=True)
@@ -77,6 +90,14 @@ def test_the_kernel_is_ragged_dot(m, k, n, sizes, tiling):
     (12_288, 1920, 2688, (128, 1920, 2688)),
     (96, 232, 384, (96, 232, 384)),        # no lane-tile divisor: whole
     (2048, 4096, 2048, (128, 4096, 1024)),  # the latent block's: a half
+    # the feed cell's: the whole 7.3 MB matrix a piece from two row tiles
+    # on, two pieces an expert under a lone turn's row tile
+    (512, 2048, 1792, (128, 2048, 1792)),
+    (64, 2048, 1792, (64, 2048, 896)),
+    (512, 1792, 2048, (128, 1792, 2048)),
+    # the sparse-index block's: the whole 3.1 MB matrix at every row count
+    (16_384, 2048, 768, (128, 2048, 768)),
+    (64, 2048, 768, (64, 2048, 768)),
 ])
 def test_tiles_divide_the_widths_they_are_given(m, k, n, want):
     tm, tk, tn = tiles(m, k, n)
@@ -127,20 +148,24 @@ def test_the_gradient_is_ragged_dots():
     assert not np.asarray(got[1][1]).any()       # a group with no row
 
 
-@pytest.mark.parametrize("stored, narrow", [
+@pytest.mark.parametrize("stored, a_tile", [
     ((64, 2688, 1920), True),      # the visitor cell: 21 and 15 lane tiles
     ((64, 2688, 1856), True),
-    ((64, 2048, 1920), True),      # one width is enough
+    ((64, 2048, 1920), True),
     ((64, 2688, 2048), True),
-    ((32, 4096, 2048), False),     # the latent block's experts
-    ((128, 2048, 768), False),     # the sparse-index block's
+    ((32, 4096, 2048), True),      # the latent block's experts
+    ((128, 2048, 768), True),      # the sparse-index block's
+    ((16, 2048, 1792), True),      # the feed cell's (7 x 256)
     ((8, 64, 256), True),          # tests/fixtures/ssm_tiny.py
     ((8, 64, 32), False),          # under one tile: the pinned toy programs
     ((4, 32, 16), False),
 ], ids=str)
 @pytest.mark.parametrize("backend", [None, "interpret", "mosaic"])
 def test_the_expert_form_is_chosen_from_the_widths_and_the_backend(
-        stored, narrow, backend, monkeypatch):
+        stored, a_tile, backend, monkeypatch):
+    """One form on a backend that runs the package's kernels, at every
+    stored width of at least a lane tile; ``ragged_dot`` with no such
+    backend and for the toys under a tile."""
     monkeypatch.setattr(lm, "kernel_backend", lambda: backend)
-    want = "kernel" if narrow and backend else "ragged"
+    want = "kernel" if a_tile and backend else "ragged"
     assert lm.expert_form(stored) == want

@@ -938,8 +938,9 @@ def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
 
 def test_the_expert_kernel_lies_under_the_moe_experts_scope(monkeypatch):
     """Where the routed experts run through the Pallas grouped matmul
-    (stored widths that are no multiples of 256 lanes, on a TPU), its two
-    calls are lowered under ``moe_experts``: ``LatentServing.
+    (a stored width of at least one lane tile, on a backend that runs the
+    package's kernels: every served width on a TPU), its two calls are
+    lowered under ``moe_experts``: ``LatentServing.
     device_scopes()`` places a trace's operations by that path, and the
     experts' roofline reads the scope. Lowered for the TPU from here; no
     ``ragged_dot`` is left, and no activation over every held expert."""

@@ -25,6 +25,7 @@ All such compiles live in THIS file, and the topology is described inside a
 fixture: only the worker that is given this file loads the TPU's library.
 """
 
+import contextlib
 import json
 import math
 import os
@@ -51,6 +52,28 @@ def one_chip():
     except Exception as e:  # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def _chip_kernels():
+    """The code asks the backend which grouped matmul the routed experts
+    run (``latent_moe.expert_form``): this process's is the CPU, the
+    programs compiled here are the chip's."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+        yield
+
+
+def _expert_kernels(text: str) -> list:
+    """The Pallas calls of a compiled program's text, every one of them
+    under the scope the experts' roofline reads; no ``ragged_dot`` is left."""
+    calls = re.findall(r'^.*custom_call_target="tpu_custom_call".*$', text,
+                       re.M)
+    assert all("/moe_experts/" in call for call in calls)
+    assert "ragged-dot" not in text
+    return calls
 
 
 def _rerank_args(one_chip, bucket, row_mask):
@@ -143,10 +166,12 @@ def test_sparse_index_layer_compiles_for_v5e_at_context_32768(
         return latent_moe.layer_step(lw, cache, counters, h, pages, offsets,
                                      counts, cfg=cfg, form=form)
 
-    compiled = jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
-        lw, cache, s((130,), jnp.int32), s((batch, block, 2048), jnp.float32),
-        s((batch, ctx // cfg.cache_page), jnp.int32), s((batch,), jnp.int32),
-        s((batch,), jnp.int32)).compile()
+    with _chip_kernels():
+        compiled = jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
+            lw, cache, s((130,), jnp.int32),
+            s((batch, block, 2048), jnp.float32),
+            s((batch, ctx // cfg.cache_page), jnp.int32),
+            s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
     mem = compiled.memory_analysis()
     cache_bytes = rows * (1024 + 128) * 2
     assert mem.alias_size_in_bytes >= cache_bytes       # donated, not copied
@@ -159,6 +184,7 @@ def test_sparse_index_layer_compiles_for_v5e_at_context_32768(
     for scope in ("gqa_proj", "idx_score", "idx_select", "sparse_attn",
                   "moe_router", "moe_experts"):
         assert f"/{scope}/" in text, scope
+    assert len(_expert_kernels(text)) == 3
 
 
 # -- the latent block's layer in a lone turn's bucket and in the parent's one ------
@@ -215,12 +241,14 @@ def latent_turn_layers(one_chip):
                                      counts, cfg=cfg, form=ladder.short_form)
 
     assert (cfg.d_model, cache["latent"].shape[1]) == (4096, 384)
-    return rows, {
-        (batch, ctx): jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
-            lw, cache, s((34,), jnp.int32), s((batch, 16, 4096), jnp.float32),
-            s((batch, ctx // cfg.cache_page), jnp.int32),
-            s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
-        for batch, ctx in ((1, 1024), (4, 4096))}
+    with _chip_kernels():
+        return rows, {
+            (batch, ctx): jax.jit(layer, donate_argnums=(1, 2, 3)).lower(
+                lw, cache, s((34,), jnp.int32),
+                s((batch, 16, 4096), jnp.float32),
+                s((batch, ctx // cfg.cache_page), jnp.int32),
+                s((batch,), jnp.int32), s((batch,), jnp.int32)).compile()
+            for batch, ctx in ((1, 1024), (4, 4096))}
 
 
 @pytest.mark.parametrize("batch, ctx", [(1, 1024), (4, 4096)])
@@ -237,6 +265,7 @@ def test_latent_turn_layer_compiles_for_v5e(latent_turn_layers, batch, ctx):
     for scope in ("mla_proj", "mla_attn", "moe_router", "moe_experts",
                   "moe_shared"):
         assert f"/{scope}/" in text, scope
+    assert len(_expert_kernels(text)) == 3
 
 
 def _scope_array_bytes(text: str, scope: str, but_rows: int) -> int:
@@ -333,10 +362,7 @@ def pattern_layers(one_chip):
     assert kept["S"]["conv"].shape == (257, 3 * 6144)
     counters = {"S": (), "A": (), "E": s((66,), jnp.int32)}
     out = {}
-    # (the code asks the backend which grouped matmul to build: this
-    # process's is the CPU, the programs are the chip's)
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+    with _chip_kernels():
         for batch, block, ctx in PATTERN_BUCKETS:
             for kind in "SAE":
                 lw = {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
@@ -429,8 +455,7 @@ def pattern_turn(one_chip):
     def seq_turn_b1_t16_c512(*args):
         return turn_step(*args, cfg=cfg, form="step", k=16)
 
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(latent_moe, "kernel_backend", lambda: "mosaic")
+    with _chip_kernels():
         compiled = jax.jit(
             seq_turn_b1_t16_c512, donate_argnums=TURN_KEPT).lower(
             emb, s((rows,), jnp.int32), layers, [kept[k] for k in kinds],
@@ -477,12 +502,34 @@ def test_pattern_turn_program_carries_every_scope(pattern_turn):
     assert "ragged-dot" not in text
 
 
-def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(one_chip):
-    """The Mistral cell's turn program at ``1x16@1024``: the TPU compiler's
-    own grouped-matmul kernel (``ragged_dot``: these widths are multiples of
-    256) comes back named ``…/ragged-dot-none`` with no scope on its path,
-    and ``instruction_scopes`` books all 18 (three a layer) to
-    ``moe_experts``: the experts' roofline reads them through that map."""
+def _latent_turn():
+    """The Mistral cell's turn program at ``1x16@1024``: ``(cfg, a layer's
+    cache widths by row kind, its counters, the short form, the context)``."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    cfg = _latent_cfg()
+    return cfg, {"latent": latent_moe.cache_width(cfg)}, 34, "absorbed", 1024
+
+
+def _sparse_turn():
+    """The lifelong cell's turn program at ``1x16@4096``, the smallest
+    context bucket of its ladder."""
+    from incubator_predictionio_tpu.models import sparse_gqa
+
+    cfg = _sparse_cfg()
+    return cfg, sparse_gqa.row_layout(cfg), 130, "select", 4096
+
+
+@pytest.mark.parametrize("bucket", [_latent_turn, _sparse_turn],
+                         ids=["latent", "sparse_index"])
+def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(
+        one_chip, bucket):
+    """The Mistral and the lifelong cells' turn programs: the routed
+    experts' grouped matmuls (three a layer) are the Pallas kernel of
+    ``ops/grouped_matmul.py`` at these widths too (multiples of 256 lanes:
+    XLA's own ``ragged_dot`` until PR 40), every call under ``moe_experts``,
+    and ``instruction_scopes`` books them there: the experts' roofline reads
+    them through that map."""
     from incubator_predictionio_tpu.models import latent_moe
     from incubator_predictionio_tpu.serving.latent_cache import (
         TURN_KEPT,
@@ -490,7 +537,7 @@ def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(one_chip):
         turn_step,
     )
 
-    cfg = _latent_cfg()
+    cfg, layout, n_counters, form, ctx = bucket()
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -500,26 +547,34 @@ def test_latent_turn_programs_grouped_matmuls_read_as_the_experts(one_chip):
     layers = [{k: s(shape, jnp.float32 if f32 else bf16)
                for k, (shape, f32) in latent_moe.layer_shapes(cfg).items()}
               for _ in range(cfg.n_layers)]
-    caches = [{"latent": s((rows, latent_moe.cache_width(cfg)), bf16)}
+    caches = [{kind: s((rows, width), bf16) for kind, width in layout.items()}
               for _ in range(cfg.n_layers)]
     emb = s((cfg.vocab_size, cfg.d_model), bf16)
 
-    def seq_turn_b1_t16_c1024(*args):
-        return turn_step(*args, cfg=cfg, form="absorbed", k=16)
+    def seq_turn(*args):
+        return turn_step(*args, cfg=cfg, form=form, k=16)
 
-    compiled = jax.jit(seq_turn_b1_t16_c1024, donate_argnums=TURN_KEPT).lower(
-        emb, s((rows,), jnp.int32), layers, caches,
-        [s((34,), jnp.int32)] * cfg.n_layers, s((cfg.d_model,), jnp.float32),
-        emb, s((1, 16), jnp.int32), s((1, 1024 // cfg.cache_page), jnp.int32),
-        s((1,), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32)).compile()
+    with _chip_kernels():
+        compiled = jax.jit(seq_turn, donate_argnums=TURN_KEPT).lower(
+            emb, s((rows,), jnp.int32), layers, caches,
+            [s((n_counters,), jnp.int32)] * cfg.n_layers,
+            s((cfg.d_model,), jnp.float32), emb, s((1, 16), jnp.int32),
+            s((1, ctx // cfg.cache_page), jnp.int32), s((1,), jnp.int32),
+            s((1,), jnp.int32), s((1,), jnp.int32)).compile()
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= cfg.n_layers * rows * 384 * 2
+    # every layer's cache donated, not copied
+    assert mem.alias_size_in_bytes >= \
+        cfg.n_layers * rows * sum(layout.values()) * 2
     assert mem.temp_size_in_bytes < 64e6
-    found = instruction_scopes(compiled.as_text(), latent_moe.scopes(cfg))
-    kernels = [name for name in found if name.startswith("ragged-dot-none")]
+    text = compiled.as_text()
+    assert len(_expert_kernels(text)) == 3 * cfg.n_layers
+    found = instruction_scopes(text, latent_moe.scopes(cfg))
+    kernels = [name for name in found if name.startswith("grouped_matmul")]
     assert len(kernels) == 3 * cfg.n_layers
     assert all(found[name] == "moe_experts" for name in kernels)
-    assert set(found.values()) == set(latent_moe.scopes(cfg))
+    # (the sparse-index block has no shared expert)
+    assert set(found.values()) == set(latent_moe.scopes(cfg)) - (
+        set() if cfg.n_shared_experts else {"moe_shared"})
 
 
 # -- the feed cell's stack: the short-convolution pattern at its full depth --------------
@@ -578,14 +633,16 @@ def feed_turn(one_chip):
     def seq_turn_b16_t16_c4096(*args):
         return turn_step(*args, cfg=cfg, form="step", k=16)
 
-    compiled = jax.jit(
-        seq_turn_b16_t16_c4096, donate_argnums=TURN_KEPT).lower(
-        emb, s((rows,), jnp.int32), [layers[k] for k in kinds],
-        [kept[k] for k in kinds], [counters[k] for k in kinds],
-        s((cfg.d_model,), jnp.float32), emb, s((batch, block), jnp.int32),
-        s((batch, ctx // cfg.cache_page), jnp.int32),
-        s((batch,), jnp.int32), s((batch,), jnp.int32),
-        s((batch,), jnp.int32)).compile()
+    with _chip_kernels():
+        compiled = jax.jit(
+            seq_turn_b16_t16_c4096, donate_argnums=TURN_KEPT).lower(
+            emb, s((rows,), jnp.int32), [layers[k] for k in kinds],
+            [kept[k] for k in kinds], [counters[k] for k in kinds],
+            s((cfg.d_model,), jnp.float32), emb,
+            s((batch, block), jnp.int32),
+            s((batch, ctx // cfg.cache_page), jnp.int32),
+            s((batch,), jnp.int32), s((batch,), jnp.int32),
+            s((batch,), jnp.int32)).compile()
     return cfg, kinds, rows, compiled
 
 
@@ -611,12 +668,13 @@ def test_feed_turn_program_holds_the_whole_depth_in_place(feed_turn):
     assert set(found.values()) == {
         "conv_proj", "conv_mix", "ffn_dense", "gqa_proj", "gqa_attn",
         "moe_router", "moe_experts", "head_topk"}
-    # both expert widths are multiples of 256: XLA's grouped matmul, three a
-    # layer, and no copy of a layer's 16 experts in front of it
-    kernels = [name for name in found if name.startswith("ragged-dot-none")]
+    # the routed experts' grouped matmuls are the Pallas kernel, three a
+    # layer, booked to the experts' scope, and no copy of a layer's 16
+    # experts in front of it
+    assert len(_expert_kernels(text)) == 3 * 22
+    kernels = [name for name in found if name.startswith("grouped_matmul")]
     assert len(kernels) == 3 * 22
     assert all(found[name] == "moe_experts" for name in kernels)
-    assert "grouped_matmul" not in text       # no Pallas kernel here
     assert not re.search(r"bf16\[16,(?:2048|1792),\d+\][^\n]* copy\(", text)
     # what makes a convolution's scopes read under their bytes' floor in a
     # trace: its weights are copied to fast memory ahead of their use
@@ -633,13 +691,14 @@ def test_feed_long_block_letters_compile_for_v5e(one_chip, kind):
     s, rows, kept, counters, layers = _feed_arguments(one_chip, cfg)
     step = latent_moe.step_of(kind, cfg)
     own = s((1, 4096 // cfg.cache_page) if kind == "A" else (1,), jnp.int32)
-    compiled = jax.jit(
-        lambda lw, cache, counters, h, own, offsets, counts: step(
-            lw, cache, counters, h, own, offsets, counts, cfg=cfg,
-            form="scan"), donate_argnums=(1, 2, 3)).lower(
-        layers[kind], kept[kind], counters[kind],
-        s((1, 4096, cfg.d_model), jnp.float32), own, s((1,), jnp.int32),
-        s((1,), jnp.int32)).compile()
+    with _chip_kernels():
+        compiled = jax.jit(
+            lambda lw, cache, counters, h, own, offsets, counts: step(
+                lw, cache, counters, h, own, offsets, counts, cfg=cfg,
+                form="scan"), donate_argnums=(1, 2, 3)).lower(
+            layers[kind], kept[kind], counters[kind],
+            s((1, 4096, cfg.d_model), jnp.float32), own, s((1,), jnp.int32),
+            s((1,), jnp.int32)).compile()
     mem = compiled.memory_analysis()
     held = {"C": (cfg.state_slots + 1) * 4096 * 2, "A": rows * 1024 * 2,
             "D": 0, "E": 0}[kind]
@@ -650,3 +709,5 @@ def test_feed_long_block_letters_compile_for_v5e(one_chip, kind):
                   "A": ("gqa_proj", "gqa_attn"),
                   "E": ("moe_router", "moe_experts")}[kind]:
         assert f"/{scope}/" in text, scope
+    # 16,384 sorted picks through the kernel, three matrices
+    assert len(_expert_kernels(text)) == (3 if kind == "E" else 0)
