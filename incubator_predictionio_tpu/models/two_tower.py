@@ -692,9 +692,11 @@ class TwoTowerMF:
             key = jax.random.key(cfg.seed)
             ku, ki = jax.random.split(key)
             scale = 1.0 / np.sqrt(cfg.rank)
-            # biases live as the LAST COLUMN of each table: TPU gathers operate
-            # on rows — a separate 1-D bias table means 65k scalar gathers per
-            # step, measured ~3× the cost of the whole [B, rank] row gather.
+            # biases live as the LAST COLUMN of each table: the fused row is
+            # the stored and served layout (checkpoint, deploy, row updates,
+            # the serving gathers fetch vector + bias in one row). What the
+            # step loop carries is _carry_cols' choice; it splits and joins
+            # inside _train_epochs and nothing here sees it.
             #
             # The tables materialize through ShardedTable (sharding/table.py):
             # rows padded to the model-axis multiple and row-sharded via
@@ -720,7 +722,9 @@ class TwoTowerMF:
 
             # phase fence: on-device table/moment init bills to init
             jax.block_until_ready((params, opt_state))
-        with span("train.fit.compute") as sp_compute:
+        cols = _carry_cols(cfg.rank)
+        with span("train.fit.compute", carry_cols=cols,
+                  carry_pad_pct=round(_carry_pad_pct(cols), 1)) as sp_compute:
             # distributed members checkpoint by owned slice and fence-check at
             # every chunk boundary (DistContext.dist_hooks); a plain ctx has no
             # hooks and trains exactly as before
@@ -1198,6 +1202,28 @@ def _join_batches(blocks, n_batches, batch, out):
         for bs in zip(*blocks))
 
 
+def _carry_cols(rank: int) -> int:
+    """Columns of the table the step loop carries. A fused ``[rows, rank +
+    1]`` row (bias last) is one gather a table and a step, so the loop
+    carries it wherever the bias column costs no lane tile (rank 10, 64,
+    200). Where it does (rank 128: ``[rows, 129]`` holds two tiles a row,
+    the second with one live column, and dense adam's passes over p, m, v
+    move both) the loop carries the embedding ``[rows, rank]`` and the bias
+    ``[rows]`` apart. On a v5e (PERF.md section 6, PR 43): 1M x 100k rows at
+    rank 128, 17.55 ms a step fused and 10.90 split, the bias look-ups
+    (65,536 scalar gathers and their scatter-adds a table) included; at 100k
+    x 10k rows and rank 64 the same look-ups make the split step 3.85 ms
+    against 1.94 fused."""
+    # rank + 1 crosses a multiple of 128 lanes that rank does not
+    return rank if rank % 128 == 0 else rank + 1
+
+
+def _carry_pad_pct(cols: int) -> float:
+    """Padded lanes per 100 live ones of a float32 ``[rows, cols]`` array
+    under the TPU's ``(8, 128)`` tile: 0.0 at 128, 98.4 at 129."""
+    return 100.0 * (-cols % 128) / cols
+
+
 @partial(jax.jit, static_argnames=("lr", "reg", "n_epochs"), donate_argnums=(0, 1))
 def _train_epochs(p, o, ub, ib, rb, wb, lr, reg, n_epochs):
     """``n_epochs`` epochs in one dispatch: lax.scan over epochs of lax.scan
@@ -1206,22 +1232,49 @@ def _train_epochs(p, o, ub, ib, rb, wb, lr, reg, n_epochs):
     static (lr, reg, n_epochs) so repeated fits of the same shapes reuse one
     executable. Returns the last epoch's mean loss. Adam runs through
     utils/optim.adam_apply (optax-equivalent math; moment storage dtype —
-    fp32 or bf16 — is carried by the state ``o`` itself)."""
+    fp32 or bf16 — is carried by the state ``o`` itself).
+
+    ``p`` and the moments of ``o`` come in and go out as the fused
+    ``[rows, rank + 1]`` tables every other module stores and serves (bias
+    in the last column). What the loop carries follows :func:`_carry_cols`:
+    the fused table, or ``(embedding [rows, rank], bias [rows])`` split at
+    entry and joined at exit, two passes a dispatch and none a step."""
     from incubator_predictionio_tpu.utils.optim import adam_apply
 
+    rank = p["ue"].shape[1] - 1
+    apart = _carry_cols(rank) == rank
+
+    def split(tree):
+        if not apart:
+            return tree
+        return {k: (t[:, :-1], t[:, -1]) for k, t in tree.items()}
+
+    def join(tree):
+        if not apart:
+            return tree
+        return {k: jnp.concatenate([e, b[:, None]], axis=1)
+                for k, (e, b) in tree.items()}
+
+    def emb(g):     # of a batch's gathered rows, as the carry holds them
+        return g[0] if apart else g[:, :-1]
+
+    def bias(g):
+        return g[1] if apart else g[:, -1]
+
     def loss_fn(p, bu, bi, br, bw):
-        # one ROW gather per table fetches vector + bias together (bias is
-        # the last column — see fit); no 1-D scalar gathers on the hot path.
         # batches are user-sorted at staging, so the user-table gather (and
-        # its transpose scatter-add) walks the big table quasi-sequentially
+        # its transpose scatter-add) walks the big table quasi-sequentially.
+        # A fused table gives vector + bias in one ROW gather; a split one
+        # looks its bias up as scalars (see _carry_cols for what each costs)
         with jax.named_scope("gather"):
-            gu = jnp.take(p["ue"], bu, axis=0, indices_are_sorted=True)
-            gi = p["ie"][bi]
-        ue = gu[:, :-1].astype(jnp.bfloat16)
-        ie = gi[:, :-1].astype(jnp.bfloat16)
+            gu = jax.tree.map(lambda t: jnp.take(
+                t, bu, axis=0, indices_are_sorted=True), p["ue"])
+            gi = jax.tree.map(lambda t: t[bi], p["ie"])
+        ue = emb(gu).astype(jnp.bfloat16)
+        ie = emb(gi).astype(jnp.bfloat16)
         pred = (
             jnp.sum(ue * ie, axis=-1).astype(jnp.float32)
-            + gu[:, -1] + gi[:, -1]
+            + bias(gu) + bias(gi)
         )
         err = (pred - br) ** 2
         denom = jnp.maximum(jnp.sum(bw), 1.0)
@@ -1250,8 +1303,10 @@ def _train_epochs(p, o, ub, ib, rb, wb, lr, reg, n_epochs):
         carry, losses = jax.lax.scan(step, carry, (ub, ib, rb, wb))
         return carry, losses.mean()
 
-    (p, o), epoch_losses = jax.lax.scan(epoch, (p, o), None, length=n_epochs)
-    return p, o, epoch_losses[-1]
+    count, m, v = o
+    (p, (count, m, v)), epoch_losses = jax.lax.scan(
+        epoch, (split(p), (count, split(m), split(v))), None, length=n_epochs)
+    return join(p), (count, join(m), join(v)), epoch_losses[-1]
 
 
 @partial(jax.jit, static_argnames=("num",))

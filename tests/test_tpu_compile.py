@@ -711,3 +711,35 @@ def test_feed_long_block_letters_compile_for_v5e(one_chip, kind):
         assert f"/{scope}/" in text, scope
     # 16,384 sorted picks through the kernel, three matrices
     assert len(_expert_kernels(text)) == (3 if kind == "E" else 0)
+
+
+# -- the two-tower train loop (models/two_tower.py) ---------------------------
+
+def test_train_epochs_carries_128_lane_tables_for_v5e(one_chip):
+    """A tenth of the train cell's tables (100,000 x 129 / 10,000 x 129, 8
+    batches of 65,536): the step loop carries each table as embedding +
+    bias, so a row is ONE 128-lane tile where the fused ``[rows, 129]``
+    carry held two, and the program's temporaries are a fraction of the
+    474 MB the fused carry's padded copies took at these shapes."""
+    from incubator_predictionio_tpu.models import two_tower
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"ue": s((100_000, RANK + 1)), "ie": s((10_000, RANK + 1))}
+    idx, val = s((8, 65_536), jnp.int32), s((8, 65_536))
+    compiled = two_tower._train_epochs.lower(
+        p, (s((), jnp.int32), dict(p), dict(p)), idx, idx, val, val,
+        0.03, 0.5, 2).compile()
+    loops = re.findall(r"^\s*%?while[.\d]* = \((.*?)\) while\(",
+                       compiled.as_text(), re.M)
+    assert len(loops) == 2   # epochs of steps
+    for carry in loops:
+        for rows in (100_000, 10_000):
+            assert carry.count(f"f32[{rows},128]{{1,0:T(8,128)}}") == 3
+            assert carry.count(f"f32[{rows}]{{") == 3      # p, m, v: bias
+        assert ",129]" not in carry
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 100e6
+    # the fused tables come in and go out, donated
+    assert mem.alias_size_in_bytes >= 3 * 110_000 * (RANK + 1) * 4
