@@ -257,7 +257,14 @@ class TwoTowerModel:
                 # the member-order rerank tables — the k-means is skipped
                 self._ivf.rehydrate(*self._host_item_table())
             return
-        self._ivf = ann.build_ivf(*self._host_item_table(), key=key)
+        if self.item_emb is None:
+            # the build reads the item table where it lives: a
+            # device-resident model's fused rows go in as they are, and of
+            # the catalog only the finished member-order tables come back
+            self._ivf = ann.build_ivf_fused(
+                self._tables["ie"], self._n_items, key)
+        else:
+            self._ivf = ann.build_ivf(self.item_emb, self.item_bias, key=key)
 
     def _host_item_table(self) -> tuple[np.ndarray, np.ndarray]:
         """Host ``(item_emb, item_bias)`` WITHOUT materializing the full

@@ -29,7 +29,11 @@ See docs/serving.md ("Batched serving & mask compilation",
 "Two-stage retrieval").
 """
 
-from incubator_predictionio_tpu.serving.ann import IVFIndex, build_ivf
+from incubator_predictionio_tpu.serving.ann import (
+    IVFIndex,
+    build_ivf,
+    build_ivf_fused,
+)
 from incubator_predictionio_tpu.serving.cache import TTLCache, constraint_ttl_sec
 from incubator_predictionio_tpu.serving.masks import (
     CategoryIndex,
@@ -46,6 +50,7 @@ __all__ = [
     "TTLCache",
     "ban_rows",
     "build_ivf",
+    "build_ivf_fused",
     "constraint_ttl_sec",
     "grouped_topk",
     "topk_row",

@@ -5,7 +5,8 @@ Exact serving scores every query against the whole catalog — an O(catalog)
 allows" at the 10M-item shapes ALX (arxiv 2112.02194) targets. This module
 is the coarse-to-fine answer:
 
-- **Build** (deploy time, :func:`build_ivf`): k-means over the item
+- **Build** (train or deploy time, :func:`build_ivf_fused`; jitted programs
+  on the device that holds the item table): k-means over the item
   embeddings *augmented with the item bias as an extra coordinate* (the
   query side implicitly carries a 1.0 there, so a centroid's coarse score
   ``q·c_emb + c_bias`` is an unbiased estimate of its members' exact
@@ -53,10 +54,6 @@ import numpy as np
 from incubator_predictionio_tpu.obs.metrics import REGISTRY
 from incubator_predictionio_tpu.obs.trace import span
 from incubator_predictionio_tpu.serving.topk import topk_row
-
-#: Rows per chunk for the full-catalog assignment pass at build time — keeps
-#: the [chunk, C] distance buffer bounded regardless of catalog size.
-ASSIGN_CHUNK = 131_072
 
 COARSE_SEC = REGISTRY.histogram(
     "pio_retrieval_coarse_seconds",
@@ -863,78 +860,87 @@ class IVFIndex:
 
 # -- build -------------------------------------------------------------------
 
-def _assign(x: np.ndarray, cent: np.ndarray,
-            chunk: int = ASSIGN_CHUNK) -> np.ndarray:
-    """Nearest-centroid (euclidean) assignment, chunked over rows."""
-    half = 0.5 * np.einsum("cd,cd->c", cent, cent)
-    out = np.empty(len(x), np.int32)
-    for lo in range(0, len(x), chunk):
-        d = x[lo:lo + chunk] @ cent.T
-        d -= half[None, :]
-        out[lo:lo + chunk] = np.argmax(d, axis=1)
-    return out
-
-
-def _kmeans(x: np.ndarray, c: int, iters: int,
-            rng: np.random.Generator) -> np.ndarray:
-    """Lloyd's k-means on (a sample of) the augmented rows. Per-dimension
-    ``bincount`` accumulation keeps the update pass in C loops; empty
-    clusters reseed from random rows so every centroid stays live."""
-    cent = x[rng.choice(len(x), size=c, replace=False)].copy()
-    d = x.shape[1]
-    for _ in range(iters):
-        a = _assign(x, cent)
-        counts = np.bincount(a, minlength=c).astype(np.float64)
-        for j in range(d):
-            cent[:, j] = np.bincount(a, weights=x[:, j], minlength=c)
-        live = counts > 0
-        cent[live] /= counts[live, None]
-        n_dead = int((~live).sum())
-        if n_dead:
-            cent[~live] = x[rng.choice(len(x), size=n_dead, replace=False)]
-    return cent
-
-
 def build_ivf(item_emb: np.ndarray, item_bias: np.ndarray,
               key: Optional[dict] = None) -> IVFIndex:
+    """:func:`build_ivf_fused` over host rows: the catalog goes to the
+    default backend's device as one ``[n, D+1]`` array, bias column last."""
+    return build_ivf_fused(np.concatenate(
+        [np.asarray(item_emb, np.float32),
+         np.asarray(item_bias, np.float32)[:, None]], axis=1),
+        len(item_emb), key)
+
+
+def build_ivf_fused(rows, n: int, key: Optional[dict] = None) -> IVFIndex:
     """Cluster the catalog and lay out the member-order rerank tables.
 
-    Deploy-time cost: k-means on a bounded sample plus ONE full-catalog
-    assignment pass (chunked matmuls) — minutes at 10M rows, amortized over
-    every query the deployment serves.
+    ``rows`` is the catalog fused ``[>= n, D+1]`` (embedding + bias column;
+    rows past ``n`` are padding): host numpy, or a jax Array where a device
+    already holds it, as a device-resident model's item table does, and
+    then nothing of it is copied. The build is jitted programs on that
+    device (``ops/retrieval.py`` ``ivf_*``): k-means on a bounded sample,
+    ONE assignment pass over the catalog, then the member-order gather and
+    its int8 quantization, of which only the finished tables come to the
+    host. Milliseconds of a chip at a million rows; on a process without
+    one the same programs are XLA's CPU code. The host keeps the generator:
+    which rows are sampled, which seed a centroid and which replace a dead
+    one are ``key["seed"]``'s draws, in that order.
     """
-    n, d = item_emb.shape
+    import jax
+    import jax.numpy as jnp
+
+    from incubator_predictionio_tpu.ops import retrieval as dev
+
     key = dict(key if key is not None else build_key(n))
     if key.get("n_items") != n:
         key["n_items"] = n
     rng = np.random.default_rng(key["seed"])
-    c = min(key["n_partitions"], max(1, n))
     t0 = time.perf_counter()
-    item_emb = np.asarray(item_emb, np.float32)
-    item_bias = np.asarray(item_bias, np.float32)
-    aug = np.concatenate([item_emb, item_bias[:, None]], axis=1)
     sample = min(int(key["train_sample"]), n)
-    train = aug if sample >= n else \
-        aug[rng.choice(n, size=sample, replace=False)]
-    c = min(c, len(train))  # can't seed more centroids than training rows
-    cent = _kmeans(train, c, int(key["kmeans_iters"]), rng)
-    assign = _assign(aug, cent)
-    order = np.argsort(assign, kind="stable")
-    sizes = np.bincount(assign, minlength=c)
-    offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
-    emb_m = np.ascontiguousarray(item_emb[order])
-    index = IVFIndex(
-        centroids=cent,
-        member_ids=order.astype(np.int32),
-        offsets=offsets,
-        bias_m=np.ascontiguousarray(item_bias[order]),
-        key=key,
-    )
-    if key["quantize"]:
-        from incubator_predictionio_tpu.ops.retrieval import quantize_rows
-
-        index.emb_q, index.scales_m = quantize_rows(emb_m)
-    else:
-        index.emb_m = emb_m
+    sel = np.arange(n) if sample >= n else \
+        rng.choice(n, size=sample, replace=False)
+    # can't seed more centroids than training rows
+    c = min(key["n_partitions"], max(1, sample))
+    iters = int(key["kmeans_iters"])
+    with span("train.index.cluster", backend=jax.default_backend(), rows=n,
+              partitions=c, iters=iters) as sp:
+        rows = jnp.asarray(rows)
+        train, cent = dev.ivf_sample(
+            rows, sel.astype(np.int32),
+            rng.choice(sample, size=c, replace=False).astype(np.int32))
+        train_host, reseeded = None, 0
+        for _ in range(iters):
+            cent, counts = dev.ivf_update(
+                train, dev.ivf_assign(train, cent, n=sample), c=c)
+            dead = np.flatnonzero(np.asarray(counts) == 0)
+            if len(dead):
+                # every centroid stays live: an empty one restarts from a
+                # random row of the sample. Patched on the host into the
+                # whole [C, D+1] array, so the next program sees the shape
+                # it was compiled for however many died
+                if train_host is None:
+                    train_host = np.asarray(train)
+                patched = np.array(cent)
+                patched[dead] = train_host[
+                    rng.choice(sample, size=len(dead), replace=False)]
+                cent = jnp.asarray(patched)
+                reseeded += len(dead)
+        assign = np.asarray(dev.ivf_assign(rows, cent, n=n))
+        sp.set_attr("reseeded", reseeded)
+    with span("train.index.layout"):
+        order = np.argsort(assign, kind="stable").astype(np.int32)
+        sizes = np.bincount(assign, minlength=c)
+        quantize = bool(key["quantize"])
+        emb, scales, bias_m = jax.device_get(
+            dev.ivf_layout(rows, order, quantize=quantize))
+        index = IVFIndex(
+            centroids=np.asarray(cent),
+            member_ids=order,
+            offsets=np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64),
+            bias_m=bias_m,
+            key=key,
+            emb_m=None if quantize else emb,
+            emb_q=emb if quantize else None,
+            scales_m=scales,
+        )
     index.build_seconds = time.perf_counter() - t0
     return index

@@ -655,6 +655,15 @@ def test_run_train_is_one_trace_under_train_verb(trained):
              if s["parentId"] not in (None, root["spanId"])}
     assert below["train.persist.orbax"] == "train.verb.persist"
     assert below["train.epochs.chunk"] == "train.fit.compute"
+    # and the index build's two phases (the fixture forces two-stage
+    # retrieval, so its 400 items are clustered): the programs' backend and
+    # what they were given on the first
+    assert below["train.index.cluster"] == "train.verb.index"
+    assert below["train.index.layout"] == "train.verb.index"
+    (cluster,) = [s for s in tree if s["name"] == "train.index.cluster"]
+    assert cluster["attrs"] == {
+        "backend": jax.default_backend(), "rows": 400, "partitions": 20,
+        "iters": 6, "reseeded": cluster["attrs"]["reseeded"]}
     assert all(s["status"] == "ok" for s in tree)
 
 
@@ -849,6 +858,22 @@ def _rerank_lowered():
         nprobe=3, k=16, interpret=True)
 
 
+def _ivf_lowered(program: str):
+    """A program of the index build (``serving/ann.build_ivf_fused``) at a
+    small catalog: 64 rows of rank 8, 16 sampled, 4 partitions."""
+    from incubator_predictionio_tpu.ops import retrieval
+
+    rows, cent = jnp.zeros((64, 9)), jnp.zeros((4, 9))
+    args, static = {
+        "ivf_sample": ((rows, jnp.zeros(16, jnp.int32),
+                        jnp.zeros(4, jnp.int32)), {}),
+        "ivf_assign": ((rows, cent), {"n": 64}),
+        "ivf_update": ((rows[:16], jnp.zeros(16, jnp.int32)), {"c": 4}),
+        "ivf_layout": ((rows, jnp.zeros(64, jnp.int32)), {"quantize": True}),
+    }[program]
+    return getattr(retrieval, program).lower(*args, **static)
+
+
 def _seq_layer_lowered(kind: str, bucket: tuple):
     """A layer executable of the sequence template's paged-cache serving,
     by the block's kind, as ``LatentServing`` names and lowers it."""
@@ -918,10 +943,16 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
      ("gather", "quantize")),
     (_rerank_lowered, "jit_two_stage_rerank",
      ("probe_select", "rerank", "gather", "topk")),
+    (lambda: _ivf_lowered("ivf_sample"), "jit_ivf_sample", ("gather",)),
+    (lambda: _ivf_lowered("ivf_assign"), "jit_ivf_assign", ("assign",)),
+    (lambda: _ivf_lowered("ivf_update"), "jit_ivf_update", ("update",)),
+    (lambda: _ivf_lowered("ivf_layout"), "jit_ivf_layout",
+     ("gather", "quantize")),
 ], ids=["seq_layer_latent", "seq_layer_sparse_turn", "seq_layer_sparse_piece",
         "seq_ssm_step", "seq_ssm_scan", "seq_gqa", "seq_moe",
         "train_epochs", "topk_quantized", "score_centroids", "init",
-        "order_batches", "quantize_user_rows", "two_stage_rerank"])
+        "order_batches", "quantize_user_rows", "two_stage_rerank",
+        "ivf_sample", "ivf_assign", "ivf_update", "ivf_layout"])
 def test_executable_names_and_scopes_are_pinned(lower, module, scopes):
     """``benchmarks/layer_metrics/*_roofline.py`` find these executables by
     name in a device trace's ``XLA Modules`` line (the sequence template's

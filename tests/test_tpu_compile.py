@@ -16,6 +16,10 @@ layers of 3 taps, 6 rotary 32 / 8-head attention layers of 64, 2 dense parts
 of 7168, 16 of 32 experts top-4 of width 1792, 2,049 carry slots, 327,808
 cache rows): its whole 48-sub-block turn program in the widest turn bucket and
 each letter's step over the longest block.
+The index build's clustering programs (``ops/retrieval.py`` ``ivf_*``)
+compile at the train cell's shapes (a 65,536-row sample and 100,000 rows of
+128 + the bias column, 316 partitions) and the two-stage serve cell's
+(476,002 rows, 690 partitions) with the score block never held whole.
 What the
 Pallas interpreter accepts, Mosaic can still refuse (tiling, scoped memory);
 that has to fail here and not on the chip. Nothing runs, so nothing here is
@@ -743,3 +747,48 @@ def test_train_epochs_carries_128_lane_tables_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 100e6
     # the fused tables come in and go out, donated
     assert mem.alias_size_in_bytes >= 3 * 110_000 * (RANK + 1) * 4
+
+
+# -- the index build's clustering (serving/ann.build_ivf) ---------------------
+
+#: What a train verb's peak leaves of the chip's 16 GB: the train cell's
+#: 1.1M-row trainer reads ``memory_peak_bytes`` 3.744 GB (PERF.md section 5)
+HBM_BESIDE_TRAINER = 16e9 - 3.744e9
+
+
+@pytest.mark.parametrize("program, rows, partitions", [
+    ("ivf_assign", 65_536, 316), ("ivf_update", 65_536, 316),
+    ("ivf_assign", 100_000, 316), ("ivf_layout", 100_000, 316),
+    ("ivf_assign", 65_536, PARTITIONS), ("ivf_update", 65_536, PARTITIONS),
+    ("ivf_assign", N_ITEMS, PARTITIONS), ("ivf_layout", N_ITEMS, PARTITIONS),
+])
+def test_ivf_build_compiles_for_v5e(one_chip, program, rows, partitions):
+    """A Lloyd iteration over the 65,536-row sample (assignment, update),
+    the catalog's assignment and the member-order layout, at the train
+    cell's catalog and the serve cell's: the ``[rows, partitions]`` float32
+    score block (126 MB / 1.3 GB) is never held, and the layout's
+    temporaries are the gathered rows and their embedding columns (975 MB at
+    476,002 rows), so
+    either build fits beside the trainer whose table it reads."""
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table, cent = s((rows, RANK + 1)), s((partitions, RANK + 1))
+    compiled = {
+        "ivf_assign": lambda: retrieval.ivf_assign.lower(table, cent, n=rows),
+        "ivf_update": lambda: retrieval.ivf_update.lower(
+            table, s((rows,), jnp.int32), c=partitions),
+        "ivf_layout": lambda: retrieval.ivf_layout.lower(
+            table, s((rows,), jnp.int32), quantize=True),
+    }[program]().compile()
+    mem = compiled.memory_analysis()
+    if program == "ivf_assign":
+        assert mem.temp_size_in_bytes <= 2 * 4 * (
+            retrieval.ASSIGN_ROWS * retrieval.CENTROID_BLOCK)
+    assert mem.temp_size_in_bytes <= 4.1 * rows * RANK * 4
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BESIDE_TRAINER
+    text = compiled.as_text()
+    for scope in {"ivf_assign": ("assign",), "ivf_update": ("update",),
+                  "ivf_layout": ("gather", "quantize")}[program]:
+        assert f"/{scope}/" in text, scope

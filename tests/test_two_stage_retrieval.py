@@ -204,6 +204,167 @@ def test_ivf_build_partitions_cover_catalog(two_stage_env):
         ivf.emb_m, np.asarray(model.item_emb)[ivf.member_ids])
 
 
+# -- the clustering's programs against the numpy reference (ISSUE 44) --------
+#
+# build_ivf clusters with jitted programs (ops/retrieval.py ivf_*); the numpy
+# Lloyd loop it ran before is tests/fixtures/ivf_reference.py. Same key, same
+# generator, same order of draws: the index is the same index, up to rows a
+# float32 segment sum moves across a near-tie where float64 bincount did not.
+
+def _parity_catalog(case):
+    rows, rank, partitions, sample = {
+        "4096x33": (4096, 32, 64, 65_536),
+        "20000x129": (20_000, 128, 141, 8192),
+        "dead_cluster": (4096, 32, 64, 65_536),
+    }[case]
+    model = _clustered_model(seed=3, n_items=rows, rank=rank)
+    emb, bias = model.item_emb.copy(), model.item_bias.copy()
+    if case == "dead_cluster":
+        # a third of the catalog is ONE row: the seeds draw it many times
+        # over, and of centroids that are equal only the first gets members
+        twin = np.random.default_rng(4).choice(rows, rows // 3, replace=False)
+        emb[twin], bias[twin] = emb[twin[0]], bias[twin[0]]
+    key = dict(ann.build_key(rows), n_partitions=partitions, quantize=False,
+               train_sample=sample)
+    return emb, bias, key
+
+
+def _partition_of(ivf):
+    out = np.empty(ivf.n_items, np.int32)
+    out[ivf.member_ids] = np.repeat(
+        np.arange(ivf.n_partitions), np.diff(ivf.offsets))
+    return out
+
+
+def _reference_index(emb, bias, key):
+    """The index the numpy reference's clustering gives, laid out by the
+    package's own rehydrate."""
+    from tests.fixtures import ivf_reference
+
+    cent, assign, reseeded = ivf_reference.cluster(emb, bias, key)
+    sizes = np.bincount(assign, minlength=len(cent))
+    index = ann.IVFIndex(
+        centroids=cent,
+        member_ids=np.argsort(assign, kind="stable").astype(np.int32),
+        offsets=np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64),
+        bias_m=None, key=dict(key)).rehydrate(emb, bias)
+    return index, assign, reseeded
+
+
+@pytest.mark.parametrize("case", ["4096x33", "20000x129", "dead_cluster"])
+def test_clustering_programs_match_the_numpy_reference(case):
+    from incubator_predictionio_tpu.obs import trace
+    from incubator_predictionio_tpu.ops import retrieval
+    from tests.fixtures import ivf_reference
+
+    emb, bias, key = _parity_catalog(case)
+    n = len(emb)
+    trace.TRACES.clear()
+    ivf = ann.build_ivf(emb, bias, key=key)
+    ref, ref_assign, ref_reseeded = _reference_index(emb, bias, key)
+
+    # a partition of the catalog, the same one
+    np.testing.assert_array_equal(np.sort(ivf.member_ids), np.arange(n))
+    assert ivf.offsets[0] == 0 and ivf.offsets[-1] == n
+    assert np.all(np.diff(ivf.offsets) >= 0)
+    assert ivf.centroids.shape == ref.centroids.shape
+    assign = _partition_of(ivf)
+    assert np.mean(assign == ref_assign) >= 0.995
+    aug = np.concatenate([emb, bias[:, None]], axis=1).astype(np.float64)
+    inertia = ((aug - ivf.centroids[assign]) ** 2).sum()
+    ref_inertia = ((aug - ref.centroids[ref_assign]) ** 2).sum()
+    assert inertia == pytest.approx(ref_inertia, rel=1e-4)
+    # (a float32 sum over a partition's rows, thousands of them, against
+    # float64's: the mean keeps four digits and more)
+    np.testing.assert_allclose(ivf.centroids, ref.centroids, rtol=1e-4,
+                               atol=2e-5)
+
+    # the generator's draws: as many dead centroids re-seeded, iteration by
+    # iteration (the span says how many)
+    (cluster,) = [s for s in trace.TRACES.spans()
+                  if s["name"] == "train.index.cluster"]
+    assert cluster["attrs"]["reseeded"] == sum(ref_reseeded)
+    assert sum(ref_reseeded) > 0 or case != "dead_cluster"
+    assert cluster["attrs"]["rows"] == n
+    assert cluster["attrs"]["partitions"] == len(ref.centroids)
+
+    # the int8 form of the same build: the reference's members, quantized
+    # by the host routine (a rounding at .5 may fall the other way)
+    from incubator_predictionio_tpu.ops.retrieval import quantize_rows
+
+    int8 = ann.build_ivf(emb, bias, key=dict(key, quantize=True))
+    np.testing.assert_array_equal(int8.member_ids, ivf.member_ids)
+    want_q, want_scales = quantize_rows(emb[int8.member_ids])
+    assert int8.emb_q.dtype == np.int8 and int8.emb_m is None
+    assert np.abs(int8.emb_q.astype(np.int32) - want_q).max() <= 1
+    assert np.mean(int8.emb_q != want_q) < 1e-5
+    np.testing.assert_allclose(int8.scales_m, want_scales, rtol=1e-6)
+    np.testing.assert_array_equal(int8.bias_m, bias[int8.member_ids])
+
+    # one iteration alone: a centroid's last column is its members' mean
+    # bias, and one without members comes back empty for the host to re-seed
+    sel = np.random.default_rng(5).choice(n, 2048, replace=False)
+    train = aug[sel].astype(np.float32)
+    members = ivf_reference.assign(train, ref.centroids)
+    np.testing.assert_array_equal(
+        retrieval.ivf_assign(train, ref.centroids, n=len(train)), members)
+    cent, counts = retrieval.ivf_update(train, members, c=len(ref.centroids))
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(members, minlength=len(cent)))
+    live = np.asarray(counts) > 0
+    mean_bias = np.bincount(members, weights=bias[sel], minlength=len(cent))
+    np.testing.assert_allclose(
+        np.asarray(cent)[live, -1], mean_bias[live] / np.asarray(counts)[live],
+        atol=1e-6)
+    assert not np.asarray(cent)[~live].any()
+
+
+def test_assignment_in_blocks_is_the_whole_products_argmax(monkeypatch):
+    """The assignment goes through in blocks of rows (the last one
+    overlapping where the rows do not divide) and of centroids: the numpy
+    reference's answer, row for row, with rows past ``n`` left out and the
+    first of equal centroids winning across blocks."""
+    import jax
+
+    from incubator_predictionio_tpu.ops import retrieval
+    from tests.fixtures import ivf_reference
+
+    rng = np.random.default_rng(0)
+    rows = rng.standard_normal((10_007, 33)).astype(np.float32)
+    cent = rng.standard_normal((50, 33)).astype(np.float32)
+    cent[[7, 23, 41]] = cent[3]      # twins: the first one gets the rows
+    want = ivf_reference.assign(rows[:10_000], cent)
+    assert (want == 3).any() and not np.isin(want, (7, 23, 41)).any()
+    np.testing.assert_array_equal(
+        retrieval.ivf_assign(rows, cent, n=10_000), want)
+    # 4 blocks of 2,500 rows and 4 of 16 centroids; then rows that do not
+    # divide: 4 blocks of 2,502 over 10,007
+    monkeypatch.setattr(retrieval, "ASSIGN_ROWS", 3000)
+    monkeypatch.setattr(retrieval, "CENTROID_BLOCK", 16)
+    blocked = jax.jit(retrieval.ivf_assign.__wrapped__, static_argnames="n")
+    np.testing.assert_array_equal(blocked(rows, cent, n=10_000), want)
+    np.testing.assert_array_equal(
+        blocked(rows, cent, n=10_007), ivf_reference.assign(rows, cent))
+
+
+def test_clustering_programs_keep_the_reference_indexs_recall(two_stage_env):
+    """recall@10 of ``IVFIndex.search`` against the exact scores, over the
+    index the programs build and over the reference's: the same to 0.005."""
+    model = _clustered_model()
+    key = ann.build_key(model.n_items)
+    built = ann.build_ivf(model.item_emb, model.item_bias, key=key)
+    ref, _, _ = _reference_index(model.item_emb, model.item_bias, key)
+    exact = model.user_emb @ model.item_emb.T + model.item_bias[None, :]
+    oracle = np.argsort(-exact, axis=1, kind="stable")[:, :10]
+    recalls = []
+    for index in (built, ref):
+        got, _ = index.search(model.user_emb, model.user_bias, model.mean,
+                              10, nprobe=8, observe=False)
+        recalls.append(_recall(oracle, got))
+    assert min(recalls) >= 0.9
+    assert abs(recalls[0] - recalls[1]) <= 0.005
+
+
 def test_small_catalog_auto_mode_stays_exact_parity(monkeypatch):
     """Below PIO_RETRIEVAL_MIN_ITEMS the auto mode must not build an index
     — small templates keep bitwise parity with the seed behavior."""
