@@ -55,9 +55,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CLI = [sys.executable, "-m", "incubator_predictionio_tpu.tools.cli"]
 APP = "chipsmoke"
 
-#: the shape bench.py / PERF.md call production-representative; rank, batch
-#: and the catalog are never cut — events and user ids are what a time limit
-#: may cut, and a cut is printed
+#: rank, batch, catalog and user ids of the benchmark's train configuration
+#: (``rec-1Mx100k-r128``). Rank, batch and the catalog are never cut — events
+#: and user ids are what a time limit may cut, and a cut is printed
 FULL = {"rank": 128, "batch": 65536, "iterations": 2, "n_items": 100_000,
         "user_ids": 1_000_000, "n_events": 2_000_000}
 #: rehearsal keeps the width (rank) and the events-per-item / events-per-user

@@ -1,20 +1,14 @@
-"""Benchmark suite: all five BASELINE.md configs + serving latency on one chip.
+"""Host-plane drills: the failure, overload and recovery exercises the docs
+tell an operator to run (docs/dr.md, jobs.md, replication.md, resilience.md,
+serving.md, sharding.md, streaming.md, tenancy.md). Not a benchmark: the
+repo's benchmark is ``BENCHMARK.json`` + ``benchmarks/``, and nothing here
+measures an accelerator.
 
-Prints ONE JSON line whose headline is the north-star metric
-(BASELINE.md:21-23): recommendation-template training throughput in
-events/sec/chip, plus ``mfu``, ``predict_p50_ms`` / ``predict_p95_ms``
-(measured through the deployed query server under concurrent load), and a
-``configs`` matrix covering classification / recommendation / similarproduct /
-ecommerce retrieval / sequential transformer and event-server ingestion.
-
-Device lanes need a chip: with none they fail, and a failed lane fails the
-run. The JSON line records ``platform``/``device`` as JAX reports them.
-
-``vs_baseline``: the reference publishes no numbers (BASELINE.md), so the
-baseline is measured in-process — the identical adam epoch in pure numpy on
-the host. MFU is the honest hardware-utilization figure: analytic FLOPs of
-each schedule ÷ chip peak (embedding workloads are HBM-bound, so their
-``hbm_util`` is reported as well).
+``python drills.py`` runs every drill, each in a child process of its own
+held to the CPU, and prints ONE JSON line ``{"configs": {<name>: <result>}}``;
+it exits 1 if a drill failed. ``python drills.py --config <name>`` runs one
+in this process and prints ``CONFIG_RESULT=<json>``. ``PIO_BENCH_SMALL=1``
+cuts the shapes down, ``PIO_BENCH_CONFIGS=a,b`` selects drills.
 """
 
 from __future__ import annotations
@@ -30,933 +24,10 @@ import numpy as np
 SMALL = bool(os.environ.get("PIO_BENCH_SMALL"))
 ONLY = set(filter(None, os.environ.get("PIO_BENCH_CONFIGS", "").split(",")))
 
-# -- chip peak tables: bf16 FLOPs/s comes from the profiler's single source
-#    of truth (obs/profile.py TPU_PEAK_FLOPS — the table behind the
-#    pio_training_mfu gauge, so bench MFU and live MFU can never disagree);
-#    the HBM bytes/s column is bench-only
-_HBM_PEAKS = [
-    ("v6", 1640e9), ("trillium", 1640e9),
-    ("v5p", 2765e9),
-    ("v5e", 819e9), ("v5 lite", 819e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-]
-
 
 def _log(msg: str) -> None:
-    print(f"[bench] {msg}", file=sys.stderr, flush=True)
+    print(f"[drill] {msg}", file=sys.stderr, flush=True)
 
-
-def chip_peaks(device) -> tuple[float, float]:
-    from incubator_predictionio_tpu.obs.profile import peak_flops_for
-
-    kind = device.device_kind.lower()
-    flops = peak_flops_for(device.platform, kind)
-    bw = next((b for key, b in _HBM_PEAKS if key in kind), None)
-    if flops is None or bw is None:
-        raise RuntimeError(
-            f"no peaks known for {device.platform} device {device.device_kind!r}"
-            ": device lanes need a listed TPU")
-    return flops, bw
-
-
-def _mfu(total_flops: float, dt: float, peak: float | None) -> float | None:
-    return None if peak is None else round(total_flops / dt / peak, 4)
-
-
-def _bw(total_bytes: float, dt: float, peak: float | None) -> float | None:
-    return None if peak is None else round(total_bytes / dt / peak, 4)
-
-
-# ---------------------------------------------------------------------------
-# 1+2+3. two-tower family: recommendation (explicit), similarproduct
-#        (implicit, sampled negatives), and the numpy host baseline
-# ---------------------------------------------------------------------------
-
-REC_USERS, REC_ITEMS = 6040, 3706           # MovieLens-1M shape
-REC_EVENTS = 120_000 if SMALL else 1_000_000
-REC_RANK, REC_BATCH, REC_EPOCHS = 64, 65536, 20
-
-
-def _two_tower_flops_bytes(n_events, rank, batch, epochs, n_users, n_items,
-                           moment_bytes=4):
-    """Analytic per-schedule FLOPs and HBM bytes of the fused train loop.
-    ``moment_bytes`` reflects the adam moment STORAGE dtype (4 = fp32,
-    2 = bf16 via ``adam_moments_dtype``) so hbm_util stays honest when the
-    traffic really shrinks."""
-    n_batches = max(1, (n_events + batch - 1) // batch)
-    steps = epochs * n_batches
-    n_params = (n_users + n_items) * (rank + 1)
-    flops_step = 12 * rank * batch + 12 * n_params  # fwd+bwd dots + dense adam
-    # adam state r/w (params fp32 + m + v at their storage width, read+write)
-    # + batch embedding gathers
-    bytes_step = (n_params * (4 * 2 + moment_bytes * 4)
-                  + batch * rank * 4 * 4)
-    return steps * flops_step, steps * bytes_step
-
-
-def _bench_two_tower(
-    ctx, peaks, n_users, n_items, rank, n_events, batch,
-    epochs, data_seed, moments_dtype="float32",
-) -> "tuple[dict, np.ndarray, np.ndarray, np.ndarray, object]":
-    """Shared warmup+timed two-tower run. Distinct model seeds per run: a
-    timed run identical to the warmup could be served from an execution
-    cache. Utilization is computed over the train phase — the one-time model
-    pull (timings["gather_sec"]) says nothing about the chip."""
-    from incubator_predictionio_tpu.models.two_tower import TwoTowerConfig, TwoTowerMF
-
-    rng = np.random.default_rng(data_seed)
-    users = rng.integers(0, n_users, n_events).astype(np.int32)
-    items = rng.integers(0, n_items, n_events).astype(np.int32)
-    ratings = (1.0 + 4.0 * rng.random(n_events)).astype(np.float32)
-
-    def run(seed):
-        return TwoTowerMF(TwoTowerConfig(
-            rank=rank, batch_size=batch, epochs=epochs, seed=seed,
-            adam_moments_dtype=moments_dtype,
-        )).fit(ctx, users, items, ratings, n_users, n_items)
-
-    run(0)  # warmup: pays every compile
-    t0 = time.perf_counter()
-    model = run(1)
-    dt = time.perf_counter() - t0
-    flops, bts = _two_tower_flops_bytes(
-        n_events, rank, batch, epochs, n_users, n_items,
-        moment_bytes=2 if moments_dtype == "bfloat16" else 4)
-    t_train = model.timings["train_sec"]
-    return ({
-        "events_per_sec": round(epochs * n_events / dt, 1),
-        "train_events_per_sec": round(epochs * n_events / t_train, 1),
-        "mfu": _mfu(flops, t_train, peaks[0]),
-        "hbm_util": _bw(bts, t_train, peaks[1]),
-        "timings": model.timings,
-    }, users, items, ratings, model)
-
-
-def bench_recommendation(ctx, peaks) -> dict:
-    out, users, items, ratings, _ = _bench_two_tower(
-        ctx, peaks, REC_USERS, REC_ITEMS, REC_RANK, REC_EVENTS,
-        REC_BATCH, REC_EPOCHS, data_seed=42)
-    host_eps = bench_numpy_baseline(users, items, ratings)
-    out["vs_host_numpy"] = round(out["events_per_sec"] / host_eps, 2)
-    return out
-
-
-def bench_recommendation_scaled(ctx, peaks, device) -> dict:
-    """Production-representative two-tower shapes (VERDICT r2: ≥1M users,
-    ≥100k items, rank 128): the dominant HBM traffic is the dense adam
-    streaming over the 142M-parameter fused tables — the config whose
-    ``hbm_util`` tells whether the schedule saturates the chip's bandwidth.
-
-    The tables exceed HOST_SERVE_MAX_ELEMENTS so TwoTowerConfig's
-    gather="auto" keeps them DEVICE-RESIDENT (round-4: no full-table host
-    pull — round 3 lost 80% of end-to-end throughput to a 21.7s gather).
-    persist/load time the orbax sharded-checkpoint save and the device-
-    resident restore — the full train→persist→deploy cycle without the
-    tables ever visiting host numpy."""
-    import shutil
-    import tempfile
-
-    import jax
-
-    small = SMALL
-    n_users, n_items, rank = (
-        (100_000, 20_000, 64) if small else (1_000_000, 100_000, 128))
-    # bf16 moment storage: 6 → 4 fp32-equivalent table passes per step on
-    # the dense-adam traffic that dominates this config (parity:
-    # tests/test_optim_parity.py). PIO_BENCH_ADAM_MOMENTS=float32 ablates.
-    moments = os.environ.get("PIO_BENCH_ADAM_MOMENTS", "bfloat16")
-    out, _u, _i, _r, model = _bench_two_tower(
-        ctx, peaks, n_users, n_items, rank,
-        n_events=200_000 if small else 4_000_000,
-        batch=65536, epochs=2 if small else 4, data_seed=9,
-        moments_dtype=moments)
-    out["adam_moments_dtype"] = moments
-    # the headline ratio must compare THIS config against its own numpy
-    # baseline (same table shapes/rank), not the MovieLens-shaped one
-    host_eps = bench_numpy_baseline(
-        _u, _i, _r, n_users=n_users, n_items=n_items, rank=rank)
-    out["vs_host_numpy"] = round(out["events_per_sec"] / host_eps, 2)
-    if model is not None and model.device_resident:
-        from incubator_predictionio_tpu.data.bimap import BiMap
-        from incubator_predictionio_tpu.templates.recommendation import RecModel
-
-        d = tempfile.mkdtemp(prefix="bench_devmodel_")
-        prev_basedir = os.environ.get("PIO_FS_BASEDIR")
-        os.environ["PIO_FS_BASEDIR"] = d
-        try:
-            rec = RecModel(model, BiMap({}), BiMap({}))
-            t0 = time.perf_counter()
-            saved = rec.save("bench_0", None, ctx)
-            t_persist = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            loaded = RecModel.load("bench_0", None, ctx)
-            jax.block_until_ready(loaded.mf._tables)
-            t_load = time.perf_counter() - t0
-            out["device_resident"] = bool(saved)
-            out["persist_sec"] = round(t_persist, 4)
-            out["deploy_load_sec"] = round(t_load, 4)
-        finally:
-            if prev_basedir is None:
-                os.environ.pop("PIO_FS_BASEDIR", None)
-            else:
-                os.environ["PIO_FS_BASEDIR"] = prev_basedir
-            shutil.rmtree(d, ignore_errors=True)
-    return out
-
-
-def bench_similarproduct(ctx, peaks) -> dict:
-    """Implicit MF: positives + sampled negatives through the same towers
-    (reference ALS.trainImplicit, similarproduct ALSAlgorithm.scala:61-135)."""
-    from incubator_predictionio_tpu.models.negative_sampling import sample_negatives
-    from incubator_predictionio_tpu.models.two_tower import TwoTowerConfig, TwoTowerMF
-
-    n_users, n_items = 10_000, 10_000
-    n_pos = 40_000 if SMALL else 250_000
-    negs = 3
-    rng = np.random.default_rng(7)
-    pos_u = rng.integers(0, n_users, n_pos).astype(np.int32)
-    pos_i = rng.integers(0, n_items, n_pos).astype(np.int32)
-    neg_u, neg_i = sample_negatives(pos_u, pos_i, n_items, negs, rng)
-    users = np.concatenate([pos_u, neg_u])
-    items = np.concatenate([pos_i, neg_i])
-    ratings = np.concatenate(
-        [np.ones(n_pos, np.float32), np.zeros(len(neg_u), np.float32)])
-    epochs, batch, rank = 10, 65536, 64
-
-    def run(seed):
-        return TwoTowerMF(TwoTowerConfig(
-            rank=rank, batch_size=batch, epochs=epochs, seed=seed,
-        )).fit(ctx, users, items, ratings, n_users, n_items)
-
-    run(0)
-    t0 = time.perf_counter()
-    model = run(1)
-    dt = time.perf_counter() - t0
-    flops, bts = _two_tower_flops_bytes(
-        len(users), rank, batch, epochs, n_users, n_items)
-    t_train = model.timings["train_sec"]
-    return {
-        "events_per_sec": round(epochs * len(users) / dt, 1),
-        "mfu": _mfu(flops, t_train, peaks[0]),
-        "hbm_util": _bw(bts, t_train, peaks[1]),
-    }
-
-
-def bench_numpy_baseline(users, items, ratings, n_events: int = 100_000,
-                         n_users: int = REC_USERS, n_items: int = REC_ITEMS,
-                         rank: int = REC_RANK) -> float:
-    """Identical per-event math (adam over embedding gathers), pure numpy."""
-    n_events = min(n_events, len(users))
-    rng = np.random.default_rng(0)
-    ue = (rng.standard_normal((n_users, rank)) / np.sqrt(rank)).astype(np.float32)
-    ie = (rng.standard_normal((n_items, rank)) / np.sqrt(rank)).astype(np.float32)
-    ub = np.zeros(n_users, np.float32)
-    ib = np.zeros(n_items, np.float32)
-    m = {k: np.zeros_like(v) for k, v in (("ue", ue), ("ie", ie), ("ub", ub), ("ib", ib))}
-    v = {k: np.zeros_like(val) for k, val in (("ue", ue), ("ie", ie), ("ub", ub), ("ib", ib))}
-    lr, b1, b2, eps = 3e-2, 0.9, 0.999, 1e-8
-    mean = ratings[:n_events].mean()
-    t0 = time.perf_counter()
-    step = 0
-    for start in range(0, n_events, REC_BATCH):
-        step += 1
-        bu = users[start:start + REC_BATCH]
-        bi = items[start:start + REC_BATCH]
-        br = ratings[start:start + REC_BATCH] - mean
-        e_u, e_i = ue[bu], ie[bi]
-        pred = np.sum(e_u * e_i, axis=1) + ub[bu] + ib[bi]
-        err = pred - br
-        gu = 2 * err[:, None] * e_i / len(bu)
-        gi = 2 * err[:, None] * e_u / len(bu)
-        gb = 2 * err / len(bu)
-        grads = {
-            "ue": np.zeros_like(ue), "ie": np.zeros_like(ie),
-            "ub": np.zeros_like(ub), "ib": np.zeros_like(ib),
-        }
-        np.add.at(grads["ue"], bu, gu)
-        np.add.at(grads["ie"], bi, gi)
-        np.add.at(grads["ub"], bu, gb)
-        np.add.at(grads["ib"], bi, gb)
-        for k, p in (("ue", ue), ("ie", ie), ("ub", ub), ("ib", ib)):
-            m[k] = b1 * m[k] + (1 - b1) * grads[k]
-            v[k] = b2 * v[k] + (1 - b2) * grads[k] ** 2
-            mh = m[k] / (1 - b1 ** step)
-            vh = v[k] / (1 - b2 ** step)
-            p -= lr * mh / (np.sqrt(vh) + eps)
-    return n_events / (time.perf_counter() - t0)
-
-
-# ---------------------------------------------------------------------------
-# 4. classification MLP
-# ---------------------------------------------------------------------------
-
-def bench_classification(ctx, peaks) -> dict:
-    from incubator_predictionio_tpu.models.mlp import MLPClassifier, MLPConfig
-
-    n, d, hidden, epochs, batch = (
-        20_000 if SMALL else 100_000), 3, (128, 128), 40, 4096
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(n, d)).astype(np.float32)
-    y = (x[:, 0] + 0.5 * x[:, 1] > 0).astype(np.int32)
-    cfg = MLPConfig(hidden_dims=hidden, epochs=epochs, batch_size=batch)
-
-    MLPClassifier(cfg).fit(ctx, x, y)
-    t0 = time.perf_counter()
-    MLPClassifier(cfg).fit(ctx, x, y)
-    dt = time.perf_counter() - t0
-    dims = [d, *hidden, 2]
-    flops_per_example = 6 * sum(a * b for a, b in zip(dims[:-1], dims[1:]))
-    return {
-        "events_per_sec": round(epochs * n / dt, 1),
-        "mfu": _mfu(epochs * n * flops_per_example, dt, peaks[0]),
-    }
-
-
-# ---------------------------------------------------------------------------
-# 5. ecommerce retrieval (serving-side scoring over a large catalog)
-# ---------------------------------------------------------------------------
-
-def bench_ecommerce_retrieval(ctx, peaks, device) -> dict:
-    """Rule-filtered template serving at scale: the ECommAlgorithm predict
-    path with live business rules (categories, white/black lists, the
-    unavailable-items constraint read, unseen-only history) — serial
-    per-query with reference read-per-query semantics (TTL=0) vs the
-    vectorized ``batch_predict`` (mask compilation + cached/batched store
-    reads + axis-wise top-k). Both paths are parity-checked query-for-query
-    before timing; store-read counts and the coalesced batch-size
-    distribution are recorded so the speedup is attributable. On TPU this
-    also asserts the Pallas int8 kernel (plain + row-masked) against the
-    jnp oracle."""
-    import datetime as _dt
-
-    from incubator_predictionio_tpu.data import DataMap, Event
-    from incubator_predictionio_tpu.data.bimap import BiMap
-    from incubator_predictionio_tpu.data.storage import App, Storage, use_storage
-    from incubator_predictionio_tpu.models.two_tower import (
-        TwoTowerConfig,
-        TwoTowerModel,
-    )
-    from incubator_predictionio_tpu.serving import TTLCache
-    from incubator_predictionio_tpu.templates.ecommerce import (
-        ECommAlgorithm,
-        ECommAlgorithmParams,
-        ECommModel,
-        Query,
-    )
-
-    # SMALL trims the catalog and query volume to keep wall time down, but
-    # keeps a production-depth view history — the serial lane's cost IS the
-    # per-query store reads, so shallow histories would understate the gap
-    n_users, n_items, rank = (200, 1_500, 32) if SMALL else (500, 4_000, 32)
-    views_per_user = 80 if SMALL else 40
-    rng = np.random.default_rng(3)
-    utc = _dt.timezone.utc
-    t0_ev = _dt.datetime(2020, 1, 1, tzinfo=utc)
-    storage = Storage({"PIO_STORAGE_SOURCES_BENCHMEM_TYPE": "memory"})
-    app_id = storage.get_meta_data_apps().insert(App(0, "bench-ecomm"))
-    events = storage.get_events()
-    events.init(app_id)
-    cats = {f"i{i}": (f"c{i % 8}", f"g{i % 3}") for i in range(n_items)}
-    for i in range(n_items):
-        events.insert(Event(
-            event="$set", entity_type="item", entity_id=f"i{i}",
-            properties=DataMap({"categories": list(cats[f"i{i}"])}),
-            event_time=t0_ev), app_id)
-    for u in range(n_users):
-        for i in map(int, rng.integers(0, n_items, views_per_user)):
-            events.insert(Event(
-                event="view", entity_type="user", entity_id=f"u{u}",
-                target_entity_type="item", target_entity_id=f"i{i}",
-                event_time=t0_ev), app_id)
-    events.insert(Event(
-        event="$set", entity_type="constraint", entity_id="unavailableItems",
-        properties=DataMap({"items": [f"i{i}" for i in range(0, 40)]}),
-        event_time=t0_ev), app_id)
-    norm = rng.standard_normal((n_items, rank)).astype(np.float32)
-    norm /= np.linalg.norm(norm, axis=1, keepdims=True) + 1e-9
-    model = ECommModel(
-        mf=TwoTowerModel(
-            user_emb=rng.standard_normal((n_users, rank)).astype(np.float32),
-            item_emb=rng.standard_normal((n_items, rank)).astype(np.float32),
-            user_bias=np.zeros(n_users, np.float32),
-            item_bias=np.zeros(n_items, np.float32),
-            mean=3.0, config=TwoTowerConfig(rank=rank)),
-        user_map=BiMap.string_int(f"u{u}" for u in range(n_users)),
-        item_map=BiMap.string_int(f"i{i}" for i in range(n_items)),
-        categories=cats,
-        popularity=rng.integers(0, 100, n_items).astype(np.float32),
-        item_vecs_norm=norm,
-    ).prepare_for_serving()
-    parity = None
-    if device.platform == "tpu":
-        parity = _pallas_parity_check(model.mf)
-    # the query mix: all four filter kinds + unknown users, like live traffic
-    def make_query(j: int) -> Query:
-        u = f"u{int(rng.integers(0, n_users))}" if j % 16 else "coldstart"
-        kind = j % 4
-        if kind == 0:
-            return Query(user=u, num=10)
-        if kind == 1:
-            return Query(user=u, num=10, categories=(f"c{j % 8}",))
-        if kind == 2:
-            return Query(user=u, num=10,
-                         black_list=tuple(f"i{i}" for i in range(j % 7)))
-        return Query(user=u, num=10, categories=(f"g{j % 3}",),
-                     white_list=tuple(f"i{i}" for i in range(100, 1100)))
-
-    # throughput-oriented coalesce depth: the store-read + scan cost is per
-    # BATCH, so deeper batches amortize further (the server's max_batch knob;
-    # the recorded batch_size_distribution keeps the artifact honest). The
-    # query count is deliberately NOT a batch multiple — the tail batch is
-    # the partial coalesce a draining queue produces
-    batch = 128
-    n_serial = 128 if SMALL else 256
-    n_batched = 2016 if SMALL else 4064
-    queries = [make_query(j) for j in range(max(n_serial, n_batched))]
-    from tests.fixtures.counting_events import CountingEvents
-
-    counting = CountingEvents(events)
-    storage.get_events = lambda: counting
-    prev = use_storage(storage)
-    try:
-        serial_algo = ECommAlgorithm(
-            ECommAlgorithmParams(app_name="bench-ecomm"))
-        serial_algo._constraint_cache = TTLCache(0)  # reference semantics
-        batch_algo = ECommAlgorithm(
-            ECommAlgorithmParams(app_name="bench-ecomm"))
-        # parity first: the serial path is the oracle
-        want = [serial_algo.predict(model, q) for q in queries[:batch]]
-        got = dict(batch_algo.batch_predict(
-            model, list(enumerate(queries[:batch]))))
-        parity_ok = all(
-            [(s.item, s.score) for s in want[i].item_scores]
-            == [(s.item, s.score) for s in got[i].item_scores]
-            for i in range(batch))
-        if not parity_ok:
-            # the headline number is only meaningful for a path that
-            # answers identically — fail the config, don't publish a
-            # speedup for divergent results
-            raise RuntimeError(
-                "batched-vs-serial parity failure in ecommerce_retrieval")
-        # serial timing (reference read-per-query semantics)
-        reads0 = counting.total_reads
-        t0 = time.perf_counter()
-        for q in queries[:n_serial]:
-            serial_algo.predict(model, q)
-        dt_serial = time.perf_counter() - t0
-        serial_reads = (counting.total_reads - reads0) / n_serial
-        serial_qps = n_serial / dt_serial
-        # batched timing through coalesced micro-batches
-        batch_sizes: dict[str, int] = {}
-        reads0 = counting.total_reads
-        t0 = time.perf_counter()
-        for off in range(0, n_batched, batch):
-            chunk = queries[off:off + batch]
-            batch_algo.batch_predict(model, list(enumerate(chunk)))
-            batch_sizes[str(len(chunk))] = batch_sizes.get(str(len(chunk)), 0) + 1
-        dt_batched = time.perf_counter() - t0
-        n_dispatched = sum(int(k) * v for k, v in batch_sizes.items())
-        batched_reads = (counting.total_reads - reads0) / max(1, sum(batch_sizes.values()))
-        batched_qps = n_dispatched / dt_batched
-    finally:
-        use_storage(prev)
-        storage.close()
-    flops = 2 * rank * n_items * n_dispatched  # the scoring matmuls
-    out = {
-        "queries_per_sec": round(batched_qps, 1),
-        "serial_queries_per_sec": round(serial_qps, 1),
-        "speedup_vs_serial": round(batched_qps / serial_qps, 1),
-        "batched_parity": parity_ok,
-        "batch_size_distribution": batch_sizes,
-        "store_reads": {
-            "serial_per_query": round(serial_reads, 2),
-            "batched_per_batch": round(batched_reads, 2),
-        },
-        "mfu": _mfu(flops, dt_batched, peaks[0]),
-    }
-    if parity is not None:
-        out["pallas_kernel_parity"] = parity
-    return out
-
-
-def _pallas_parity_check(model) -> bool:
-    """Quantized Pallas scorer (plain + per-row rule mask) vs the jnp
-    oracle on identical inputs."""
-    import jax.numpy as jnp
-
-    from incubator_predictionio_tpu.ops.retrieval import (
-        pad_catalog,
-        quantize_rows,
-        score_catalog_quantized,
-        score_catalog_reference,
-    )
-
-    n = min(2048, model.item_emb.shape[0])
-    items_q, scales = quantize_rows(np.asarray(model.item_emb[:n]))
-    items_q, scales, bias, mask = pad_catalog(
-        items_q, scales,
-        np.asarray(model.item_bias[:n], np.float32),
-        np.zeros(n, np.float32))
-    b = min(64, model.user_emb.shape[0])
-    ue = jnp.asarray(np.asarray(model.user_emb)[:b], jnp.float32)
-    rng = np.random.default_rng(0)
-    row_mask = np.zeros((b, items_q.shape[0]), np.float32)
-    row_mask[np.arange(b), rng.integers(0, n, b)] = -np.inf
-    row_mask = jnp.asarray(row_mask)
-    ok = True
-    for rm in (None, row_mask):
-        got = np.asarray(score_catalog_quantized(
-            ue, items_q, scales, bias, mask, rm))
-        want = np.asarray(score_catalog_reference(
-            ue, items_q, scales, bias, mask, rm))
-        good = bool(np.allclose(got, want, rtol=2e-2, atol=2e-2,
-                                equal_nan=True))
-        if not good:
-            _log(f"PALLAS PARITY FAILURE (row_mask={rm is not None}): "
-                 f"max abs diff {np.max(np.abs(got - want)):.4f}")
-        ok = ok and good
-    return ok
-
-
-# ---------------------------------------------------------------------------
-# 5b. two-stage retrieval at catalog scale (docs/serving.md)
-# ---------------------------------------------------------------------------
-
-def bench_retrieval_scale(ctx, peaks, device) -> dict:
-    """Exact full-catalog top-k vs the two-stage (IVF coarse prune + exact
-    rerank) path across catalog sizes × ``nprobe`` — the qps-vs-recall@10
-    curve that justifies PIO_RETRIEVAL_MODE=two_stage for big catalogs.
-
-    Catalogs are mixture-of-concepts synthetic towers (√N concepts,
-    σ=0.5) — the clustered geometry trained MF factors actually have, and
-    the regime the recall floor is specified over (an iid-gaussian catalog
-    has no structure to prune by; see tests/test_two_stage_retrieval.py).
-    The exact lane is the oracle: recall@10 is measured against ITS answers
-    on a held-out query set, and the headline speedup is only quoted at
-    operating points with recall ≥ 0.95."""
-    from incubator_predictionio_tpu.models.two_tower import (
-        TwoTowerConfig,
-        TwoTowerModel,
-        TwoTowerMF,
-    )
-
-    rank = 32
-    n_users = 10_000
-    # coalesced serving batches (the server's max_batch regime — cf. the
-    # ecommerce serving bench above): the int8 rerank amortizes each probed
-    # partition's upcast+GEMM across every query in the batch that probes
-    # it, so the quantized lane's speedup is measured at serve batch depth
-    batch, num = 128, 10
-    n_eval = 256            # oracle/recall query users
-    sizes = (100_000, 250_000) if SMALL else (100_000, 1_000_000)
-    # the int8 amortization win compounds with probes per query (more
-    # probers share each partition's upcast+GEMM), so the bigger-catalog
-    # operating points sit at the deep end of the grid
-    nprobes = (8, 16, 32, 64, 128)
-    prev_env = {k: os.environ.get(k) for k in
-                ("PIO_RETRIEVAL_MODE", "PIO_RETRIEVAL_NPROBE",
-                 "PIO_RETRIEVAL_QUANTIZE")}
-    points = []
-    headline = {}
-    try:
-        for n_items in sizes:
-            rng = np.random.default_rng(11)
-            n_concepts = max(64, int(round(np.sqrt(n_items))))
-            concepts = rng.standard_normal((n_concepts, rank)).astype(np.float32)
-            item = concepts[rng.integers(0, n_concepts, n_items)] \
-                + 0.5 * rng.standard_normal((n_items, rank)).astype(np.float32)
-            user = concepts[rng.integers(0, n_concepts, n_users)] \
-                + 0.5 * rng.standard_normal((n_users, rank)).astype(np.float32)
-            model = TwoTowerModel(
-                user_emb=user, item_emb=item,
-                user_bias=(rng.standard_normal(n_users) * 0.1).astype(np.float32),
-                item_bias=(rng.standard_normal(n_items) * 0.1).astype(np.float32),
-                mean=3.0, config=TwoTowerConfig(rank=rank))
-            qusers = rng.integers(0, n_users, (64, batch)).astype(np.int32)
-            eusers = rng.integers(0, n_users, (n_eval // batch, batch)).astype(np.int32)
-
-            def lane_qps(min_sec=2.0):
-                # warm one batch, then timed closed-loop batches
-                TwoTowerMF.recommend_batch(model, qusers[0], num)
-                done = 0
-                t0 = time.perf_counter()
-                while True:
-                    TwoTowerMF.recommend_batch(
-                        model, qusers[done % len(qusers)], num)
-                    done += 1
-                    dt = time.perf_counter() - t0
-                    if dt >= min_sec and done >= 8:
-                        return done * batch / dt
-
-            os.environ["PIO_RETRIEVAL_MODE"] = "exact"
-            model.prepare_for_serving(serve_k=num)
-            exact_qps = lane_qps()
-            oracle = [TwoTowerMF.recommend_batch(model, row, num)[0]
-                      for row in eusers]
-            os.environ["PIO_RETRIEVAL_MODE"] = "two_stage"
-            # fp32 lane first: int8 is the serving default, so the
-            # comparison lane opts out explicitly
-            os.environ["PIO_RETRIEVAL_QUANTIZE"] = "0"
-            model.prepare_for_serving(serve_k=num)  # builds the IVF index
-            build_sec = model._ivf.build_seconds
-            assert not model._ivf.quantized
-            for nprobe in nprobes:
-                os.environ["PIO_RETRIEVAL_NPROBE"] = str(nprobe)
-                got = [TwoTowerMF.recommend_batch(model, row, num)[0]
-                       for row in eusers]
-                recall = float(np.mean([
-                    len(set(o[r]) & set(g[r])) / num
-                    for o, g in zip(oracle, got) for r in range(batch)]))
-                qps = lane_qps()
-                points.append({
-                    "n_items": n_items, "nprobe": nprobe,
-                    "n_partitions": model._ivf.n_partitions,
-                    "qps": round(qps, 1), "recall_at_10": round(recall, 4),
-                    "exact_qps": round(exact_qps, 1),
-                    "speedup_vs_exact": round(qps / exact_qps, 1),
-                })
-                _log(f"retrieval_scale n={n_items} nprobe={nprobe}: "
-                     f"{qps:.0f} qps vs exact {exact_qps:.0f} "
-                     f"(recall@10 {recall:.3f})")
-            # int8 lane: both stages quantized (int8 coarse probe + int8
-            # rerank, one fp32 rescale each) at the SAME nprobe grid —
-            # the acceptance gate is ≥1.5× qps over the fp32 two-stage
-            # lane at an operating point holding recall@10 ≥ 0.95
-            fp32_qps = {p["nprobe"]: p["qps"] for p in points
-                        if p["n_items"] == n_items and "lane" not in p}
-            os.environ["PIO_RETRIEVAL_QUANTIZE"] = "1"
-            model.prepare_for_serving(serve_k=num)  # int8 index rebuild
-            int8_build_sec = model._ivf.build_seconds
-            assert model._ivf.quantized
-            for nprobe in nprobes:
-                os.environ["PIO_RETRIEVAL_NPROBE"] = str(nprobe)
-                got = [TwoTowerMF.recommend_batch(model, row, num)[0]
-                       for row in eusers]
-                recall = float(np.mean([
-                    len(set(o[r]) & set(g[r])) / num
-                    for o, g in zip(oracle, got) for r in range(batch)]))
-                qps = lane_qps()
-                points.append({
-                    "lane": "int8", "n_items": n_items, "nprobe": nprobe,
-                    "n_partitions": model._ivf.n_partitions,
-                    "qps": round(qps, 1), "recall_at_10": round(recall, 4),
-                    "exact_qps": round(exact_qps, 1),
-                    "speedup_vs_exact": round(qps / exact_qps, 1),
-                    "speedup_vs_fp32_two_stage":
-                        round(qps / fp32_qps[nprobe], 2),
-                })
-                _log(f"retrieval_scale[int8] n={n_items} nprobe={nprobe}: "
-                     f"{qps:.0f} qps ({qps / fp32_qps[nprobe]:.2f}x fp32 "
-                     f"two-stage, recall@10 {recall:.3f})")
-            os.environ["PIO_RETRIEVAL_QUANTIZE"] = "0"
-            os.environ.pop("PIO_RETRIEVAL_NPROBE", None)
-            model.prepare_for_serving(serve_k=num)  # back to the fp32 index
-            good = [p for p in points
-                    if p["n_items"] == n_items and "lane" not in p
-                    and p["recall_at_10"] >= 0.95]
-            good_int8 = [p for p in points
-                         if p["n_items"] == n_items
-                         and p.get("lane") == "int8"
-                         and p["recall_at_10"] >= 0.95]
-            # the int8 gate, asserted IN the lane: some nprobe holds the
-            # recall floor AND clears 1.5x over fp32 two-stage
-            assert good_int8, \
-                f"int8 lane lost the 0.95 recall floor at n={n_items}"
-            best_int8 = max(
-                p["speedup_vs_fp32_two_stage"] for p in good_int8)
-            assert best_int8 >= 1.5, \
-                (f"int8 lane gate: best speedup over fp32 two-stage at the "
-                 f"recall floor is {best_int8:.2f}x < 1.5x (n={n_items})")
-            headline[str(n_items)] = {
-                "exact_qps": round(exact_qps, 1),
-                "index_build_sec": round(build_sec, 1),
-                **({"best_qps": max(p["qps"] for p in good),
-                    "best_speedup": max(p["speedup_vs_exact"] for p in good),
-                    "recall_floor": 0.95} if good else
-                   {"best_speedup": None}),
-                "int8_build_sec": round(int8_build_sec, 1),
-                "int8_best_qps": max(p["qps"] for p in good_int8),
-                "int8_best_speedup_vs_fp32": best_int8,
-                "int8_recall_floor": 0.95,
-            }
-    finally:
-        for k, v in prev_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return {"points": points, "headline": headline,
-            "batch": batch, "num": num, "rank": rank}
-
-
-def bench_sharded_serving(ctx, peaks, device) -> dict:
-    """Sharded serving (docs/sharding.md) next to the exact and two-stage
-    lanes: the same catalog served (a) exact single-host, (b) two-stage
-    single-host IVF, (c) per-shard exact top-k + cross-shard merge from
-    model-axis-sharded device tables, (d) the composed per-shard-IVF +
-    merge-rerank path. Archives qps per lane, recall@10 vs the exact
-    oracle for the pruned lanes, and the per-lane ``pio_shard_*`` metric
-    deltas (merge fan-in, per-shard top-k/merge time, fallbacks).
-
-    Runs on 8 virtual CPU devices (run_one_config sets the XLA flag for
-    this config) — like the fleet scenario it measures the ARCHITECTURE
-    (merge overhead and layout), not chip throughput. The sharded_exact
-    lane's recall is vs the f32 HOST oracle, so slightly under 1.0 purely
-    from bf16 device scoring re-ordering near-ties — the sharded-vs-
-    single-DEVICE parity is bitwise and pinned in tests/test_sharding.py."""
-    import jax
-
-    from incubator_predictionio_tpu.models.two_tower import (
-        TwoTowerConfig,
-        TwoTowerModel,
-        TwoTowerMF,
-    )
-    from incubator_predictionio_tpu.obs.metrics import REGISTRY
-    from incubator_predictionio_tpu.parallel.mesh import MeshContext
-
-    rank = 32
-    n_users = 10_000
-    n_items = 60_000 if SMALL else 150_000
-    batch, num = 16, 10
-    n_shards = min(8, len(jax.devices()))
-    rng = np.random.default_rng(13)
-    n_concepts = max(64, int(round(np.sqrt(n_items))))
-    concepts = rng.standard_normal((n_concepts, rank)).astype(np.float32)
-    item = concepts[rng.integers(0, n_concepts, n_items)] \
-        + 0.5 * rng.standard_normal((n_items, rank)).astype(np.float32)
-    user = concepts[rng.integers(0, n_concepts, n_users)] \
-        + 0.5 * rng.standard_normal((n_users, rank)).astype(np.float32)
-    user_bias = (rng.standard_normal(n_users) * 0.1).astype(np.float32)
-    item_bias = (rng.standard_normal(n_items) * 0.1).astype(np.float32)
-
-    def host_model():
-        return TwoTowerModel(
-            user_emb=user, item_emb=item, user_bias=user_bias,
-            item_bias=item_bias, mean=3.0,
-            config=TwoTowerConfig(rank=rank))
-
-    def device_sharded_model():
-        """The same towers resident as model-axis-sharded device tables —
-        what a sharded fit/restore produces (fused bias column, rows
-        padded to the shard multiple)."""
-        mctx = MeshContext.create(axes={"data": 1, "model": n_shards})
-        m = TwoTowerModel(mean=3.0, config=TwoTowerConfig(rank=rank))
-
-        def fused(emb, bias):
-            t = np.concatenate([emb, bias[:, None]], axis=1)
-            pad = -(-t.shape[0] // n_shards) * n_shards - t.shape[0]
-            return np.pad(t, ((0, pad), (0, 0)))
-
-        m._tables = {
-            "ue": mctx.put(fused(user, user_bias), "model", None),
-            "ie": mctx.put(fused(item, item_bias), "model", None),
-        }
-        m._n_users, m._n_items = n_users, n_items
-        return m
-
-    qusers = rng.integers(0, n_users, (64, batch)).astype(np.int32)
-    eusers = rng.integers(0, n_users, (256 // batch, batch)).astype(np.int32)
-
-    def lane_qps(model, min_sec=2.0):
-        TwoTowerMF.recommend_batch(model, qusers[0], num)
-        done = 0
-        t0 = time.perf_counter()
-        while True:
-            TwoTowerMF.recommend_batch(model, qusers[done % len(qusers)], num)
-            done += 1
-            dt = time.perf_counter() - t0
-            if dt >= min_sec and done >= 8:
-                return done * batch / dt
-
-    def shard_delta(before):
-        after = _metrics_snapshot(REGISTRY.expose())
-        return {k: v for k, v in _snapshot_delta(before, after).items()
-                if k.startswith("pio_shard_")}
-
-    prev_env = {k: os.environ.get(k) for k in
-                ("PIO_SHARD_SERVE", "PIO_SHARD_SERVE_SHARDS",
-                 "PIO_RETRIEVAL_MODE", "PIO_RETRIEVAL_NPROBE")}
-    lanes: dict[str, dict] = {}
-    try:
-        os.environ["PIO_RETRIEVAL_NPROBE"] = "16"
-        # (a) exact single-host oracle lane
-        os.environ["PIO_SHARD_SERVE"] = "0"
-        os.environ["PIO_RETRIEVAL_MODE"] = "exact"
-        m = host_model()
-        m.prepare_for_serving(serve_k=num)
-        m.warmup(max_batch=batch)
-        lanes["exact"] = {"qps": round(lane_qps(m), 1)}
-        oracle = [TwoTowerMF.recommend_batch(m, row, num)[0]
-                  for row in eusers]
-
-        def recall(model):
-            got = [TwoTowerMF.recommend_batch(model, row, num)[0]
-                   for row in eusers]
-            return round(float(np.mean([
-                len(set(o[r]) & set(g[r])) / num
-                for o, g in zip(oracle, got) for r in range(batch)])), 4)
-
-        # (b) two-stage single-host lane
-        os.environ["PIO_RETRIEVAL_MODE"] = "two_stage"
-        m = host_model()
-        m.prepare_for_serving(serve_k=num)
-        m.warmup(max_batch=batch)
-        lanes["two_stage"] = {"qps": round(lane_qps(m), 1),
-                              "recall_at_10": recall(m)}
-        # (c) sharded exact from device tables
-        os.environ["PIO_SHARD_SERVE"] = "1"
-        os.environ["PIO_RETRIEVAL_MODE"] = "exact"
-        md = device_sharded_model()
-        md.prepare_for_serving(serve_k=num)
-        md.warmup(max_batch=batch)
-        before = _metrics_snapshot(REGISTRY.expose())
-        lanes["sharded_exact"] = {
-            "qps": round(lane_qps(md), 1), "n_shards": n_shards,
-            "recall_at_10": recall(md),  # exact: must be 1.0
-        }
-        lanes["sharded_exact"]["pio_shard"] = shard_delta(before)
-        # (d) composed per-shard IVF + merge rerank
-        os.environ["PIO_RETRIEVAL_MODE"] = "two_stage"
-        md = device_sharded_model()
-        md.prepare_for_serving(serve_k=num)
-        md.warmup(max_batch=batch)
-        before = _metrics_snapshot(REGISTRY.expose())
-        lanes["sharded_two_stage"] = {
-            "qps": round(lane_qps(md), 1), "n_shards": n_shards,
-            "recall_at_10": recall(md),
-        }
-        lanes["sharded_two_stage"]["pio_shard"] = shard_delta(before)
-    finally:
-        for k, v in prev_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    for name, lane in lanes.items():
-        _log(f"sharded_serving {name}: {lane['qps']} qps"
-             + (f" recall@10 {lane['recall_at_10']}"
-                if "recall_at_10" in lane else ""))
-    return {"lanes": lanes, "n_items": n_items, "batch": batch, "num": num,
-            "rank": rank, "n_shards": n_shards,
-            "n_devices": len(jax.devices())}
-
-
-# ---------------------------------------------------------------------------
-# 6. sequential transformer (the long-context flagship)
-# ---------------------------------------------------------------------------
-
-def bench_sequential(ctx, peaks, device) -> dict:
-    from incubator_predictionio_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerRecommender,
-    )
-
-    # production-representative shapes (VERDICT r2: d_model ≥512, seq ≥512)
-    small = SMALL
-    if small:
-        vocab, max_len, d, layers, heads = 10_000, 128, 256, 4, 4
-        n, epochs, batch = 256, 1, 128
-    else:
-        vocab, max_len, d, layers, heads = 10_000, 512, 512, 6, 8
-        n, epochs, batch = 2048, 2, 64
-    import dataclasses as _dc
-
-    rng = np.random.default_rng(11)
-    seqs = rng.integers(1, vocab, (n, max_len + 1)).astype(np.int32)
-    cfg = TransformerConfig(
-        vocab_size=vocab, max_len=max_len, d_model=d, n_heads=heads,
-        n_layers=layers, batch_size=batch, epochs=epochs, attention="local")
-
-    TransformerRecommender(cfg).fit(ctx, seqs, None)
-    t0 = time.perf_counter()
-    # distinct seed: an identical re-run could be served from an execution
-    # cache (no recompile — seed is data, not static)
-    model = TransformerRecommender(_dc.replace(cfg, seed=1)).fit(ctx, seqs, None)
-    dt = time.perf_counter() - t0
-    tokens = epochs * n * max_len
-    n_nonemb = 12 * layers * d * d  # attn(4d²) + mlp(8d²) per layer
-    flops_per_token = 6 * n_nonemb + 12 * layers * d * max_len
-    t_train = model.timings["train_sec"]
-    return {
-        "tokens_per_sec": round(tokens / dt, 1),
-        "train_tokens_per_sec": round(tokens / t_train, 1),
-        "mfu": _mfu(tokens * flops_per_token, t_train, peaks[0]),
-        "timings": model.timings,
-    }
-
-
-# ---------------------------------------------------------------------------
-# 7. serving latency through the deployed query server (north-star p50)
-# ---------------------------------------------------------------------------
-
-#: Standalone load client (argv: base_url, duration_s, n_users). Runs in its
-#: own process — no jax, no shared event loop with the server — over raw
-#: keep-alive sockets, and prints one JSON line of client-observed stats.
-_SERVING_CLIENT_SCRIPT = """
-# Raw-socket HTTP/1.1 keep-alive load generator: the client shares the
-# host's core(s) with the server under test, and an aiohttp client costs
-# more per request than the server handler — measuring through it reports
-# the client, not the server (same rationale as the ingestion driver).
-import asyncio, json, sys, time, urllib.parse
-
-import numpy as np
-
-base, duration, n_users = sys.argv[1], float(sys.argv[2]), int(sys.argv[3])
-host = urllib.parse.urlsplit(base).hostname
-port = urllib.parse.urlsplit(base).port
-lat_ms = []
-
-
-def req_bytes(user):
-    body = json.dumps({"user": user, "num": 10}).encode()
-    return (f"POST /queries.json HTTP/1.1\\r\\nHost: {host}:{port}\\r\\n"
-            f"Content-Type: application/json\\r\\n"
-            f"Content-Length: {len(body)}\\r\\n\\r\\n").encode() + body
-
-
-async def post(r, w, user):
-    w.write(req_bytes(user))
-    await w.drain()
-    status = await r.readline()
-    assert b" 200 " in status, status
-    length = None
-    while True:
-        line = await r.readline()
-        if line in (b"\\r\\n", b""):
-            break
-        if line.lower().startswith(b"content-length:"):
-            length = int(line.split(b":")[1])
-    assert length is not None
-    await r.readexactly(length)
-
-
-async def main():
-    conns = [await asyncio.open_connection(host, port) for _ in range(16)]
-    await post(*conns[0], "u1")  # warmup round trip
-    stop_at = time.perf_counter() + duration
-
-    async def worker(conn, wid):
-        rng = np.random.default_rng(wid)
-        while time.perf_counter() < stop_at:
-            t0 = time.perf_counter()
-            await post(*conn, f"u{rng.integers(0, n_users)}")
-            lat_ms.append((time.perf_counter() - t0) * 1e3)
-
-    await asyncio.gather(*(worker(c, i) for i, c in enumerate(conns)))
-    for _, w in conns:
-        w.close()
-
-asyncio.run(main())
-a = np.sort(np.asarray(lat_ms))
-pct = lambda q: float(a[min(len(a) - 1, int(q * (len(a) - 1)))])
-print(json.dumps({
-    "p50_ms": round(pct(0.50), 2), "p95_ms": round(pct(0.95), 2),
-    "p99_ms": round(pct(0.99), 2), "qps": round(len(a) / duration, 1),
-    "count": len(a),
-}))
-"""
 
 def _metrics_snapshot(text: str) -> dict:
     """Trim a /metrics page into a JSON-friendly snapshot: counter/gauge
@@ -1000,9 +71,9 @@ def _train_recommendation(ctx, storage, tmp: str, n_users: int,
                               "recommendation.RecommendationEngine")) -> str:
     """Seed rating events and train the recommendation template through
     the real workflow; returns the engine-variant path. Shared by the
-    serving, overload, and fleet scenarios (one training recipe, several
-    load shapes); ``factory_path`` lets a scenario deploy a wrapped engine
-    (the fleet scenario's service-floor fixture) around the same model."""
+    serving drills (one training recipe, several load shapes);
+    ``factory_path`` lets a scenario deploy a wrapped engine (the fleet
+    scenario's service-floor fixture) around the same model."""
     import datetime as dt_mod
 
     from incubator_predictionio_tpu.core.controller import (
@@ -1051,402 +122,11 @@ def _train_recommendation(ctx, storage, tmp: str, n_users: int,
     return variant_path
 
 
-def bench_serving(ctx) -> dict:
-    """Train the recommendation template through the real workflow, deploy it
-    in the real query server, and measure client-observed latency under
-    concurrent load (16 closed-loop clients) — exercising bind → supplement →
-    MicroBatcher → batch_predict → serve, the full CreateServer.scala:464-494
-    path."""
-    from incubator_predictionio_tpu.data.storage import Storage, use_storage
-    from incubator_predictionio_tpu.server.query_server import QueryServer, ServerConfig
-    from incubator_predictionio_tpu.templates.recommendation import RecommendationEngine
-
-    import tempfile
-
-    n_users, n_items, n_events = 2000, 1000, (5_000 if SMALL else 50_000)
-    storage = Storage({"PIO_STORAGE_SOURCES_MEM_TYPE": "memory"})
-    prev = use_storage(storage)
-    tmp = tempfile.mkdtemp(prefix="pio-bench-")
-    try:
-        variant_path = _train_recommendation(
-            ctx, storage, tmp, n_users, n_items, n_events)
-
-        # The server runs IN the bench process (it owns the accelerator); the
-        # LOAD CLIENT is a separate OS process driving a real TCP socket —
-        # client-observed latency includes the wire, not a shared event loop.
-        import subprocess
-        import sys as _sys
-
-        from incubator_predictionio_tpu.parallel.launcher import free_port
-
-        duration = 2.0 if SMALL else 6.0
-        port = free_port()
-        client_script = _SERVING_CLIENT_SCRIPT
-
-        # gauge serving-only compiles: earlier configs in this process (e.g.
-        # the retrieval bench) already registered jit keys
-        from incubator_predictionio_tpu.utils import jitstats
-
-        jitstats.reset()
-
-        async def drive() -> tuple[dict, dict]:
-            server = QueryServer(
-                ServerConfig(engine_variant=variant_path, ip="127.0.0.1",
-                             port=port),
-                storage=storage, ctx=ctx)
-            await server.start()
-            try:
-                proc = await asyncio.create_subprocess_exec(
-                    _sys.executable, "-c", client_script,
-                    f"http://127.0.0.1:{port}", str(duration), str(n_users),
-                    stdout=subprocess.PIPE,
-                )
-                try:
-                    stdout, _ = await asyncio.wait_for(
-                        proc.communicate(), timeout=duration + 120)
-                except asyncio.TimeoutError:
-                    proc.kill()  # a wedged load generator must not outlive us
-                    await proc.wait()
-                    raise
-                assert proc.returncode == 0, proc.returncode
-                client_stats = json.loads(stdout.decode().strip().splitlines()[-1])
-                import aiohttp
-
-                async with aiohttp.ClientSession() as s:
-                    status = await (await s.get(
-                        f"http://127.0.0.1:{port}/")).json()
-                    metrics_text = await (await s.get(
-                        f"http://127.0.0.1:{port}/metrics")).text()
-                return client_stats, status, metrics_text
-            finally:
-                await server.shutdown()
-
-        client_stats, status, metrics_text = asyncio.run(drive())
-        metrics_snapshot = _metrics_snapshot(metrics_text)
-        out = {
-            "predict_p50_ms": client_stats["p50_ms"],
-            "predict_p95_ms": client_stats["p95_ms"],
-            "predict_p99_ms": client_stats["p99_ms"],
-            "queries_per_sec": client_stats["qps"],
-            "max_batch_seen": status.get("maxBatchSeen"),
-            "jit_compile_keys": status.get("jitCompileKeys"),
-            "server_p50_ms": round(
-                status["servingSecPercentiles"]["p50"] * 1e3, 2),
-            # the /metrics fold (ISSUE 2): the same counters/gauges a
-            # Prometheus scrape would see during the run, archived with the
-            # bench so telemetry regressions show up in artifact diffs
-            "metrics": metrics_snapshot,
-        }
-        # Pallas/oracle parity on the DEPLOYED model's factors. The bench
-        # catalog itself serves from the host fast path (small catalog); this
-        # asserts that had it been large enough for the device path, the
-        # quantized scorer agrees — on the trained weights, not synthetic ones
-        import jax
-
-        if jax.devices()[0].platform == "tpu":
-            instances = storage.get_meta_data_engine_instances()
-            inst = instances.get_latest_completed(
-                "bench", "1", os.path.abspath(variant_path))
-            blob = storage.get_model_data_models().get(inst.id)
-            from incubator_predictionio_tpu.utils.serialization import (
-                deserialize_model,
-            )
-
-            with open(variant_path) as f:
-                variant = json.load(f)
-            engine = RecommendationEngine().apply()
-            engine_params = engine.engine_params_from_variant(variant)
-            persisted = deserialize_model(blob.models)
-            models = engine.prepare_deploy(
-                ctx, engine_params, persisted, inst.id)
-            # read-only check on the trained factor tables
-            out["pallas_kernel_parity"] = _pallas_parity_check(models[0].mf)
-        return out
-    finally:
-        use_storage(prev)
-        storage.close()
-
-
 # ---------------------------------------------------------------------------
-# 7a½. trace-plane overhead (docs/observability.md "The trace plane"):
-#      serving qps with the durable span spool at 0% / 1% / 100% head
-#      sampling vs tracing-off — the measurement plane must not tax the
-#      thing it measures (≤5% at 1% sampling asserted)
-# ---------------------------------------------------------------------------
-
-
-def bench_trace_overhead(ctx) -> dict:
-    """Deploy the recommendation template in the real query server and
-    drive the same 16-connection closed loop under four trace-plane
-    configurations: export off, spool at PIO_TRACE_SAMPLE 0 / 0.01 / 1.0.
-    Two passes per lane, best qps kept (the lanes share one noisy host
-    with the load client). Archives the assembled slowest-trace waterfall
-    from the 100% lane — the artifact `pio-tpu trace slowest` would show."""
-    import subprocess
-    import sys as _sys
-    import tempfile
-
-    from incubator_predictionio_tpu.data.storage import Storage, use_storage
-    from incubator_predictionio_tpu.obs import collect
-    from incubator_predictionio_tpu.obs import spool as trace_spool
-    from incubator_predictionio_tpu.parallel.launcher import free_port
-    from incubator_predictionio_tpu.server.query_server import (
-        QueryServer,
-        ServerConfig,
-    )
-
-    n_users, n_items, n_events = 2000, 1000, (5_000 if SMALL else 20_000)
-    duration = 2.0 if SMALL else 4.0
-    storage = Storage({"PIO_STORAGE_SOURCES_MEM_TYPE": "memory"})
-    prev = use_storage(storage)
-    tmp = tempfile.mkdtemp(prefix="pio-traceov-")
-    # one spool dir PER LANE: the archived artifact and byte figure must
-    # describe a single configuration, not the union of all four lanes
-    spool_100 = os.path.join(tmp, "spool-100pct")
-    trace_envs = {
-        "off": {},
-        "sample_0": {"PIO_TRACE_SPOOL_DIR": os.path.join(tmp, "spool-0"),
-                     "PIO_TRACE_SAMPLE": "0"},
-        "sample_1pct": {"PIO_TRACE_SPOOL_DIR": os.path.join(tmp, "spool-1"),
-                        "PIO_TRACE_SAMPLE": "0.01"},
-        "sample_100pct": {"PIO_TRACE_SPOOL_DIR": spool_100,
-                          "PIO_TRACE_SAMPLE": "1"},
-    }
-    touched = sorted({k for env in trace_envs.values() for k in env})
-    saved_env = {k: os.environ.get(k) for k in touched}
-
-    def _apply_env(env: dict) -> None:
-        for k in touched:
-            os.environ.pop(k, None)
-        os.environ.update(env)
-
-    async def drive(variant_path: str, port: int) -> dict:
-        server = QueryServer(
-            ServerConfig(engine_variant=variant_path, ip="127.0.0.1",
-                         port=port),
-            storage=storage, ctx=ctx)
-        await server.start()
-        try:
-            proc = await asyncio.create_subprocess_exec(
-                _sys.executable, "-c", _SERVING_CLIENT_SCRIPT,
-                f"http://127.0.0.1:{port}", str(duration), str(n_users),
-                stdout=subprocess.PIPE)
-            try:
-                stdout, _ = await asyncio.wait_for(
-                    proc.communicate(), timeout=duration + 120)
-            except asyncio.TimeoutError:
-                proc.kill()
-                await proc.wait()
-                raise
-            assert proc.returncode == 0, proc.returncode
-            return json.loads(stdout.decode().strip().splitlines()[-1])
-        finally:
-            await server.shutdown()
-
-    try:
-        variant_path = _train_recommendation(
-            ctx, storage, tmp, n_users, n_items, n_events)
-        lanes: dict[str, dict] = {}
-        for _pass in range(2):
-            for lane, env in trace_envs.items():
-                _apply_env(env)
-                if not env:
-                    # an earlier lane configured the module-wide exporter;
-                    # "off" must really mean export disabled
-                    trace_spool.close_export()
-                stats = asyncio.run(drive(variant_path, free_port()))
-                prev_best = lanes.get(lane)
-                if prev_best is None or stats["qps"] > prev_best["qps"]:
-                    lanes[lane] = stats
-        trace_spool.close_export()
-
-        # assemble the 100% lane's spool: the slowest trace's waterfall is
-        # the bench artifact an operator would pull via `pio-tpu trace`
-        spans, problems = collect.read_spool_dir(spool_100)
-        trees = collect.slowest(collect.assemble(spans), 1)
-        slowest_artifact = None
-        if trees:
-            t = trees[0]
-            slowest_artifact = {
-                "traceId": t["traceId"],
-                "durationMs": round(t["durationSec"] * 1e3, 2),
-                "spanCount": t["spanCount"],
-                "services": t["services"],
-                "complete": t["complete"],
-                "waterfall": collect.waterfall(t),
-            }
-        spool_bytes = sum(
-            os.path.getsize(p) for p in trace_spool.spool_files(spool_100))
-        qps_off = lanes["off"]["qps"]
-        qps_1pct = lanes["sample_1pct"]["qps"]
-        regression_1pct = (1.0 - qps_1pct / qps_off) if qps_off else 0.0
-        out = {
-            "lanes": lanes,
-            "qps_off": qps_off,
-            "qps_sample_0": lanes["sample_0"]["qps"],
-            "qps_sample_1pct": qps_1pct,
-            "qps_sample_100pct": lanes["sample_100pct"]["qps"],
-            "regression_1pct_vs_off": round(regression_1pct, 4),
-            "spool_bytes_after_100pct": spool_bytes,
-            "spool_problems": problems,
-            "slowest_trace": slowest_artifact,
-            "spooled_spans": len(spans),
-        }
-        # acceptance: 1% sampling with the spool on costs ≤5% qps vs off
-        assert regression_1pct <= 0.05, (
-            f"trace plane at 1% sampling cost {regression_1pct:.1%} qps "
-            f"({qps_1pct:.0f} vs {qps_off:.0f})")
-        return out
-    finally:
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        trace_spool.close_export()
-        use_storage(prev)
-        storage.close()
-
-
-# ---------------------------------------------------------------------------
-# 7a2. performance-plane overhead (docs/observability.md "Metrics history &
-#      SLOs"): the continuous plane must be cheap enough to leave on
-# ---------------------------------------------------------------------------
-
-
-def bench_obs_overhead(ctx) -> dict:
-    """Deploy the recommendation template in the real query server and
-    drive the same 16-connection closed loop under three performance-plane
-    configurations: plane off; history + SLO engine on (the always-on
-    default, with the self-scrape interval cranked 20× faster than the
-    5000 ms default so its cost is actually exercised inside a short
-    lane); and the full plane with the wall-stack sampler at 97 Hz on
-    top. Two passes per lane, best qps kept. Archives the durable
-    history's record count and on-disk bytes from the full lane — the
-    artifact ``pio-tpu history <dir>`` would summarize."""
-    import subprocess
-    import sys as _sys
-    import tempfile
-
-    from incubator_predictionio_tpu.data.storage import Storage, use_storage
-    from incubator_predictionio_tpu.obs import history as hist
-    from incubator_predictionio_tpu.obs.plane import close_perf_plane
-    from incubator_predictionio_tpu.parallel.launcher import free_port
-    from incubator_predictionio_tpu.server.query_server import (
-        QueryServer,
-        ServerConfig,
-    )
-
-    n_users, n_items, n_events = 2000, 1000, (5_000 if SMALL else 20_000)
-    duration = 2.0 if SMALL else 4.0
-    storage = Storage({"PIO_STORAGE_SOURCES_MEM_TYPE": "memory"})
-    prev = use_storage(storage)
-    tmp = tempfile.mkdtemp(prefix="pio-obsov-")
-    slo_conf = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "conf", "slo.json")
-    hist_full = os.path.join(tmp, "hist-full")
-    # one history dir PER LANE: the archived byte/record figures must
-    # describe a single configuration, not the union of both on-lanes
-    plane_envs = {
-        "off": {},
-        "history_slo": {
-            "PIO_HISTORY_DIR": os.path.join(tmp, "hist-default"),
-            "PIO_HISTORY_INTERVAL_MS": "250",
-            "PIO_SLO_CONFIG": slo_conf,
-        },
-        "full_profiler": {
-            "PIO_HISTORY_DIR": hist_full,
-            "PIO_HISTORY_INTERVAL_MS": "250",
-            "PIO_SLO_CONFIG": slo_conf,
-            "PIO_PROFILE_HZ": "97",
-        },
-    }
-    touched = sorted({k for env in plane_envs.values() for k in env})
-    saved_env = {k: os.environ.get(k) for k in touched}
-
-    def _apply_env(env: dict) -> None:
-        for k in touched:
-            os.environ.pop(k, None)
-        os.environ.update(env)
-
-    async def drive(variant_path: str, port: int) -> dict:
-        server = QueryServer(
-            ServerConfig(engine_variant=variant_path, ip="127.0.0.1",
-                         port=port),
-            storage=storage, ctx=ctx)
-        await server.start()
-        try:
-            proc = await asyncio.create_subprocess_exec(
-                _sys.executable, "-c", _SERVING_CLIENT_SCRIPT,
-                f"http://127.0.0.1:{port}", str(duration), str(n_users),
-                stdout=subprocess.PIPE)
-            try:
-                stdout, _ = await asyncio.wait_for(
-                    proc.communicate(), timeout=duration + 120)
-            except asyncio.TimeoutError:
-                proc.kill()
-                await proc.wait()
-                raise
-            assert proc.returncode == 0, proc.returncode
-            return json.loads(stdout.decode().strip().splitlines()[-1])
-        finally:
-            await server.shutdown()
-
-    try:
-        variant_path = _train_recommendation(
-            ctx, storage, tmp, n_users, n_items, n_events)
-        lanes: dict[str, dict] = {}
-        for _pass in range(2):
-            for lane, env in plane_envs.items():
-                _apply_env(env)
-                if not env:
-                    # an earlier lane configured the module-wide recorder /
-                    # sampler; "off" must really mean the plane is down
-                    close_perf_plane()
-                stats = asyncio.run(drive(variant_path, free_port()))
-                prev_best = lanes.get(lane)
-                if prev_best is None or stats["qps"] > prev_best["qps"]:
-                    lanes[lane] = stats
-        close_perf_plane()
-
-        records = hist.read_history(hist_full)
-        hist_bytes = sum(
-            os.path.getsize(os.path.join(hist_full, f))
-            for f in os.listdir(hist_full)) if os.path.isdir(hist_full) else 0
-        qps_off = lanes["off"]["qps"]
-        qps_on = lanes["history_slo"]["qps"]
-        regression_on = (1.0 - qps_on / qps_off) if qps_off else 0.0
-        out = {
-            "lanes": lanes,
-            "qps_off": qps_off,
-            "qps_history_slo": qps_on,
-            "qps_full_profiler": lanes["full_profiler"]["qps"],
-            "regression_history_slo_vs_off": round(regression_on, 4),
-            "history_records_full_lane": len(records),
-            "history_bytes_full_lane": hist_bytes,
-        }
-        # acceptance: history + SLO engine (scraping 20× faster than the
-        # default interval) costs ≤3% qps vs plane-off
-        assert regression_on <= 0.03, (
-            f"performance plane cost {regression_on:.1%} qps "
-            f"({qps_on:.0f} vs {qps_off:.0f})")
-        return out
-    finally:
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        close_perf_plane()
-        use_storage(prev)
-        storage.close()
-
-
-# ---------------------------------------------------------------------------
-# 7b. goodput under overload (docs/resilience.md "Overload & admission
-#     control"): offered load at ~3× measured capacity through the real
-#     admission layer — goodput and admitted-p99, not peak qps, are what a
-#     production stack is judged on
+# goodput under overload (docs/resilience.md "Overload & admission
+# control"): offered load at ~3× measured capacity through the real
+# admission layer — goodput and admitted-p99, not peak qps, are what a
+# production stack is judged on
 # ---------------------------------------------------------------------------
 
 #: Three-phase load client (argv after the repo root: base_url, warm_s,
@@ -1568,9 +248,9 @@ def bench_overload(ctx) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 7c. fleet serving (docs/serving.md "Fleet serving"): 1 vs 3 query-server
-#     replicas behind the fleet router at a FIXED offered load — the
-#     horizontal-scaling story the router exists for
+# fleet serving (docs/serving.md "Fleet serving"): 1 vs 3 query-server
+# replicas behind the fleet router at a FIXED offered load — the
+# horizontal-scaling story the router exists for
 # ---------------------------------------------------------------------------
 
 #: Load-client shim for the fleet scenario (argv after the repo root:
@@ -1787,11 +467,11 @@ def bench_fleet(ctx) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 7d. multi-tenant serving (docs/tenancy.md): four tenants in ONE
-#     query-server process under a shared byte budget, one tenant offering
-#     3× its quota — the noisy-neighbor containment + packing numbers whose
-#     acceptance bars the chaos test asserts
-#     (tests/test_chaos_procs.py::test_multi_tenant_noisy_neighbor_contained)
+# multi-tenant serving (docs/tenancy.md): four tenants in ONE
+# query-server process under a shared byte budget, one tenant offering
+# 3× its quota — the noisy-neighbor containment + packing numbers whose
+# acceptance bars the chaos test asserts
+# (tests/test_chaos_procs.py::test_multi_tenant_noisy_neighbor_contained)
 # ---------------------------------------------------------------------------
 
 #: Per-tenant load driver (argv after the repo root: host, port, path,
@@ -1998,10 +678,10 @@ def bench_multi_tenant(ctx) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 7c'. sharded fleet (docs/sharding.md "Multi-host shard owners"): the
-#      catalog split ACROSS processes — scatter/gather parity cost vs one
-#      process holding everything, plus failover MTTR when an owner takes
-#      a SIGKILL
+# sharded fleet (docs/sharding.md "Multi-host shard owners"): the
+# catalog split ACROSS processes — scatter/gather parity cost vs one
+# process holding everything, plus failover MTTR when an owner takes
+# a SIGKILL
 # ---------------------------------------------------------------------------
 
 
@@ -2213,9 +893,9 @@ def bench_sharded_fleet(ctx) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 7d. storage failover (docs/replication.md): sustained ingest, SIGKILL the
-#     primary storage server, promote the follower — MTTR and zero acked
-#     loss through the quorum-replicated eventlog
+# storage failover (docs/replication.md): sustained ingest, SIGKILL the
+# primary storage server, promote the follower — MTTR and zero acked
+# loss through the quorum-replicated eventlog
 # ---------------------------------------------------------------------------
 
 
@@ -2790,7 +1470,7 @@ def _dr_follower_backup_phase(tmp, pre_s, event_body, ingest_loop) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 8. event-server ingestion throughput (EventServer.scala:261-462 hot path)
+# event-server ingestion throughput (EventServer.scala:261-462 hot path)
 # ---------------------------------------------------------------------------
 
 #: Standalone event-server process (argv: port, backend, path). Seeds the
@@ -2979,8 +1659,8 @@ def bench_ingest_durability() -> dict:
             wal.close()
         out[f"{label}_events_per_sec"] = N_BATCHES * BATCH / dt
         out[f"{label}_batch_ms"] = dt / N_BATCHES * 1e3
-    # the headline ratio BENCH_*.json tracks from this PR on: how much of
-    # the in-memory ack rate survives the fsync-on-ack contract
+    # the headline ratio: how much of the in-memory ack rate survives the
+    # fsync-on-ack contract
     out["fsync_tax_vs_memory"] = (
         out["wal_fsync_events_per_sec"] / out["memory_events_per_sec"])
     out["fsync_tax_vs_nofsync"] = (
@@ -2988,69 +1668,9 @@ def bench_ingest_durability() -> dict:
     return out
 
 
-def build_result_line(configs: dict, device_info: dict,
-                      wedged: str | None = None) -> str:
-    """The single JSON artifact line."""
-    rec = configs.get("recommendation", {})
-    rec_scaled = configs.get("recommendation_scaled", {})
-    serving = configs.get("serving", {})
-    line = {
-        "metric": "recommendation_scaled_train_throughput",
-        "value": rec_scaled.get("events_per_sec", 0.0),
-        "unit": "events/sec/chip",
-        "vs_baseline": rec_scaled.get(
-            "vs_host_numpy", rec.get("vs_host_numpy", 0.0)),
-        "platform": device_info.get("platform"),
-        "device": device_info.get("device"),
-        "mfu": rec_scaled.get("mfu"),
-        "hbm_util": rec_scaled.get("hbm_util", rec.get("hbm_util")),
-        "predict_p50_ms": serving.get("predict_p50_ms"),
-        "predict_p95_ms": serving.get("predict_p95_ms"),
-        "configs": configs,
-    }
-    if wedged:
-        line["wedged"] = wedged
-    return json.dumps(line)
-
-
-# suite order; "ingestion" and "ingest_durability" never touch the device
-# (they bench the event servers' durable write paths)
-CONFIG_NAMES = ["recommendation", "recommendation_scaled", "classification",
-                "similarproduct", "ecommerce_retrieval", "retrieval_scale",
-                "sharded_serving", "sequential", "serving", "trace_overhead",
-                "obs_overhead", "overload", "fleet", "multi_tenant",
-                "sharded_fleet",
-                "ingestion", "ingest_durability",
-                "streaming_freshness", "storage_failover",
-                "continuous_training", "disaster_recovery",
-                "distributed_training"]
-# "fleet" and "sharded_fleet" are device-free too: their replicas are CPU
-# subprocesses (a fleet on one host) — the scenarios measure the ROUTER's
-# horizontal scaling and scatter/gather cost, not chip throughput; "sharded_serving" likewise runs on 8 virtual CPU
-# devices (merge/layout architecture, not chip throughput);
-# "continuous_training" measures the control plane's recovery clock, not
-# the chip
-DEVICE_FREE = {"ingestion", "ingest_durability", "fleet", "multi_tenant",
-               "sharded_fleet",
-               "streaming_freshness", "storage_failover",
-               "sharded_serving", "continuous_training",
-               "disaster_recovery", "distributed_training"}
-
-
-def _build_suite(ctx, peaks, device) -> dict:
+def _build_suite(ctx) -> dict:
+    """The drills in suite order, by the name ``--config`` takes."""
     return {
-        "recommendation": lambda: bench_recommendation(ctx, peaks),
-        "recommendation_scaled": lambda: bench_recommendation_scaled(
-            ctx, peaks, device),
-        "classification": lambda: bench_classification(ctx, peaks),
-        "similarproduct": lambda: bench_similarproduct(ctx, peaks),
-        "ecommerce_retrieval": lambda: bench_ecommerce_retrieval(ctx, peaks, device),
-        "retrieval_scale": lambda: bench_retrieval_scale(ctx, peaks, device),
-        "sharded_serving": lambda: bench_sharded_serving(ctx, peaks, device),
-        "sequential": lambda: bench_sequential(ctx, peaks, device),
-        "serving": lambda: bench_serving(ctx),
-        "trace_overhead": lambda: bench_trace_overhead(ctx),
-        "obs_overhead": lambda: bench_obs_overhead(ctx),
         "overload": lambda: bench_overload(ctx),
         "fleet": lambda: bench_fleet(ctx),
         "multi_tenant": lambda: bench_multi_tenant(ctx),
@@ -3065,10 +1685,13 @@ def _build_suite(ctx, peaks, device) -> dict:
     }
 
 
+CONFIG_NAMES = list(_build_suite(None))
+
+
 # ---------------------------------------------------------------------------
-# 10. streaming freshness (docs/streaming.md): event→recommendation-visible
-#     latency through the incremental delta pipeline vs the full
-#     retrain+redeploy cycle, plus the updater's sustained fold throughput
+# streaming freshness (docs/streaming.md): event→recommendation-visible
+# latency through the incremental delta pipeline vs the full
+# retrain+redeploy cycle, plus the updater's sustained fold throughput
 # ---------------------------------------------------------------------------
 
 
@@ -3239,10 +1862,10 @@ def bench_streaming_freshness() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 11. continuous training (docs/jobs.md): SIGKILL the training worker
-#     mid-epoch and measure retrain MTTR (kill → new instance serving),
-#     then trip the streaming quarantine and measure the auto-retrain loop's
-#     quarantine → fresh-recommendations end-to-end time
+# continuous training (docs/jobs.md): SIGKILL the training worker
+# mid-epoch and measure retrain MTTR (kill → new instance serving),
+# then trip the streaming quarantine and measure the auto-retrain loop's
+# quarantine → fresh-recommendations end-to-end time
 # ---------------------------------------------------------------------------
 
 
@@ -3481,11 +2104,11 @@ def bench_continuous_training() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 12. distributed training (docs/sharding.md "Multi-host training"): 1 vs N
-#     supervised member processes training the recommendation template with
-#     row-sharded tables, then SIGKILL one member mid-epoch — MTTR, the
-#     pinned resume epoch, and zero divergence vs the uninterrupted N-member
-#     run, plus the supervisor plane's pio_dist_* metric deltas
+# distributed training (docs/sharding.md "Multi-host training"): 1 vs N
+# supervised member processes training the recommendation template with
+# row-sharded tables, then SIGKILL one member mid-epoch — MTTR, the
+# pinned resume epoch, and zero divergence vs the uninterrupted N-member
+# run, plus the supervisor plane's pio_dist_* metric deltas
 # ---------------------------------------------------------------------------
 
 
@@ -3639,52 +2262,42 @@ def bench_distributed_training() -> dict:
 
 
 def run_one_config(name: str) -> None:
-    """Child mode: run exactly one config and print ``CONFIG_RESULT=<json>``.
+    """Child mode: run exactly one drill and print ``CONFIG_RESULT=<json>``.
 
-    A device lane needs a chip: with none, ``chip_peaks`` raises, the child
-    exits non-zero without a result and the parent fails the run."""
-    import jax
+    Every drill exercises the host plane (admission, routing, storage,
+    recovery), so the process and the servers it spawns are held to the
+    CPU: beside a live deploy a drill must never claim the accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     from incubator_predictionio_tpu.parallel.mesh import MeshContext
 
-    ctx = MeshContext.create()
-    device = jax.devices()[0]
-    peaks = (None, None) if name in DEVICE_FREE else chip_peaks(device)
     t0 = time.perf_counter()
-    result = _build_suite(ctx, peaks, device)[name]()
+    result = _build_suite(MeshContext.create())[name]()
     _log(f"{name}: {result} ({time.perf_counter() - t0:.1f}s)")
-    result.setdefault("platform", device.platform)
-    result.setdefault("device", device.device_kind)
     print("CONFIG_RESULT=" + json.dumps(result), flush=True)
 
 
-def _run_config_subprocess(name: str, timeout_s: float):
-    """Run one config in a child process. Returns (result_dict, wedged_bool).
+def _child_argv(name: str) -> list[str]:
+    """One drill's child process: this file's child mode, so the child is
+    held to the CPU by ``run_one_config`` like a drill started by hand."""
+    return [sys.executable, os.path.abspath(__file__), "--config", name]
 
-    A device dispatch that hangs sits inside the PJRT C++ layer where signal
-    handlers never run — killing the child is the only reliable escape, and
-    it leaves the parent free to run the remaining configs."""
+
+def _run_config_subprocess(name: str, timeout_s: float):
+    """Run one drill in a child process. Returns (result_dict, wedged_bool).
+
+    A drill that hangs (a spawned server that never answers, a wedged
+    runtime call where signal handlers never run) is killed with its whole
+    process group, which leaves the parent free to run the remaining
+    drills."""
     import signal
     import subprocess
 
-    env = dict(os.environ)
-    if name in DEVICE_FREE:
-        # host-plane lanes: explicitly on the CPU, never claiming the chip
-        env["JAX_PLATFORMS"] = "cpu"
-        if (name == "sharded_serving"
-                and "xla_force_host_platform_device_count"
-                not in env.get("XLA_FLAGS", "")):
-            # the sharded lanes need a multi-device mesh; 8 virtual CPU
-            # devices (the tests/conftest.py trick) — set before jax init
-            env["XLA_FLAGS"] = (
-                env.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count=8").strip()
     # start_new_session: on timeout the whole process GROUP is killed —
     # a config's own children (spawned event/query servers) would otherwise
     # survive and hold the stdout pipe open, hanging the parent's drain
     proc = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), "--config", name],
-        env=env, stdout=subprocess.PIPE, stderr=None,
+        _child_argv(name), stdout=subprocess.PIPE, stderr=None,
         text=True, start_new_session=True,
     )
     try:
@@ -3712,10 +2325,6 @@ def main() -> int:
     config_timeout = float(os.environ.get("PIO_BENCH_CONFIG_TIMEOUT_S", "1800"))
 
     configs: dict[str, dict] = {}
-    wedged_reason = None
-    # headline = the production-representative scaled config (VERDICT r3
-    # weak #6: the MovieLens-shaped run is mostly dispatch and overstates
-    # the chip story); the small config stays in configs for r3 deltas
     for name in CONFIG_NAMES:
         if ONLY and name not in ONLY:
             continue
@@ -3727,15 +2336,9 @@ def main() -> int:
             name, min(config_timeout, remaining))
         configs[name] = result
         if wedged:
-            wedged_reason = f"config '{name}': {result['error']}"
-            _log(f"WATCHDOG: {wedged_reason}")
+            _log(f"WATCHDOG: config '{name}': {result['error']}")
 
-    # the device as the device lanes' own processes reported it
-    device_info = next(
-        ({"platform": r["platform"], "device": r.get("device")}
-         for n, r in configs.items()
-         if n not in DEVICE_FREE and "platform" in r), {})
-    print(build_result_line(configs, device_info, wedged_reason), flush=True)
+    print(json.dumps({"configs": configs}), flush=True)
     failed = [n for n, r in configs.items() if "error" in r]
     if failed:
         _log(f"FAILED lanes: {failed}")

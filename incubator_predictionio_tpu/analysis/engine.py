@@ -3,7 +3,7 @@
 The pipeline (docs/analysis.md):
 
 1. parse every package module (stdlib ``ast``; cross-file rules scan
-   their own extra roots — R4 reads tests/ and bench.py);
+   their own extra roots — R4 reads tests/ and drills.py);
 2. run each selected rule's per-module and per-project hooks;
 3. apply inline suppressions (``# pio-lint: disable=R<n> (reason)``)
    and the checked-in baseline (conf/lint_baseline.txt);
@@ -133,7 +133,7 @@ def run_lint(root: Optional[str] = None,
     for relpath, fs in by_path.items():
         table = supp_tables.get(relpath)
         if table is None:
-            # R4 scans roots outside the package (tests/, bench.py):
+            # R4 scans roots outside the package (tests/, drills.py):
             # build a table on demand so those sites can be suppressed
             path = os.path.join(root, relpath)
             if relpath.endswith(".py") and os.path.exists(path):

@@ -17,7 +17,7 @@ from incubator_predictionio_tpu.analysis.model import Finding, Module
 class Project:
     """Everything a cross-file rule may need: the repo root and the
     parsed package modules (extra roots are scanned by the rule itself —
-    e.g. R4 reads tests/ and bench.py for env reads)."""
+    e.g. R4 reads tests/ and drills.py for env reads)."""
 
     def __init__(self, root: str, modules: list):
         self.root = root

@@ -6,7 +6,7 @@ checked contract instead of a hope. The same cross-reference engine
 (:mod:`incubator_predictionio_tpu.analysis.crossref`) runs twice:
 
 - **knobs**: ``PIO_*`` env reads across the package, tests/ and
-  bench.py (tests and bench read documented ``PIO_TEST_*`` /
+  drills.py (tests and drills read documented ``PIO_TEST_*`` /
   ``PIO_BENCH_*`` knobs — they are part of the configuration surface)
   ↔ `docs/configuration.md` table rows, exceptions in
   `docs/config_allowlist.txt`;
@@ -35,9 +35,9 @@ from incubator_predictionio_tpu.analysis.rules.base import Project, Rule
 
 #: roots scanned for env reads, relative to the repo root; the package
 #: itself rides the engine's already-parsed modules (see check_project)
-KNOB_CODE_ROOTS = ("incubator_predictionio_tpu", "tests", "bench.py")
+KNOB_CODE_ROOTS = ("incubator_predictionio_tpu", "tests", "drills.py")
 #: the roots NOT covered by Project.modules
-EXTRA_CODE_ROOTS = ("tests", "bench.py")
+EXTRA_CODE_ROOTS = ("tests", "drills.py")
 #: fixture trees containing DELIBERATE violations for the linter's own
 #: tests must not count as project code
 EXCLUDE_DIRS = ("__pycache__", "lint_cases")
@@ -62,7 +62,7 @@ def knob_code_names(root: str, package_modules=None) -> list:
 
     ``package_modules`` lets the engine hand over its already-parsed
     package (Project.modules) so a lint run parses each file ONCE; the
-    extra roots (tests/, bench.py) are always scanned here.
+    extra roots (tests/, drills.py) are always scanned here.
     """
     names = []
     if package_modules is not None:

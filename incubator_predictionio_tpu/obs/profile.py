@@ -27,9 +27,10 @@ leave on in production:
   always-on rate) and aggregates self-symbolized collapsed stacks — the
   top-N lands in ``GET /profile.json`` and ``pio-tpu profile <url>``. No
   external profiler, no dump files: the aggregation IS the artifact.
-- **MFU per training step** (:func:`record_training_step`): the analytic
-  flops model bench.py uses, folded into a live ``pio_training_mfu``
-  gauge so sustained efficiency is observable outside bench runs.
+- **MFU per training step** (:func:`record_training_step`): the step's
+  analytic flops over the chip's peak, folded into a live
+  ``pio_training_mfu`` gauge so sustained efficiency is observable on a
+  running trainer.
 - **Device-memory watermark**: the high-water mark of
   :func:`device_memory_report`'s point read, sampled at exposition time
   and from the sampler thread, on ``pio_device_bytes_peak``.
@@ -65,9 +66,10 @@ DEFAULT_TOPN = 30
 #: apart without unbounded key cardinality
 STACK_DEPTH = 8
 
-#: chip peak dense-compute tables (bf16 FLOPs/s per chip) — the flops half
-#: of bench.py's ``_PEAKS``; lives here so the live MFU gauge and the bench
-#: artifact can never disagree on what "peak" means.
+#: chip peak dense compute (bf16 FLOPs/s per chip): the denominator of the
+#: live ``pio_training_mfu`` gauge that ``pio-tpu`` shows an operator, and
+#: its only reader. The benchmark's judged numbers are divided by
+#: ``benchmarks/peaks.json``, not by this.
 TPU_PEAK_FLOPS = [
     ("v6", 918e12), ("trillium", 918e12),
     ("v5p", 459e12),

@@ -33,8 +33,9 @@ def jit_adam_init(learning_rate: float, mu_dtype: str | None = None):
 # optax's ``mu_dtype`` covers the first moment only; the dense-adam HBM
 # traffic of an embedding-table trainer is 6 table passes per step
 # (p/m/v × read+write), so storing BOTH moments in bf16 cuts it to 4
-# fp32-equivalent passes (p×2 + m×1 + v×1) — a ~33% traffic cut on the
-# bandwidth-bound recommendation_scaled schedule. Math stays fp32: moments
+# fp32-equivalent passes (p×2 + m×1 + v×1) — a ~33% traffic cut on a
+# bandwidth-bound dense-adam step (the benchmark's train cell,
+# ``rec-1Mx100k-r128``, is one). Math stays fp32: moments
 # are upcast, updated, applied, and stored back rounded.
 #
 # Rounding: round-to-nearest-even, NOT stochastic. SR needs ≥1 random byte

@@ -1,9 +1,8 @@
 """Transparent event-store proxy counting storage READ calls.
 
-Shared by the batched-serving regression tests and the bench (bench.py):
-the O(1)-reads-per-batch property is asserted/attributed by counting the
-same method set in both places, so they can never drift on what counts as
-a read.
+The batched-serving regression tests assert the O(1)-reads-per-batch
+property by counting this method set: one place says what counts as a
+read.
 """
 
 from __future__ import annotations

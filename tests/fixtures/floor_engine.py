@@ -1,4 +1,4 @@
-"""Service-floor recommendation engine for the bench ``fleet`` scenario.
+"""Service-floor recommendation engine for the ``fleet`` drill (drills.py).
 
 Real fleet replicas are service-time-bound — each query pays an
 accelerator dispatch and storage hops — so adding replicas adds capacity.
@@ -12,7 +12,7 @@ This engine pins per-query service cost to a configured floor
 compute), so each replica's capacity is a known constant and the fleet
 scenario's goodput scaling measures what it claims to: the router's
 spreading, health-aware balancing, and retry behaviour.  Model-math
-throughput has its own scenarios (``serving``, ``ecommerce_retrieval``).
+throughput is the benchmark's (``BENCHMARK.json``), not a drill's.
 """
 
 from __future__ import annotations

@@ -1,12 +1,12 @@
 """Shared raw-socket HTTP/1.1 load generator — ONE implementation behind
-both the ``bench.py overload`` scenario (spawned as a subprocess via
+both the ``drills.py overload`` drill (spawned as a subprocess via
 ``bench_main``) and the chaos storm test (imported in-process).
 
 Raw keep-alive sockets, not aiohttp: the client shares the host's cores
 with the server under test, and an aiohttp client costs more per request
 than the server's whole handler — measuring through it reports the
-client, not the server (same rationale as the serving/ingestion bench
-drivers).
+client, not the server (same rationale as the ingestion drill's
+driver).
 
 Load shapes:
 
@@ -134,7 +134,7 @@ async def open_loop(host: str, port: int, n_conns: int, duration: float,
 
 def three_phase(base_url: str, warm_s: float, cap_s: float, over_s: float,
                 req_fn, overload_factor: float = 3.0) -> dict:
-    """The ``bench.py overload`` protocol: serial warm (strictly below
+    """The ``drills.py overload`` protocol: serial warm (strictly below
     capacity, where zero sheds are allowed) → 16-conn closed-loop capacity
     → open-loop at ``overload_factor``× the measured capacity."""
     host = urllib.parse.urlsplit(base_url).hostname
@@ -175,7 +175,7 @@ def three_phase(base_url: str, warm_s: float, cap_s: float, over_s: float,
 def fixed_load(base_url: str, warm_s: float, over_s: float,
                offered_qps: float, req_fn, n_conns: int = 48) -> dict:
     """Warm (single closed-loop connection) then open-loop at a FIXED
-    offered rate — the ``bench.py fleet`` comparison shape: the same
+    offered rate — the ``drills.py fleet`` comparison shape: the same
     absolute load offered to different fleet topologies, so goodput/p99
     deltas are the topology's, not the load's."""
     host = urllib.parse.urlsplit(base_url).hostname
@@ -220,7 +220,7 @@ def _rotating_user_req_fn(base: str, n_users: int):
 
 
 def bench_main(argv: list[str]) -> None:
-    """Subprocess entry for ``bench.py overload``:
+    """Subprocess entry for ``drills.py overload``:
     ``argv = [base_url, warm_s, cap_s, over_s, n_users]``. Prints one JSON
     line of the three-phase results."""
     base, warm_s, cap_s, over_s, n_users = (
@@ -232,7 +232,7 @@ def bench_main(argv: list[str]) -> None:
 
 def tenant_main(argv: list[str]) -> None:
     """Subprocess entry for per-tenant drivers (the multi-tenant chaos
-    test and ``bench.py multi_tenant``): drive ONE tenant's path at a
+    test and ``drills.py multi_tenant``): drive ONE tenant's path at a
     fixed open-loop rate from its own process, so concurrent tenant
     drivers cannot pollute each other's latency measurements through
     client-side GIL/scheduler contention.
@@ -254,7 +254,7 @@ def tenant_main(argv: list[str]) -> None:
 
 
 def fleet_main(argv: list[str]) -> None:
-    """Subprocess entry for ``bench.py fleet``:
+    """Subprocess entry for ``drills.py fleet``:
     ``argv = [base_url, warm_s, cap_s, over_s, n_users, offered_qps]``.
     ``offered_qps <= 0`` runs the full three-phase protocol (measuring
     capacity, overload at 3×); ``> 0`` skips capacity measurement and

@@ -158,6 +158,10 @@ class ReplayServer:
                 self._serve_one(conn, entries)
             finally:
                 conn.close()
+        # nothing left to send: a client that connects again (a retry after
+        # a divergence) must be refused at once, not parked in the backlog
+        # until its own handshake timeout
+        self._lsock.close()
 
     def _recv_exact(self, conn, n: int) -> bytes:
         # a divergence that SHORTENS the client's stream must fail fast,

@@ -4,8 +4,8 @@ verified restore, incremental chains, and the staleness health row.
 Everything here is tier-1: in-process, tmpdir stores, zero wall sleeps.
 The process-boundary version (SIGKILL the event server mid-ingest, rm -rf
 its data dir, restore, restart, ack parity by id set) lives in
-tests/test_chaos_procs.py; the measured RPO/RTO drill is bench.py's
-``disaster_recovery`` lane.
+tests/test_chaos_procs.py; the measured RPO/RTO drill is
+``python drills.py --config disaster_recovery``.
 """
 
 import datetime as dt

@@ -260,8 +260,8 @@ def _train_classification(tmp_path, factory=None):
     return store_cfg, variant_path
 
 
-# the raw-socket driver and load shapes are shared with bench.py's
-# overload scenario — ONE implementation (tests/fixtures/loadgen.py)
+# the raw-socket driver and load shapes are shared with drills.py's
+# overload drill — ONE implementation (tests/fixtures/loadgen.py)
 from tests.fixtures.loadgen import (  # noqa: E402
     closed_loop,
     open_loop,

@@ -2,8 +2,9 @@
 
 CPU tier-1 can only pin what the seam DECIDES: where the compile cache goes,
 that no unknown chip gets a default peak, that a metrics scrape never
-creates a backend, that N local processes are refused on a TPU host, and
-that chip_smoke.py's parent stays off jax. What the chip does with it is
+creates a backend, that N local processes are refused on a TPU host, that
+chip_smoke.py's parent stays off jax, and that drills.py's children are
+held to the CPU. What the chip does with it is
 ``python chip_smoke.py`` through the chip tool (README "Running").
 """
 
@@ -72,23 +73,49 @@ def test_unknown_device_kind_has_no_peak():
     assert prof.peak_flops_for("cpu", "cpu") is None
 
 
-def test_bench_refuses_unknown_or_missing_chip():
-    """bench.py device lanes: no chip, or a chip the peaks table does not
-    list, is an error — never v5e's numbers."""
+DRILLS = ["overload", "fleet", "multi_tenant", "sharded_fleet", "ingestion",
+          "ingest_durability", "streaming_freshness", "storage_failover",
+          "continuous_training", "disaster_recovery", "distributed_training"]
+
+
+def _drills():
     sys.path.insert(0, REPO)
     try:
-        import bench
+        import drills
     finally:
         sys.path.remove(REPO)
+    return drills
 
-    class Dev:
-        def __init__(self, platform, kind):
-            self.platform, self.device_kind = platform, kind
 
-    assert bench.chip_peaks(Dev("tpu", "TPU v5 lite")) == (197e12, 819e9)
-    for dev in (Dev("cpu", "cpu"), Dev("tpu", "TPU v99 imaginary")):
-        with pytest.raises(RuntimeError, match="no peaks known"):
-            bench.chip_peaks(dev)
+def test_drill_registry_is_the_eleven_host_plane_lanes():
+    assert _drills().CONFIG_NAMES == DRILLS
+
+
+@pytest.mark.parametrize("name", DRILLS)
+def test_drill_resolves_and_its_child_is_held_to_the_cpu(
+        name, monkeypatch, capsys):
+    """Every drill exercises the host plane: its child process never claims
+    a chip, whatever the operator's shell had set. The one pin is child
+    mode's own (``run_one_config``), which a drill started by hand and a
+    child of the runner both pass through."""
+    drills = _drills()
+    assert callable(drills._build_suite(None)[name])
+    assert drills._child_argv(name)[1:] == [
+        os.path.join(REPO, "drills.py"), "--config", name]
+    # child mode with the drill's body stubbed: what the body would see
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(drills, "_build_suite", lambda ctx: {
+        name: lambda: {"platform": os.environ["JAX_PLATFORMS"]}})
+    monkeypatch.setattr(sys, "argv", ["drills.py", "--config", name])
+    assert drills.main() == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out == ['CONFIG_RESULT={"platform": "cpu"}']
+
+
+def test_importing_drills_leaves_jax_out(tmp_path):
+    code = ("import sys, drills; "
+            "print('jax' if 'jax' in sys.modules else 'ok')")
+    assert _py(code, cwd=str(tmp_path)) == "ok"
 
 
 def test_metrics_scrape_never_creates_a_backend(tmp_path):

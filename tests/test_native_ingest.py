@@ -146,6 +146,10 @@ def run_pair(tmp_path, scenarios, monkeypatch, backend="eventlog"):
             nb2, pb2 = dict(nb), dict(pb)
             if "eventId" in nb2 and "eventId" in pb2:
                 nb2["eventId"] = pb2["eventId"] = "<id>"
+            if ns >= 500:
+                # the obs middleware mints a fresh trace id into every 5xx
+                # body: both fronts must carry one, and it is theirs alone
+                assert nb2.pop("traceId") and pb2.pop("traceId"), (i, nb, pb)
             assert nb2 == pb2, (i, nb, pb)
 
     nk = sorted(map(repr, (_event_key(e, t0) for e in native_events)))

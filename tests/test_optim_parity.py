@@ -1,6 +1,7 @@
 """Fused adam parity (VERDICT r4 next #5).
 
-Three claims, each load-bearing for the recommendation_scaled HBM lever:
+Three claims, each load-bearing for the bf16-moments HBM lever of a
+dense-adam table trainer:
 
 1. ``adam_apply`` in fp32-moments mode IS optax.adam — same update math,
    elementwise-close over many steps on random trees (the two-tower trainer

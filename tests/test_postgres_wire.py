@@ -103,7 +103,10 @@ def test_digit_only_text_values_stay_verbatim():
 @pytest.mark.skipif(_PG_SKIP is not None, reason=_PG_SKIP or "")
 def test_poisoned_connection_reconnects():
     """A mid-exchange socket failure must not leave stale frames for the
-    next query: the connection is poisoned and transparently re-established."""
+    next query: the connection is poisoned and transparently re-established.
+    An idempotent read answers through ONE reconnect inside the call; a
+    mutation keeps its single attempt (a lost response may have committed),
+    so it raises and the NEXT call reconnects."""
     from incubator_predictionio_tpu.data.storage.base import App
 
     server = FakePG()
@@ -111,12 +114,20 @@ def test_poisoned_connection_reconnects():
         c = PostgresStorageClient({"HOST": "127.0.0.1",
                                    "PORT": str(server.port)})
         app_id = c.apps().insert(App(0, "pre-crash", None))
+        assert len(server._threads) == 1
         # sever the socket under the client mid-session
         c._conn._sock.close()
+        assert [a.name for a in c.apps().get_all()] == ["pre-crash"]
+        assert len(server._threads) == 2
+        # the same fault under a mutation: one attempt, no silent re-send
+        c._conn._sock.close()
         with pytest.raises(StorageError):
-            c.apps().get_all()
+            c.apps().insert(App(0, "lost", None))
+        assert len(server._threads) == 2
         # next call reconnects and sees the (server-side) state again
         assert c.apps().get(app_id).name == "pre-crash"
+        assert c.apps().get_by_name("lost") is None
+        assert len(server._threads) == 3
         c.close()
     finally:
         server.close()

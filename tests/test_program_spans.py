@@ -348,6 +348,8 @@ def test_two_spaced_requests_book_alternating_occupancy():
 
     trace.TRACES.clear()
     before = prof.phase_snapshot().get("serve.server", {}).get("phases", {})
+    row_before = _phase_row("pio_profile_phase_seconds_total",
+                            "serve.server", "occupied")
     exported = []
     trace.set_exporter(exported.append)
     try:
@@ -364,11 +366,15 @@ def test_two_spaced_requests_book_alternating_occupancy():
     _assert_alternates_and_ends_empty(batcher, spans)
     assert len(spans) == 5
     dur = [s["durationSec"] for s in spans]
-    assert sum(dur) == pytest.approx(wall, rel=0.02)
-    # each interval is what the schedule made it: the waits empty, a
-    # dispatch's 20 ms (and the hand-over around it) occupied
+    # the intervals cover the batcher's life and nothing else. Only what no
+    # loaded host can break is held: a sleep and a dispatch's 20 ms are
+    # floors, and how long the scheduler kept anyone waiting is not the
+    # server's to promise (Track reads the process's clock, not one a test
+    # can step)
+    assert sum(dur) <= wall
+    assert sum(dur) == pytest.approx(wall, abs=0.02)
     assert dur[0] >= 0.03 and dur[2] >= 0.04 and dur[4] >= 0.01
-    assert 0.02 <= dur[1] < 0.03 and 0.02 <= dur[3] < 0.03
+    assert dur[1] >= 0.02 and dur[3] >= 0.02
     # back to back on one clock: each starts where the last one ended
     for a, b in zip(spans, spans[1:]):
         assert b["startUnix"] == pytest.approx(
@@ -382,9 +388,11 @@ def test_two_spaced_requests_book_alternating_occupancy():
             for k in ("empty", "occupied")}
     assert grew["empty"] == pytest.approx(dur[0] + dur[2] + dur[4])
     assert grew["occupied"] == pytest.approx(dur[1] + dur[3])
+    # (the registry's family outlives ``reset_phases()`` and every batcher
+    # an earlier test of this process ran: its growth is what is compared)
     assert _phase_row("pio_profile_phase_seconds_total", "serve.server",
-                      "occupied") == pytest.approx(
-        phases["occupied"]["seconds"])
+                      "occupied") - row_before == pytest.approx(
+        grew["occupied"])
 
 
 async def _coalesced(MicroBatcher):

@@ -30,6 +30,12 @@ TRANSCRIPTS = os.path.join(os.path.dirname(__file__), "transcripts")
 _PG_SKIP = pg_fake_skip_reason()
 
 
+#: connect / handshake and read timeouts (seconds) of the replayed client: a
+#: replay that stalls fails the test in seconds, not in the client's default
+#: 30 s a handshake x 3 attempts
+_PG_FAST = {"TIMEOUT": "1", "READ_TIMEOUT": "2"}
+
+
 def _load(name: str) -> dict:
     with open(os.path.join(TRANSCRIPTS, name)) as f:
         return json.load(f)
@@ -53,7 +59,7 @@ def test_postgres_wire_replay(monkeypatch):
     server = ReplayServer(tr, mode="exact")
     try:
         client = PostgresStorageClient(
-            {"HOST": "127.0.0.1", "PORT": str(server.port),
+            {"HOST": "127.0.0.1", "PORT": str(server.port), **_PG_FAST,
              **tr["meta"].get("client_config", {})})
         results = pg_scenario(client)
         client.close()
@@ -61,6 +67,43 @@ def test_postgres_wire_replay(monkeypatch):
         server.close()
     assert server.errors == [], server.errors
     assert results == tr["meta"]["expected_results"]
+
+
+@pytest.mark.skipif(_PG_SKIP is not None, reason=_PG_SKIP or "")
+def test_postgres_replay_with_nothing_left_fails_the_client_fast():
+    """A client whose stream outgrew the recording (a statement the capture
+    never saw) must get an error, never a hang: the replay server hangs up
+    on the divergence and REFUSES the retry's connection, so no attempt of
+    the client waits out a handshake timeout."""
+    import socket
+    import time
+
+    from incubator_predictionio_tpu.data.storage.base import StorageError
+    from incubator_predictionio_tpu.data.storage.postgres import (
+        PostgresStorageClient,
+    )
+
+    tr = _load("postgres_scenario.json")
+    # the recording, cut off after the handshake: no statement is answered
+    conn = tr["connections"][0]
+    ddl = next(i for i, (tag, h) in enumerate(conn)
+               if tag == "C" and b"CREATE TABLE" in bytes.fromhex(h))
+    server = ReplayServer({"connections": [conn[:ddl]]}, mode="exact")
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(StorageError):
+            PostgresStorageClient(
+                {"HOST": "127.0.0.1", "PORT": str(server.port), **_PG_FAST})
+        took = time.monotonic() - t0
+        # what makes it fast: the one recorded connection is spent and the
+        # listener is gone, so a reconnect is refused, not parked
+        with pytest.raises(ConnectionRefusedError):
+            socket.create_connection(("127.0.0.1", server.port), timeout=1.0)
+    finally:
+        server.close()
+    # 0.14 s on an idle host; the bound is a loaded host's slack, and still
+    # far under the 61 s that parked handshakes once cost the test above
+    assert took < 5.0
 
 
 def test_elasticsearch_wire_replay():
