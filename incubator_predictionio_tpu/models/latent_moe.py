@@ -37,9 +37,10 @@ module's for both kinds.
 A config with a ``layer_pattern`` (``attention_kind="gqa"``) makes each layer
 ONE thing behind one norm (``layer_kinds``): a state-space mixer (``"S"``,
 models/state_space.py), dense grouped-query attention (``"A"``,
-models/sparse_gqa.py's plain part), this module's router and experts alone
-(``"E"``, ``expert_layer``), a gated short convolution (``"C"``) or a dense
-gated feed-forward part (``"D"``, both models/short_conv.py). The experts'
+models/sparse_gqa.py's plain part; ``"W"``: the same over a window of the
+last keys), this module's router and experts alone (``"E"``,
+``expert_layer``), a gated short convolution (``"C"``) or a dense gated
+feed-forward part (``"D"``, both models/short_conv.py). The experts'
 activation is the config's: gated SiLU (three matrices) or squared ReLU (two).
 """
 
@@ -193,8 +194,9 @@ LAYER = "layer"
 
 def layer_kinds(cfg) -> tuple:
     """What each layer is: the pattern's letters (``"S"`` a state-space
-    mixer, ``"A"`` attention, ``"E"`` experts, ``"C"`` a gated short
-    convolution, ``"D"`` a dense feed-forward part), or ``LAYER`` for each."""
+    mixer, ``"A"`` attention, ``"W"`` window attention, ``"E"`` experts,
+    ``"C"`` a gated short convolution, ``"D"`` a dense feed-forward part), or
+    ``LAYER`` for each."""
     return tuple(cfg.layer_pattern) or (LAYER,) * cfg.n_layers
 
 

@@ -46,7 +46,17 @@ The plain part, for the ``"A"`` layers of a layer pattern
 (``attention_kind="gqa"``, composed in models/state_space.py): the same
 grouped heads and key/value rows with no index, no head norms and no
 positional encoding, every query attending to all it sees (``dense_*``;
-scopes ``gqa_proj``, ``gqa_attn``).
+scopes ``gqa_proj``, ``gqa_attn``). With the config's ``rope_parameters`` of
+``rope_type`` "yarn" the ``"A"`` layers turn their pairs by the scaled angles
+and multiply cos and sin by the rule's amplitude (``rotary_rule``).
+
+The window part, a pattern's ``"W"`` layers: the ``"A"`` letter's weights and
+projections, plain rotary angles at ``rope_theta``, and query i sees keys j
+with ``i - sliding_window < j <= i``. What a session keeps for such a layer
+is a **ring** of its last ``sliding_window`` key/value rows (the row of
+position p at ring row ``p % sliding_window``; ``window_step``), whatever its
+length; a long block attends in a band (``attend_dense``). Scopes
+``gqa_proj`` and ``win_attn`` (ring read and write, scores, sum).
 """
 
 from __future__ import annotations
@@ -56,6 +66,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from incubator_predictionio_tpu.models.latent_moe import (
+    BATCH_LADDER,
+    CONTEXT_BATCH,
     F32,
     NEG,
     Q_CHUNK,
@@ -64,6 +76,8 @@ from incubator_predictionio_tpu.models.latent_moe import (
     _einsum,
     _mm,
     rms_norm,
+    rope_amplitude,
+    yarn_inv_freq,
 )
 from incubator_predictionio_tpu.obs.metrics import REGISTRY
 
@@ -132,16 +146,20 @@ def published(cfg) -> dict:
 
 # -- pieces ------------------------------------------------------------------------
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, inv_freq=None, amplitude: float = 1.0):
     """Half-split rope on the last axis of ``x [B, T, ..., dim]`` at ``pos
     [B, T]``: the pair ``(i, i + dim / 2)`` turns by ``pos * theta ** (-2 i /
-    dim)``."""
+    dim)``, or by ``pos * inv_freq[i]`` where a rule gives its own
+    frequencies; cos and sin times ``amplitude``."""
     half = x.shape[-1] // 2
-    inv_freq = jnp.asarray(
-        float(theta) ** (-np.arange(half, dtype=np.float64) / half), F32)
+    if inv_freq is None:
+        inv_freq = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+    inv_freq = jnp.asarray(inv_freq, F32)
     ang = pos.astype(F32)[..., None] * inv_freq
     shape = pos.shape + (1,) * (x.ndim - 3) + (half,)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
@@ -401,38 +419,85 @@ def dense_row_layout(cfg) -> dict:
     return {"kv": _lanes(2 * cfg.n_kv_heads * cfg.head_dim)}
 
 
-def attend_dense(q, rows, q_index, key_valid, cfg, wdt):
+#: the score elements a head of one chunk of queries may make: the chunk is
+#: ``Q_CHUNK`` queries up to this context, then a half and a quarter of it (a
+#: 16k context would make 1 GB of float32 scores over the 32 heads of 512
+#: queries)
+CHUNK_SCORES = Q_CHUNK * 4096
+
+
+def attend_dense(q, rows, q_index, key_valid, cfg, wdt, window: int = 0):
     """``q [B, T, H, dh]`` (scaled) over the context's key/value ``rows [B,
-    Tc, W]``: causal softmax over every visible key, queries in chunks."""
+    Tc, W]``: causal softmax over every visible key, queries in chunks.
+    With ``window`` (a ``"W"`` layer) ``key_valid`` is each key's POSITION
+    in its session (negative: no key) and a query sees the keys at ``i -
+    window < position <= i``; a long block whose context is ``lead`` rows
+    from before it and then itself attends in a band: a chunk of queries
+    reads the ``lead`` keys before its first and its own, no others (``lead
+    >= window - 1``: what lies further back is out of every query's sight)."""
     b, t, h, dh = q.shape
+    tc = rows.shape[1]
     k, v = _split_kv(rows, cfg)
+    qn = Q_CHUNK
+    while qn * tc > CHUNK_SCORES and qn > Q_CHUNK // 4:
+        qn //= 2
+    band = tc - t + qn if window and tc > t > qn else 0
 
     def chunk(args):
-        qc, qi = args
-        s = _einsum("btngd,bsnd->bngts", _grouped(qc, cfg), k, wdt)
-        seen = _visible(qi, key_valid, 0, key_valid.shape[1])
+        qc, qi, *at = args
+        kc, vc, valid = (
+            jax.lax.dynamic_slice_in_dim(a, at[0], band, 1)
+            for a in (k, v, key_valid)) if band else (k, v, key_valid)
+        s = _einsum("btngd,bsnd->bngts", _grouped(qc, cfg), kc, wdt)
+        if window:
+            near = valid[:, None, :]
+            seen = (near >= 0) & (near <= qi[:, :, None]) \
+                & (near > qi[:, :, None] - window)
+        else:
+            seen = _visible(qi, valid, 0, valid.shape[1])
         p = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG), axis=-1)
-        return _einsum("bngts,bsnd->btngd", p, v, wdt).reshape(
+        return _einsum("bngts,bsnd->btngd", p, vc, wdt).reshape(
             b, qc.shape[1], h * dh)
 
-    if t <= Q_CHUNK:
+    if t <= qn:
         return chunk((q, q_index))
-    n = t // Q_CHUNK
+    n = t // qn
     out = jax.lax.map(chunk, (
-        jnp.moveaxis(q.reshape(b, n, Q_CHUNK, h, dh), 1, 0),
-        jnp.moveaxis(q_index.reshape(b, n, Q_CHUNK), 1, 0)))
+        jnp.moveaxis(q.reshape(b, n, qn, h, dh), 1, 0),
+        jnp.moveaxis(q_index.reshape(b, n, qn), 1, 0),
+        *((jnp.arange(n) * qn,) if band else ())))
     return jnp.moveaxis(out, 0, 1).reshape(b, t, h * dh)
 
 
-def dense_layer(lw, h, cfg, q_index, context, pos=None):
+def rotary_rule(cfg, kind: str) -> tuple:
+    """``(inv_freq or None, amplitude)`` of a pattern's attention letter: an
+    ``"A"`` layer takes the config's ``rope_parameters`` where their
+    ``rope_type`` is "yarn" (the scaled frequencies of
+    models/reference/mla_moe.py's ``yarn_inv_freq`` over the whole head, cos
+    and sin times the printed ``attention_factor``, else the rule's own);
+    a ``"W"`` layer, and an ``"A"`` layer without them, plain angles at
+    ``rope_theta``."""
+    scaled = dict(cfg.rope_parameters)
+    if kind != "A" or scaled.get("rope_type", "yarn") != "yarn" \
+            or "factor" not in scaled:
+        return None, 1.0
+    return yarn_inv_freq(scaled, cfg.head_dim), float(
+        scaled.get("attention_factor") or rope_amplitude(scaled))
+
+
+def dense_layer(lw, h, cfg, q_index, context, pos=None, kind: str = "A"):
     """``h + W_o attention(norm(h))``; ``context(rows)`` takes the block's
     new key/value rows and returns ``(rows of the context, key_valid,
     state)``. Returns ``(h, state)``. With the config's ``qk_norm`` each
     head's q and k are RMS-normed (a gain each), with ``attention_rope`` both
     are rotated at ``pos`` (default ``q_index``: a token's index in its
-    session); the rows kept are the normed, rotated keys and the values."""
+    session) by the ``kind``'s ``rotary_rule``; the rows kept are the normed,
+    rotated keys and the values. ``kind="W"``: the context's ``key_valid``
+    is its keys' positions and a query sees the last ``sliding_window`` of
+    them (``attend_dense``), under the scope ``win_attn``."""
     b, t, _ = h.shape
     wdt = lw["w_q"].dtype
+    windowed = kind == "W"
     with jax.named_scope("gqa_proj"):
         x = rms_norm(h, lw["norm1"], cfg.rms_norm_eps)
         q = _mm(x, lw["w_q"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
@@ -446,12 +511,14 @@ def dense_layer(lw, h, cfg, q_index, context, pos=None):
                 k = rms_norm(k, lw["norm_kh"], cfg.rms_norm_eps)
             if cfg.attention_rope:
                 pos = q_index if pos is None else pos
-                q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
+                rule = rotary_rule(cfg, kind)
+                q, k = (rope(a, pos, cfg.rope_theta, *rule) for a in (q, k))
             k = k.reshape(b, t, -1)
         rows = jnp.concatenate([k, _mm(x, lw["w_v"])], -1).astype(wdt)
-    with jax.named_scope("gqa_attn"):
+    with jax.named_scope("win_attn" if windowed else "gqa_attn"):
         ctx, key_valid, state = context(rows)   # cache write and gather
-        a = attend_dense(q, ctx, q_index, key_valid, cfg, wdt)
+        a = attend_dense(q, ctx, q_index, key_valid, cfg, wdt,
+                         cfg.sliding_window if windowed else 0)
     with jax.named_scope("gqa_proj"):
         return h + _mm(a, lw["w_o"]), state
 
@@ -459,17 +526,62 @@ def dense_layer(lw, h, cfg, q_index, context, pos=None):
 def dense_step(lw, cache, counters, h, pages, offsets, counts, *, cfg, form):
     """An ``"A"`` layer of "extend a batch of sessions by a block each": the
     block's key/value rows go to the sessions' pages, every query attends
-    over its session's cached rows."""
-    q_index, _, write, read, key_valid = _block_geometry(
+    over its session's cached rows, read a PAGE at a time through the page
+    table (a session's context is whole pages; read a row at a time, 16k
+    rows of 2 KB came at 95 GB/s on the v5e and a turn's time followed its
+    session's length: PERF.md PR 46)."""
+    q_index, _, write, _, key_valid = _block_geometry(
         pages, offsets, counts, h.shape[1], cfg.cache_page)
 
     def context(rows):
-        pad = cache["kv"].shape[-1] - rows.shape[-1]
+        width = cache["kv"].shape[-1]
         kv = cache["kv"].at[write].set(
-            jnp.pad(rows, [(0, 0), (0, 0), (0, pad)]))
-        return kv[read], key_valid, {"kv": kv}
+            jnp.pad(rows, [(0, 0), (0, 0), (0, width - rows.shape[-1])]))
+        ctx = kv.reshape(-1, cfg.cache_page, width)[pages]
+        return ctx.reshape(pages.shape[0], -1, width), key_valid, {"kv": kv}
 
     h, cache = dense_layer(lw, h, cfg, q_index, context)
+    return h, cache, counters
+
+
+def ring_layout(cfg) -> dict:
+    """What a ``"W"`` layer keeps for a session, ``{name: ((rows, width),
+    dtype)}``: its last ``sliding_window`` key/value rows."""
+    return {"ring": ((cfg.sliding_window, dense_row_layout(cfg)["kv"]),
+                     jnp.dtype(cfg.weight_dtype))}
+
+
+def window_step(lw, cache, counters, h, slots, offsets, counts, *, cfg, form):
+    """A ``"W"`` layer of "extend a batch of sessions by a block each". The
+    layer's ``ring`` is ``[slots, R, W]``, ``R = sliding_window`` rows a
+    session, the row of position p at ``[slot, p % R]`` (read and written
+    here as the rows ``slot * R + p % R`` of one ``[slots x R, W]`` array,
+    the way the paged rows are). A block reads
+    the ``R`` rows before its offset in position order (those before
+    position 0 are no keys: a block that starts at offset 0 sees nothing of
+    what its slot held), attends over them and itself, and leaves its last
+    ``R`` real rows in the ring. Padding writes land in slot 0, which
+    belongs to nobody."""
+    r = cfg.sliding_window
+    ring = cache["ring"].reshape(-1, cache["ring"].shape[-1])
+    step = jnp.arange(h.shape[1])[None, :]
+    q_index = offsets[:, None] + step
+    token_valid = step < counts[:, None]
+    before = offsets[:, None] - r + jnp.arange(r)[None, :]
+    base = slots[:, None] * r
+
+    def context(rows):
+        rows = jnp.pad(rows, [(0, 0), (0, 0),
+                              (0, ring.shape[-1] - rows.shape[-1])])
+        keep = token_valid & (step >= counts[:, None] - r)
+        new = ring.at[jnp.where(keep, base + q_index % r, step % r)].set(rows)
+        key_pos = jnp.concatenate([
+            jnp.where(before >= 0, before, -1),
+            jnp.where(token_valid, q_index, -1)], 1)
+        return jnp.concatenate([ring[base + before % r], rows], 1), \
+            key_pos, {"ring": new.reshape(cache["ring"].shape)}
+
+    h, cache = dense_layer(lw, h, cfg, q_index, context, kind="W")
     return h, cache, counters
 
 
@@ -494,6 +606,26 @@ def serve_shapes(cfg) -> ServeShapes:
         "device-kv-index-cache",
         tuple(b for b in (SHORT_BLOCK,) if b < piece) + (piece,), (1,), False,
         tuple(contexts) + (full,), "select", "chunk", False)
+
+
+def window_serve_shapes(cfg) -> ServeShapes:
+    """The ladder of a pattern with ``"W"`` layers: short blocks batch over
+    the smallest context (the piece's length doubled up to ``max_len``) that
+    holds the longest session's ``"A"`` rows, batches past ``CONTEXT_BATCH``
+    over the whole length; anything longer is cut into pieces of
+    ``PIECE_TILES x index_kv_tile`` tokens, each resuming from the rings and
+    the pages the pieces before it left, one session a dispatch."""
+    full = cfg.max_len
+    piece = min(PIECE_TILES * cfg.index_kv_tile, full)
+    contexts, c = [], piece
+    while c < full:
+        contexts.append(c)
+        c *= 2
+    return ServeShapes(
+        "device-window-kv-cache",
+        tuple(b for b in (SHORT_BLOCK,) if b < piece) + (piece,),
+        BATCH_LADDER, True, tuple(contexts) + (full,), "step",
+        "band", False, CONTEXT_BATCH)
 
 
 def count_dispatch(cfg, extents) -> None:

@@ -2,10 +2,10 @@
 ``TransformerConfig(attention_kind="gqa", layer_pattern=...)``. Each layer is
 ``x <- x + f(norm(x))`` with ``f`` one thing, in the pattern's order: this
 module's mixer (``"S"``), models/sparse_gqa.py's dense grouped-query
-attention (``"A"``), models/latent_moe.py's router and experts (``"E"``) or
-models/short_conv.py's gated short convolution (``"C"``) and dense
-feed-forward part (``"D"``); the norms, the head and the serving steps'
-geometry are latent_moe's.
+attention (``"A"``) or the same over a window of the last keys (``"W"``),
+models/latent_moe.py's router and experts (``"E"``) or models/short_conv.py's
+gated short convolution (``"C"``) and dense feed-forward part (``"D"``); the
+norms, the head and the serving steps' geometry are latent_moe's.
 
 The mixer (``inner = ssm_heads * ssm_head_dim``, ``G = ssm_groups``, ``N =
 ssm_state``)::
@@ -63,17 +63,19 @@ from incubator_predictionio_tpu.models.latent_moe import (
 
 #: the named scopes of each letter beside the experts'
 KIND_SCOPES = {"S": ("ssm_proj", "ssm_conv", "ssm_scan"),
-               "A": ("gqa_proj", "gqa_attn"), **short_conv.SCOPES}
+               "A": ("gqa_proj", "gqa_attn"), "W": ("gqa_proj", "win_attn"),
+               **short_conv.SCOPES}
 #: the letters whose layers keep a per-session state in a slot
-STATEFUL = ("S", "C")
+STATEFUL = ("S", "C", "W")
 HI = jax.lax.Precision.HIGHEST
 
 
 def pattern_scopes(cfg) -> tuple:
     """The named scopes of the pattern's letters, in the order the letters
     first appear."""
-    return tuple(s for kind in dict.fromkeys(cfg.layer_pattern)
-                 for s in KIND_SCOPES.get(kind, ()))
+    return tuple(dict.fromkeys(
+        s for kind in dict.fromkeys(cfg.layer_pattern)
+        for s in KIND_SCOPES.get(kind, ())))
 
 
 def published(cfg) -> dict:
@@ -107,9 +109,9 @@ def _conv_width(cfg) -> int:
 
 
 def mixer_shapes(cfg, kind: str) -> dict:
-    """The arrays of an ``"S"``, ``"A"``, ``"C"`` or ``"D"`` layer beside
-    its norm."""
-    if kind == "A":
+    """The arrays of an ``"S"``, ``"A"``, ``"W"``, ``"C"`` or ``"D"`` layer
+    beside its norm (a ``"W"`` layer's are the ``"A"`` letter's)."""
+    if kind in "AW":
         return sparse_gqa.dense_shapes(cfg)
     if kind in short_conv.STEPS:
         return short_conv.shapes(cfg, kind)
@@ -149,9 +151,13 @@ def row_layout(cfg) -> dict:
 def state_layout(cfg, kind: str = "S") -> dict:
     """What a layer of a ``STATEFUL`` kind keeps for a session, ``{name:
     (values, dtype)}``: an ``"S"`` layer the recurrent state and its
-    convolution's last inputs, a ``"C"`` layer its convolution's alone."""
+    convolution's last inputs, a ``"C"`` layer its convolution's alone, a
+    ``"W"`` layer a ring of key/value rows (``values`` the ring's ``(rows,
+    width)``)."""
     if kind == "C":
         return short_conv.state_layout(cfg)
+    if kind == "W":
+        return sparse_gqa.ring_layout(cfg)
     return {
         "state": (_inner(cfg) * cfg.ssm_state, jnp.dtype(cfg.state_dtype)),
         "conv": ((cfg.conv_kernel - 1) * _conv_width(cfg),
@@ -259,6 +265,12 @@ def mixer_layer(kind: str, lw, h, cfg, q_index, token_valid, context,
     sessions where they are not ``q_index``). Returns ``(h, None)``."""
     if kind == "A":
         return sparse_gqa.dense_layer(lw, h, cfg, q_index, context, pos)
+    if kind == "W":   # the keys' positions are their indices in the row
+        def near(rows):
+            ctx, valid, _ = context(rows)
+            return ctx, jnp.where(valid, q_index, -1), None
+
+        return sparse_gqa.dense_layer(lw, h, cfg, q_index, near, pos, "W")
     if kind in short_conv.STEPS:
         return short_conv.layer(kind, lw, h, cfg, token_valid), None
     return mixer(lw, h, cfg, token_valid)[0], None
@@ -291,7 +303,8 @@ def mixer_step(lw, cache, counters, h, slots, offsets, counts, *, cfg, form):
     return h, {"state": new_state, "conv": new_conv}, counters
 
 
-STEPS = {"S": mixer_step, "A": sparse_gqa.dense_step, **short_conv.STEPS}
+STEPS = {"S": mixer_step, "A": sparse_gqa.dense_step,
+         "W": sparse_gqa.window_step, **short_conv.STEPS}
 
 
 def serve_shapes(cfg) -> latent_moe.ServeShapes:
@@ -299,7 +312,10 @@ def serve_shapes(cfg) -> latent_moe.ServeShapes:
     batch (``step``: one tile from the sessions' cached states) over the
     smallest of a quarter, a half and the whole of ``max_len`` that holds
     the longest session's key/value rows; a long block runs whole, one
-    session a dispatch (``scan``)."""
+    session a dispatch (``scan``). A pattern with ``"W"`` layers has a
+    ladder of its own (``sparse_gqa.window_serve_shapes``)."""
+    if "W" in cfg.layer_pattern:
+        return sparse_gqa.window_serve_shapes(cfg)
     return dataclasses.replace(
         latent_moe.serve_shapes(cfg), path="device-state-kv-cache",
         short_form="step", long_form="scan")

@@ -105,14 +105,20 @@ class TransformerConfig:
     # (models/state_space.py), "A" the attention of attention_kind="gqa",
     # "E" the router and experts of models/latent_moe.py, "C" a gated short
     # convolution over conv_kernel taps and "D" a dense gated-SiLU
-    # feed-forward part of intermediate_size (models/short_conv.py). "" (every
-    # other attention_kind): every layer is an attention half, then the experts
+    # feed-forward part of intermediate_size (models/short_conv.py), "W" the
+    # "A" letter's attention over the last sliding_window keys only (plain
+    # rotary angles; models/sparse_gqa.py). "" (every other attention_kind):
+    # every layer is an attention half, then the experts
     layer_pattern: str = ""
     # the "A" layers of a pattern: RMSNorm over each head's head_dim values
     # of q and k (a gain each), and half-split rotary pairs at rope_theta
-    # over the whole head, at a token's index in its session
+    # over the whole head, at a token's index in its session (with
+    # rope_parameters of rope_type "yarn": its scaled angles and amplitude;
+    # the "W" layers keep the plain ones)
     qk_norm: bool = False
     attention_rope: bool = False
+    # the "W" layers: query i sees keys j with i - sliding_window < j <= i
+    sliding_window: int = 0
     # the "D" layers' width
     intermediate_size: int = 0
     # the "S" layers: ssm_heads x ssm_head_dim inner values a token, a state
@@ -134,6 +140,7 @@ class TransformerConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     # the published ``rope_parameters`` (yarn) as sorted (key, value) pairs
+    # ("mla", and the "A" layers of a pattern with attention_rope)
     rope_parameters: tuple = ()
     n_routed_experts: int = 0
     experts_per_token: int = 0
@@ -156,7 +163,8 @@ class TransformerConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     # keys are read in tiles of index_kv_tile rows (the published
-    # kv_chunk_size); serving cuts a long block into pieces of 4 tiles
+    # kv_chunk_size); serving cuts a long block into pieces of 4 tiles (a
+    # pattern with "W" layers cuts its long blocks the same way)
     index_kv_tile: int = 512
     # one chip's share of an expert-parallel deployment: experts
     # [expert_offset, expert_offset + experts_held) live here (0 = all); the
@@ -195,11 +203,12 @@ class TransformerConfig:
                 "a layer_pattern takes its 'A' layers from "
                 "attention_kind='gqa', and that kind is served in a pattern "
                 "only")
-        if (self.qk_norm or self.attention_rope or self.intermediate_size) \
-                and not self.layer_pattern:
+        if (self.qk_norm or self.attention_rope or self.intermediate_size
+                or self.sliding_window) and not self.layer_pattern:
             raise ValueError(
                 "qk_norm, attention_rope and intermediate_size belong to the "
-                "'A' and 'D' layers of a layer_pattern")
+                "'A' and 'D' layers of a layer_pattern, sliding_window to "
+                "its 'W' layers")
         if self.attention_kind == "mla":
             if not self.rope_parameters:
                 raise ValueError(
@@ -220,12 +229,13 @@ class TransformerConfig:
                     f"index_kv_tile={tile} must be whole pages of "
                     f"{self.cache_page} and divide max_len={self.max_len}")
         elif self.attention_kind == "gqa":
-            if (set(self.layer_pattern) - set("SAECD")
+            if (set(self.layer_pattern) - set("SAECDW")
                     or len(self.layer_pattern) != self.n_layers):
                 raise ValueError(
                     f"layer_pattern={self.layer_pattern!r} is one of 'S' "
                     "(state-space mixer), 'A' (attention), 'E' (experts), "
-                    "'C' (gated short convolution), 'D' (dense feed-forward) "
+                    "'C' (gated short convolution), 'D' (dense feed-forward), "
+                    "'W' (window attention) "
                     f"a layer, n_layers={self.n_layers} of them")
             if (not self.n_kv_heads or self.n_heads % self.n_kv_heads
                     or not self.head_dim):
@@ -236,6 +246,19 @@ class TransformerConfig:
                 raise ValueError(
                     "attention_rope turns pairs of a head's values: an even "
                     "head_dim")
+            if ("W" in self.layer_pattern) != bool(self.sliding_window) or (
+                    self.sliding_window and (
+                        self.sliding_window < 1 or not self.attention_rope
+                        or 4 * self.index_kv_tile % self.cache_page)):
+                raise ValueError(
+                    "a 'W' layer needs sliding_window >= 1 (and no other "
+                    "layer takes one), attention_rope, and pieces of 4 x "
+                    f"index_kv_tile={self.index_kv_tile} that are whole "
+                    f"pages of {self.cache_page}")
+            if self.rope_parameters and not self.attention_rope:
+                raise ValueError(
+                    "rope_parameters scale the rotary angles of the 'A' "
+                    "layers: attention_rope set")
             if "C" in self.layer_pattern and self.conv_kernel < 2:
                 raise ValueError(
                     "a 'C' layer needs conv_kernel >= 2 (the taps of its "

@@ -245,19 +245,20 @@ def _attention_cases(interpret: bool, rng) -> Iterator[dict]:
         rtol=5e-2, atol=5e-2)
 
 
-#: ``(held, d, f, [(sorted rows, experts touched)])`` of the four sequence
+#: ``(held, d, f, [(sorted rows, experts touched)])`` of the five sequence
 #: cells' routed experts as served: a lone turn's picks, then a long block's
 GROUPED_MATMUL_WIDTHS = (
     (64, 2688, 1920, [(96, 12), (768, 64), (12_288, 64)]),    # visitor cell
     (16, 2048, 1792, [(64, 4), (16_384, 16)]),                 # feed cell
     (32, 4096, 2048, [(64, 4), (12_288, 32)]),                 # Mistral cell
     (128, 2048, 768, [(128, 24), (16_384, 128)]),              # lifelong cell
+    (16, 2304, 896, [(32, 6), (16_384, 16)]),                  # histories cell
 )
 
 
 def _grouped_matmul_cases(interpret: bool, rng) -> Iterator[dict]:
     """``grouped_matmul`` as models/latent_moe.moe_experts calls it at the
-    four sequence cells' widths (the held experts, both matrices, bfloat16):
+    five sequence cells' widths (the held experts, both matrices, bfloat16):
     a lone turn's sorted picks on a few experts and a long block's over all
     of them, about half of the rows past the groups (picks on experts held
     elsewhere sort behind them)."""

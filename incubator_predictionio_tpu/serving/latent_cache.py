@@ -49,7 +49,15 @@ items.
   last inputs alone (the block's ``state_layout`` a kind sizes a slot): a
   session owns its pages AND one slot, eviction frees both, slot 0 belongs
   to nobody (padding lands there), and a block that starts at offset 0
-  starts from zeros whatever its slot held. An ``"E"`` or a ``"D"`` layer
+  starts from zeros whatever its slot held. A ``"W"`` (window attention)
+  layer keeps in the session's slot a **ring** of its last
+  ``sliding_window`` key/value rows and no page: what a session costs such a
+  layer does not grow with its length (one ``[slots, window, width]`` array
+  a layer). A pattern with ``"W"``
+  layers cuts a block longer than its piece (``PIECE_TILES x index_kv_tile``
+  tokens) into pieces, each resuming from the rings and the ``"A"`` layers'
+  pages the pieces before it left, the lock offered between them. An
+  ``"E"`` or a ``"D"`` layer
   keeps nothing. A state stands at exactly
   one position, the length last computed, so the **reuse rule** is: a
   session's state stands at n tokens; an incoming list whose first n tokens
@@ -61,7 +69,8 @@ items.
   the last answer is kept, and the last token cannot be recomputed from a
   state that already holds it.) A long bucket compiles one program a layer
   kind (``seq_ssm_b<B>_t<T>``, ``seq_moe_b<B>_t<T>``, ``seq_conv_b<B>_t<T>``,
-  ``seq_ffn_b<B>_t<T>``: no context, shared by the bucket's contexts;
+  ``seq_ffn_b<B>_t<T>``, ``seq_win_b<B>_t<T>``: no context, shared by the
+  bucket's contexts;
   ``seq_gqa_b<B>_t<T>_c<C>``), called in pattern
   order; the pieces of a cut block hand the state on through the slot.
 - One dispatch runs at a time (``_TurnLock``), and between the pieces of a
@@ -152,11 +161,21 @@ _STATE_TOKENS = REGISTRY.counter(
 _STATE_STEP_SESSIONS = REGISTRY.counter(
     "pio_seq_state_step_sessions_total",
     "Sessions in short-block dispatches of a stateful pattern")
+_WINDOW_HELD = REGISTRY.counter(
+    "pio_seq_window_rows_held_total",
+    "Key/value rows a window layer read for the sessions of short-block "
+    "dispatches: each session's ring rows before its block, and the block "
+    "(a layer; every window layer reads the same)")
+_WINDOW_UNWINDOWED = REGISTRY.counter(
+    "pio_seq_window_rows_unwindowed_total",
+    "Rows the same layer would have read for them without the window: every "
+    "token of each session, its block included")
 TOP_K = 16                       # the head's k the ladder is warmed for
 #: a pattern's layer kinds: the names of their programs, and which take no
 #: context (one program a (batch, block), shared by the bucket's contexts)
-PROGRAM = {"S": "ssm", "A": "gqa", "E": "moe", "C": "conv", "D": "ffn"}
-CONTEXT_FREE = ("S", "E", "C", "D")
+PROGRAM = {"S": "ssm", "A": "gqa", "E": "moe", "C": "conv", "D": "ffn",
+           "W": "win"}
+CONTEXT_FREE = ("S", "E", "C", "D", "W")
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*)op_name="([^"]*)"', re.M)
 #: control flow has no device time of its own: a trace shows a loop's
@@ -306,16 +325,18 @@ class LatentServing:
         paged = sum(k in (latent_moe.LAYER, "A") for k in self.kinds)
         self.bytes_per_token = paged * sum(self.layout.values()) \
             * wdt.itemsize + 4
-        # what a layer of a stateful kind ("S", "C") keeps for a session:
-        # {kind: {name: (values, dtype)}}
+        # what a layer of a stateful kind ("S", "C", "W") keeps for a
+        # session: {kind: {name: (values, dtype)}}, ``values`` a count or
+        # the shape of a slot's share (a ring's (rows, width))
         self.state_layout = {
             kind: self.block.state_layout(cfg, kind)
             for kind in (self.block.STATEFUL if cfg.layer_pattern else ())
             if kind in self.kinds}
         self.state_bytes_per_session = sum(
-            self.kinds.count(kind) * n * dt.itemsize
+            self.kinds.count(kind) * int(np.prod(n)) * dt.itemsize
             for kind, layout in self.state_layout.items()
             for n, dt in layout.values())
+        self.window = cfg.sliding_window if "W" in self.kinds else 0
         # ``cache_tokens`` is the operator's: live sessions x the length they
         # may reach (default: 16 sessions of ``max_len``); never less than
         # two whole sessions. Page 0 belongs to nobody.
@@ -330,8 +351,9 @@ class LatentServing:
 
         def kept(kind):
             if kind in self.state_layout:
-                return {name: jnp.zeros((self.n_slots + 1, n), dt)
-                        for name, (n, dt) in self.state_layout[kind].items()}
+                return {name: jnp.zeros(
+                    (self.n_slots + 1, *np.atleast_1d(n)), dt)
+                    for name, (n, dt) in self.state_layout[kind].items()}
             if kind not in (latent_moe.LAYER, "A"):
                 return {}
             return {name: jnp.zeros((rows, width), wdt)
@@ -845,6 +867,11 @@ class LatentServing:
             else:
                 _CONTEXT_HELD.inc(sum(len(b.tokens) for b in group))
                 _CONTEXT_READ.inc(batch * ctx)
+                if self.window:
+                    _WINDOW_UNWINDOWED.inc(sum(len(b.tokens) for b in group))
+                    _WINDOW_HELD.inc(sum(
+                        min(b.offset, self.window) + len(b.tokens) - b.offset
+                        for b in group))
             if self.n_slots:
                 _STATE_TOKENS.labels(form=form).inc(n_new)
                 if short:
@@ -881,6 +908,7 @@ class LatentServing:
             **({"state_slots": self.n_slots,
                 "state_bytes_per_session": self.state_bytes_per_session,
                 "layer_pattern": cfg.layer_pattern} if self.n_slots else {}),
+            **({"sliding_window": self.window} if self.window else {}),
             "sessions": len(self._sessions),
             "buckets": [f"{self.label(*b)}:{self.form(b[1])}"
                         for b in self.ladder()],
@@ -893,8 +921,9 @@ class LatentServing:
         }
 
     def session_state(self, key: str, layer: int) -> Optional[tuple]:
-        """What an ``"S"`` or ``"C"`` layer keeps for a session the table
-        holds: ``(the tokens its state stands at, {name: its slot's row})``;
+        """What an ``"S"``, ``"C"`` or ``"W"`` layer keeps for a session the
+        table holds: ``(the tokens its state stands at, {name: its slot's
+        row, a ring's rows})``;
         ``None`` for a session that is not held or whose block is still being
         cut. Waits for the dispatch under way. (For tests and for a comparison of the
         served state with a reference's: nothing on the serve path reads

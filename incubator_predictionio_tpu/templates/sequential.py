@@ -16,9 +16,10 @@ With the latent-attention block (``attentionKind: "mla"``), the
 sparse-index block (``"gqa_sparse"``) or a layer pattern (``"gqa"`` with
 ``layerPattern``: state-space, attention and expert layers in a given order)
 the deployed model keeps a device-resident cache per session
-(serving/latent_cache.py): per-token rows, and for state-space layers a
-recurrent state, which stands at one position: it is reused only by a list
-that CONTINUES the cached one. ``recent_items`` is still the whole session as the
+(serving/latent_cache.py): per-token rows, for state-space layers a
+recurrent state and for window-attention layers a ring of the last
+``slidingWindow`` key/value rows, which stand at one position: they are
+reused only by a list that CONTINUES the cached one. ``recent_items`` is still the whole session as the
 application knows it; ``user``, when given WITH it, is the **cache key**: the
 server reuses the longest prefix of the incoming list that equals what it has
 cached under that key, token for token, and computes only the rest. The
@@ -274,11 +275,15 @@ class TransformerAlgorithmParams(Params):
     # attention (num_key_value_heads, head_dim; qk_norm: per-head RMSNorm of
     # q and k; attention_rope: rotary pairs at rope_theta), "E" the routed
     # experts, "C" a gated short convolution (conv_kernel taps), "D" a dense
-    # gated feed-forward part (intermediate_size)
+    # gated feed-forward part (intermediate_size), "W" the "A" letter's
+    # attention over the last sliding_window keys (plain rotary angles; the
+    # "A" layers take rope_parameters, the published "yarn" dict or the dict
+    # that holds it under "full_attention", when given)
     attention_kind: str = "mha"
     layer_pattern: str = ""
     qk_norm: bool = False
     attention_rope: bool = False
+    sliding_window: int = 0
     intermediate_size: int = 0
     ssm_num_heads: int = 0
     ssm_head_dim: int = 0
@@ -308,6 +313,9 @@ class TransformerAlgorithmParams(Params):
     indexer_num_heads: int = 0        # ... and its indexer (sa_config)
     indexer_head_dim: int = 0
     index_topk: int = 0
+    # rows a key tile of a long block: it is cut into pieces of 4 tiles, by
+    # the sparse-index block (which also reads keys a tile at a time) and by
+    # a pattern with "W" layers, which has no index and only the pieces
     index_kv_tile: int = 512
     experts_held: int = 0         # this chip's share (0 = all), from expert_offset
     expert_offset: int = 0
@@ -345,7 +353,12 @@ class TransformerAlgorithm(PAlgorithm):
                 index_head_dim=p.indexer_head_dim, index_topk=p.index_topk,
                 index_kv_tile=p.index_kv_tile)
         elif p.attention_kind == "gqa":
+            scaled = p.rope_parameters or {}
             latent = dict(
+                sliding_window=p.sliding_window,
+                index_kv_tile=p.index_kv_tile,
+                rope_parameters=tuple(sorted(
+                    scaled.get("full_attention", scaled).items())),
                 n_kv_heads=p.num_key_value_heads, head_dim=p.head_dim,
                 layer_pattern=p.layer_pattern, ssm_heads=p.ssm_num_heads,
                 ssm_head_dim=p.ssm_head_dim, ssm_state=p.ssm_state_size,

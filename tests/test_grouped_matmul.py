@@ -65,6 +65,11 @@ def _operands(m, k, n, groups, dtype=jnp.float32, seed=0):
      (128, 256, 256)),
     (576, 512, 512, [40, 0, 0, 31, 0, 70, 0, 2, 0, 55, 0, 1, 30, 0, 59, 0],
      None),
+    # the histories cell's experts at their own widths (18 lane tiles into
+    # 7 and back), a turn's picks on a few of the 16 held: a whole matrix a
+    # piece by the rule
+    (64, 2304, 896, [0, 9, 0, 0, 1, 0, 14, 0, 2, 0, 3, 0, 0, 2, 0, 1], None),
+    (64, 896, 2304, [0, 9, 0, 0, 1, 0, 14, 0, 2, 0, 3, 0, 0, 2, 0, 1], None),
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, (list, tuple))
     else str(v))
 def test_the_kernel_is_ragged_dot(m, k, n, sizes, tiling):
@@ -98,6 +103,10 @@ def test_the_kernel_is_ragged_dot(m, k, n, sizes, tiling):
     # the sparse-index block's: the whole 3.1 MB matrix at every row count
     (16_384, 2048, 768, (128, 2048, 768)),
     (64, 2048, 768, (64, 2048, 768)),
+    # the histories cell's: the whole 4.1 MB matrix at every row count
+    (32, 2304, 896, (32, 2304, 896)),
+    (4096, 2304, 896, (128, 2304, 896)),
+    (4096, 896, 2304, (128, 896, 2304)),
 ])
 def test_tiles_divide_the_widths_they_are_given(m, k, n, want):
     tm, tk, tn = tiles(m, k, n)
@@ -156,6 +165,7 @@ def test_the_gradient_is_ragged_dots():
     ((32, 4096, 2048), True),      # the latent block's experts
     ((128, 2048, 768), True),      # the sparse-index block's
     ((16, 2048, 1792), True),      # the feed cell's (7 x 256)
+    ((16, 2304, 896), True),       # the histories cell's (18 and 7 tiles)
     ((8, 64, 256), True),          # tests/fixtures/ssm_tiny.py
     ((8, 64, 32), False),          # under one tile: the pinned toy programs
     ((4, 32, 16), False),

@@ -906,6 +906,15 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
             attention_kind="gqa_sparse", n_kv_heads=1, head_dim=16,
             index_n_heads=2, index_head_dim=16, index_topk=8,
             index_kv_tile=8, router_scoring="softmax", **shared)
+    elif kind == "W":   # the window letter beside a full layer
+        cfg = TransformerConfig(**{
+            **shared, "n_layers": 4, "attention_kind": "gqa",
+            "layer_pattern": "WEAE", "n_kv_heads": 1, "head_dim": 16,
+            "attention_rope": True, "sliding_window": 8, "index_kv_tile": 8,
+            "router_scoring": "softmax", "rope_parameters": tuple(sorted({
+                "rope_type": "yarn", "beta_fast": 32, "beta_slow": 1,
+                "factor": 4, "original_max_position_embeddings": 32,
+                "rope_theta": 10000}.items()))})
     else:   # a letter of a layer pattern
         cfg = TransformerConfig(**{
             **shared, "n_layers": 3, "attention_kind": "gqa",
@@ -916,7 +925,9 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
     serving = LatentServing(
         latent_moe.init_params(jax.random.key(0), cfg), cfg)
     try:
-        return serving._lower(*bucket)[kind if kind in "SAE" else "layer"]
+        if kind == "W" and bucket[1] == 16:   # the pattern's turn program
+            return serving._lower_turn(*bucket, 16)
+        return serving._lower(*bucket)[kind if kind in "SAEW" else "layer"]
     finally:
         serving.close()
 
@@ -941,6 +952,11 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
      ("gqa_proj", "gqa_attn")),
     (lambda: _seq_layer_lowered("E", (4, 16, 32)), "jit_seq_moe_b4_t16",
      ("moe_router", "moe_experts", "moe_shared")),
+    (lambda: _seq_layer_lowered("W", (1, 32, 64)), "jit_seq_win_b1_t32",
+     ("gqa_proj", "win_attn")),
+    (lambda: _seq_layer_lowered("W", (4, 16, 64)), "jit_seq_turn_b4_t16_c64",
+     ("gqa_proj", "win_attn", "gqa_attn", "moe_router", "moe_experts",
+      "head_topk")),
     (_train_lowered, "jit__train_epochs",
      ("gather", "loss_grad", "scatter", "adam_user", "adam_item")),
     (_topk_lowered, "jit__topk_quantized", ("score", "topk")),
@@ -957,8 +973,8 @@ def _seq_layer_lowered(kind: str, bucket: tuple):
     (lambda: _ivf_lowered("ivf_layout"), "jit_ivf_layout",
      ("gather", "quantize")),
 ], ids=["seq_layer_latent", "seq_layer_sparse_turn", "seq_layer_sparse_piece",
-        "seq_ssm_step", "seq_ssm_scan", "seq_gqa", "seq_moe",
-        "train_epochs", "topk_quantized", "score_centroids", "init",
+        "seq_ssm_step", "seq_ssm_scan", "seq_gqa", "seq_moe", "seq_win_piece",
+        "seq_win_turn", "train_epochs", "topk_quantized", "score_centroids", "init",
         "order_batches", "quantize_user_rows", "two_stage_rerank",
         "ivf_sample", "ivf_assign", "ivf_update", "ivf_layout"])
 def test_executable_names_and_scopes_are_pinned(lower, module, scopes):

@@ -519,9 +519,9 @@ def test_the_controls_fail_the_tiny_limits(sessions, control, monkeypatch):
 
 @pytest.mark.parametrize("program, digest", [
     ("turn",
-     "7acf287ac6c37d8f17d544a4cdaa84deb8f90c997de0f2a05c9837d2cb4871b1"),
+     "1042fb88302298b2d9ba657f0ee3a023b8d4f4438f6f90728b00f2c99dc84196"),
     ("S", "7c692e4bbcc4947bac8a6736e0e035a8bdcef818bbe20d26bbf55e486852e063"),
-    ("A", "92e5fc28f1196d575e3368917930f84f7bdf94e3b5fefce19e1318c0d3716c1f"),
+    ("A", "c2c9c6a83ca329440eda418c6d5c47359afe430232d3372f06d3d80eb13b64a7"),
     ("E", "76452e00dde4284c3a833773dfd6549bded34c0560bb1887730b62e5f35c5e80"),
 ])
 def test_the_state_space_pattern_lowers_to_the_parents_programs(program,
@@ -529,9 +529,13 @@ def test_the_state_space_pattern_lowers_to_the_parents_programs(program,
     """ISSUE 38 gave the pattern two more letters, the ``A`` letter two
     options and the session table a slot sized by kind, and had to leave the
     accepted pattern's programs alone: the lowered text of the state-space
-    pattern's turn program and of its three long-block layer programs is
-    commit 4a4fc15's (the latent and the sparse-index block's digests are
-    pinned in their own test files)."""
+    pattern's ``S`` and ``E`` long-block layer programs is commit 4a4fc15's
+    (the latent and the sparse-index block's digests are pinned in their own
+    test files). The ``A`` layer program and the turn program that holds it
+    are PR 46's: ``dense_step`` reads a session's context a page at a time
+    (one slice a page of the table, not one a row), a deliberate change to
+    every pattern's ``A`` layers; nothing else of them moved
+    (``test_window_block.py`` holds the read to the row-wise one's values)."""
     cfg = ssm_tiny.config()
     serving = LatentServing(ssm_tiny.seeded_params(cfg), cfg)
     serving.batches = (1, 4)

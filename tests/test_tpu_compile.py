@@ -15,7 +15,12 @@ short-convolution pattern's at the feed cell's (hidden 2048, 18 convolution
 layers of 3 taps, 6 rotary 32 / 8-head attention layers of 64, 2 dense parts
 of 7168, 16 of 32 experts top-4 of width 1792, 2,049 carry slots, 327,808
 cache rows): its whole 48-sub-block turn program in the widest turn bucket and
-each letter's step over the longest block.
+each letter's step over the longest block, and the window / full attention
+pattern's at the histories cell's (hidden 2304, 21 window layers of 1,024
+keys and 7 full layers of 32 / 4 heads of 128, 16 of 64 experts top-8 of
+width 896, 33 rings of 1,024 rows a window layer, 278,656 cache rows): its
+whole 56-sub-block turn program in the widest turn bucket and each letter's
+step over a 2,048-token piece against the longest context.
 The index build's clustering programs (``ops/retrieval.py`` ``ivf_*``)
 compile at the train cell's shapes (a 65,536-row sample and 100,000 rows of
 128 + the bias column, 316 partitions) and the two-stage serve cell's
@@ -713,6 +718,147 @@ def test_feed_long_block_letters_compile_for_v5e(one_chip, kind):
                   "A": ("gqa_proj", "gqa_attn"),
                   "E": ("moe_router", "moe_experts")}[kind]:
         assert f"/{scope}/" in text, scope
+    # 16,384 sorted picks through the kernel, three matrices
+    assert len(_expert_kernels(text)) == (3 if kind == "E" else 0)
+
+
+# -- the window / full attention pattern at the histories cell's widths -----------
+
+def _histories_cfg():
+    """The Mellum cell's stack, from the cell's own configuration file, as
+    ``benchmarks/engines/seeded_window.algorithm_params`` binds it."""
+    from benchmarks.engines import seeded_window
+    from incubator_predictionio_tpu.utils.params import params_from_json
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmarks", "configs", "seq-mellum2-12b-ep4.json")
+    with open(path) as f:
+        c = json.load(f)
+    algo = seeded_window.SeededWindowAlgorithm(params_from_json(
+        seeded_window.SeededWindowParams,
+        seeded_window.algorithm_params(c, 1)))
+    return algo.model_config(c["vocab_size"])
+
+
+def _histories_arguments(one_chip, cfg):
+    from incubator_predictionio_tpu.models import latent_moe
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (cfg.cache_tokens // cfg.cache_page + 1) * cfg.cache_page
+    ring = (cfg.state_slots + 1) * cfg.sliding_window
+    kept = {"W": {"ring": s((cfg.state_slots + 1, cfg.sliding_window, 1024),
+                             jnp.bfloat16)},
+            "A": {"kv": s((rows, 1024), jnp.bfloat16)}, "E": {}}
+    counters = {"W": (), "A": (), "E": s((18,), jnp.int32)}
+    layers = {kind: {k: s(shape, jnp.float32 if f32 else jnp.bfloat16)
+                     for k, (shape, f32) in latent_moe.layer_shapes(
+                         cfg, kind).items()} for kind in "WAE"}
+    return s, rows, ring, kept, counters, layers
+
+
+@pytest.fixture(scope="module")
+def histories_turn(one_chip):
+    """``turn_step`` at ``8x16@16384``, the widest turn bucket, with the
+    cell's 56 sub-blocks of weights, its 7 key/value caches, 21 rings and 28
+    counters, the kept ones donated."""
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        TURN_KEPT,
+        turn_step,
+    )
+
+    cfg = _histories_cfg()
+    kinds = latent_moe.layer_kinds(cfg)
+    s, rows, ring, kept, counters, layers = _histories_arguments(one_chip, cfg)
+    emb = s((cfg.vocab_size, cfg.d_model), jnp.bfloat16)
+    batch, block, ctx = 8, 16, 16384
+
+    def seq_turn_b8_t16_c16384(*args):
+        return turn_step(*args, cfg=cfg, form="step", k=16)
+
+    with _chip_kernels():
+        compiled = jax.jit(
+            seq_turn_b8_t16_c16384, donate_argnums=TURN_KEPT).lower(
+            emb, s((rows,), jnp.int32), [layers[k] for k in kinds],
+            [kept[k] for k in kinds], [counters[k] for k in kinds],
+            s((cfg.d_model,), jnp.float32), emb,
+            s((batch, block), jnp.int32),
+            s((batch, ctx // cfg.cache_page), jnp.int32),
+            s((batch,), jnp.int32), s((batch,), jnp.int32),
+            s((batch,), jnp.int32)).compile()
+    return cfg, kinds, rows, ring, compiled
+
+
+def test_histories_turn_program_holds_the_whole_depth_in_place(histories_turn):
+    from incubator_predictionio_tpu.models import latent_moe
+    from incubator_predictionio_tpu.serving.latent_cache import (
+        instruction_scopes,
+    )
+
+    cfg, kinds, rows, ring, compiled = histories_turn
+    assert "".join(kinds) == "WEWEWEAE" * 7
+    assert (kinds.count("W"), kinds.count("A"), kinds.count("E")) == (
+        21, 7, 28)
+    mem = compiled.memory_analysis()
+    caches = 7 * rows * 1024 * 2 + 21 * ring * 1024 * 2
+    assert mem.alias_size_in_bytes >= caches          # donated, not copied
+    # 7.66 GB of weights + 5.45 GB of rows and rings come in; beside them
+    # the widest turn's temporaries stay under a gigabyte
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.2e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    found = instruction_scopes(text, latent_moe.scopes(cfg))
+    assert set(found.values()) == {
+        "gqa_proj", "win_attn", "gqa_attn", "moe_router", "moe_experts",
+        "head_topk"}
+    # the routed experts' grouped matmuls are the Pallas kernel, three a
+    # layer, booked to the experts' scope, and no copy of a layer's 16
+    # experts, of a whole ring array or of a whole page array in front
+    assert len(_expert_kernels(text)) == 3 * 28
+    kernels = [name for name in found if name.startswith("grouped_matmul")]
+    assert len(kernels) == 3 * 28
+    assert all(found[name] == "moe_experts" for name in kernels)
+    assert not re.search(r"bf16\[16,(?:2304|896),\d+\][^\n]* copy\(", text)
+    assert not re.search(
+        rf"bf16\[(?:{ring}|{rows}|33,1024),1024\][^\n]* copy\(", text)
+
+
+@pytest.mark.parametrize("kind", ["W", "A", "E"])
+def test_histories_piece_letters_compile_for_v5e(one_chip, kind):
+    """Each letter's serving step over a 2,048-token piece (one session
+    against the longest context, 16,384 rows), its cache donated."""
+    from incubator_predictionio_tpu.models import latent_moe
+
+    cfg = _histories_cfg()
+    s, rows, ring, kept, counters, layers = _histories_arguments(one_chip, cfg)
+    step = latent_moe.step_of(kind, cfg)
+    own = s((1, 16384 // cfg.cache_page) if kind == "A" else (1,), jnp.int32)
+    with _chip_kernels():
+        compiled = jax.jit(
+            lambda lw, cache, counters, h, own, offsets, counts: step(
+                lw, cache, counters, h, own, offsets, counts, cfg=cfg,
+                form="band"), donate_argnums=(1, 2, 3)).lower(
+            layers[kind], kept[kind], counters[kind],
+            s((1, 2048, cfg.d_model), jnp.float32), own, s((1,), jnp.int32),
+            s((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    held = {"W": ring * 1024 * 2, "A": rows * 1024 * 2, "E": 0}[kind]
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    for scope in {"W": ("gqa_proj", "win_attn"), "A": ("gqa_proj", "gqa_attn"),
+                  "E": ("moe_router", "moe_experts")}[kind]:
+        assert f"/{scope}/" in text, scope
+    if kind == "W":
+        # the band: a chunk of 512 queries against the 1,024 keys before
+        # its first and its own, never the piece's 3,072 keys whole
+        assert re.search(r"f32\[1,4,8,512,1536\]", text)
+        assert not re.search(r"f32\[1,4,8,\d+,3072\]", text)
+    if kind == "A":
+        # 128 queries a chunk at 16,384 keys: 268 MB of float32 scores
+        assert re.search(r"f32\[1,4,8,128,16384\]", text)
     # 16,384 sorted picks through the kernel, three matrices
     assert len(_expert_kernels(text)) == (3 if kind == "E" else 0)
 
